@@ -15,7 +15,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-os.environ.setdefault("RAY_TPU_WORKER_JAX_PLATFORMS", "cpu")
 
 import ray_tpu  # noqa: E402
 from ray_tpu._private import native  # noqa: E402

@@ -31,7 +31,6 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export RAY_TPU_WORKER_JAX_PLATFORMS="${RAY_TPU_WORKER_JAX_PLATFORMS:-cpu}"
 
 # -m '' = no marker filter: the slow soak schedules run here (the
 # tier-1 command excludes them with its own -m 'not slow').

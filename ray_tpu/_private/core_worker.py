@@ -64,6 +64,25 @@ logger = logging.getLogger(__name__)
 _tracing_mod = None
 
 
+def _check_tpu_demand(resources: Optional[Dict[str, float]],
+                      actor: bool) -> None:
+    """A chip belongs to one process from TPU start-up until that
+    process exits, so ``TPU`` is leased only to an actor — the raylet
+    starts a process of its own for it — and only in whole chips. A
+    task would run on a pool worker, which is pinned to CPU jax."""
+    k = (resources or {}).get("TPU", 0)
+    if not k:
+        return
+    if not actor:
+        raise ValueError(
+            "a task cannot hold TPU: the chip goes to one dedicated "
+            "process for that process's lifetime. Put the work in an "
+            "actor created with num_tpus=k")
+    if k != int(k) or k < 0:
+        raise ValueError(
+            f"num_tpus counts whole chips bound to one process, got {k}")
+
+
 def _trace_ctx():
     """Span context for a submission, or None when tracing is off.
 
@@ -1500,6 +1519,7 @@ class CoreWorker:
         in ONE all-or-nothing lease round. See :class:`SpmdGang`."""
         if world_size < 1:
             raise ValueError("world_size must be >= 1")
+        _check_tpu_demand(resources, actor=False)
         gang = SpmdGang(self, world_size, resources or {"CPU": 1.0},
                         self._resolve_runtime_env(runtime_env))
         return self._run(gang._form())
@@ -2131,6 +2151,7 @@ class CoreWorker:
                     placement_group_bundle_index: int = -1,
                     scheduling_strategy: str = "DEFAULT",
                     runtime_env: Dict | None = None) -> List[ObjectRef]:
+        _check_tpu_demand(resources, actor=False)
         # Hot path: raw-bytes task id (lineage prefix + random suffix)
         # instead of TaskID/ActorID wrapper churn — ~4 object
         # constructions per submit otherwise.
@@ -2172,6 +2193,7 @@ class CoreWorker:
         remote function: runtime env resolved and scheduling class
         interned ONCE, per-call work reduced to id generation + arg
         prep + a slot-copy clone (see TaskSpec.clone_for)."""
+        _check_tpu_demand(resources, actor=False)
         proto = TaskSpec(
             task_id=b"", job_id=self.job_id,
             task_type=TASK_NORMAL, name=name, fn_key=fn_key, args=[],
@@ -3232,6 +3254,7 @@ class CoreWorker:
                      placement_group_bundle_index: int = -1,
                      max_pending_calls: int = -1,
                      runtime_env: Dict | None = None) -> bytes:
+        _check_tpu_demand(resources, actor=True)
         actor_id = ActorID.of(JobID(self.job_id)).binary()
         prepared_args, arg_holds = self._prepare_args(args)
         spec = TaskSpec(

@@ -253,18 +253,25 @@ class MemoryMonitor:
 
     # ------------------------------------------------------------- sampling
 
-    def _workers_rss(self, sim_rss: Optional[Dict[int, int]]
-                     ) -> Dict[str, int]:
-        out: Dict[str, int] = {}
-        for w in self.workers():
-            if not w.pid or w.state == "dead":
-                continue
-            if sim_rss and w.pid in sim_rss:
-                rss = int(sim_rss[w.pid])
-            else:
-                rss = process_rss(w.pid)
-            out[w.worker_id.hex()[:12]] = rss
-        return out
+    def due(self) -> bool:
+        """Whether an unforced :meth:`poll` would sample now."""
+        return self.enabled and \
+            time.monotonic() - self._last_poll >= self.interval_s
+
+    def live_pids(self) -> list:
+        return [w.pid for w in self.workers()
+                if w.pid and w.state != "dead"]
+
+    @staticmethod
+    def read_procfs(pids) -> Tuple[Tuple[int, int], Dict[int, int]]:
+        """Everything one poll reads from procfs: ((used, total) of the
+        node, {pid: rss}). Touches no monitor state, so the raylet runs
+        it on an executor thread: these reads are µs-scale on a quiet
+        node but block for SECONDS while a process maps or unmaps a
+        TPU's memory (measured on a v5e host: 3-5 s per TPU-client
+        start or exit), and a raylet loop blocked that long misses its
+        heartbeats."""
+        return node_memory_usage(), {pid: process_rss(pid) for pid in pids}
 
     def _pick_victim(self):
         """The most-recently-started retriable task's worker — never the
@@ -301,12 +308,14 @@ class MemoryMonitor:
         self.kills += 1
         _monitor_metrics()["kills"].inc()
 
-    def poll(self, force: bool = False) -> None:
+    def poll(self, force: bool = False, procfs=None) -> None:
         """One watchdog evaluation (interval-gated unless ``force``).
         Runs the ordered degradation sequence when over the threshold:
         store relief first, then at most ONE worker kill per poll (a
         storm kills one victim per interval, not the whole pool at
-        once — each kill frees memory the next poll re-measures)."""
+        once — each kill frees memory the next poll re-measures).
+        ``procfs`` is a :meth:`read_procfs` result taken just before
+        (off the event loop); without one the poll reads inline."""
         if not self.enabled:
             # never leave pressure LATCHED by a disable: the raylet
             # gates lease admission on this flag, and no future poll
@@ -325,16 +334,17 @@ class MemoryMonitor:
             # sequence deterministically; ``drop`` skips this poll.
             # ``pids`` carries the live worker pids so seeded chaos
             # hooks can ramp a random worker's simulated RSS.
-            pids = [w.pid for w in self.workers()
-                    if w.pid and w.state != "dead"]
             act = faultpoints.fire("memory.poll", node=self.nid12,
-                                   sim=sim, pids=pids)
+                                   sim=sim, pids=self.live_pids())
             if act == "drop":
                 return
-        used, total = node_memory_usage()
+        (used, total), rss = procfs or self.read_procfs(self.live_pids())
         if "usage_fraction" in sim and total > 0:
             used = int(float(sim["usage_fraction"]) * total)
-        self.workers_rss = self._workers_rss(sim.get("rss_by_pid"))
+        rss = {**rss, **(sim.get("rss_by_pid") or {})}
+        self.workers_rss = {
+            w.worker_id.hex()[:12]: int(rss.get(w.pid, 0))
+            for w in self.workers() if w.pid and w.state != "dead"}
         self.used, self.total = used, total
         self.usage_fraction = used / total if total else 0.0
         if total <= 0 or self.usage_fraction < self.threshold:

@@ -46,13 +46,8 @@ class Node:
         self.config = config or RayTpuConfig.create()
         self.num_cpus = num_cpus
         resources = dict(custom_resources or {})
-        if num_tpus is None:
-            try:
-                # TPU resource autodetection without importing jax (workers
-                # must stay light): the driver sets it explicitly instead.
-                num_tpus = 0
-            except Exception:
-                num_tpus = 0
+        # No chip autodetection: counting them through jax would make
+        # THIS process the chip's holder. The caller states num_tpus.
         if num_tpus:
             resources["TPU"] = float(num_tpus)
         self.custom_resources = resources

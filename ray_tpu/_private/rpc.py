@@ -1280,9 +1280,14 @@ class RpcServer:
     async def close(self):
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
         for conn in list(self.connections):
             await conn.close()
+        if self._server is not None:
+            # after the connections: since Python 3.12 wait_closed()
+            # waits for every accepted connection to be gone, so
+            # awaiting it first never returns while a peer stays
+            # connected
+            await self._server.wait_closed()
 
 
 async def connect(address: str, handlers: Dict[str, Handler] | None = None,

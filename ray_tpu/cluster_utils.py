@@ -81,7 +81,6 @@ class Cluster:
             f"cluster_{os.getpid()}_{int(time.time() * 1000)}")
         os.makedirs(self._tmpdir, exist_ok=True)
         self._env = dict(os.environ)
-        self._env.setdefault("RAY_TPU_WORKER_JAX_PLATFORMS", "cpu")
         if env:
             self._env.update(env)
         self._counter = 0

@@ -13,10 +13,12 @@ from ray_tpu.models.decode import (  # noqa: F401
     prefill,
 )
 from ray_tpu.models.transformer import (  # noqa: F401
+    DENSE_168M,
     ParallelConfig,
     TransformerConfig,
     forward,
     init_params,
+    init_train_state,
     loss_fn,
     make_train_step,
     param_specs,

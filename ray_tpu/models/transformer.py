@@ -4,8 +4,7 @@ One model definition, two execution modes sharing every line of math:
 
 * **oracle** — ``ParallelConfig()`` with all axes ``None``: plain
   single-device forward (the differential-test reference).
-* **SPMD** — inside ``shard_map`` (the version-portable accessor in
-  ray_tpu.parallel.collectives) over the 4-axis mesh
+* **SPMD** — inside ``jax.shard_map`` over the 4-axis mesh
   (``ray_tpu.parallel.mesh``): Megatron-style tensor parallelism on
   ``tp`` (column-parallel QKV/gate/up, row-parallel O/down + ``psum``;
   backward fixed up by ``tp_copy``), ring or Ulysses attention on
@@ -55,6 +54,14 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+
+# The dense decoder the repo serves and trains at full width on one
+# v5e chip: 168M parameters, head_dim 64, bf16. B16 x T1024 with
+# remat fits the chip's HBM; without remat it does not.
+DENSE_168M = TransformerConfig(
+    vocab=32768, d_model=1024, n_heads=16, n_layers=8, d_ff=4096,
+    max_seq=1024, dtype=jnp.bfloat16)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,8 +138,9 @@ def _attend(q, k, v, pcfg: ParallelConfig):
     if impl == "auto":
         impl = "ring" if pcfg.sp else "local"
     if impl == "local" or not pcfg.sp:
-        # Pallas blocked online-softmax kernel on TPU; transparent
-        # XLA-attention fallback off-TPU / at non-block-aligned T.
+        # Pallas blocked online-softmax kernel when the default backend
+        # is the TPU and T is a multiple of the 128 block; the XLA
+        # reference otherwise (ops.attention.flash_attention).
         return flash_attention(q, k, v, causal=True)
     if impl == "ring":
         return ring_attention(q, k, v, axis=pcfg.sp, causal=True)
@@ -292,6 +300,27 @@ def make_train_step(cfg: TransformerConfig, pcfg: ParallelConfig,
         out_specs=(pspecs, opt_specs, P()),
         check_vma=False)
     return jax.jit(step), optimizer
+
+
+def init_train_state(key, cfg: TransformerConfig, pcfg: ParallelConfig,
+                     mesh, optimizer):
+    """(params, opt_state) for ``make_train_step(cfg, pcfg, mesh)``,
+    each leaf created in its mesh sharding — no device ever holds the
+    whole tree."""
+    from jax.sharding import NamedSharding
+
+    def named(specs):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    pspecs = param_specs(pcfg)
+    params = jax.jit(functools.partial(init_params, cfg=cfg),
+                     out_shardings=named(pspecs))(key)
+    opt_state = jax.jit(
+        optimizer.init,
+        out_shardings=named(_opt_state_specs(optimizer, cfg, pspecs)),
+    )(params)
+    return params, opt_state
 
 
 def _opt_state_specs(optimizer, cfg: TransformerConfig, pspecs):

@@ -47,11 +47,12 @@ def attention(q, k, v, *, causal: bool = True,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+@functools.cache
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    """Whether this process's default jax backend is the TPU, read
+    once. A backend that cannot start raises here, as it would at the
+    first array: picking a kernel is no place to absorb that."""
+    return jax.default_backend() == "tpu"
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
@@ -326,9 +327,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_k: int = 128, interpret: bool = False):
     """Blockwise online-softmax attention (Pallas on TPU).
 
-    Falls back to ``attention`` off-TPU (unless ``interpret``), for
-    decode steps (Tq != Tk), and for sequences not divisible by the
-    block sizes.
+    Which implementation runs is decided by two things the caller can
+    see: the platform — the Mosaic kernel exists only for the TPU, so a
+    process whose default backend is anything else runs ``attention``
+    unless it asks for the kernel under ``interpret`` — and the shape:
+    decode steps (Tq != Tk) and sequences not divisible by the block
+    sizes run ``attention`` on every platform. A caller that must know
+    the kernel ran looks for the Mosaic custom call in its compiled
+    program (chip_smoke.py does).
     """
     B, T, H, D = q.shape
     sm_scale = sm_scale if sm_scale is not None else D ** -0.5
@@ -337,12 +343,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
         # it runs even at small T (no Mosaic tiling constraints on CPU).
         block_q = min(block_q, T)
         block_k = min(block_k, T)
-    # On real TPU, short / unaligned sequences use the XLA reference:
-    # sub-tile Pallas blocks (sublane 8 / lane 128 granularity) are
-    # where Mosaic lowering gets fragile, and at these sizes XLA's
-    # fused attention wins anyway.
-    if ((not interpret and not _on_tpu()) or T < block_q or T % block_q
-            or T % block_k or k.shape[1] != T):
+    # Shape: short / unaligned sequences use the XLA reference — Mosaic
+    # blocks come in sublane 8 / lane 128 granules.
+    unaligned = (T < block_q or T % block_q or T % block_k
+                 or k.shape[1] != T)
+    if unaligned or not (interpret or _on_tpu()):
         return attention(q, k, v, causal=causal, sm_scale=sm_scale)
     return _flash(q, k, v, causal, sm_scale, block_q, block_k,
                   interpret)

@@ -5,8 +5,7 @@ TPU-native equivalent of ``ray.util.collective``'s op surface
 allgather :409, reducescatter :457, broadcast :358, send/recv :514+),
 expressed as XLA collectives over mesh axis names so they compile onto
 ICI instead of going through NCCL communicators. Used inside
-``shard_map``/``pjit`` bodies (see :func:`shard_map` below for the
-version-portable accessor).
+``jax.shard_map``/``pjit`` bodies.
 """
 
 from __future__ import annotations
@@ -18,45 +17,8 @@ import jax.numpy as jnp
 from jax import lax
 
 
-# ------------------------------------------------------------- shard_map
-#
-# jax moved shard_map across versions: old releases ship it only as
-# ``jax.experimental.shard_map.shard_map`` with a ``check_rep=`` kwarg;
-# newer ones promote it to ``jax.shard_map`` and rename the kwarg to
-# ``check_vma=``. Everything in this repo (parallel schedules, the SPMD
-# train step, the differential tests) routes through this accessor so
-# the pinned jax can move in either direction without touching call
-# sites.
-
-@functools.lru_cache(maxsize=1)
-def _shard_map_impl():
-    """(callable, accepted_kwarg_names) for the hosting jax."""
-    import inspect
-
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-    try:
-        params = frozenset(inspect.signature(impl).parameters)
-    except (TypeError, ValueError):
-        params = frozenset()
-    return impl, params
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Version-portable ``jax.shard_map``.
-
-    Accepts either spelling of the replication-check kwarg
-    (``check_vma=`` / ``check_rep=``) and translates to whatever the
-    hosting jax understands; every other kwarg passes through.
-    """
-    impl, params = _shard_map_impl()
-    for ours, theirs in (("check_vma", "check_rep"),
-                         ("check_rep", "check_vma")):
-        if ours in kwargs and ours not in params and theirs in params:
-            kwargs[theirs] = kwargs.pop(ours)
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kwargs)
+# Re-exported so every SPMD body in the repo names one symbol.
+shard_map = jax.shard_map
 
 
 def psum(x, axis: str):
@@ -98,17 +60,9 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str) -> int:
-    """Static (Python int) size of a mesh axis, version-portably:
-    ``lax.axis_size`` where it exists; on older jax the axis frame —
-    which some releases hand back as the bare int itself. Every
-    schedule needing the size for Python-level control flow (pipeline
-    step counts, ring permutations) goes through here."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    from jax import core
-    frame = core.axis_frame(axis)
-    return getattr(frame, "size", frame)
+    """Static (Python int) size of a mesh axis, for Python-level
+    control flow (pipeline step counts, ring permutations)."""
+    return lax.axis_size(axis)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))

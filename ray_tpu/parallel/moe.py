@@ -7,8 +7,7 @@ and exchanged with a single tiled ``lax.all_to_all`` each way, which XLA
 lowers to ICI all-to-all. Static shapes throughout (dropped tokens pass
 through on the residual path, standard Switch-Transformer behavior).
 
-Call inside ``shard_map`` (ray_tpu.parallel.collectives' version-
-portable accessor); x: [T_local, D]; experts sharded so each
+Call inside ``jax.shard_map``; x: [T_local, D]; experts sharded so each
 rank owns E_local = E / axis_size experts.
 """
 

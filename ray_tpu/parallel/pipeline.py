@@ -8,8 +8,7 @@ hop between neighbor ranks with ``lax.ppermute`` inside a ``lax.scan``
 ``(n_stages - 1) / n_microbatches``. Differentiable: jax.grad through
 the scan yields the reverse (backward) schedule automatically.
 
-Call inside ``shard_map`` (the version-portable accessor in
-ray_tpu.parallel.collectives) over the ``pp`` axis.
+Call inside ``jax.shard_map`` over the ``pp`` axis.
 """
 
 from __future__ import annotations

@@ -7,8 +7,7 @@ K/V shards rotate around the ``sp`` axis ring via ``lax.ppermute``
 online-softmax accumulator over its local Q shard, so attention over a
 sequence of length ``n_sp * T_local`` never materializes on one chip.
 
-Call inside ``shard_map`` (ray_tpu.parallel.collectives' version-
-portable accessor) with q/k/v sharded on dim 1 (seq) over
+Call inside ``jax.shard_map`` with q/k/v sharded on dim 1 (seq) over
 ``axis``. Shapes: [batch, seq_local, heads, head_dim].
 """
 

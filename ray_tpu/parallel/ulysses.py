@@ -7,8 +7,7 @@ ordinary (full-sequence) attention on the local heads, then converts
 back. Two all-to-alls per attention; wins when heads ≥ sp and the
 sequence fits per-device once head-sharded.
 
-Call inside ``shard_map`` (ray_tpu.parallel.collectives' version-
-portable accessor); q/k/v: [B, T_local, H, D], H % sp == 0.
+Call inside ``jax.shard_map``; q/k/v: [B, T_local, H, D], H % sp == 0.
 """
 
 from __future__ import annotations

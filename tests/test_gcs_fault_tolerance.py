@@ -31,7 +31,6 @@ def _spawn_gcs(port: int, journal: str, tmpdir: str, tag: str,
     addr_file = os.path.join(tmpdir, f"gcs_{tag}.addr")
     env = dict(os.environ)
     env["RAY_TPU_GCS_JOURNAL_PATH"] = journal
-    env.setdefault("RAY_TPU_WORKER_JAX_PLATFORMS", "cpu")
     if faultpoints_spec is not None:
         # deterministic fault schedule armed at GCS boot
         # (faultpoints.arm_from_env in node.main)
@@ -53,7 +52,6 @@ def _spawn_gcs(port: int, journal: str, tmpdir: str, tag: str,
 def _spawn_raylet(gcs_address: str, tmpdir: str) -> NodeHandle:
     addr_file = os.path.join(tmpdir, "raylet.addr")
     env = dict(os.environ)
-    env.setdefault("RAY_TPU_WORKER_JAX_PLATFORMS", "cpu")
     proc = subprocess.Popen(
         [sys.executable, "-m", "ray_tpu._private.node",
          "--gcs-address", gcs_address, "--num-cpus", "2",

@@ -175,6 +175,8 @@ def test_attachment_deferred_release():
     arr = np.arange(4096, dtype=np.float64)
     name, size = shm_store.write_segment(ctx.serialize(arr))
     try:
+        gc.collect()  # an earlier test's deferred mapping must not
+        # be released by THIS test's collect and skew the count
         base = shm_store.deferred_count()
         att = shm_store.AttachedObject(name)
         # Zero-copy view into the mapping, as ray_tpu.get() produces.

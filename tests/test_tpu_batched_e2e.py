@@ -72,7 +72,6 @@ def test_tpu_batched_stress_10k_pending():
     try:
         node = ray_tpu.worker.global_worker.node
         backend = node.raylet.backend
-        assert backend.wait_ready(60), "kernel backend failed to init"
 
         # 32 distinct functions = 32 scheduling classes (class interning
         # includes fn_key), so the kernel sees a WIDE demand matrix,
