@@ -156,6 +156,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -280,6 +281,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
                                 lambda b, h, j, i: (b, h, j, 0)),) * 2,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32)] * 2,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
     qspec2 = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
@@ -295,6 +297,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
         out_specs=qspec2,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))

@@ -35,18 +35,41 @@ test_decode_scheduler.py uses a fake engine); :class:`JaxSlotEngine`
 adapts the real per-slot cache. Engine calls run in the default
 executor — a jitted decode step must not block the replica's asyncio
 loop, which keeps accepting/queueing requests mid-step.
+
+Where the time of a step goes is recorded from inside, always, by
+``util.phases.phase`` (a host clock into the scheduler's own table, and
+a span on the device trace's clock while a profiler session is open).
+``stats()["phases"]`` is that table, cumulative ``[count, seconds]`` a
+name, to be read as deltas:
+
+* loop thread: ``serve.admit`` (one ``_admit`` that took a request off
+  the queue) holds a ``serve.prefill`` per request (executor submit to
+  resumed); ``serve.step`` is the decode step from executor submit to
+  resumed; ``serve.emit`` the bookkeeping after it (tokens appended,
+  futures resolved). Two are sums without a span:
+  ``serve.admit_stall``, the part of ``serve.admit`` during which
+  active slots stood still for a prefill, and ``serve.hop``, what
+  ``serve.step`` and ``serve.prefill`` took beyond the engine call as
+  timed on the executor thread (hand-off, GIL, loop lag);
+* executor thread, inside :class:`JaxSlotEngine`'s ``step``:
+  ``serve.engine.check`` / ``.put`` / ``.dispatch`` / ``.wait`` /
+  ``.read`` (``wait`` is the first slot's read, the one that waits for
+  the device; ``read`` the other slots'). The engine's ``prefill`` has
+  no spans of its own: ``serve.prefill`` less its hop is the call.
 """
 
 from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 from ray_tpu._private import rpc
 from ray_tpu.exceptions import ServeOverloadedError
+from ray_tpu.util.phases import phase, phase_add, phase_totals, recording
 
 logger = logging.getLogger(__name__)
 
@@ -57,6 +80,7 @@ class _Request:
     max_tokens: int
     eos_token: Optional[int]
     future: asyncio.Future
+    t_submit: float         # time.perf_counter() at submit
     tokens: List[int] = field(default_factory=list)
     joined_mid_batch: bool = False
 
@@ -91,6 +115,16 @@ class DecodeScheduler:
         self.admitted = 0
         self.admitted_mid_batch = 0
         self.tokens_generated = 0
+        # per-request sums since submit, in seconds: until taken for a
+        # slot and until the first token (over ``admitted``; a prefill
+        # that raised adds no first token), until resolved (over
+        # ``completed``)
+        self.queue_wait_s = 0.0
+        self.first_token_s = 0.0
+        self.request_s = 0.0
+        # name -> [count, seconds] of every phase of this scheduler's
+        # loop and of the engine calls it makes (module docstring)
+        self._phases: Dict[str, list] = {}
 
     # ------------------------------------------------------------ public
 
@@ -112,7 +146,8 @@ class DecodeScheduler:
                 f"{self._max_queue_depth})",
                 retry_after_s=self._retry_after_s)
         req = _Request(prompt, int(max_tokens), eos_token,
-                       asyncio.get_running_loop().create_future())
+                       asyncio.get_running_loop().create_future(),
+                       time.perf_counter())
         self._queue.append(req)
         self._wakeup.set()
         if self._loop_task is None or self._loop_task.done():
@@ -137,6 +172,10 @@ class DecodeScheduler:
             "admitted": self.admitted,
             "admitted_mid_batch": self.admitted_mid_batch,
             "tokens_generated": self.tokens_generated,
+            "queue_wait_s": self.queue_wait_s,
+            "first_token_s": self.first_token_s,
+            "request_s": self.request_s,
+            "phases": phase_totals(table=self._phases),
         }
 
     async def aclose(self) -> None:
@@ -160,20 +199,46 @@ class DecodeScheduler:
 
     # ------------------------------------------------------------- loop
 
+    async def _engine_call(self, name: str, fn, *args):
+        """One engine call under the span ``name``: awaited where the
+        engine is a coroutine, else run on the executor (which records
+        into this scheduler's table for as long), and then what the
+        span took beyond the call itself goes to ``serve.hop``."""
+        span = phase(name)
+        if asyncio.iscoroutinefunction(fn):
+            with span:
+                return await fn(*args)
+        ran = None
+
+        def timed():
+            nonlocal ran
+            t0 = time.perf_counter()
+            try:
+                with recording(self._phases):
+                    return fn(*args)
+            finally:
+                ran = time.perf_counter() - t0
+
+        try:
+            with span:
+                return await asyncio.get_running_loop().run_in_executor(
+                    None, timed)
+        finally:
+            if ran is not None:     # None: cancelled before the call ended
+                phase_add("serve.hop", span.seconds - ran)
+
     async def _prefill(self, slot: int, req: _Request) -> None:
-        loop = asyncio.get_running_loop()
-        if asyncio.iscoroutinefunction(self._engine.prefill):
-            first = await self._engine.prefill(slot, req.prompt)
-        else:
-            first = await loop.run_in_executor(
-                None, self._engine.prefill, slot, req.prompt)
+        first = await self._engine_call(
+            "serve.prefill", self._engine.prefill, slot, req.prompt)
         req.tokens.append(int(first))
+        self.first_token_s += time.perf_counter() - req.t_submit
         self.tokens_generated += 1
 
     def _finish(self, slot: int, req: _Request) -> None:
-        del self._active[slot]
+        self._active.pop(slot, None)
         self._free.append(slot)
         self.completed += 1
+        self.request_s += time.perf_counter() - req.t_submit
         if not req.future.done():
             req.future.set_result(req.tokens)
 
@@ -184,34 +249,44 @@ class DecodeScheduler:
 
     async def _admit(self) -> None:
         """Fill open slots from the queue head (step boundary only)."""
-        while self._free and self._queue:
-            req = self._queue.popleft()
-            if req.future.done():   # caller gave up while queued
-                continue
-            slot = self._free.pop()
-            req.joined_mid_batch = bool(self._active)
-            self.admitted += 1
-            if req.joined_mid_batch:
-                self.admitted_mid_batch += 1
-            try:
-                await self._prefill(slot, req)
-            except Exception as e:  # noqa: BLE001 — one bad prompt
-                # must not kill the batch: fail ITS future, free the
-                # slot, keep decoding everyone else
-                self._free.append(slot)
-                if not req.future.done():
-                    req.future.set_exception(e)
-                continue
-            if self._done(req):
-                self._free.append(slot)
-                self.completed += 1
-                if not req.future.done():
-                    req.future.set_result(req.tokens)
-            else:
-                self._active[slot] = req
+        if not (self._free and self._queue):
+            return
+        stalled = 0.0       # of this call, with active slots waiting
+        with phase("serve.admit"):
+            while self._free and self._queue:
+                req = self._queue.popleft()
+                if req.future.done():   # caller gave up while queued
+                    continue
+                slot = self._free.pop()
+                req.joined_mid_batch = bool(self._active)
+                self.admitted += 1
+                t_taken = time.perf_counter()
+                self.queue_wait_s += t_taken - req.t_submit
+                if req.joined_mid_batch:
+                    self.admitted_mid_batch += 1
+                try:
+                    await self._prefill(slot, req)
+                except Exception as e:  # noqa: BLE001 — one bad prompt
+                    # must not kill the batch: fail ITS future, free the
+                    # slot, keep decoding everyone else
+                    self._free.append(slot)
+                    if not req.future.done():
+                        req.future.set_exception(e)
+                    continue
+                finally:
+                    if req.joined_mid_batch:
+                        stalled += time.perf_counter() - t_taken
+                if self._done(req):
+                    self._finish(slot, req)
+                else:
+                    self._active[slot] = req
+        phase_add("serve.admit_stall", stalled)
 
     async def _run(self) -> None:
-        loop = asyncio.get_running_loop()
+        with recording(self._phases):   # this task's phases
+            await self._loop()
+
+    async def _loop(self) -> None:
         while not self._closed:
             await self._admit()
             if not self._active:
@@ -222,11 +297,8 @@ class DecodeScheduler:
             tokens = {slot: req.tokens[-1]
                       for slot, req in self._active.items()}
             try:
-                if asyncio.iscoroutinefunction(self._engine.step):
-                    out = await self._engine.step(tokens)
-                else:
-                    out = await loop.run_in_executor(
-                        None, self._engine.step, tokens)
+                out = await self._engine_call(
+                    "serve.step", self._engine.step, tokens)
             except Exception as e:  # noqa: BLE001 — a failed device
                 # step fails the IN-FLIGHT requests typed; the loop and
                 # the queue survive (shed at the door, never collapse)
@@ -237,16 +309,17 @@ class DecodeScheduler:
                     if not req.future.done():
                         req.future.set_exception(e)
                 continue
-            self.steps += 1
-            self.slot_steps += len(tokens)
-            for slot, tok in out.items():
-                req = self._active.get(slot)
-                if req is None:
-                    continue
-                req.tokens.append(int(tok))
-                self.tokens_generated += 1
-                if self._done(req):
-                    self._finish(slot, req)
+            with phase("serve.emit"):
+                self.steps += 1
+                self.slot_steps += len(tokens)
+                for slot, tok in out.items():
+                    req = self._active.get(slot)
+                    if req is None:
+                        continue
+                    req.tokens.append(int(tok))
+                    self.tokens_generated += 1
+                    if self._done(req):
+                        self._finish(slot, req)
 
 
 class JaxSlotEngine:
@@ -281,17 +354,31 @@ class JaxSlotEngine:
 
     def step(self, tokens: Dict[int, int]) -> Dict[int, int]:
         jnp = self._jnp
-        tok = [0] * self.slots
-        act = [False] * self.slots
-        for slot, t in tokens.items():
-            # a slot at capacity would silently clamp its cache write;
-            # refuse loudly (the scheduler's max_tokens bound plus the
-            # engine's prompt-length check make this unreachable)
-            if int(self._cache["pos"][slot]) >= self.max_len:
-                raise ValueError(f"slot {slot} KV cache full")
-            tok[slot], act[slot] = int(t), True
-        logits, self._cache = self._decode.slot_decode_step(
-            self._params, self._cache, jnp.asarray(tok, jnp.int32),
-            jnp.asarray(act), self._cfg)
-        nxt = jnp.argmax(logits, axis=-1)
-        return {slot: int(nxt[slot]) for slot in tokens}
+        with phase("serve.engine.check"):
+            tok = [0] * self.slots
+            act = [False] * self.slots
+            for slot, t in tokens.items():
+                # a slot at capacity would silently clamp its cache
+                # write; refuse loudly (the scheduler's max_tokens bound
+                # plus the engine's prompt-length check make this
+                # unreachable)
+                if int(self._cache["pos"][slot]) >= self.max_len:
+                    raise ValueError(f"slot {slot} KV cache full")
+                tok[slot], act[slot] = int(t), True
+        with phase("serve.engine.put"):
+            tok = jnp.asarray(tok, jnp.int32)
+            act = jnp.asarray(act)
+        with phase("serve.engine.dispatch"):
+            logits, self._cache = self._decode.slot_decode_step(
+                self._params, self._cache, tok, act, self._cfg)
+            nxt = jnp.argmax(logits, axis=-1)
+        slots = list(tokens)
+        with phase("serve.engine.wait"):
+            # the first read is the one that waits for the device: the
+            # device's share of the step as the host sees it. (Its own
+            # dispatch overlaps the step; a block_until_ready before it
+            # cost 0.9 ms a step on a v5e: PERF.md, PR 25.)
+            out = {slot: int(nxt[slot]) for slot in slots[:1]}
+        with phase("serve.engine.read"):
+            out.update({slot: int(nxt[slot]) for slot in slots[1:]})
+        return out

@@ -8,13 +8,17 @@ inside task metadata; ``_function_span_consumer_name`` names the
 server-side span). This implementation is dependency-free: spans are
 plain records, the context rides :attr:`TaskSpec.trace_ctx`, and
 finished spans are exported to the cluster KV, where
-:func:`get_trace` reassembles the tree from any driver. If the real
-``opentelemetry`` package is installed, spans are additionally
-mirrored to its current tracer (best-effort bridge).
+:func:`get_trace` reassembles the tree from any driver.
 
 Tracing is OFF by default (zero overhead on the submit hot path
 beyond one falsy check); enable with ``RAY_TPU_TRACE=1`` or
 :func:`enable`.
+
+A loop that turns every few milliseconds (the decode step) is too hot
+for a span with a uuid, a wall clock and a KV export: :func:`phase` is
+the span for it, always on, summed in the process and read as deltas
+through :func:`phase_totals`. It lives in ``ray_tpu.util.phases``, which
+imports nothing of ``ray_tpu``, and is offered from here too.
 
 Usage::
 
@@ -38,6 +42,9 @@ import time
 import uuid
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
+
+from ray_tpu.util.phases import (  # noqa: F401 — offered beside the spans
+    phase, phase_add, phase_totals, recording)
 
 _KV_PREFIX = b"__traces__/"
 
@@ -156,7 +163,7 @@ def task_execution_span(spec_name: str, task_id_hex: str,
 
 def _export(span: Span) -> None:
     """Finished spans go to the cluster KV (fire-and-forget off the
-    caller's thread); also mirrored to opentelemetry if present."""
+    caller's thread)."""
     try:
         import ray_tpu.worker as worker_mod
 
@@ -166,12 +173,6 @@ def _export(span: Span) -> None:
                    span.span_id.encode())
             w.core.kv_put_nowait(key, span.to_json())
     except Exception:  # noqa: BLE001 — tracing must never break tasks
-        pass
-    try:  # pragma: no cover - otel not in this environment
-        from opentelemetry import trace as otel_trace  # noqa: F401
-        # presence-only bridge: real otel exporters pick spans up via
-        # their own instrumentation; we avoid double-accounting.
-    except ImportError:
         pass
 
 

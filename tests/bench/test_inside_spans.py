@@ -1,0 +1,270 @@
+"""The program's own spans and counters (``ray_tpu.util.phases.phase``
+in ``DecodeScheduler`` and ``JaxSlotEngine``, the flash kernels' names)
+as the benchmark reads them: in a real profiler trace beside
+``bench.window``, through each new reader on hand-built observations,
+and in the traced line of the tiny closed-loop cell.
+"""
+
+import asyncio
+import json
+import os
+
+import pytest
+from test_bench_run import (TracedOnCpuLM, check_line,  # noqa: F401
+                            compile_cache, cpu_tpu_workers, tiny_bench)
+
+from benchmarks import inside, loader, peaks, run, trace
+
+SERVING = ("decode_device_wait_ms.closed", "decode_host_ms.closed",
+           "decode_slot_reads_ms.closed", "scheduler_overhead_ms.closed",
+           "prefill_stall_pct.closed")
+ROOFLINES = ("flash_fwd_roofline_pct.train", "flash_bwd_roofline_pct.train")
+LOOP_SPANS = ("serve.admit", "serve.prefill", "serve.step", "serve.emit")
+STEP_SPANS = tuple("serve.engine." + n for n in (
+    "check", "put", "dispatch", "wait", "read"))
+
+
+@pytest.fixture(scope="module")
+def bench():
+    return loader.load_benchmark()
+
+
+def reader(bench, name):
+    return loader.load_reader(bench, name)
+
+
+# ------------------------------------------------- in a profiler's trace
+
+@pytest.fixture(scope="module")
+def traced_planes(tmp_path_factory):
+    """A few admissions and steps of the real scheduler and engine on
+    CPU jax, inside ``bench.window``, under a profiler session opened as
+    the benchmark's worker opens it."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.decode_scheduler import DecodeScheduler, JaxSlotEngine
+
+    cfg = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
+                            d_ff=128, max_seq=64, dtype=jnp.float32)
+    engine = JaxSlotEngine(init_params(jax.random.key(0), cfg), cfg,
+                           slots=2, max_len=32)
+
+    async def drive(n):
+        sched = DecodeScheduler(engine)
+        await asyncio.gather(*[sched.submit(p, max_tokens=n) for p in (
+            [5, 11, 23], [40, 2, 9], [88, 17, 3])])
+        await sched.aclose()
+
+    asyncio.run(drive(2))                   # compiles
+    log_dir = str(tmp_path_factory.mktemp("inside_trace"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation(trace.WINDOW):
+            asyncio.run(drive(4))
+    finally:
+        jax.profiler.stop_trace()
+    return trace.read_xplane(log_dir)
+
+
+def program_spans(planes):
+    """(the loop thread's ``serve.*`` spans, the other threads', the
+    window), each sorted by start."""
+    loop, others, window = [], [], None
+    for lines in planes.values():
+        for events in lines.values():
+            mine = [e for e in events if e[0].startswith("serve.")]
+            marks = [e for e in events if e[0] == trace.WINDOW]
+            if marks:           # asyncio.run: the loop is on this thread
+                window, loop = marks[0], loop + mine
+            else:
+                others += mine
+    by_start = lambda evs: sorted(evs, key=lambda e: e[1])  # noqa: E731
+    return by_start(loop), by_start(others), window
+
+
+def covers(outer, inner):
+    return outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
+
+
+def test_the_programs_spans_lie_nested_in_order_inside_the_window(
+        traced_planes):
+    loop, engine, window = program_spans(traced_planes)
+    assert window is not None
+    assert {e[0] for e in loop} == set(LOOP_SPANS)
+    assert {e[0] for e in engine} == set(STEP_SPANS)
+    assert all(covers(window, e) for e in loop + engine)
+    # the loop thread: admissions hold their prefills, every step is
+    # followed by its emit, and nothing else overlaps
+    top = [e for e in loop if e[0] != "serve.prefill"]
+    for a, b in zip(top, top[1:]):
+        assert a[1] + a[2] <= b[1], (a, b)
+    admits = [e for e in top if e[0] == "serve.admit"]
+    prefills = [e for e in loop if e[0] == "serve.prefill"]
+    assert len(prefills) == 3 and len(admits) == 2
+    assert all(any(covers(a, p) for a in admits) for p in prefills)
+    kinds = [e[0] for e in top if e[0] != "serve.admit"]
+    assert kinds == ["serve.step", "serve.emit"] * (len(kinds) // 2)
+    # the executor's side: each step's phases in order, back to back,
+    # inside the loop-side span that waited for them
+    steps = [e for e in loop if e[0] == "serve.step"]
+    assert [e[0] for e in engine] == list(STEP_SPANS) * len(steps)
+    for a, b in zip(engine, engine[1:]):
+        assert a[1] + a[2] <= b[1], (a, b)
+    for i, step in enumerate(steps):
+        call = engine[i * len(STEP_SPANS):(i + 1) * len(STEP_SPANS)]
+        assert all(covers(step, e) for e in call), (step, call)
+
+
+def test_no_program_span_takes_a_name_of_the_benchmarks(traced_planes):
+    names = {e[0] for lines in traced_planes.values()
+             for events in lines.values() for e in events}
+    assert not names & set(trace.HOST_SPANS)
+    assert trace.WINDOW in names
+
+
+# ------------------------------------------------ the readers, by hand
+
+def phases(scale):
+    """A program's table after ``scale`` times: 10 steps of 2 + 3 + 5 +
+    20 + 10 ms, 2 admissions of 40 ms of which 30 stalled."""
+    ms = {"serve.engine.check": 2, "serve.engine.put": 3,
+          "serve.engine.dispatch": 5, "serve.engine.wait": 20,
+          "serve.engine.read": 10, "serve.step": 41, "serve.hop": 0.6,
+          "serve.emit": 0.4}
+    table = {name: [10 * scale, 10 * scale * v / 1e3]
+             for name, v in ms.items()}
+    table["serve.admit"] = [2 * scale, 2 * scale * 0.040]
+    table["serve.admit_stall"] = [2 * scale, 2 * scale * 0.030]
+    return table
+
+
+def serving_obs(before=1, after=3, steps=(10, 30)):
+    return {"decode_before": {"steps": steps[0], "phases": phases(before)},
+            "decode_after": {"steps": steps[1], "phases": phases(after)}}
+
+
+@pytest.mark.parametrize("name,want", [
+    ("decode_device_wait_ms.closed", 20.0),
+    ("decode_host_ms.closed", 2.0 + 3.0 + 5.0 + 10.0),
+    ("decode_slot_reads_ms.closed", 2.0 + 10.0),
+    ("scheduler_overhead_ms.closed", 0.6 + 0.4),
+    # 2 x 2 x 30 ms stalled of 2 x (2 x 40 + 10 x 41 + 10 x 0.4) ms
+    ("prefill_stall_pct.closed", 100.0 * 120.0 / 988.0)])
+def test_a_serving_reader_takes_the_windows_delta(bench, name, want):
+    read = reader(bench, name)
+    assert read(serving_obs()) == pytest.approx(want)
+    # a program that records no phases (the parent commit), or not this
+    # one: nothing to read
+    absent = serving_obs()
+    del absent["decode_before"]["phases"], absent["decode_after"]["phases"]
+    assert read(absent) is None
+    renamed = serving_obs()
+    for name_ in list(renamed["decode_after"]["phases"]):
+        if name_ in ("serve.engine.wait", "serve.engine.read", "serve.hop",
+                     "serve.admit_stall"):
+            del renamed["decode_after"]["phases"][name_]
+    assert read(renamed) is None
+    # no step in the window
+    assert read(serving_obs(steps=(10, 10))) is None
+    # a table that began inside the window counts from nothing
+    fresh = serving_obs()
+    fresh["decode_before"]["phases"] = {}
+    assert read(fresh) is not None
+
+
+def training_obs(op_totals):
+    return {"run": {"config": {"num_attention_heads": 2, "head_dim": 4,
+                               "torch_dtype": "bfloat16"},
+                    "traffic": {"batch": 3, "seq": 8}},
+            "device": {"kind": "TPU v5 lite"},
+            "trace": {"window_s": 1.0, "op_totals": op_totals}}
+
+
+def test_the_roofline_readers_against_a_hand_worked_shape(bench):
+    chip = peaks.PEAKS["TPU v5 lite"]
+    # 3 x 2 heads x 8 x 9 / 2 = 216 unmasked pairs; forward 4 x 4 FLOPs a
+    # pair = 3456, q k v o at 3 x 8 x 2 x 4 x 2 bytes each + the logsumexp
+    # row: 1536 + 192 = 1728 bytes. At this size the bytes bound both.
+    fwd = max(3456 / chip["bf16_flops_per_s"], 1728 / chip["hbm_bytes_per_s"])
+    bwd = max(2 * 4 * 216 * 5 / chip["bf16_flops_per_s"],
+              (8 * 384 + 8 * 48) / chip["hbm_bytes_per_s"])
+    assert fwd == 1728 / 819e9 and bwd == 3456 / 819e9
+    totals = {"local_step/flash_fwd.15": [2e-6, 4],
+              "local_step/flash_fwd.16": [1e-6, 2],
+              "local_step/flash_bwd_dkv.11": [3e-6, 3],
+              "local_step/flash_bwd_dq.11": [2e-6, 3],
+              "local_step/flash_fwd_helper.1": [9.0, 9],
+              "local_step/jvp_flash_fwd_.1": [9.0, 9],
+              "local_step/fusion.3": [9.0, 9],
+              "slot_prefill/flash_fwd.1": [9.0, 9]}
+    obs = training_obs(totals)
+    assert reader(bench, ROOFLINES[0])(obs) == pytest.approx(
+        100.0 * 6 * fwd / 3e-6)
+    assert reader(bench, ROOFLINES[1])(obs) == pytest.approx(
+        100.0 * 3 * bwd / 5e-6)
+    # the kernels under the compiler's own numbering (before PR 25), a
+    # CPU trace with no Mosaic call, an untraced run: nothing to read
+    unnamed = training_obs({"local_step/closed_call.7": [0.3, 48],
+                            "local_step/checkpoint.22": [0.3, 48]})
+    untraced = dict(training_obs({}), trace=None)
+    for name in ROOFLINES:
+        assert reader(bench, name)(unnamed) is None
+        assert reader(bench, name)(untraced) is None
+    with pytest.raises(ValueError, match="no peaks on record"):
+        reader(bench, ROOFLINES[0])(dict(obs, device={"kind": "cpu"}))
+
+
+@pytest.mark.parametrize("instruction,kernel", [
+    ("flash_fwd.15", "flash_fwd"), ("flash_fwd", "flash_fwd"),
+    ("flash_bwd_dq.11", "flash_bwd_dq"),
+    ("flash_bwd_dkv.11", "flash_bwd_dkv"),
+    ("jvp_flash_fwd_.1", None), ("flash_fwd.1.2", None),
+    ("flash_fwd_helper.1", None), ("my_flash_fwd2.1", None),
+    ("closed_call.7", None), ("flash_bwd.1", None)])
+def test_a_kernel_is_found_by_its_own_name(instruction, kernel):
+    assert inside.kernel_of(instruction, (
+        "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")) == kernel
+
+
+def test_the_put_off_rooflines_wait_beside_their_readers(bench):
+    with open(os.path.join(bench["root"], "benchmarks", "put_off",
+                           "kernel-rooflines.json")) as f:
+        put_off = json.load(f)
+    assert [m["name"] for m in put_off["per_layer"]] == list(ROOFLINES)
+    entered = {m["name"] for m in bench["per_layer"]}
+    assert set(SERVING) <= entered and not entered & set(ROOFLINES)
+    for m in put_off["per_layer"]:
+        assert m["workloads"] == ["ouro-2.6b-d12.train-2k"]
+        assert m["layer"] == "kernels: ops/attention.py"
+        assert m["moves"] == "train_tokens_per_s"
+        assert callable(reader(bench, m["name"]))
+    # merged as a later PR will enter them, the cell reports them traced
+    merged = dict(bench, per_layer=bench["per_layer"] + put_off["per_layer"])
+    names = [m["name"] for m in loader.cell_metrics(
+        merged, "ouro-2.6b-d12.train-2k", True)]
+    assert names[-2:] == list(ROOFLINES)
+
+
+# -------------------------------------------- in the tiny cell's line
+
+def test_the_traced_closed_cell_prints_the_inside_metrics(
+        tiny_bench, cpu_tpu_workers):
+    line = run.run_cell(tiny_bench, "tiny.closed", seed=2**31 + 17,
+                        seconds=3.0, trace=True, platform="cpu",
+                        lm_class=TracedOnCpuLM)
+    assert line["correct"], line["faults"]
+    check_line(tiny_bench, "tiny.closed", line, True)
+    got = {n: line["metrics"][n]["value"] for n in SERVING}
+    assert 0.0 < got["prefill_stall_pct.closed"] < 100.0
+    assert got["decode_slot_reads_ms.closed"] < got["decode_host_ms.closed"]
+    # the inside pair is the step the benchmark's own wrapper times
+    # (means against a median, on a shared CPU: loosely)
+    inside_ms = (got["decode_host_ms.closed"]
+                 + got["decode_device_wait_ms.closed"])
+    outside_ms = line["metrics"]["decode_step_ms.closed"]["value"]
+    assert 0.5 * outside_ms < inside_ms < 2.0 * outside_ms

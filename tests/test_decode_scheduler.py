@@ -13,6 +13,9 @@ re-prefilled mid-flight.
 """
 
 import asyncio
+import subprocess
+import sys
+import time
 
 import pytest
 
@@ -224,6 +227,256 @@ def test_zero_slot_engine_rejected():
         DecodeScheduler(eng)
 
 
+# ------------------------------------------- phases, from the inside
+
+SCHEDULER_PHASES = ("serve.admit", "serve.admit_stall", "serve.prefill",
+                    "serve.step", "serve.hop", "serve.emit")
+ENGINE_STEP_PHASES = tuple("serve.engine." + n for n in (
+    "check", "put", "dispatch", "wait", "read"))
+
+
+class SleepyEngine(FreeRunEngine):
+    """Sync engine (runs on the executor) whose calls take a known
+    time, and which keeps its own clock of them: ``calls`` holds
+    (kind, start, end) on ``time.perf_counter``, so a test compares the
+    scheduler's sums with what the engine saw and not with what a busy
+    box made of a sleep."""
+
+    def __init__(self, slots, prefill_s=0.02, step_s=0.01):
+        super().__init__(slots)
+        self.prefill_s, self.step_s = prefill_s, step_s
+        self.calls = []
+
+    def _sleep(self, kind, seconds):
+        t0 = time.perf_counter()
+        time.sleep(seconds)
+        self.calls.append((kind, t0, time.perf_counter()))
+
+    def prefill(self, slot, prompt):
+        self._sleep("prefill", self.prefill_s)
+        return super().prefill(slot, prompt)
+
+    def step(self, tokens):
+        self._sleep("step", self.step_s)
+        return super().step(tokens)
+
+    def ran(self, kind):
+        return sum(end - t0 for k, t0, end in self.calls if k == kind)
+
+
+class StampedScheduler(DecodeScheduler):
+    """Keeps the loop's own stamps of its first admission and its last
+    completion: the window its top spans are held against."""
+
+    first_admit = last_finish = None
+
+    async def _admit(self):
+        if self.first_admit is None and self._queue:
+            self.first_admit = time.perf_counter()
+        await super()._admit()
+
+    def _finish(self, slot, req):
+        super()._finish(slot, req)
+        self.last_finish = time.perf_counter()
+
+
+def test_the_scheduler_imports_no_jax():
+    """A replica's control code and the fake-engine tests stay off jax:
+    the phase helper annotates the profiler's trace only where jax is
+    already there."""
+    code = ("import sys; import ray_tpu.serve.decode_scheduler; "
+            "from ray_tpu.util.tracing import phase\n"
+            "with phase('serve.probe'): pass\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+def test_the_phase_module_imports_the_standard_library_only():
+    """The hot path's helper arms nothing: ``util/tracing.py`` (whose
+    import sets the submit path's hook) offers its names, not the other
+    way round."""
+    import ast
+
+    import ray_tpu.util.phases as phases
+    from ray_tpu.util import tracing
+
+    with open(phases.__file__) as f:
+        tree = ast.parse(f.read())
+    imported = {a.name.split(".")[0] for n in ast.walk(tree)
+                if isinstance(n, ast.Import) for a in n.names}
+    imported |= {n.module.split(".")[0] for n in ast.walk(tree)
+                 if isinstance(n, ast.ImportFrom)}
+    assert imported <= set(sys.stdlib_module_names), imported
+    assert tracing.phase is phases.phase
+    assert tracing.phase_totals is phases.phase_totals
+
+
+def test_phases_count_steps_and_admissions():
+    async def run():
+        eng = SleepyEngine(slots=2, prefill_s=0.04, step_s=0.02)
+        sched = StampedScheduler(eng)
+        assert sched.stats()["phases"] == {}
+        await asyncio.gather(*[sched.submit([i], max_tokens=4)
+                               for i in range(4)])
+        st = sched.stats()
+        await sched.aclose()
+        return eng, sched, st
+
+    eng, sched, st = asyncio.run(run())
+    got = st["phases"]
+    assert set(got) == set(SCHEDULER_PHASES)
+    assert got["serve.step"][0] == st["steps"] == len(eng.step_slots)
+    assert got["serve.emit"][0] == st["steps"]
+    assert got["serve.prefill"][0] == st["admitted"] == 4
+    assert got["serve.hop"][0] == st["steps"] + st["admitted"]
+    assert got["serve.admit_stall"][0] == got["serve.admit"][0] >= 2
+    # the spans hold the engine's calls, and the hop is what they hold
+    # besides, by the engine's own clock
+    step_s, prefill_s = got["serve.step"][1], got["serve.prefill"][1]
+    assert eng.ran("step") <= step_s
+    assert eng.ran("prefill") <= prefill_s <= got["serve.admit"][1]
+    beyond = step_s + prefill_s - eng.ran("step") - eng.ran("prefill")
+    assert 0.0 <= got["serve.hop"][1] <= beyond
+    assert got["serve.hop"][1] == pytest.approx(beyond, abs=0.01)
+    # what the loop does outside its three top spans is small: they
+    # cover the loop's time from its first admission to its last
+    # completion (the last emit ends a moment after that stamp)
+    window = sched.last_finish - sched.first_admit
+    covered = sum(got[n][1] for n in ("serve.admit", "serve.step",
+                                      "serve.emit"))
+    assert 0.95 * window <= covered <= window + 0.005
+
+
+def test_phase_sums_only_grow():
+    async def run():
+        sched = DecodeScheduler(SleepyEngine(slots=1, prefill_s=0.002,
+                                             step_s=0.002))
+        seen = [sched.stats()["phases"]]
+        for i in range(3):
+            await sched.submit([i], max_tokens=3)
+            seen.append(sched.stats()["phases"])
+        await sched.aclose()
+        return seen
+
+    seen = asyncio.run(run())
+    for earlier, later in zip(seen, seen[1:]):
+        for name, (n, s) in earlier.items():
+            assert later[name][0] >= n and later[name][1] >= s
+    assert [t["serve.step"][0] for t in seen[1:]] == [2, 4, 6]
+
+
+def test_two_schedulers_keep_their_phases_apart():
+    """The table is the scheduler's: what an engine times inside a call
+    lands with the scheduler that made the call, through whatever wraps
+    the engine (the benchmark hands the scheduler a wrapper that shows
+    ``slots``, ``prefill`` and ``step`` only), and two schedulers at
+    work at once in one process do not mix."""
+    from ray_tpu.util.phases import phase, phase_totals
+
+    class PhasedEngine(SleepyEngine):
+        def step(self, tokens):
+            with phase("serve.engine.check"):
+                return super().step(tokens)
+
+    class Wrapped:
+        def __init__(self, engine):
+            self.slots = engine.slots
+            self.prefill, self.step = engine.prefill, engine.step
+
+    async def run():
+        scheds = [DecodeScheduler(Wrapped(PhasedEngine(
+            slots=1, prefill_s=0.002, step_s=0.002))) for _ in range(2)]
+        await asyncio.gather(scheds[0].submit([1], max_tokens=3),
+                             scheds[1].submit([2], max_tokens=6))
+        stats = [s.stats() for s in scheds]
+        for s in scheds:
+            await s.aclose()
+        return stats
+
+    process = phase_totals("serve.")
+    one, two = asyncio.run(run())
+    assert (one["steps"], two["steps"]) == (2, 5)
+    for st in (one, two):
+        assert st["phases"]["serve.engine.check"][0] == st["steps"]
+        assert st["phases"]["serve.step"][0] == st["steps"]
+        assert st["phases"]["serve.prefill"][0] == 1
+    # and nothing of either went to the process's own table
+    assert phase_totals("serve.") == process
+
+
+def test_request_sums_match_the_engines_clock():
+    """One slot, two requests at once: the second waits for the whole
+    of the first. Sums are over ``admitted`` and ``completed``, and are
+    told from the engine's own stamps: a request is submitted just
+    before the first prefill starts, taken when the slot comes free,
+    has its first token when its prefill ends."""
+    async def run():
+        eng = SleepyEngine(slots=1, prefill_s=0.1, step_s=0.05)
+        sched = DecodeScheduler(eng)
+        await asyncio.gather(sched.submit([1], max_tokens=3),
+                             sched.submit([2], max_tokens=3))
+        st = sched.stats()
+        await sched.aclose()
+        return eng, st
+
+    eng, st = asyncio.run(run())
+    assert st["admitted"] == st["completed"] == 2
+    assert [k for k, _, _ in eng.calls] == ["prefill", "step", "step"] * 2
+    t0 = eng.calls[0][1]
+    ends = [end - t0 for _, _, end in eng.calls]
+    # under the least a sum counted twice would add (a prefill: 0.1)
+    near = dict(abs=0.08)
+    assert st["queue_wait_s"] == pytest.approx(0.0 + ends[2], **near)
+    assert st["first_token_s"] == pytest.approx(ends[0] + ends[3], **near)
+    assert st["request_s"] == pytest.approx(ends[2] + ends[5], **near)
+    assert st["queue_wait_s"] >= 0.1 + 2 * 0.05
+    assert st["request_s"] >= 3 * (0.1 + 2 * 0.05)
+
+
+def test_admit_stall_is_the_prefill_time_of_mid_batch_admissions():
+    async def run(late):
+        eng = SleepyEngine(slots=2, prefill_s=0.03, step_s=0.005)
+        sched = DecodeScheduler(eng)
+        a = asyncio.ensure_future(sched.submit([1], max_tokens=12))
+        if late:
+            while not eng.step_slots:       # A is decoding
+                await asyncio.sleep(0.001)
+            await sched.submit([2], max_tokens=2)
+        await a
+        st = sched.stats()
+        await sched.aclose()
+        return st["phases"], st["admitted_mid_batch"]
+
+    alone, mid = asyncio.run(run(late=False))
+    assert mid == 0 and alone["serve.admit_stall"] == [1, 0.0]
+    joined, mid = asyncio.run(run(late=True))
+    assert mid == 1
+    stall = joined["serve.admit_stall"][1]
+    assert 0.03 <= stall <= joined["serve.admit"][1] - 0.03
+
+
+def test_a_raising_step_still_closes_and_counts_its_span():
+    class FlakyEngine(SleepyEngine):
+        def step(self, tokens):
+            time.sleep(self.step_s)
+            raise RuntimeError("device fell over")
+
+    async def run():
+        sched = DecodeScheduler(FlakyEngine(slots=1))
+        with pytest.raises(RuntimeError, match="device fell over"):
+            await sched.submit([1], max_tokens=3)
+        st = sched.stats()
+        await sched.aclose()
+        return st
+
+    st = asyncio.run(run())
+    got = st["phases"]
+    assert st["steps"] == 0
+    assert got["serve.step"][0] == 1 and got["serve.step"][1] >= 0.01
+    assert got["serve.hop"][0] == 2         # the prefill's and the step's
+    assert "serve.emit" not in got
+
+
 # ------------------------------------------------------------- jax oracle
 
 
@@ -262,3 +515,38 @@ def test_slot_cache_matches_whole_batch_generate():
     outs = asyncio.run(run())
     for prompt, n, got in zip(prompts, steps, outs):
         assert got == oracle(prompt, n), (prompt, n)
+
+
+def test_engine_phases_cover_the_step():
+    """Every step of the real engine records its five phases once, a
+    prefill none of its own, and the step's phases are the step: their
+    sum is 90 to 100 % of its wall time (the median step's, so that one
+    stall of a shared box between two spans does not decide it)."""
+    jax = pytest.importorskip("jax")
+    import statistics
+
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.decode_scheduler import JaxSlotEngine
+    from ray_tpu.util.phases import recording
+
+    cfg = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
+                            d_ff=128, max_seq=64, dtype=jnp.float32)
+    eng = JaxSlotEngine(init_params(jax.random.key(0), cfg), cfg,
+                        slots=2, max_len=32)
+    with recording({}) as table:
+        last = {0: eng.prefill(0, [5, 11, 23]), 1: eng.prefill(1, [40, 2])}
+    assert table == {}
+    last = eng.step(last)                   # compiles
+    shares = []
+    for _ in range(15):
+        with recording({}) as table:
+            t0 = time.perf_counter()
+            last = eng.step(last)
+            wall = time.perf_counter() - t0
+        assert sorted(table) == sorted(ENGINE_STEP_PHASES)
+        assert [table[p][0] for p in ENGINE_STEP_PHASES] == [1] * 5
+        shares.append(sum(table[p][1] for p in ENGINE_STEP_PHASES) / wall)
+    assert max(shares) <= 1.0
+    assert statistics.median(shares) >= 0.9
