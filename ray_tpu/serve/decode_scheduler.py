@@ -53,9 +53,13 @@ name, to be read as deltas:
   timed on the executor thread (hand-off, GIL, loop lag);
 * executor thread, inside :class:`JaxSlotEngine`'s ``step``:
   ``serve.engine.check`` / ``.put`` / ``.dispatch`` / ``.wait`` /
-  ``.read`` (``wait`` is the first slot's read, the one that waits for
-  the device; ``read`` the other slots'). The engine's ``prefill`` has
-  no spans of its own: ``serve.prefill`` less its hop is the call.
+  ``.read``. ``check`` is host only: the capacity check against the
+  engine's own mirror of the slots' positions, and the ``tok``/``act``
+  lists. ``wait`` is the step's one device-to-host transfer, the whole
+  argmax row fetched at once: it waits for the device and carries the
+  transfer. ``read`` builds the returned dict from that host array.
+  The engine's ``prefill`` has no spans of its own: ``serve.prefill``
+  less its hop is the call.
 """
 
 from __future__ import annotations
@@ -326,12 +330,28 @@ class JaxSlotEngine:
     """Adapts the per-slot KV cache (models/decode.py) to the
     scheduler's engine protocol. Greedy decoding; prompts are int
     token-id sequences. One compiled prefill program per distinct
-    prompt length, one compiled step program total."""
+    prompt length, one compiled step program total.
+
+    A decode step makes one device-to-host transfer, and none before
+    its dispatch. The slots' positions are mirrored on the host
+    (``_pos``: ``prefill`` sets a slot's entry to the prompt's length,
+    ``step`` adds one per active slot, each once the program has
+    returned and ``_cache`` is reassigned, so a call that raises leaves
+    the mirror where the cache is); the device's ``cache["pos"]`` stays
+    what the programs compute from and the mirror never writes it.
+    ``step``'s phases: ``check`` reads the mirror (host only), ``wait``
+    fetches the whole argmax row once, ``read`` builds the dict from
+    that host array. No ``block_until_ready`` here or in ``prefill``:
+    the fetch waits for the device, and its own enqueue overlaps the
+    step (an explicit wait before it cost 0.9 ms a step on a v5e:
+    PERF.md, PR 25)."""
 
     def __init__(self, params, cfg, *, slots: int, max_len: int):
-        import jax.numpy as jnp  # deferred: scheduler users without a
-        from ray_tpu.models import decode as decode_mod  # model never pay
+        import jax  # deferred: scheduler users without a
+        import jax.numpy as jnp  # model never pay
+        from ray_tpu.models import decode as decode_mod
 
+        self._jax = jax
         self._jnp = jnp
         self._decode = decode_mod
         self._params = params
@@ -339,6 +359,7 @@ class JaxSlotEngine:
         self.slots = int(slots)
         self.max_len = int(max_len)
         self._cache = decode_mod.init_slot_cache(cfg, slots, max_len)
+        self._pos = [0] * self.slots    # host mirror of _cache["pos"]
 
     def prefill(self, slot: int, prompt) -> int:
         jnp = self._jnp
@@ -350,6 +371,7 @@ class JaxSlotEngine:
         logits, self._cache = self._decode.slot_prefill(
             self._params, tokens, self._cache, jnp.int32(slot),
             self._cfg)
+        self._pos[slot] = tokens.shape[1]
         return int(jnp.argmax(logits[0]))
 
     def step(self, tokens: Dict[int, int]) -> Dict[int, int]:
@@ -362,7 +384,7 @@ class JaxSlotEngine:
                 # write; refuse loudly (the scheduler's max_tokens bound
                 # plus the engine's prompt-length check make this
                 # unreachable)
-                if int(self._cache["pos"][slot]) >= self.max_len:
+                if self._pos[slot] >= self.max_len:
                     raise ValueError(f"slot {slot} KV cache full")
                 tok[slot], act[slot] = int(t), True
         with phase("serve.engine.put"):
@@ -371,14 +393,13 @@ class JaxSlotEngine:
         with phase("serve.engine.dispatch"):
             logits, self._cache = self._decode.slot_decode_step(
                 self._params, self._cache, tok, act, self._cfg)
+            for slot in tokens:
+                self._pos[slot] += 1
             nxt = jnp.argmax(logits, axis=-1)
-        slots = list(tokens)
         with phase("serve.engine.wait"):
-            # the first read is the one that waits for the device: the
-            # device's share of the step as the host sees it. (Its own
-            # dispatch overlaps the step; a block_until_ready before it
-            # cost 0.9 ms a step on a v5e: PERF.md, PR 25.)
-            out = {slot: int(nxt[slot]) for slot in slots[:1]}
+            # the step's one transfer: waits for the device, then brings
+            # the whole int32[slots] row
+            row = self._jax.device_get(nxt)
         with phase("serve.engine.read"):
-            out.update({slot: int(nxt[slot]) for slot in slots[1:]})
-        return out
+            row = row.tolist()
+            return {slot: row[slot] for slot in tokens}

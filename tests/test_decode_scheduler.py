@@ -550,3 +550,183 @@ def test_engine_phases_cover_the_step():
         shares.append(sum(table[p][1] for p in ENGINE_STEP_PHASES) / wall)
     assert max(shares) <= 1.0
     assert statistics.median(shares) >= 0.9
+
+
+# ------------------------------------ one transfer a step, positions on host
+
+
+def _tiny_engine(slots, max_len):
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.decode_scheduler import JaxSlotEngine
+
+    cfg = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
+                            d_ff=128, max_seq=64, dtype=jnp.float32)
+    return JaxSlotEngine(init_params(jax.random.key(0), cfg), cfg,
+                         slots=slots, max_len=max_len)
+
+
+def _device_pos(eng):
+    import numpy as np
+
+    return np.asarray(eng._cache["pos"]).tolist()
+
+
+def test_position_mirror_equals_the_device_after_every_call():
+    """Prefills, steps with some slots inactive, a slot freed and
+    prefilled again: after each call the host's positions are the
+    device's ``cache["pos"]`` in every row, the frozen ones too."""
+    eng = _tiny_engine(slots=3, max_len=32)
+    assert eng._pos == _device_pos(eng) == [0, 0, 0]
+    last = {}
+    script = [("prefill", 0, [5, 11, 23]), ("prefill", 2, [40, 2]),
+              ("step", (0, 2)), ("step", (0,)),      # slot 2 sits one out
+              ("prefill", 1, [88, 17, 3, 9, 1]), ("step", (0, 1, 2)),
+              ("step", (1, 2)),                      # slot 0 is done
+              ("prefill", 0, [7]),                   # ... and taken again
+              ("step", (0, 1, 2)), ("step", (2,))]
+    want = [0, 0, 0]
+    for kind, *args in script:
+        if kind == "prefill":
+            slot, prompt = args
+            last[slot] = eng.prefill(slot, prompt)
+            want[slot] = len(prompt)
+        else:
+            out = eng.step({s: last[s] for s in args[0]})
+            assert sorted(out) == sorted(args[0])
+            last.update(out)
+            for s in args[0]:
+                want[s] += 1
+        assert eng._pos == want == _device_pos(eng), (kind, args)
+
+
+def test_full_slot_is_refused_before_anything_is_dispatched():
+    """A slot whose position has reached ``max_len`` makes ``step``
+    raise from the host-side check: no put, no dispatch (the cache is
+    the object it was), and no slot of the call advances."""
+    from ray_tpu.util.phases import recording
+
+    eng = _tiny_engine(slots=2, max_len=8)
+    last = {0: eng.prefill(0, [5, 11, 23, 4, 9]), 1: eng.prefill(1, [40])}
+    for _ in range(3):
+        last = eng.step(last)               # the last one fills row 7
+    assert eng._pos == _device_pos(eng) == [8, 4]
+    cache = eng._cache
+    with recording({}) as table:
+        with pytest.raises(ValueError, match="^slot 0 KV cache full$"):
+            eng.step({1: last[1], 0: last[0]})
+    assert eng._cache is cache
+    assert eng._pos == _device_pos(eng) == [8, 4]
+    assert sorted(table) == ["serve.engine.check"]
+    assert sorted(eng.step({1: last[1]})) == [1]    # the other decodes on
+    assert eng._pos == _device_pos(eng) == [8, 5]
+
+
+@pytest.mark.parametrize("call", ["prefill", "step"])
+def test_a_raising_program_leaves_the_mirror_where_the_cache_is(call):
+    import types
+
+    eng = _tiny_engine(slots=2, max_len=16)
+    last = {0: eng.prefill(0, [5, 11, 23])}
+
+    def boom(*a, **k):
+        raise RuntimeError("device lost")
+
+    cache = eng._cache
+    eng._decode = types.SimpleNamespace(slot_prefill=boom,
+                                        slot_decode_step=boom)
+    with pytest.raises(RuntimeError):
+        if call == "prefill":
+            eng.prefill(1, [40, 2])
+        else:
+            eng.step(last)
+    assert eng._cache is cache
+    assert eng._pos == _device_pos(eng) == [3, 0]
+
+
+class _HostReads:
+    """Counts what reaches for a device array from the host while it is
+    open: ``indexed`` (``x[i]``, a slice program each) and ``fetched``
+    (a conversion to the host: ``int(x)``, ``x.tolist()``,
+    ``jax.device_get(x)`` through the array's ``_value``; ``np.asarray``
+    and ``np.array`` by name, since on the CPU backend numpy reads the
+    buffer in place and calls nothing of jax). The transfer guard cannot
+    serve: on the CPU backend of jax 0.9.0 every one of these passes
+    under ``transfer_guard_device_to_host("disallow")``."""
+
+    def __init__(self, monkeypatch):
+        import numpy as np
+        from jax._src.array import ArrayImpl
+
+        self.indexed = self.fetched = 0
+        getitem, value = ArrayImpl.__getitem__, ArrayImpl._value
+
+        def counted_getitem(arr, idx):
+            self.indexed += 1
+            return getitem(arr, idx)
+
+        def counted_value(arr):
+            self.fetched += 1
+            return value.fget(arr)
+
+        def counted(fn):
+            def call(a, *args, **kwargs):
+                self.fetched += isinstance(a, ArrayImpl)
+                return fn(a, *args, **kwargs)
+            return call
+
+        monkeypatch.setattr(ArrayImpl, "__getitem__", counted_getitem)
+        monkeypatch.setattr(ArrayImpl, "_value", property(counted_value))
+        monkeypatch.setattr(np, "asarray", counted(np.asarray))
+        monkeypatch.setattr(np, "array", counted(np.array))
+
+
+def test_the_seam_counts_every_way_to_read_a_device_array(monkeypatch):
+    """The counter itself: each of the reads the engine used to make,
+    and each it could make, is seen once."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+    import numpy as np
+
+    y = jnp.arange(8, dtype=jnp.int32) + 1
+    reads = {"int(y[0])": lambda: int(y[0]),
+             "np.asarray": lambda: np.asarray(y),
+             "np.array": lambda: np.array(y),
+             "device_get": lambda: jax.device_get(y),
+             "tolist": lambda: y.tolist()}
+    with monkeypatch.context() as m:
+        seen = _HostReads(m)
+        for name, read in reads.items():
+            y = jnp.arange(8, dtype=jnp.int32) + 1  # nothing cached on it
+            seen.indexed = seen.fetched = 0
+            read()
+            assert (seen.indexed, seen.fetched) == (
+                (1, 1) if name == "int(y[0])" else (0, 1)), name
+
+
+@pytest.mark.parametrize("active", [(1,), (0, 2), (0, 1, 2)])
+def test_a_step_indexes_no_device_array_and_fetches_one(monkeypatch,
+                                                        active):
+    """However many slots are active, a step asks the device for one
+    thing: the argmax row, whole. The tokens it returns are those the
+    row held."""
+    eng = _tiny_engine(slots=3, max_len=32)
+    import jax.numpy as jnp
+    import numpy as np
+
+    last = {0: eng.prefill(0, [5, 11, 23]), 1: eng.prefill(1, [40, 2]),
+            2: eng.prefill(2, [88])}
+    last = eng.step(last)                   # compiles
+    logits, _ = eng._decode.slot_decode_step(   # pure: nothing is donated
+        eng._params, eng._cache,
+        jnp.asarray([last[s] for s in range(3)], jnp.int32),
+        jnp.asarray([s in active for s in range(3)]), eng._cfg)
+    row = np.asarray(jnp.argmax(logits, axis=-1)).tolist()
+    with monkeypatch.context() as m:
+        seen = _HostReads(m)
+        out = eng.step({s: last[s] for s in active})
+    assert (seen.indexed, seen.fetched) == (0, 1)
+    assert out == {s: row[s] for s in active}
+    assert all(type(t) is int for t in out.values())
