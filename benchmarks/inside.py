@@ -14,6 +14,7 @@ from __future__ import annotations
 import re
 
 from benchmarks import peaks
+from benchmarks.readers import family_costs
 
 ENGINE_HOST = ("serve.engine.check", "serve.engine.put",
                "serve.engine.dispatch", "serve.engine.read")
@@ -24,7 +25,6 @@ ADMIT_STALL = ("serve.admit_stall",)
 LOOP = ("serve.admit", "serve.step", "serve.emit")
 
 TRAIN_PROGRAM = "local_step"
-ITEMSIZE = {"bfloat16": 2, "float32": 4}
 
 
 def phase_seconds(obs, names):
@@ -79,25 +79,19 @@ def kernel_totals(obs, program, kernels):
     return found
 
 
-def flash_shape(obs):
-    """batch, seq, heads, head_dim, itemsize of the training cell's
-    attention calls, from its configuration and traffic files."""
-    config, mix = obs["run"]["config"], obs["run"]["traffic"]
-    return (int(mix["batch"]), int(mix["seq"]),
-            int(config["num_attention_heads"]), int(config["head_dim"]),
-            ITEMSIZE[config["torch_dtype"]])
-
-
 def roofline_pct(obs, kernels, counted, cost_of):
     """The least time the chip could take for the calls of ``counted``
-    (``cost_of`` prices one, from the cell's shapes) over the device time
+    (``cost_of`` prices one, at the shape the cell's family gives for its
+    configuration and traffic files) over the device time
     of all the ``kernels`` that do that work together."""
     found = kernel_totals(obs, TRAIN_PROGRAM, kernels)
     seconds = sum(s for s, _ in found.values())
     calls = found[counted][1]
     if seconds <= 0.0 or calls == 0:
         return None
-    least = peaks.roofline_seconds(cost_of(*flash_shape(obs)),
+    shape = family_costs(obs).flash_shape(obs["run"]["config"],
+                                          obs["run"]["traffic"])
+    least = peaks.roofline_seconds(cost_of(*shape),
                                    peaks.peaks_of(obs["device"]["kind"]))
     return 100.0 * calls * least["seconds"] / seconds
 

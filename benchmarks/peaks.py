@@ -1,5 +1,8 @@
-"""The chip's peaks and the arithmetic of operations and bytes: what the
-algorithm needs, computed from shapes, never read from the program.
+"""The chip's peaks and the arithmetic of a kernel's operations and
+bytes: what the algorithm needs, computed from shapes, never read from
+the program. A model's own counts (parameters, a forward's and a train
+step's FLOPs, the shape its kernels are priced at) are its family's:
+``families/<model_type>/costs.py``.
 
 Peaks are keyed by jax's exact ``device_kind``. Source: Google Cloud
 documentation, "TPU v5e" (system architecture): 197 TFLOP/s bf16,
@@ -24,44 +27,6 @@ def peaks_of(device_kind: str) -> dict:
         raise ValueError(
             f"no peaks on record for device_kind {device_kind!r}; add it "
             f"to benchmarks/peaks.py with its source") from None
-
-
-def n_params(config: dict) -> int:
-    """Parameters of the model as run (tied embedding counted once)."""
-    D, F = int(config["hidden_size"]), int(config["intermediate_size"])
-    HD = int(config["num_attention_heads"]) * int(config["head_dim"])
-    per_layer = 4 * D * HD + 3 * D * F + 2 * D
-    return (int(config["vocab_size"]) * D
-            + int(config["num_hidden_layers"]) * per_layer + D)
-
-
-def attention_flops(config: dict, context_sum: int) -> float:
-    """QK^T and PV over all layers: 4 * head_dim * heads FLOPs for each
-    (query, key) pair the mask lets through. ``context_sum`` is the
-    number of such pairs (for a causal prompt of T tokens T(T+1)/2; for
-    a decode step the positions each active row attends)."""
-    HD = int(config["num_attention_heads"]) * int(config["head_dim"])
-    return 4.0 * HD * int(config["num_hidden_layers"]) * context_sum
-
-
-def forward_flops(config: dict, tokens: int, context_sum: int,
-                  logit_rows: int) -> float:
-    """2 FLOPs per parameter of the layers for each token, the
-    unembedding for the ``logit_rows`` positions whose logits are needed
-    (a prefill needs its last one only; the embedding lookup is no
-    matrix product), plus attention."""
-    unembed = int(config["vocab_size"]) * int(config["hidden_size"])
-    return (2.0 * (n_params(config) - unembed) * tokens
-            + 2.0 * unembed * logit_rows
-            + attention_flops(config, context_sum))
-
-
-def train_flops(config: dict, batch: int, seq: int) -> float:
-    """One optimizer step: 6 * N * tokens plus three times the forward's
-    causal attention. Recomputation (remat) is not counted."""
-    pairs = batch * seq * (seq + 1) // 2
-    return 6.0 * n_params(config) * batch * seq + 3.0 * attention_flops(
-        config, pairs)
 
 
 def flash_fwd_cost(batch: int, seq: int, heads: int, head_dim: int,

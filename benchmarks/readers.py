@@ -3,7 +3,8 @@ run's observations (``obs``: what the driver and the worker recorded)
 and returns a number, or None where it finds nothing to read; each
 metric's own file under ``metrics/`` picks one.
 
-``obs`` holds: ``run`` (the cell's configuration, traffic mix, seconds),
+``obs`` holds: ``run`` (the cell's configuration, its family's
+directory, traffic mix, seconds),
 ``window`` [t0, t1] on the host's monotonic clock, ``setup_s``,
 ``requests`` (serving: one record per request, with the client's and the
 replica's stamps), ``reports`` (training: [arrival, phase, step, loss]),
@@ -18,7 +19,7 @@ from __future__ import annotations
 import math
 import statistics
 
-from benchmarks import peaks
+from benchmarks import loader, peaks
 
 
 def median_ms(seconds):
@@ -139,6 +140,11 @@ def _peaks(obs):
     return peaks.peaks_of(obs["device"]["kind"])
 
 
+def family_costs(obs):
+    """The arithmetic of the cell's model family (its ``costs.py``)."""
+    return loader.family_module(obs["run"]["family"], "costs")
+
+
 def device_idle_pct(obs):
     trace = _traced(obs)
     if trace is None:
@@ -157,12 +163,12 @@ def serve_mfu_pct(obs):
     trace = _traced(obs)
     if trace is None:
         return None
-    config = obs["run"]["config"]
+    config, costs = obs["run"]["config"], family_costs(obs)
     flops = 0.0
     for _, _, rows, attended in _started_in_slice(obs["steps"], trace):
-        flops += peaks.forward_flops(config, rows, attended, logit_rows=rows)
+        flops += costs.forward_flops(config, rows, attended, logit_rows=rows)
     for _, _, length in _started_in_slice(obs["prefills"], trace):
-        flops += peaks.forward_flops(
+        flops += costs.forward_flops(
             config, length, length * (length + 1) // 2, logit_rows=1)
     if flops == 0.0:
         return None
@@ -178,7 +184,7 @@ def train_mfu_pct(obs):
     steps = len(_started_in_slice(obs["steps"], trace))
     if not steps:
         return None
-    flops = steps * peaks.train_flops(obs["run"]["config"], mix["batch"],
-                                      mix["seq"])
+    flops = steps * family_costs(obs).train_flops(
+        obs["run"]["config"], mix["batch"], mix["seq"])
     return 100.0 * flops / (trace["window_s"]
                             * _peaks(obs)["bf16_flops_per_s"])

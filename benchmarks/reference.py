@@ -1,17 +1,18 @@
-"""The plain reference every cell's ``correct`` is decided against.
+"""What every family's plain reference shares, and how a cell's
+``correct`` is read off it.
 
-Straightforward ``jax.numpy`` in float32 at ``highest`` matmul
-precision: no kernels, no cache, no batching of requests. It imports
-nothing of ``ray_tpu`` and takes nothing the program made: the weights
-come from :func:`seeded_params` (the benchmark's own; the program is
-handed the same function's output), the training batches from
-``traffic.train_batch``.
-
-The block is the one Ouro-2.6B's ``config.json`` describes, run once
-(``total_ut_steps`` 1): RMSNorm -> MHA with rotate-half RoPE -> residual
--> RMSNorm -> SwiGLU -> residual; final RMSNorm; tied unembedding.
-Departures from the published model are listed in the configuration
-files under ``assumed``.
+A family (``families/<model_type>/reference.py``, found by
+``loader.family_module``) brings its block: ``sizes_of``,
+``seeded_params``, ``forward`` and ``by_leaf``. This module holds what
+no family owns: the key a seed gives, the matrix product with its
+lower-precision controls, the serving and training readings made
+through a family's ``forward``, AdamW, and the comparisons. Plain
+``jax.numpy`` in float32 at ``highest`` matmul precision: no kernels,
+no cache, no batching of requests. It imports nothing of ``ray_tpu``
+and takes nothing the program made: the weights come from the family's
+``seeded_params`` (the program is handed the same function's output),
+the training batches from ``traffic.train_batch``. In the functions
+below ``ref`` is a family's reference module.
 
 The control is the same code with ``quant="int8"``: every linear layer
 (and the unembedding) multiplies int8-rounded activations (one scale
@@ -24,60 +25,13 @@ the other step below bfloat16, read beside it when limits are set.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 HIGHEST = lax.Precision.HIGHEST
-LAYER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm",
-                "w_gate", "w_up", "w_down")
-
-
-@dataclasses.dataclass(frozen=True)
-class Sizes:
-    vocab: int
-    d_model: int
-    n_heads: int
-    head_dim: int
-    d_ff: int
-    n_layers: int
-    rope_theta: float
-    eps: float
-    dtype: str
-
-
-def sizes_of(config: dict) -> Sizes:
-    """The sizes a configuration file states, under its published
-    (Hugging Face) key names. Refuses what this block cannot express."""
-    heads = int(config["num_attention_heads"])
-    problems = []
-    if int(config["num_key_value_heads"]) != heads:
-        problems.append("grouped-query attention")
-    if int(config["head_dim"]) * heads != int(config["hidden_size"]):
-        problems.append("head_dim * heads != hidden_size")
-    if config.get("hidden_act") != "silu":
-        problems.append(f"hidden_act {config.get('hidden_act')!r}")
-    if config.get("rope_scaling") or config.get("use_sliding_window"):
-        problems.append("rope scaling or a sliding window")
-    if int(config.get("total_ut_steps", 1)) != 1:
-        problems.append("a looped stack (total_ut_steps > 1)")
-    if not config.get("tie_word_embeddings", False):
-        problems.append("untied embeddings")
-    if problems:
-        raise ValueError("the reference block cannot express: "
-                         + "; ".join(problems))
-    return Sizes(
-        vocab=int(config["vocab_size"]), d_model=int(config["hidden_size"]),
-        n_heads=heads, head_dim=int(config["head_dim"]),
-        d_ff=int(config["intermediate_size"]),
-        n_layers=int(config["num_hidden_layers"]),
-        rope_theta=float(config["rope_theta"]),
-        eps=float(config["rms_norm_eps"]),
-        dtype=str(config.get("torch_dtype", "bfloat16")))
 
 
 def seed_key(seed: int):
@@ -86,37 +40,7 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF), seed >> 31)
 
 
-@functools.partial(jax.jit, static_argnames=("sz",))
-def _params(key, sz: Sizes):
-    k = jax.random.split(key, 8)
-    D, HD, F, L = sz.d_model, sz.n_heads * sz.head_dim, sz.d_ff, sz.n_layers
-    dt = jnp.dtype(sz.dtype)
-
-    def w(kk, shape):
-        return (0.02 * jax.random.normal(kk, shape, jnp.float32)).astype(dt)
-
-    return {
-        "embed": w(k[0], (sz.vocab, D)),
-        "layers": {
-            "attn_norm": jnp.ones((L, D), dt),
-            "wq": w(k[1], (L, D, HD)), "wk": w(k[2], (L, D, HD)),
-            "wv": w(k[3], (L, D, HD)), "wo": w(k[4], (L, HD, D)),
-            "mlp_norm": jnp.ones((L, D), dt),
-            "w_gate": w(k[5], (L, D, F)), "w_up": w(k[6], (L, D, F)),
-            "w_down": w(k[7], (L, F, D)),
-        },
-        "final_norm": jnp.ones((D,), dt),
-    }
-
-
-def seeded_params(seed: int, sz: Sizes):
-    """The model's weights from the seed, made on the device in one
-    jitted call, in the type they are served and trained in. Layer
-    weights are stacked on a leading layer dimension."""
-    return _params(seed_key(seed), sz)
-
-
-# ------------------------------------------------------------------ block
+# ------------------------------------------- the controls' matrix product
 
 def _fake_int8(a, axis):
     scale = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 127.0
@@ -133,7 +57,8 @@ def _fake_fp8(a, axis):
     return a + lax.stop_gradient(rounded - a)   # straight-through
 
 
-def _mm(x, w, quant):
+def mm(x, w, quant):
+    """``x @ w`` at ``highest``, or in the control precision ``quant``."""
     if quant == "int8":
         x, w = _fake_int8(x, -1), _fake_int8(w, 0)
     elif quant == "fp8":
@@ -143,56 +68,10 @@ def _mm(x, w, quant):
     return jnp.matmul(x, w, precision=HIGHEST)
 
 
-def _rms(x, weight, eps):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * lax.rsqrt(var + eps) * weight
-
-
-def _rope(x, theta):
-    """x [B, T, H, Dh]; rotate-half convention, positions 0..T-1."""
-    T, Dh = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, Dh, 2, dtype=jnp.float32) / Dh))
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(x, lp, sz: Sizes, quant):
-    B, T, _ = x.shape
-    lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
-    h = _rms(x, lp["attn_norm"], sz.eps)
-    q, k, v = (_mm(h, lp[n], quant).reshape(B, T, sz.n_heads, sz.head_dim)
-               for n in ("wq", "wk", "wv"))
-    q, k = _rope(q, sz.rope_theta), _rope(k, sz.rope_theta)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST)
-    s = s / math.sqrt(sz.head_dim)
-    causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
-    p = jax.nn.softmax(jnp.where(causal[None, None], s, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HIGHEST)
-    x = x + _mm(o.reshape(B, T, -1), lp["wo"], quant)
-    h = _rms(x, lp["mlp_norm"], sz.eps)
-    gated = jax.nn.silu(_mm(h, lp["w_gate"], quant)) * _mm(h, lp["w_up"],
-                                                           quant)
-    return x + _mm(gated, lp["w_down"], quant)
-
-
-def forward(params, tokens, sz: Sizes, quant=None, remat=False):
-    """tokens [B, T] int32 -> logits [B, T, V] float32."""
-    layer = functools.partial(_layer, sz=sz, quant=quant)
-    if remat:
-        layer = jax.checkpoint(layer)
-    embed = params["embed"].astype(jnp.float32)
-    x, _ = lax.scan(lambda x, lp: (layer(x, lp), None), embed[tokens],
-                    params["layers"])
-    x = _rms(x, params["final_norm"].astype(jnp.float32), sz.eps)
-    return _mm(x, embed.T, quant)
-
-
 # --------------------------------------------------------------- serving
 
-@functools.partial(jax.jit, static_argnames=("sz", "quant"))
-def _next_token_gaps(params, tokens, sz: Sizes, quant):
+@functools.partial(jax.jit, static_argnames=("sz", "quant", "forward"))
+def _next_token_gaps(params, tokens, sz, quant, forward):
     """For one sequence [1, T]: at each position t, how far below the
     reference's best next-token logit lies (a) the token the sequence
     really has at t+1, (b) the token the ``quant`` control puts first."""
@@ -208,7 +87,7 @@ def _next_token_gaps(params, tokens, sz: Sizes, quant):
     return out
 
 
-def served_logit_gaps(params, prompt, served, sz: Sizes, quant=None,
+def served_logit_gaps(ref, params, prompt, served, sz, quant=None,
                       pad_to: int = 128):
     """The reference over ``prompt + served`` (token id lists), once.
 
@@ -221,7 +100,7 @@ def served_logit_gaps(params, prompt, served, sz: Sizes, quant=None,
     first, last = len(prompt) - 1, len(seq) - 1     # positions t read
     padded = seq + [0] * (-len(seq) % pad_to)
     gaps = _next_token_gaps(params, jnp.asarray([padded], jnp.int32), sz,
-                            quant)
+                            quant, ref.forward)
     return {k: [float(x) for x in v[first:last]] for k, v in gaps.items()}
 
 
@@ -231,26 +110,27 @@ ADAMW = {"lr": 3e-4, "b1": 0.9, "b2": 0.999, "eps": 1e-8,
          "weight_decay": 1e-4}      # optax.adamw(3e-4)'s defaults
 
 
-def _row_loss(params, tokens, targets, sz: Sizes, quant):
+def _row_loss(params, tokens, targets, sz, quant, forward):
     logits = forward(params, tokens, sz, quant, remat=True)
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], -1))
 
 
-@functools.partial(jax.jit, static_argnames=("sz", "quant"),
+@functools.partial(jax.jit, static_argnames=("sz", "quant", "forward"),
                    donate_argnames=("acc",))
-def _accumulate_row(params, acc, tokens, targets, weight, sz: Sizes, quant):
+def _accumulate_row(params, acc, tokens, targets, weight, sz, quant,
+                    forward):
     """acc += weight * d(row loss)/d(params). The arithmetic is float32;
     a row's gradient leaves autodiff in the parameters' stored type and
     is summed in float32."""
     loss, grads = jax.value_and_grad(_row_loss)(params, tokens, targets,
-                                                sz, quant)
+                                                sz, quant, forward)
     acc = jax.tree.map(lambda a, g: a + weight * g.astype(jnp.float32),
                        acc, grads)
     return loss, acc
 
 
-def loss_and_grads(params, batch, sz: Sizes, quant=None, rows=None):
+def loss_and_grads(ref, params, batch, sz, quant=None, rows=None):
     """Mean next-token cross-entropy over the batch's rows and its
     gradient (float32), one row at a time so that it fits beside
     nothing else on a chip. ``rows`` keeps only those rows (the
@@ -261,7 +141,7 @@ def loss_and_grads(params, batch, sz: Sizes, quant=None, rows=None):
     for r in rows:
         loss, acc = _accumulate_row(
             params, acc, batch["tokens"][r:r + 1], batch["targets"][r:r + 1],
-            1.0 / len(rows), sz, quant)
+            1.0 / len(rows), sz, quant, ref.forward)
         total += float(loss) / len(rows)
     return total, acc
 
@@ -290,37 +170,27 @@ def adamw_update(params, mu, nu, grads, count):
     return pick(0), pick(1), pick(2)
 
 
-def _by_leaf(tree):
-    """{"embed": leaf, "wq.0": layer 0's slice, ...}: the stacked layer
-    leaves split by layer."""
-    out = {"embed": tree["embed"], "final_norm": tree["final_norm"]}
-    for name in LAYER_LEAVES:
-        leaf = tree["layers"][name]
-        for i in range(leaf.shape[0]):
-            out[f"{name}.{i}"] = leaf[i]
-    return out
-
-
-@jax.jit
-def leaf_norms(tree):
-    """Norm of every leaf of a parameter-shaped tree, the stacked layer
-    leaves split by layer: {"embed": x, "wq.0": x, ...} as float32."""
+@functools.partial(jax.jit, static_argnames=("by_leaf",))
+def leaf_norms(tree, by_leaf):
+    """Norm, as float32, of every leaf of a parameter-shaped tree as the
+    family's ``by_leaf`` names them (stacked layer leaves split by
+    layer)."""
     return {k: jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32))))
-            for k, v in _by_leaf(tree).items()}
+            for k, v in by_leaf(tree).items()}
 
 
-@jax.jit
-def leaf_diff_norms(a, b, scale_b):
+@functools.partial(jax.jit, static_argnames=("by_leaf",))
+def leaf_diff_norms(a, b, scale_b, by_leaf):
     """Norm of every leaf of ``a - scale_b * b`` (float32 arithmetic,
     nothing of the trees' size kept, so that the program's own peak of
     memory stays the one that is read)."""
-    a, b = _by_leaf(a), _by_leaf(b)
+    a, b = by_leaf(a), by_leaf(b)
     return {k: jnp.sqrt(jnp.sum(jnp.square(
         a[k].astype(jnp.float32) - scale_b * b[k].astype(jnp.float32))))
         for k in a}
 
 
-def train_reference(seed: int, sz: Sizes, batch_of, steps: int = 3,
+def train_reference(ref, seed: int, sz, batch_of, steps: int = 3,
                     quant=None, rows=None, frozen=False,
                     other_first_gradient=None, other_scale=1.0,
                     keep_first_gradient=False) -> dict:
@@ -337,26 +207,28 @@ def train_reference(seed: int, sz: Sizes, batch_of, steps: int = 3,
     this side's back too, rounded to bfloat16, for a control to be read
     against.
     """
-    params = seeded_params(seed, sz)
+    params = ref.seeded_params(seed, sz)
     mu = jax.tree.map(jnp.zeros_like, params)
     nu = jax.tree.map(jnp.zeros_like, params)
     out, losses = {}, []
     for i in range(steps):
-        loss, grads = loss_and_grads(params, batch_of(i), sz, quant, rows)
+        loss, grads = loss_and_grads(ref, params, batch_of(i), sz, quant,
+                                     rows)
         losses.append(loss)
         if i == 0:
-            out["grad_norms"] = leaf_norms(grads)
+            out["grad_norms"] = leaf_norms(grads, ref.by_leaf)
             if other_first_gradient is not None:
                 out["grad_diff_norms"] = leaf_diff_norms(
-                    grads, other_first_gradient, jnp.float32(other_scale))
+                    grads, other_first_gradient, jnp.float32(other_scale),
+                    ref.by_leaf)
             if keep_first_gradient:
                 first = jax.tree.map(lambda g: g.astype(jnp.bfloat16), grads)
         if not frozen:
             params, mu, nu = adamw_update(params, mu, nu, grads,
                                           jnp.float32(i + 1))
         del grads
-    out["change_norms"] = leaf_diff_norms(params, seeded_params(seed, sz),
-                                          jnp.float32(1.0))
+    out["change_norms"] = leaf_diff_norms(
+        params, ref.seeded_params(seed, sz), jnp.float32(1.0), ref.by_leaf)
     out = {name: {k: float(v) for k, v in norms.items()}
            for name, norms in out.items()}
     out["losses"] = losses

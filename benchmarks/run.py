@@ -239,7 +239,7 @@ def deploy_lm(run: dict, lm_class=None) -> str:
     serve.deployment(
         lm_class or BenchLM, name="lm",
         ray_actor_options={"num_tpus": run["chips"]}).deploy(
-            run["config"], mix, seed)
+            run["config"], mix, seed, run["family"])
     url = f"http://{serve.get_http_address()}/lm"
     mark("replica")         # TPU worker, TPU client, weights from the seed
     lengths = mix["prompt_lengths"]
@@ -334,8 +334,8 @@ def drive_train(run: dict, train_func=None) -> dict:
     try:
         result = trainer.run(train_func or worker.train_func,
                              {k: run[k] for k in (
-                                 "config", "traffic", "seed", "seconds",
-                                 "trace", "control", "describe")},
+                                 "config", "family", "traffic", "seed",
+                                 "seconds", "trace", "control", "describe")},
                              callbacks=[Collect()])[0]
     finally:
         trainer.shutdown()
@@ -408,6 +408,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
            "control": control, "describe": describe, "platform": platform,
            "config": loader.load_config(bench, cell["config"]),
            "traffic": loader.load_traffic(bench, cell["traffic"])}
+    run["family"] = loader.find_family(bench, run["config"])
     with session(run["chips"]):
         if run["traffic"]["kind"] == "serve":
             obs = drive_serve(run, lm_class)

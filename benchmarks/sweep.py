@@ -64,6 +64,7 @@ def main() -> int:
     run = {"seed": args.seed, "chips": int(cell["chips"]),
            "config": loader.load_config(bench, cell["config"]),
            "traffic": loader.load_traffic(bench, cell["traffic"])}
+    run["family"] = loader.find_family(bench, run["config"])
     vocab = int(run["config"]["vocab_size"])
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     from ray_tpu import serve

@@ -21,8 +21,14 @@ WINDOW = "bench.window"
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"        # one event for each run of a program
-# the benchmark's own host annotations, which label idle gaps
+# host annotations that label idle gaps: the benchmark's own, and the
+# program's (``ray_tpu.util.phases.phase``), which lie inside them
 HOST_SPANS = ("engine.step", "engine.prefill", "train.step")
+PROGRAM_SPANS = ("serve.", "train.")        # by the start of their names
+
+
+def labels_gaps(name: str) -> bool:
+    return name in HOST_SPANS or name.startswith(PROGRAM_SPANS)
 
 
 def merge(intervals: Iterable[Tuple[float, float]]) -> List[List[float]]:
@@ -105,29 +111,37 @@ def top_ops(events: Iterable[Event], lo: float, hi: float,
 
 
 def what_host_did(thread: List[Event], starts: List[float],
-                  moment: float, look_back: int = 400) -> str:
-    """The benchmark's own span that covers a moment on one host thread
-    and the innermost event inside it (``engine.step: np.asarray``), or
-    "" where none of its spans does. A thread's events nest, so the last
-    one to start before the moment that still covers it is the
+                  moment: float, look_back: int = 400) -> Tuple[float, str]:
+    """(start, label) of the innermost span that covers a moment on one
+    host thread, or (-inf, "") where none does. A span of the program
+    labels the gap by its name (``serve.engine.wait``); one of the
+    benchmark's own, which is coarser, adds the innermost event inside
+    it (``engine.prefill: PjitFunction``). A thread's events nest, so the
+    last one to start before the moment that still covers it is the
     innermost."""
     innermost = None
     i = bisect.bisect_right(starts, moment) - 1
     for name, start, dur in reversed(thread[max(0, i - look_back):i + 1]):
         if moment < start + dur:
             if name in HOST_SPANS:
-                return name if innermost is None else f"{name}: {innermost}"
+                return start, (name if innermost is None
+                               else f"{name}: {innermost}")
+            if name.startswith(PROGRAM_SPANS):
+                return start, name
             innermost = innermost or short_name(name)
-    return ""
+    return float("-inf"), ""
 
 
 def idle_gaps(device: Iterable[Event], threads: Iterable[List[Event]],
               lo: float, hi: float, k: int = 10,
               short_ns: float = 2000.0) -> List[List]:
     """[label, seconds]: idle time of the device in [lo, hi), summed by
-    what the host was doing at each gap's middle: the benchmark's span
-    there and the innermost host event inside it (``between host spans``
-    where no span covers it). Gaps under ``short_ns`` are the launches
+    what the host was doing at each gap's middle: of the spans that
+    cover it on any thread the one that started last, which is the
+    innermost (``between host spans`` where none covers it: the loop's
+    ``serve.step`` waits on one thread while the engine's
+    ``serve.engine.wait`` runs on another). Gaps under ``short_ns`` are
+    the launches
     between one operation and the next and are summed apart."""
     busy = merge((s, s + d) for _, s, d in clip(device, lo, hi))
     edges = [lo] + [x for iv in busy for x in iv] + [hi]
@@ -140,10 +154,10 @@ def idle_gaps(device: Iterable[Event], threads: Iterable[List[Event]],
         if e - s < short_ns:
             label = f"between operations, under {short_ns / 1e3:g} us each"
         else:
-            found = [what_host_did(t, st, (s + e) / 2.0)
-                     for t, st in zip(threads, starts)]
-            label = next((f"in {f}" for f in found if f),
-                         "between host spans")
+            _, found = max((what_host_did(t, st, (s + e) / 2.0)
+                            for t, st in zip(threads, starts)),
+                           default=(0.0, ""))
+            label = f"in {found}" if found else "between host spans"
         total[label] = total.get(label, 0.0) + (e - s)
     ranked = sorted(total.items(), key=lambda kv: kv[1], reverse=True)
     return [[label, ns / 1e9] for label, ns in ranked[:k]]
@@ -180,7 +194,7 @@ def reduce_trace(planes: Dict[str, Dict[str, List[Event]]]) -> Dict:
     # the host threads on which the benchmark's own spans lie
     host = [evs for name, lines in planes.items()
             if not name.startswith(DEVICE_PLANE) for evs in lines.values()
-            if any(e[0] in HOST_SPANS for e in evs)]
+            if any(labels_gaps(e[0]) for e in evs)]
     busiest = max(devices.values(), key=lambda evs: busy_ns(evs, lo, hi))
     return {
         "window_s": dur / 1e9,

@@ -4,9 +4,12 @@ and checking around the program's own calls. The driver (``run.py``)
 never imports jax; every device fact comes from here.
 
 The program is driven through its own entry points and classes
-(``serve.DecodeScheduler``, ``serve.JaxSlotEngine``,
-``models.make_train_step``); the benchmark wraps their calls with host
-clocks and ``jax.profiler.TraceAnnotation``s and changes nothing inside.
+(``serve.DecodeScheduler`` here; the slot engine and the train step
+through the family's ``program.py``); the benchmark wraps their calls
+with host clocks and ``jax.profiler.TraceAnnotation``s and changes
+nothing inside. Which model runs is data: ``run["family"]`` names the
+directory whose ``reference.py`` and ``program.py`` this module drives,
+and nothing here knows a block.
 """
 
 from __future__ import annotations
@@ -17,6 +20,8 @@ import gc
 import shutil
 import tempfile
 import time
+
+from benchmarks import loader
 
 MOSAIC_CALL = "tpu_custom_call"
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
@@ -55,21 +60,6 @@ def memory_peak_bytes() -> int:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in jax.local_devices()]
     return int(max(peaks))
-
-
-def program_config(config: dict, max_seq: int):
-    """The program's own ``TransformerConfig`` for a configuration file
-    (published key names)."""
-    import jax.numpy as jnp
-
-    from benchmarks.reference import sizes_of
-    from ray_tpu.models import TransformerConfig
-
-    sz = sizes_of(config)       # refuses what the block cannot express
-    return TransformerConfig(
-        vocab=sz.vocab, d_model=sz.d_model, n_heads=sz.n_heads,
-        n_layers=sz.n_layers, d_ff=sz.d_ff, max_seq=int(max_seq),
-        rope_theta=sz.rope_theta, dtype=jnp.dtype(sz.dtype).type)
 
 
 class Tracer:
@@ -162,24 +152,24 @@ class BenchLM:
     replica's own stamps; side routes: ``GET /stats``, ``GET /programs``,
     ``POST /trace``, ``POST /check``."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int):
+    def __init__(self, config: dict, traffic: dict, seed: int,
+                 family: dict):
         setup_jax()
-        from benchmarks import reference
         from ray_tpu import serve
 
         self.config, self.traffic, self.seed = config, traffic, int(seed)
-        self.sz = reference.sizes_of(config)
-        self.cfg = program_config(config, traffic["slot_len"])
-        self.params = reference.seeded_params(self.seed, self.sz)
+        self.ref = loader.family_module(family, "reference")
+        self.program = loader.family_module(family, "program")
+        self.sz = self.ref.sizes_of(config)
+        self.cfg = self.program.program_config(config, traffic["slot_len"])
+        self.params = self.ref.seeded_params(self.seed, self.sz)
         self.engine = self.make_engine()
         self.decode_scheduler = serve.DecodeScheduler(self.engine)
         self.tracer = Tracer()
         self.called_at = {}
 
     def make_engine(self):
-        from ray_tpu import serve
-
-        return TimedEngine(serve.JaxSlotEngine(
+        return TimedEngine(self.program.make_engine(
             self.params, self.cfg, slots=int(self.traffic["slots"]),
             max_len=int(self.traffic["slot_len"])))
 
@@ -227,22 +217,13 @@ class BenchLM:
                 "trace": self.tracer.reduce(describe == "1")}
 
     def programs(self) -> dict:
-        """Mosaic custom calls in each prefill program the mix uses (the
-        same jit the engine calls; a cache hit after the warm-up)."""
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.models import decode
-
-        cache = jax.eval_shape(lambda: decode.init_slot_cache(
-            self.cfg, self.engine.slots, self.engine.max_len))
-        out = {}
-        for length in self.traffic["prompt_lengths"]:
-            text = decode.slot_prefill.lower(
-                self.params, jax.ShapeDtypeStruct((1, length), jnp.int32),
-                cache, jnp.int32(0), self.cfg).compile().as_text()
-            out[str(length)] = text.count(MOSAIC_CALL)
-        return {"prefill_mosaic_calls": out}
+        """Mosaic custom calls in each prefill program the mix uses."""
+        texts = self.program.prefill_programs(
+            self.params, self.cfg, self.engine.slots, self.engine.max_len,
+            self.traffic["prompt_lengths"])
+        return {"prefill_mosaic_calls": {
+            str(length): text.count(MOSAIC_CALL)
+            for length, text in texts.items()}}
 
     def check(self, requests: list, control=None) -> dict:
         """Free the program's state, then run the plain reference once
@@ -256,12 +237,12 @@ class BenchLM:
         served, controlled = [], {}
         for r in requests:
             gaps = reference.served_logit_gaps(
-                params, r["prompt"], r["tokens"], self.sz)
+                self.ref, params, r["prompt"], r["tokens"], self.sz)
             served.append(max(gaps["served"]))
         # calibration only: the precisions below, at the same positions
         for quant in (control.split(",") if control else ()):
             controlled[quant] = [max(reference.served_logit_gaps(
-                params, r["prompt"], r["tokens"], self.sz,
+                self.ref, params, r["prompt"], r["tokens"], self.sz,
                 quant=quant)["control"]) for r in requests]
         return {"served_gaps": served, "control_gaps": controlled,
                 "tokens": sum(len(r["tokens"]) for r in requests),
@@ -280,21 +261,21 @@ class TrainCell:
         import jax
         import jax.numpy as jnp
 
-        from benchmarks import reference, traffic as traffic_mod
-        from ray_tpu.models import ParallelConfig, make_train_step
+        from benchmarks import traffic as traffic_mod
 
         self.run = run
         mix, config = run["traffic"], run["config"]
         self.seed = int(run["seed"])
-        self.sz = reference.sizes_of(config)
-        cfg = program_config(config, mix["seq"])
-        step, optimizer = make_train_step(
-            cfg, ParallelConfig(remat=bool(mix["remat"])))
-        self.params = reference.seeded_params(self.seed, self.sz)
+        self.ref = loader.family_module(run["family"], "reference")
+        program = loader.family_module(run["family"], "program")
+        self.sz = self.ref.sizes_of(config)
+        step, optimizer = program.make_train_step(
+            program.program_config(config, mix["seq"]), mix)
+        self.params = self.ref.seeded_params(self.seed, self.sz)
         self.opt_state = jax.jit(optimizer.init)(self.params)
         make_batch = jax.jit(functools.partial(
             traffic_mod.train_batch, self.seed, batch=int(mix["batch"]),
-            seq=int(mix["seq"]), vocab=self.sz.vocab))
+            seq=int(mix["seq"]), vocab=int(config["vocab_size"])))
         self.batch_of = lambda i: make_batch(step=jnp.int32(i))
         self.compiled = step.lower(self.params, self.opt_state,
                                    self.batch_of(0)).compile()
@@ -348,11 +329,13 @@ class TrainCell:
                 mu = self.first_moment()
                 grad_norms = {
                     k: float(v) / (1.0 - reference.ADAMW["b1"])
-                    for k, v in reference.leaf_norms(mu).items()}
+                    for k, v in reference.leaf_norms(
+                        mu, self.ref.by_leaf).items()}
                 self.first_mu = jax.device_get(mu)
                 del mu      # or it stays on the device through the window
         change = reference.leaf_diff_norms(
-            self.params, reference.seeded_params(self.seed, self.sz), 1.0)
+            self.params, self.ref.seeded_params(self.seed, self.sz),
+            1.0, self.ref.by_leaf)
         return {"losses": losses, "grad_norms": grad_norms,
                 "change_norms": {k: float(v) for k, v in change.items()}}
 
@@ -403,8 +386,8 @@ def train_func(run: dict, cell_class=TrainCell) -> dict:
     cell.release()
     t0 = time.perf_counter()
     control = run.get("control")
-    follow = functools.partial(reference.train_reference, cell.seed,
-                               cell.sz, batch_of, n_check)
+    follow = functools.partial(reference.train_reference, cell.ref,
+                               cell.seed, cell.sz, batch_of, n_check)
     want = follow(other_first_gradient=cell.first_mu,
                   other_scale=1.0 / (1.0 - reference.ADAMW["b1"]),
                   keep_first_gradient=bool(control))

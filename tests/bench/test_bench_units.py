@@ -25,6 +25,12 @@ def bench():
     return loader.load_benchmark()
 
 
+@pytest.fixture(scope="module")
+def ouro(bench):
+    """Where the ``ouro`` family's files are, as ``run["family"]``."""
+    return loader.find_family(bench, {"model_type": "ouro"})
+
+
 # ------------------------------------------------------- BENCHMARK.json
 
 def test_benchmark_json_keeps_the_contract(bench):
@@ -94,13 +100,16 @@ def test_configuration_files_keep_the_published_sizes(bench):
         changed = {k for k, v in published.items() if config.get(k) != v}
         assert changed <= set(entry["reduced"]), changed
         assert not set(entry["reduced"]) & set(widths)
-        assert peaks.n_params(config) > 0
+        costs = loader.family_module(loader.find_family(bench, config),
+                                     "costs")
+        assert costs.n_params(config) > 0
 
 
 @pytest.mark.parametrize("config,millions", [
     ("ouro-2.6b", 2567), ("ouro-2.6b-d12", 717)])
-def test_parameter_counts(bench, config, millions):
-    assert round(peaks.n_params(loader.load_config(bench, config)) / 1e6) \
+def test_parameter_counts(bench, ouro, config, millions):
+    costs = loader.family_module(ouro, "costs")
+    assert round(costs.n_params(loader.load_config(bench, config)) / 1e6) \
         == millions
 
 
@@ -238,6 +247,42 @@ def test_busy_union_clipping_and_idle_share():
         "between operations, under 2 us each": pytest.approx(1e-6)}
 
 
+def test_idle_gaps_take_the_programs_own_span_names():
+    """The program's ``serve.*`` spans lie inside the benchmark's and on
+    other threads beside them: a gap is labelled by the innermost span
+    over its middle, whichever thread it is on. Device busy [0,2),
+    [6,7), [11,12), [16,20): gaps with middles 4, 9 and 14."""
+    us = 1000.0
+    op = "%fusion.1 = bf16[8] fusion(bf16[8] %p)"
+    planes = {
+        "/device:TPU:0": {
+            "XLA Modules": [("jit_step(1)", 0.0, 20 * us)],
+            "XLA Ops": [(op, 0.0, 2 * us), (op, 6 * us, 1 * us),
+                        (op, 11 * us, 1 * us), (op, 16 * us, 4 * us)]},
+        "/host:CPU": {
+            "tracer": [(trace.WINDOW, 0.0, 20 * us)],
+            # the scheduler's loop: a step, then an admission's prefill
+            "loop": [("serve.step", 1 * us, 6.5 * us),
+                     ("serve.admit", 7.6 * us, 8 * us),
+                     ("serve.prefill", 7.8 * us, 7 * us)],
+            # the executor: the benchmark's wrapper around each call of
+            # the engine, which times its own phases inside the step
+            "executor": [("engine.step", 1.5 * us, 5.5 * us),
+                         ("serve.engine.dispatch", 1.6 * us, 0.4 * us),
+                         ("serve.engine.wait", 2 * us, 4.5 * us),
+                         ("np.asarray(jax.Array)", 2.1 * us, 4 * us),
+                         ("engine.prefill", 8 * us, 2.5 * us),
+                         ("PjitFunction(slot_prefill)", 8.2 * us, 2 * us)]},
+    }
+    gaps = dict(map(tuple, trace.reduce_trace(planes)["idle_gaps"]))
+    assert gaps == {
+        "in serve.engine.wait": pytest.approx(4e-6),
+        "in engine.prefill: PjitFunction": pytest.approx(4e-6),
+        "in serve.prefill": pytest.approx(4e-6)}
+    assert trace.labels_gaps("train.report") and trace.labels_gaps(
+        "engine.step") and not trace.labels_gaps("observe.step")
+
+
 def test_a_trace_without_its_marks_is_refused():
     planes = hand_built_trace()
     del planes["/device:TPU:0"]
@@ -279,21 +324,24 @@ def test_threads_of_one_name_stay_apart(tmp_path, monkeypatch):
 
 # ---------------------------------------------------- FLOPs and bytes
 
-SMALL = {"hidden_size": 8, "intermediate_size": 16, "head_dim": 4,
+SMALL = {"model_type": "ouro", "hidden_size": 8, "intermediate_size": 16, "head_dim": 4,
          "num_attention_heads": 2, "num_hidden_layers": 3, "vocab_size": 10}
 
 
-def test_flop_counts_against_a_hand_worked_shape():
+def test_flop_counts_against_a_hand_worked_shape(ouro):
+    costs = loader.family_module(ouro, "costs")
     # a layer: 4*8*8 + 3*8*16 + 2*8 = 656; embedding 80; final norm 8
-    assert peaks.n_params(SMALL) == 80 + 3 * 656 + 8 == 2056
+    assert costs.n_params(SMALL) == 80 + 3 * 656 + 8 == 2056
     # attention: 4 * (2 heads * 4) * 3 layers = 96 FLOPs a (query, key) pair
-    assert peaks.attention_flops(SMALL, 10) == 960
+    assert costs.attention_flops(SMALL, 10) == 960
     # a 4-token prefill: 2*(2056-80)*4 for the layers, 2*80 for the one
     # row of logits, 10 causal pairs
-    assert peaks.forward_flops(SMALL, 4, 10, logit_rows=1) == \
+    assert costs.forward_flops(SMALL, 4, 10, logit_rows=1) == \
         2 * 1976 * 4 + 160 + 960
     # a train step of 2 x 4 tokens: 6*N*8 plus 3x the forward's attention
-    assert peaks.train_flops(SMALL, 2, 4) == 6 * 2056 * 8 + 3 * 96 * 20
+    assert costs.train_flops(SMALL, 2, 4) == 6 * 2056 * 8 + 3 * 96 * 20
+    assert costs.flash_shape(dict(SMALL, torch_dtype="bfloat16"),
+                             {"batch": 2, "seq": 4}) == (2, 4, 2, 4, 2)
     fwd = peaks.flash_fwd_cost(1, 4, 2, 4)
     assert fwd["flops"] == 4 * 4 * (2 * 10)
     assert fwd["bytes"] == 4 * (4 * 2 * 4) * 2 + 4 * 2 * 4
@@ -307,19 +355,21 @@ def test_flop_counts_against_a_hand_worked_shape():
         peaks.peaks_of("TPU v9")
 
 
-def test_mfu_reads_the_traced_slice_alone():
+def test_mfu_reads_the_traced_slice_alone(ouro):
+    costs = loader.family_module(ouro, "costs")
     obs = {"trace": {"window_s": 1.0, "busy_s": 0.5, "slice": [10.0, 11.0]},
            "device": {"kind": "TPU v5 lite"},
-           "run": {"config": SMALL, "traffic": {"batch": 2, "seq": 4}},
+           "run": {"config": SMALL, "family": ouro,
+                   "traffic": {"batch": 2, "seq": 4}},
            "steps": [[9.0, 9.5, 2, 8], [10.2, 10.4, 2, 8]],
            "prefills": [[10.5, 10.6, 4], [11.5, 11.6, 4]]}
-    flops = peaks.forward_flops(SMALL, 2, 8, logit_rows=2) \
-        + peaks.forward_flops(SMALL, 4, 10, logit_rows=1)
+    flops = costs.forward_flops(SMALL, 2, 8, logit_rows=2) \
+        + costs.forward_flops(SMALL, 4, 10, logit_rows=1)
     assert readers.serve_mfu_pct(obs) == pytest.approx(
         100.0 * flops / 197e12)
     obs["steps"] = [[10.2, 10.4]]
     assert readers.train_mfu_pct(obs) == pytest.approx(
-        100.0 * peaks.train_flops(SMALL, 2, 4) / 197e12)
+        100.0 * costs.train_flops(SMALL, 2, 4) / 197e12)
     obs["steps"] = []
     assert readers.train_mfu_pct(obs) is None
 
@@ -359,16 +409,18 @@ def test_train_rate_counts_the_step_under_way_at_the_close():
 
 # ------------------------------------- the reference and its control
 
-TINY = {"head_dim": 16, "hidden_act": "silu", "hidden_size": 64,
-        "intermediate_size": 128, "num_attention_heads": 4,
-        "num_hidden_layers": 2, "num_key_value_heads": 4,
-        "rms_norm_eps": 1e-6, "rope_scaling": None, "rope_theta": 10000,
+TINY = {"model_type": "ouro", "head_dim": 16, "hidden_act": "silu",
+        "hidden_size": 64, "intermediate_size": 128,
+        "num_attention_heads": 4, "num_hidden_layers": 2,
+        "num_key_value_heads": 4, "rms_norm_eps": 1e-6,
+        "rope_scaling": None, "rope_theta": 10000,
         "tie_word_embeddings": True, "total_ut_steps": 1,
         "use_sliding_window": False, "vocab_size": 512,
         "torch_dtype": "float32"}
 
 
-def test_the_reference_agrees_with_the_program_and_its_control_does_not():
+def test_the_reference_agrees_with_the_program_and_its_control_does_not(
+        ouro):
     """Serving, at a size a test can hold: the program's own greedy
     decode lies within rounding of the reference's best logit at every
     token; the int8 control does not, and neither does an altered
@@ -378,18 +430,19 @@ def test_the_reference_agrees_with_the_program_and_its_control_does_not():
     they decide the logits, as they do at the published width.)"""
     import jax.numpy as jnp
 
-    from benchmarks import reference, worker
+    from benchmarks import reference
     from ray_tpu.models import decode
 
-    sz = reference.sizes_of(TINY)
-    params = reference.seeded_params(2**31 + 3, sz)
+    family = loader.family_module(ouro, "reference")
+    sz = family.sizes_of(TINY)
+    params = family.seeded_params(2**31 + 3, sz)
     params["layers"] = {k: v * 8 if v.ndim == 3 else v
                         for k, v in params["layers"].items()}
-    cfg = worker.program_config(TINY, 256)
+    cfg = loader.family_module(ouro, "program").program_config(TINY, 256)
     prompt = traffic.prompt_tokens(1, 0, 16, sz.vocab)
     served = decode.generate(params, jnp.asarray([prompt], jnp.int32), cfg,
                              steps=200, max_len=256)[0].tolist()
-    gaps = reference.served_logit_gaps(params, prompt, served, sz,
+    gaps = reference.served_logit_gaps(family, params, prompt, served, sz,
                                        quant="int8")
     assert len(gaps["served"]) == len(gaps["control"]) == 200
     limit = 1e-3
@@ -398,23 +451,25 @@ def test_the_reference_agrees_with_the_program_and_its_control_does_not():
     wrong = list(served)
     wrong[5] = (wrong[5] + 1) % sz.vocab
     assert max(reference.served_logit_gaps(
-        params, prompt, wrong, sz)["served"]) > 3 * limit
+        family, params, prompt, wrong, sz)["served"]) > 3 * limit
 
 
-def test_the_training_control_and_faults_fail_a_number():
+def test_the_training_control_and_faults_fail_a_number(ouro):
     from benchmarks import reference
 
-    sz = reference.sizes_of(TINY)
+    family = loader.family_module(ouro, "reference")
+    sz = family.sizes_of(TINY)
     batch_of = lambda i: traffic.train_batch(5, i, 4, 32, sz.vocab)  # noqa
-    want = reference.train_reference(5, sz, batch_of)
+    want = reference.train_reference(family, 5, sz, batch_of)
     assert want["losses"][0] == pytest.approx(6.24, abs=0.1)   # ln 512
     same = reference.compare_training(want, want)
     assert max(same.values()) == 0.0
     first = reference.train_reference(
-        5, sz, batch_of, steps=1, keep_first_gradient=True)["first_gradient"]
+        family, 5, sz, batch_of, steps=1,
+        keep_first_gradient=True)["first_gradient"]
 
     def read(**fault):
-        got = reference.train_reference(5, sz, batch_of,
+        got = reference.train_reference(family, 5, sz, batch_of,
                                         other_first_gradient=first, **fault)
         return reference.compare_training(got, want, got["grad_diff_norms"])
 
@@ -430,9 +485,8 @@ def test_the_training_control_and_faults_fail_a_number():
     assert read(frozen=True)["change_norm_gap"] == pytest.approx(1.0)
 
 
-def test_what_the_block_cannot_express_is_refused():
-    from benchmarks import reference
-
+def test_what_the_block_cannot_express_is_refused(ouro):
+    reference = loader.family_module(ouro, "reference")
     for key, value in (("num_key_value_heads", 2), ("total_ut_steps", 4),
                        ("tie_word_embeddings", False),
                        ("hidden_act", "gelu")):

@@ -6,7 +6,6 @@ and in the traced line of the tiny closed-loop cell.
 """
 
 import asyncio
-import json
 import os
 
 import pytest
@@ -178,8 +177,11 @@ def test_a_serving_reader_takes_the_windows_delta(bench, name, want):
 
 
 def training_obs(op_totals):
-    return {"run": {"config": {"num_attention_heads": 2, "head_dim": 4,
-                               "torch_dtype": "bfloat16"},
+    config = {"model_type": "ouro", "num_attention_heads": 2, "head_dim": 4,
+              "torch_dtype": "bfloat16"}
+    return {"run": {"config": config,
+                    "family": loader.find_family(loader.load_benchmark(),
+                                                 config),
                     "traffic": {"batch": 3, "seq": 8}},
             "device": {"kind": "TPU v5 lite"},
             "trace": {"window_s": 1.0, "op_totals": op_totals}}
@@ -219,6 +221,44 @@ def test_the_roofline_readers_against_a_hand_worked_shape(bench):
         reader(bench, ROOFLINES[0])(dict(obs, device={"kind": "cpu"}))
 
 
+def test_a_tpu_line_must_carry_the_rooflines_and_a_cpu_line_need_not(bench):
+    """``check_line`` lets a kernel's metric be absent where the device
+    is no TPU (its trace holds no Mosaic call) and nowhere else."""
+    cell = "ouro-2.6b-d12.train-2k"
+    kernels = {"local_step/flash_fwd.15": [2e-6, 4],
+               "local_step/flash_bwd_dkv.11": [3e-6, 3],
+               "local_step/flash_bwd_dq.11": [2e-6, 3]}
+
+    def line_of(platform, op_totals):
+        obs = training_obs(op_totals)
+        obs["run"]["config"].update(hidden_size=8, intermediate_size=16,
+                                    num_hidden_layers=3, vocab_size=10)
+        obs["trace"].update(busy_s=0.9, slice=[10.0, 11.0])
+        obs["steps"] = [[10.1, 10.4], [10.4, 10.7]]
+        return {"correct": True, "attempted": 2, "failed": 0,
+                "metrics": loader.read_metrics(bench, cell, True, obs),
+                "device": {"platform": platform, "kind": "any", "count": 1,
+                           "memory_peak_bytes": 1, "busy_s": 0.9,
+                           "window_s": 1.0},
+                "breakdown": {"device_ops": [], "idle_gaps": []},
+                "compared": {}}
+
+    on_tpu = line_of("tpu", kernels)
+    assert set(ROOFLINES) <= set(on_tpu["metrics"])
+    check_line(bench, cell, on_tpu, True)
+    without = line_of("tpu", {"local_step/fusion.3": [9.0, 9]})
+    assert not set(ROOFLINES) & set(without["metrics"])
+    with pytest.raises(AssertionError):
+        check_line(bench, cell, without, True)
+    check_line(bench, cell, dict(without, device=dict(
+        without["device"], platform="cpu")), True)
+    # only a kernel's metric may be absent, also off the TPU
+    del without["metrics"]["train_step_ms"]
+    with pytest.raises(AssertionError):
+        check_line(bench, cell, dict(without, device=dict(
+            without["device"], platform="cpu")), True)
+
+
 @pytest.mark.parametrize("instruction,kernel", [
     ("flash_fwd.15", "flash_fwd"), ("flash_fwd", "flash_fwd"),
     ("flash_bwd_dq.11", "flash_bwd_dq"),
@@ -231,23 +271,25 @@ def test_a_kernel_is_found_by_its_own_name(instruction, kernel):
         "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")) == kernel
 
 
-def test_the_put_off_rooflines_wait_beside_their_readers(bench):
-    with open(os.path.join(bench["root"], "benchmarks", "put_off",
-                           "kernel-rooflines.json")) as f:
-        put_off = json.load(f)
-    assert [m["name"] for m in put_off["per_layer"]] == list(ROOFLINES)
-    entered = {m["name"] for m in bench["per_layer"]}
-    assert set(SERVING) <= entered and not entered & set(ROOFLINES)
-    for m in put_off["per_layer"]:
+def test_the_rooflines_are_entered_beside_their_readers(bench):
+    """PR 25 put them off (a CPU trace holds no Mosaic call, and the
+    traced CPU run asked for every metric); PR 27 entered them."""
+    assert not os.path.exists(os.path.join(
+        bench["root"], "benchmarks", "put_off", "kernel-rooflines.json"))
+    entered = {m["name"]: m for m in bench["per_layer"]}
+    assert set(SERVING) | set(ROOFLINES) <= set(entered)
+    for name in ROOFLINES:
+        m = entered[name]
         assert m["workloads"] == ["ouro-2.6b-d12.train-2k"]
         assert m["layer"] == "kernels: ops/attention.py"
-        assert m["moves"] == "train_tokens_per_s"
-        assert callable(reader(bench, m["name"]))
-    # merged as a later PR will enter them, the cell reports them traced
-    merged = dict(bench, per_layer=bench["per_layer"] + put_off["per_layer"])
+        assert (m["moves"], m["unit"], m["source"]) == (
+            "train_tokens_per_s", "%", "device_trace")
+        assert callable(reader(bench, name))
     names = [m["name"] for m in loader.cell_metrics(
-        merged, "ouro-2.6b-d12.train-2k", True)]
+        bench, "ouro-2.6b-d12.train-2k", True)]
     assert names[-2:] == list(ROOFLINES)
+    assert not set(ROOFLINES) & {m["name"] for m in loader.cell_metrics(
+        bench, "ouro-2.6b.decode-closed", True)}
 
 
 # -------------------------------------------- in the tiny cell's line
