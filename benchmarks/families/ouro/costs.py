@@ -1,13 +1,16 @@
 """What the ``ouro`` family's algorithm needs, computed from shapes and
 never read from the program: parameters, FLOPs of a forward pass and of
-a train step, and the shape its attention kernels are priced at. No jax:
-the driver's process reads it. Every parameter is active for every
-token, and every layer attends with ``heads x head_dim``.
+a train step, the shape its attention kernels are priced at, and the
+bytes a decode step must move. No jax: the driver's process reads it.
+Every parameter is active for every token, and every layer attends with
+``heads x head_dim``.
 """
 
 from __future__ import annotations
 
 ITEMSIZE = {"bfloat16": 2, "float32": 4}
+# the program that makes a decode step, as the device trace names it
+DECODE_PROGRAM = "slot_decode_step"
 
 
 def n_params(config: dict) -> int:
@@ -55,3 +58,20 @@ def flash_shape(config: dict, mix: dict) -> tuple:
     return (int(mix["batch"]), int(mix["seq"]),
             int(config["num_attention_heads"]), int(config["head_dim"]),
             ITEMSIZE[config["torch_dtype"]])
+
+
+def decode_step_bytes(config: dict, rows: int, positions: int,
+                      counts: dict) -> float:
+    """The bytes one decode step of ``rows`` active rows must move,
+    whatever implements it: every parameter read once (the tied head is
+    read whole; the ``rows`` rows of the embedding it is tied to are not
+    counted again), the K and V of the ``positions`` attended read, and
+    those of the ``rows`` new tokens written, in every layer. What a
+    program copies beyond that (a cache sliced and written back whole)
+    is waste and is not counted. ``counts`` (the program's own counters
+    a step) prices nothing here: every parameter is read every step."""
+    item = ITEMSIZE[config["torch_dtype"]]
+    kv_row = (int(config["num_hidden_layers"])
+              * int(config["num_attention_heads"])
+              * int(config["head_dim"]) * 2 * item)
+    return float(n_params(config) * item + (positions + rows) * kv_row)

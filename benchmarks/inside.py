@@ -1,9 +1,10 @@
 """Readers of what the program records about itself: the phase sums of
 ``DecodeScheduler.stats()["phases"]`` (``{name: [count, seconds]}``,
 cumulative, made by ``ray_tpu.util.phases.phase`` inside the scheduler
-and the slot engine) and the flash kernels under their own names in the
-device trace. A program that records neither (the commits before PR 25)
-reads as None, so its line still prints.
+and the slot engine), the flash kernels under their own names in the
+device trace, and the decode program's device time there. A program that
+records neither (the commits before PR 25) reads as None, so its line
+still prints.
 
 Phase sums are deltas of ``obs["decode_after"]`` less
 ``obs["decode_before"]``: the interval of ``decode_occupancy_pct``.
@@ -14,7 +15,7 @@ from __future__ import annotations
 import re
 
 from benchmarks import peaks
-from benchmarks.readers import family_costs
+from benchmarks.readers import family_costs, started_in_slice, traced
 
 ENGINE_HOST = ("serve.engine.check", "serve.engine.put",
                "serve.engine.dispatch", "serve.engine.read")
@@ -23,6 +24,9 @@ ENGINE_WAIT = ("serve.engine.wait",)
 SCHEDULER_OVERHEAD = ("serve.hop", "serve.emit")
 ADMIT_STALL = ("serve.admit_stall",)
 LOOP = ("serve.admit", "serve.step", "serve.emit")
+# whatever else an engine records under this prefix is a counter of its
+# own (``phase_add(name, n)``), which its family's costs may price
+ENGINE = "serve.engine."
 
 TRAIN_PROGRAM = "local_step"
 
@@ -94,6 +98,46 @@ def roofline_pct(obs, kernels, counted, cost_of):
     least = peaks.roofline_seconds(cost_of(*shape),
                                    peaks.peaks_of(obs["device"]["kind"]))
     return 100.0 * calls * least["seconds"] / seconds
+
+
+def engine_counts(obs):
+    """name -> the window's mean a decode step of each counter the
+    engine keeps under ``serve.engine.`` beside its timed phases (none
+    in ``JaxSlotEngine``; a sparse-expert engine counts the experts it
+    touched)."""
+    steps, timed = steps_in_window(obs), ENGINE_HOST + ENGINE_WAIT
+    if steps <= 0:
+        return {}
+    return {name: phase_seconds(obs, (name,)) / steps
+            for name in obs["decode_after"].get("phases") or {}
+            if name.startswith(ENGINE) and name not in timed}
+
+
+def decode_roofline_pct(obs):
+    """The least seconds the chip could take for the decode steps begun
+    in the traced slice (each step's bytes by the family's
+    ``costs.decode_step_bytes`` over the memory bandwidth, or its FLOPs
+    over the peak where that is longer) over the device seconds in the
+    slice in which an operation of the family's decode program
+    (``costs.DECODE_PROGRAM``) ran. A family without the two names, or
+    a trace that does not name the program's operations, reads None."""
+    trace, costs = traced(obs), family_costs(obs)
+    program = getattr(costs, "DECODE_PROGRAM", None)
+    if trace is None or program is None or not hasattr(
+            costs, "decode_step_bytes"):
+        return None
+    seconds = trace["program_seconds"].get(program, 0.0)
+    steps = started_in_slice(obs["steps"], trace)
+    if seconds <= 0.0 or not steps:
+        return None
+    config, chip = obs["run"]["config"], peaks.peaks_of(obs["device"]["kind"])
+    counts = engine_counts(obs)
+    least = sum(peaks.roofline_seconds(
+        {"flops": costs.forward_flops(config, rows, attended,
+                                      logit_rows=rows),
+         "bytes": costs.decode_step_bytes(config, rows, attended, counts)},
+        chip)["seconds"] for _, _, rows, attended in steps)
+    return 100.0 * least / seconds
 
 
 def decode_device_wait_ms(obs):

@@ -22,7 +22,12 @@ the harness knows of a family is in that directory (``FAMILY_KINDS``):
     costs.py        no jax (the driver reads it): n_params(config),
                     forward_flops(config, tokens, context_sum, logit_rows),
                     train_flops(config, batch, seq),
-                    flash_shape(config, mix)
+                    flash_shape(config, mix) -> batch, seq, heads,
+                    head_dim, itemsize[, K/V heads]; and, for a decode
+                    step priced in bytes (a family without the two
+                    reads no such share): DECODE_PROGRAM, the step's
+                    program as the device trace names it, and
+                    decode_step_bytes(config, rows, positions, counts)
 
 A later PR adds files and entries and edits nothing that is there.
 """

@@ -1,2 +1,2 @@
-"""Seconds in serve.engine.wait (the first slot's read, which waits for the device) per decode step of the window."""
+"""Seconds in serve.engine.wait (the step's one device-to-host fetch: waits for the device, brings the argmax row) per decode step of the window."""
 from benchmarks.inside import decode_device_wait_ms as read  # noqa: F401

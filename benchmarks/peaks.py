@@ -30,28 +30,32 @@ def peaks_of(device_kind: str) -> dict:
 
 
 def flash_fwd_cost(batch: int, seq: int, heads: int, head_dim: int,
-                   itemsize: int = 2) -> dict:
+                   itemsize: int = 2, kv_heads: int = None) -> dict:
     """One causal flash-attention forward call [B, T, H, Dh]: the FLOPs
-    of the unmasked pairs, and the bytes of q, k, v read and o written
-    once (the logsumexp row too, in float32)."""
+    of the unmasked pairs, and the bytes of q read and o written once
+    at the query's ``heads``, of k and v read once at ``kv_heads``
+    (None: the query's), and the logsumexp row in float32."""
+    kv_heads = heads if kv_heads is None else kv_heads
     pairs = batch * heads * seq * (seq + 1) // 2
     flops = 4.0 * head_dim * pairs
-    bytes_ = 4.0 * batch * seq * heads * head_dim * itemsize \
+    bytes_ = 2.0 * batch * seq * (heads + kv_heads) * head_dim * itemsize \
         + 4.0 * batch * heads * seq
     return {"flops": flops, "bytes": bytes_}
 
 
 def flash_bwd_cost(batch: int, seq: int, heads: int, head_dim: int,
-                   itemsize: int = 2) -> dict:
+                   itemsize: int = 2, kv_heads: int = None) -> dict:
     """The two backward kernels together (dK/dV and dQ), by the usual
     accounting: five matrix products of the forward's size are needed
     (S recomputed once, dV, dP, dK, dQ), 2.5 times the forward. That
     each kernel recomputes S and dP for itself is the implementation's
-    cost, not the algorithm's, and is not counted. Bytes: q, k, v, o,
-    do read and dq, dk, dv written once."""
+    cost, not the algorithm's, and is not counted. Bytes: q, o, do read
+    and dq written once at the query's ``heads``; k, v read and dk, dv
+    written once at ``kv_heads`` (None: the query's)."""
+    kv_heads = heads if kv_heads is None else kv_heads
     pairs = batch * heads * seq * (seq + 1) // 2
     flops = 2.0 * head_dim * pairs * 5
-    bytes_ = 8.0 * batch * seq * heads * head_dim * itemsize \
+    bytes_ = 4.0 * batch * seq * (heads + kv_heads) * head_dim * itemsize \
         + 8.0 * batch * heads * seq
     return {"flops": flops, "bytes": bytes_}
 

@@ -131,7 +131,7 @@ def decode_occupancy_pct(obs):
 
 # ---- the traced slice
 
-def _traced(obs):
+def traced(obs):
     trace = obs.get("trace")
     return trace if trace and trace.get("window_s", 0) > 0 else None
 
@@ -146,13 +146,13 @@ def family_costs(obs):
 
 
 def device_idle_pct(obs):
-    trace = _traced(obs)
+    trace = traced(obs)
     if trace is None:
         return None
     return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
 
 
-def _started_in_slice(spans, trace):
+def started_in_slice(spans, trace):
     lo, hi = trace["slice"]
     return [s for s in spans if lo <= s[0] < hi]
 
@@ -160,14 +160,14 @@ def _started_in_slice(spans, trace):
 def serve_mfu_pct(obs):
     """The model FLOPs of the prefills and decode steps that started in
     the traced slice, over the slice at the chip's bf16 peak."""
-    trace = _traced(obs)
+    trace = traced(obs)
     if trace is None:
         return None
     config, costs = obs["run"]["config"], family_costs(obs)
     flops = 0.0
-    for _, _, rows, attended in _started_in_slice(obs["steps"], trace):
+    for _, _, rows, attended in started_in_slice(obs["steps"], trace):
         flops += costs.forward_flops(config, rows, attended, logit_rows=rows)
-    for _, _, length in _started_in_slice(obs["prefills"], trace):
+    for _, _, length in started_in_slice(obs["prefills"], trace):
         flops += costs.forward_flops(
             config, length, length * (length + 1) // 2, logit_rows=1)
     if flops == 0.0:
@@ -177,11 +177,11 @@ def serve_mfu_pct(obs):
 
 
 def train_mfu_pct(obs):
-    trace = _traced(obs)
+    trace = traced(obs)
     if trace is None:
         return None
     mix = obs["run"]["traffic"]
-    steps = len(_started_in_slice(obs["steps"], trace))
+    steps = len(started_in_slice(obs["steps"], trace))
     if not steps:
         return None
     flops = steps * family_costs(obs).train_flops(

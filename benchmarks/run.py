@@ -36,11 +36,17 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmarks import loader, traffic as traffic_mod      # noqa: E402
+from benchmarks import loader, readers, traffic as traffic_mod  # noqa: E402
 from benchmarks.loader import BenchmarkError               # noqa: E402
 
 PHASES = []                 # [name, seconds since T_START] of set-up
 LATE_ANSWER_S = 60.0        # how long past the close an answer is awaited
+# libtpu pins a host buffer for transfers when its client starts: 4 GiB
+# unless told otherwise, which on a host without transparent hugepages
+# takes 6 to 12 s and was the whole spread of setup_s (PERF.md section
+# 2). No cell moves more than this in one transfer; a mix that does
+# asks for more under "premapped_buffer_bytes".
+PREMAPPED_BUFFER_BYTES = 512 << 20
 
 
 def mark(phase: str) -> None:
@@ -142,10 +148,13 @@ def adopt_orphans() -> None:
 
 
 @contextlib.contextmanager
-def session(chips: int):
+def session(chips: int, premapped_buffer_bytes=None):
     """One ray_tpu session. Its files live under this run's TMPDIR, its
-    workers find the benchmark on PYTHONPATH, and their log tails go to
-    stderr when the body fails."""
+    workers find the benchmark on PYTHONPATH and start libtpu with the
+    premapped buffer the cell needs, and their log tails go to stderr
+    when the body fails."""
+    os.environ["TPU_PREMAPPED_BUFFER_SIZE"] = str(int(
+        premapped_buffer_bytes or PREMAPPED_BUFFER_BYTES))
     os.environ.setdefault(
         "RAY_TPU_TMPDIR", os.path.join(tempfile.gettempdir(), "ray_tpu"))
     os.environ["PYTHONPATH"] = os.pathsep.join(
@@ -313,6 +322,8 @@ def drive_serve(run: dict, lm_class=None) -> dict:
             "compiles": after["compiles"], "trace": after["trace"],
             "device": after["device"],
             "memory_peak_bytes": after["memory_peak_bytes"],
+            "setup_stamps": before.get("setup_stamps", []),
+            "cache_misses": before.get("cache_misses"),
             "check": check}
 
 
@@ -409,7 +420,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
            "config": loader.load_config(bench, cell["config"]),
            "traffic": loader.load_traffic(bench, cell["traffic"])}
     run["family"] = loader.find_family(bench, run["config"])
-    with session(run["chips"]):
+    with session(run["chips"], run["traffic"].get("premapped_buffer_bytes")):
         if run["traffic"]["kind"] == "serve":
             obs = drive_serve(run, lm_class)
         else:
@@ -429,13 +440,21 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
                               window_s=traced["window_s"])
         line["breakdown"] = {"device_ops": traced["device_ops"],
                              "idle_gaps": traced["idle_gaps"]}
+        # how much of each kind the slice caught: the traced readings
+        # swing with it (PERF.md section 7)
+        line["slice"] = {kind: len(readers.started_in_slice(
+            obs.get(kind, []), traced)) for kind in ("steps", "prefills")}
         if describe:
             line["describe"] = traced.get("describe")
     # what the check read besides the numbers compared (calibration)
     extra = {k: v for k, v in obs["check"].items()
              if k not in verdict["compared"] and k != "served_gaps"}
     extra.update(obs.get("calibration", {}))
-    line.update(setup_s=obs["setup_s"], setup_phases=PHASES, check=extra,
+    PHASES.extend([name, at - T_START]
+                  for name, at in obs.get("setup_stamps", []))
+    PHASES.sort(key=lambda p: p[1])
+    line.update(setup_s=obs["setup_s"], setup_phases=PHASES,
+                setup_cache_misses=obs.get("cache_misses"), check=extra,
                 faults=verdict["faults"],
                 compared=verdict["compared"])
     return line

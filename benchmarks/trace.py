@@ -12,8 +12,9 @@ from __future__ import annotations
 
 import bisect
 import glob
+import itertools
 import os
-from typing import Dict, Iterable, List, Tuple
+from typing import Dict, Iterable, Iterator, List, Tuple
 
 Event = Tuple[str, float, float]           # name, start_ns, duration_ns
 
@@ -110,39 +111,71 @@ def top_ops(events: Iterable[Event], lo: float, hi: float,
     return [[name, ns / 1e9] for name, ns in ranked[:k]]
 
 
+def covering(thread: List[Event], starts: List[float], moment: float,
+             look_back: int = 400) -> Iterator[Event]:
+    """The events of one host thread that cover a moment, innermost
+    first. A thread's events nest, so of those that started before the
+    moment the later one lies inside the earlier."""
+    i = bisect.bisect_right(starts, moment) - 1
+    for j in range(i, max(-1, i - look_back - 1), -1):
+        if moment < thread[j][1] + thread[j][2]:
+            yield thread[j]
+
+
 def what_host_did(thread: List[Event], starts: List[float],
-                  moment: float, look_back: int = 400) -> Tuple[float, str]:
+                  moment: float) -> Tuple[float, str]:
     """(start, label) of the innermost span that covers a moment on one
     host thread, or (-inf, "") where none does. A span of the program
     labels the gap by its name (``serve.engine.wait``); one of the
     benchmark's own, which is coarser, adds the innermost event inside
-    it (``engine.prefill: PjitFunction``). A thread's events nest, so the
-    last one to start before the moment that still covers it is the
-    innermost."""
+    it (``engine.prefill: PjitFunction``)."""
     innermost = None
-    i = bisect.bisect_right(starts, moment) - 1
-    for name, start, dur in reversed(thread[max(0, i - look_back):i + 1]):
-        if moment < start + dur:
-            if name in HOST_SPANS:
-                return start, (name if innermost is None
-                               else f"{name}: {innermost}")
-            if name.startswith(PROGRAM_SPANS):
-                return start, name
-            innermost = innermost or short_name(name)
+    for name, start, _ in covering(thread, starts, moment):
+        if name in HOST_SPANS:
+            return start, (name if innermost is None
+                           else f"{name}: {innermost}")
+        if name.startswith(PROGRAM_SPANS):
+            return start, name
+        innermost = innermost or short_name(name)
     return float("-inf"), ""
+
+
+def gap_label(threads: List[List[Event]], starts: List[List[float]],
+              lo: float, hi: float) -> str:
+    """What the host was doing for most of the gap [lo, hi). The gap is
+    cut wherever an event of a host thread begins or ends inside it;
+    each piece belongs to the span that covers it, of the threads' the
+    one that started last, which is the innermost (the loop's
+    ``serve.step`` waits on one thread while the engine's
+    ``serve.engine.wait`` runs on another); the label with most of the
+    gap's time names it, "" where that is no span. (By the gap's middle
+    alone a train step's 5 ms boundary gap was now the end of one step,
+    now the space before the next.)"""
+    cuts = {lo, hi}
+    for thread, begun in zip(threads, starts):
+        inside = thread[bisect.bisect_right(begun, lo):
+                        bisect.bisect_left(begun, hi)]
+        for _, start, dur in itertools.chain(
+                covering(thread, begun, lo), inside):
+            cuts.update(x for x in (start, start + dur) if lo < x < hi)
+    cuts = sorted(cuts)
+    covered: Dict[str, float] = {}
+    for a, b in zip(cuts, cuts[1:]):
+        _, label = max((what_host_did(t, st, (a + b) / 2.0)
+                        for t, st in zip(threads, starts)),
+                       default=(0.0, ""))
+        covered[label] = covered.get(label, 0.0) + (b - a)
+    return max(covered, key=covered.get)
 
 
 def idle_gaps(device: Iterable[Event], threads: Iterable[List[Event]],
               lo: float, hi: float, k: int = 10,
               short_ns: float = 2000.0) -> List[List]:
     """[label, seconds]: idle time of the device in [lo, hi), summed by
-    what the host was doing at each gap's middle: of the spans that
-    cover it on any thread the one that started last, which is the
-    innermost (``between host spans`` where none covers it: the loop's
-    ``serve.step`` waits on one thread while the engine's
-    ``serve.engine.wait`` runs on another). Gaps under ``short_ns`` are
-    the launches
-    between one operation and the next and are summed apart."""
+    what the host was doing for most of each gap (:func:`gap_label`;
+    ``between host spans`` where no span covers most of it). Gaps under
+    ``short_ns`` are the launches between one operation and the next
+    and are summed apart."""
     busy = merge((s, s + d) for _, s, d in clip(device, lo, hi))
     edges = [lo] + [x for iv in busy for x in iv] + [hi]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
@@ -154,9 +187,7 @@ def idle_gaps(device: Iterable[Event], threads: Iterable[List[Event]],
         if e - s < short_ns:
             label = f"between operations, under {short_ns / 1e3:g} us each"
         else:
-            _, found = max((what_host_did(t, st, (s + e) / 2.0)
-                            for t, st in zip(threads, starts)),
-                           default=(0.0, ""))
+            found = gap_label(threads, starts, s, e)
             label = f"in {found}" if found else "between host spans"
         total[label] = total.get(label, 0.0) + (e - s)
     ranked = sorted(total.items(), key=lambda kv: kv[1], reverse=True)
@@ -173,6 +204,20 @@ def op_totals(events: Iterable[Event], lo: float,
         entry[0] += dur / 1e9
         entry[1] += 1
     return total
+
+
+def program_seconds(events: Iterable[Event], lo: float,
+                    hi: float) -> Dict[str, float]:
+    """program -> device seconds in [lo, hi) in which an operation of
+    it ran (operations named ``<program>/<operation>`` by
+    :func:`in_programs`). A union, because a ``while`` holds the
+    operations of its body: their durations summed would count the body
+    twice."""
+    by_program: Dict[str, List[Event]] = {}
+    for event in clip(events, lo, hi):
+        by_program.setdefault(event[0].partition("/")[0], []).append(event)
+    return {program: busy_ns(evs, lo, hi) / 1e9
+            for program, evs in by_program.items()}
 
 
 def reduce_trace(planes: Dict[str, Dict[str, List[Event]]]) -> Dict:
@@ -203,6 +248,7 @@ def reduce_trace(planes: Dict[str, Dict[str, List[Event]]]) -> Dict:
         "device_ops": top_ops(busiest, lo, hi),
         "idle_gaps": idle_gaps(busiest, host, lo, hi),
         "op_totals": op_totals(busiest, lo, hi),
+        "program_seconds": program_seconds(busiest, lo, hi),
         "n_devices": len(devices),
     }
 

@@ -19,6 +19,7 @@ Training mixes (``"kind": "train"``): ``batch``, ``seq``, ``remat``,
 
 from __future__ import annotations
 
+import functools
 import math
 import random
 from typing import Dict, List, Optional
@@ -107,14 +108,33 @@ def check_sample(seed: int, finished: List[dict], k: int) -> List[dict]:
     return [longest] + rng.sample(rest, min(k - 1, len(rest)))
 
 
-def train_batch(seed: int, step, batch: int, seq: int, vocab: int) -> Dict:
+def train_batch(seed, step, batch: int, seq: int, vocab: int) -> Dict:
     """Step ``step``'s batch, made on the device: rows that all differ,
-    a fresh draw each step. (Called under jit by the worker and by the
-    reference alike; imports jax late so the driver stays off it.)"""
+    a fresh draw each step. ``seed`` is the run's seed or its key
+    (``reference.seed_key(seed)``): the same batch either way. (Called
+    under jit by the worker and by the reference alike; imports jax
+    late so the driver stays off it.)"""
     import jax
 
     from benchmarks.reference import seed_key
 
-    key = jax.random.fold_in(jax.random.fold_in(seed_key(seed), 7), step)
+    key = seed_key(seed) if isinstance(seed, int) else seed
+    key = jax.random.fold_in(jax.random.fold_in(key, 7), step)
     tokens = jax.random.randint(key, (batch, seq + 1), 0, vocab)
     return {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+
+
+def batch_maker(seed: int, batch: int, seq: int, vocab: int):
+    """``step -> batch`` through one compiled program that takes the
+    seed's key as an argument. With the seed a constant of the program
+    every seed had a program of its own, which missed the compile cache
+    in every run's set-up (PERF.md section 6)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.reference import seed_key
+
+    key = seed_key(seed)
+    make = jax.jit(functools.partial(train_batch, batch=batch, seq=seq,
+                                     vocab=vocab))
+    return lambda step: make(key, jnp.int32(step))

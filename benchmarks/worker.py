@@ -17,6 +17,7 @@ from __future__ import annotations
 import asyncio
 import functools
 import gc
+import os
 import shutil
 import tempfile
 import time
@@ -26,21 +27,53 @@ from benchmarks import loader
 MOSAIC_CALL = "tpu_custom_call"
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/compilation_cache/cache_retrieval_time_sec")
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _compile_stamps = []        # perf_counter at each compile or cache load
+_cache_misses = []          # ... at each program the cache did not hold
+STAMPS = []                 # [name, time.time()] at the end of each part of
+                            # set-up inside the process that holds the chip
+
+
+def stamp(name: str) -> None:
+    STAMPS.append([name, time.time()])
+
+
+def process_started() -> float:
+    """When this process began, on ``time.time()``'s clock (from /proc:
+    the start in clock ticks since boot)."""
+    with open("/proc/self/stat") as f:
+        ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+    with open("/proc/uptime") as f:
+        up = float(f.read().split()[0])
+    return time.time() - up + ticks / os.sysconf("SC_CLK_TCK")
 
 
 def setup_jax() -> None:
-    """Once per worker: count compiles, and let the persistent cache
-    keep programs that compile in under jax's one-second threshold."""
+    """Once per worker: start the TPU client, stamping the parts of
+    set-up so far, and count compiles. Each time: let the persistent
+    cache keep programs that compile in under jax's one-second
+    threshold."""
+    first = not STAMPS
+    if first:
+        STAMPS.append(["worker.process", process_started()])
+        stamp("worker.entered")
     import jax
 
-    if not getattr(setup_jax, "done", False):
+    if first:
+        stamp("worker.jax_imported")
+        jax.local_devices()
+        stamp("worker.tpu_client")
+
         def on_event(name, _secs, **_kw):
             if name in COMPILE_EVENTS:
                 _compile_stamps.append(time.perf_counter())
 
+        def on_miss(name, **_kw):
+            if name == CACHE_MISS_EVENT:
+                _cache_misses.append(time.perf_counter())
+
         jax.monitoring.register_event_duration_secs_listener(on_event)
-        setup_jax.done = True
+        jax.monitoring.register_event_listener(on_miss)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
@@ -162,9 +195,12 @@ class BenchLM:
         self.program = loader.family_module(family, "program")
         self.sz = self.ref.sizes_of(config)
         self.cfg = self.program.program_config(config, traffic["slot_len"])
+        stamp("worker.modules")
         self.params = self.ref.seeded_params(self.seed, self.sz)
+        stamp("worker.weights")
         self.engine = self.make_engine()
         self.decode_scheduler = serve.DecodeScheduler(self.engine)
+        stamp("worker.engine")
         self.tracer = Tracer()
         self.called_at = {}
 
@@ -214,7 +250,9 @@ class BenchLM:
                 "steps": inside(self.engine.steps),
                 "prefills": inside(self.engine.prefills),
                 "compiles": [t for t in _compile_stamps if lo <= t <= hi],
-                "trace": self.tracer.reduce(describe == "1")}
+                "trace": self.tracer.reduce(describe == "1"),
+                "setup_stamps": STAMPS,
+                "cache_misses": sum(t <= hi for t in _cache_misses)}
 
     def programs(self) -> dict:
         """Mosaic custom calls in each prefill program the mix uses."""
@@ -259,7 +297,6 @@ class TrainCell:
     def __init__(self, run: dict):
         setup_jax()
         import jax
-        import jax.numpy as jnp
 
         from benchmarks import traffic as traffic_mod
 
@@ -271,14 +308,17 @@ class TrainCell:
         self.sz = self.ref.sizes_of(config)
         step, optimizer = program.make_train_step(
             program.program_config(config, mix["seq"]), mix)
+        stamp("worker.modules")
         self.params = self.ref.seeded_params(self.seed, self.sz)
         self.opt_state = jax.jit(optimizer.init)(self.params)
-        make_batch = jax.jit(functools.partial(
-            traffic_mod.train_batch, self.seed, batch=int(mix["batch"]),
-            seq=int(mix["seq"]), vocab=int(config["vocab_size"])))
-        self.batch_of = lambda i: make_batch(step=jnp.int32(i))
-        self.compiled = step.lower(self.params, self.opt_state,
-                                   self.batch_of(0)).compile()
+        stamp("worker.state")
+        self.batch_of = traffic_mod.batch_maker(
+            self.seed, int(mix["batch"]), int(mix["seq"]),
+            int(config["vocab_size"]))
+        lowered = step.lower(self.params, self.opt_state, self.batch_of(0))
+        stamp("worker.lowered")
+        self.compiled = lowered.compile()
+        stamp("worker.compiled")
         self.mosaic_calls = self.compiled.as_text().count(MOSAIC_CALL)
         self.spans = []                 # [t0, t1] of each step
         self.steps_done = 0
@@ -319,6 +359,7 @@ class TrainCell:
         gradient itself, for the reference to be set against once the
         state is freed."""
         import jax
+        import numpy as np
 
         from benchmarks import reference
 
@@ -331,7 +372,11 @@ class TrainCell:
                     k: float(v) / (1.0 - reference.ADAMW["b1"])
                     for k, v in reference.leaf_norms(
                         mu, self.ref.by_leaf).items()}
-                self.first_mu = jax.device_get(mu)
+                # leaf by leaf: transfers in flight that together pass
+                # libtpu's premapped buffer (run.PREMAPPED_BUFFER_BYTES)
+                # are pinned on demand, at three times the time
+                self.first_mu = jax.tree.map(np.asarray, mu)
+                stamp("worker.gradient_fetched")
                 del mu      # or it stays on the device through the window
         change = reference.leaf_diff_norms(
             self.params, self.ref.seeded_params(self.seed, self.sz),
@@ -381,7 +426,9 @@ def train_func(run: dict, cell_class=TrainCell) -> dict:
            "steps": [s for s in cell.spans if s[0] >= t_window],
            "compiles": [t for t in _compile_stamps
                         if t_window <= t <= t_close],
-           "trace": tracer.reduce(bool(run.get("describe")))}
+           "trace": tracer.reduce(bool(run.get("describe"))),
+           "setup_stamps": STAMPS,
+           "cache_misses": sum(t <= t_window for t in _cache_misses)}
     batch_of = cell.batch_of
     cell.release()
     t0 = time.perf_counter()

@@ -11,6 +11,7 @@ last test leaves the worker as it is, on a machine without a TPU.
 
 import json
 import os
+import re
 import shutil
 import sys
 
@@ -54,13 +55,13 @@ MIXES = {
 }
 
 
-@pytest.fixture(scope="module")
-def tiny_bench(tmp_path_factory):
+def build_tiny_bench(root: str, real: dict) -> dict:
     """A benchmark root of its own: the repo's metric readers and model
     families, a tiny configuration and tiny mixes, added as files and
-    entries."""
-    root = str(tmp_path_factory.mktemp("tiny_bench"))
-    real = loader.load_benchmark()
+    entries. ``real`` is the repo's ``BENCHMARK.json`` however many
+    cells it has grown: of each metric's ``workloads`` the three cells
+    renamed here are kept and the rest dropped, and a metric left with
+    none is left out."""
     for sub in ("metrics", "families"):
         shutil.copytree(os.path.join(loader.ROOT, "benchmarks", sub),
                         os.path.join(root, "benchmarks", sub),
@@ -84,22 +85,32 @@ def tiny_bench(tmp_path_factory):
     with open(os.path.join(loader.ROOT, "benchmarks", "put_off",
                            "longprompt-open.json")) as f:
         put_off = json.load(f)
-    for kind in ("end_to_end", "per_layer"):
-        names = {m["name"]: m for m in real[kind]}
-        for m in put_off[kind]:
-            if m["name"] in names:
-                names[m["name"]]["workloads"] = \
-                    names[m["name"]]["workloads"] + m["workloads"]
-            else:
-                real[kind].append(m)
     bench = dict(real, root=root)
     bench["configs"] = [{"name": "tiny", "file": "benchmarks/configs/tiny.json"}]
     bench["workloads"] = [{"name": c, "config": "tiny", "traffic": t,
                            "chips": 1} for c, t in cells.items()]
     for kind in ("end_to_end", "per_layer"):
-        bench[kind] = [dict(m, workloads=[rename[w] for w in m["workloads"]])
-                       if "workloads" in m else m for m in real[kind]]
+        entries = {m["name"]: dict(m) for m in real[kind]}
+        for m in put_off[kind]:
+            if m["name"] in entries:
+                entries[m["name"]]["workloads"] = \
+                    entries[m["name"]]["workloads"] + m["workloads"]
+            else:
+                entries[m["name"]] = dict(m)
+        bench[kind] = []
+        for m in entries.values():
+            if "workloads" in m:
+                m["workloads"] = [rename[w] for w in m["workloads"]
+                                  if w in rename]
+            if m.get("workloads", True):
+                bench[kind].append(m)
     return bench
+
+
+@pytest.fixture(scope="module")
+def tiny_bench(tmp_path_factory):
+    return build_tiny_bench(str(tmp_path_factory.mktemp("tiny_bench")),
+                            loader.load_benchmark())
 
 
 @pytest.fixture(scope="module")
@@ -124,11 +135,13 @@ def cpu_tpu_workers(monkeypatch, compile_cache):
 
 
 def may_be_absent(metric, line):
-    """A kernel's reading comes from its Mosaic call in the device's
-    trace: off the TPU there is none, and its reader returns nothing."""
+    """A roofline's time is that of the device's operations under their
+    own names (a Mosaic call, a program's operations): off the TPU the
+    host's plane stands in, which names none, and the reader returns
+    nothing."""
     return (line["device"]["platform"] != "tpu"
             and metric["source"] == "device_trace"
-            and metric["layer"].startswith("kernels"))
+            and "roofline" in re.split(r"[_.]", metric["name"]))
 
 
 def check_line(bench, cell, line, trace):

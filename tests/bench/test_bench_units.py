@@ -33,7 +33,9 @@ def ouro(bench):
 
 # ------------------------------------------------------- BENCHMARK.json
 
-def test_benchmark_json_keeps_the_contract(bench):
+def keeps_the_contract(bench):
+    """The names, units, sources and counts the contract fixes, on any
+    ``BENCHMARK.json`` (the repo's, or one grown by a later PR's cell)."""
     keys = set(bench) - {"root"}
     assert keys == {"command", "paths", "run_seconds", "configs",
                     "workloads", "end_to_end", "per_layer"}
@@ -69,7 +71,11 @@ def test_benchmark_json_keeps_the_contract(bench):
     assert four <= max(1, len(cells) // 4)
 
 
-def test_every_cell_reports_what_the_contract_asks(bench):
+def test_benchmark_json_keeps_the_contract(bench):
+    keeps_the_contract(bench)
+
+
+def every_cell_reports_what_the_contract_asks(bench):
     for w in bench["workloads"]:
         flat = {m["name"] for m in loader.cell_metrics(bench, w["name"],
                                                        trace=False)}
@@ -81,6 +87,10 @@ def test_every_cell_reports_what_the_contract_asks(bench):
             assert m["moves"] in flat       # it moves a metric reported here
             loader.load_reader(bench, m["name"])
         loader.load_traffic(bench, w["traffic"])
+
+
+def test_every_cell_reports_what_the_contract_asks(bench):
+    every_cell_reports_what_the_contract_asks(bench)
 
 
 def test_configuration_files_keep_the_published_sizes(bench):
@@ -238,6 +248,10 @@ def test_busy_union_clipping_and_idle_share():
     assert out["device_ops"][0][0] == "step/fusion.1"
     assert out["op_totals"]["step/while.4"] == [pytest.approx(6e-6), 1]
     assert "?/before" not in out["op_totals"]
+    # a program's device time is a union: the while's 6 us hold its
+    # body's 4, and the copy adds 1; the other program's 1 + 1 clipped
+    assert out["program_seconds"] == {"step": pytest.approx(7e-6),
+                                      "other": pytest.approx(2e-6)}
     # idle: [0,1), [8,12), [13,19); the middle of [8,12) lies in the
     # step's transfer, that of [13,19) outside the step
     gaps = dict(map(tuple, out["idle_gaps"]))
@@ -281,6 +295,44 @@ def test_idle_gaps_take_the_programs_own_span_names():
         "in serve.prefill": pytest.approx(4e-6)}
     assert trace.labels_gaps("train.report") and trace.labels_gaps(
         "engine.step") and not trace.labels_gaps("observe.step")
+
+
+def test_an_idle_gap_is_labelled_by_what_covers_most_of_it():
+    """A traced train step's boundary gap: the device stops at 10 while
+    the host still sits in the step's ``float(loss)`` until 12.6, the
+    next step's span opens at 13 and its first operation runs at 15.
+    The gap's middle, 12.5 or 12.7 from one run to the next, fell now
+    inside the fetch, now between the spans; most of the gap is the
+    fetch's either way. Then a gap that three spans share: the longest
+    share names it, not the one over the middle."""
+    us = 1000.0
+    op = "%fusion.1 = bf16[8] fusion(bf16[8] %p)"
+
+    def gaps_of(fetch_end, first_op):
+        return dict(map(tuple, trace.reduce_trace({
+            "/device:TPU:0": {
+                "XLA Modules": [("jit_local_step(1)", 0.0, 40 * us)],
+                "XLA Ops": [(op, 0.0, 10 * us),
+                            (op, first_op * us, (40 - first_op) * us)]},
+            "/host:CPU": {
+                "worker": [(trace.WINDOW, 0.0, 40 * us),
+                           ("train.step", 1 * us, (fetch_end - 1) * us),
+                           ("np.asarray(jax.Array)", 9 * us,
+                            (fetch_end - 9) * us),
+                           ("train.step", 13 * us, 20 * us),
+                           ("PjitFunction(local_step)", 14.5 * us, 1 * us)]},
+        })["idle_gaps"]))
+
+    for fetch_end, first_op in ((12.6, 15.0), (12.4, 15.4)):
+        assert gaps_of(fetch_end, first_op) == {
+            "in train.step: np.asarray": pytest.approx(
+                (first_op - 10) * 1e-6)}
+    # 10..11 the fetch, 11..13 nothing, 13..14.5 and 15.5..19 the next
+    # step's span: 5 us of 9, though the middle (14.5+) is the dispatch
+    assert gaps_of(11.0, 19.0) == {"in train.step": pytest.approx(9e-6)}
+    # no span over most of it
+    assert gaps_of(11.0, 14.0) == {
+        "between host spans": pytest.approx(4e-6)}
 
 
 def test_a_trace_without_its_marks_is_refused():
@@ -346,6 +398,28 @@ def test_flop_counts_against_a_hand_worked_shape(ouro):
     assert fwd["flops"] == 4 * 4 * (2 * 10)
     assert fwd["bytes"] == 4 * (4 * 2 * 4) * 2 + 4 * 2 * 4
     assert peaks.flash_bwd_cost(1, 4, 2, 4)["flops"] == 2.5 * fwd["flops"]
+    # K/V heads of their own: q and o (and do, dq) at the query's 2
+    # heads, k and v (and dk, dv) at 1; the FLOPs are the query's. None
+    # is the query's heads, to the digit
+    grouped = peaks.flash_fwd_cost(1, 4, 2, 4, 2, 1)
+    assert grouped["flops"] == fwd["flops"]
+    assert grouped["bytes"] == (2 * (4 * 2 * 4) + 2 * (4 * 1 * 4)) * 2 \
+        + 4 * 2 * 4
+    assert peaks.flash_bwd_cost(1, 4, 2, 4, 2, 1)["bytes"] == \
+        (4 * (4 * 2 * 4) + 4 * (4 * 1 * 4)) * 2 + 8 * 2 * 4
+    for cost in (peaks.flash_fwd_cost, peaks.flash_bwd_cost):
+        assert cost(4, 2048, 16, 128, 2) == cost(4, 2048, 16, 128, 2, 16) \
+            == cost(4, 2048, 16, 128, 2, None)
+    assert peaks.flash_fwd_cost(4, 2048, 16, 128, 2)["bytes"] == \
+        4.0 * 4 * 2048 * 16 * 128 * 2 + 4.0 * 4 * 16 * 2048
+    assert peaks.flash_bwd_cost(4, 2048, 16, 128, 2)["bytes"] == \
+        8.0 * 4 * 2048 * 16 * 128 * 2 + 8.0 * 4 * 16 * 2048
+    # a decode step of 2 rows that attend 7 positions between them, in
+    # bfloat16: every parameter once, and K and V of 7 + 2 positions at
+    # 3 layers x 2 heads x 4 x 2 (K, V) x 2 bytes = 96 bytes each
+    assert costs.DECODE_PROGRAM == "slot_decode_step"
+    assert costs.decode_step_bytes(dict(SMALL, torch_dtype="bfloat16"),
+                                   2, 7, {}) == 2056 * 2 + 9 * 96
     v5e = peaks.peaks_of("TPU v5 lite")
     assert (v5e["bf16_flops_per_s"], v5e["hbm_bytes_per_s"],
             v5e["hbm_bytes"]) == (197e12, 819e9, 16e9)
@@ -519,3 +593,47 @@ def test_a_directory_without_the_program_gives_no_result(
                      str(2**31 + 5), "--seconds", "1", "--trace", "0"])
     out = capsys.readouterr()
     assert code != 0 and out.out == "" and "NO RESULT" in out.err
+
+
+# ------------------------------------------- set-up: libtpu's host buffer
+
+@pytest.mark.parametrize("asked,bytes_", [(None, 512 << 20),
+                                          (1 << 30, 1 << 30)])
+def test_a_session_starts_libtpu_with_the_cells_host_buffer(
+        monkeypatch, asked, bytes_):
+    """The worker that holds the chip inherits the session's
+    environment: the harness's 512 MiB, or what the mix asks for."""
+    import ray_tpu
+    from benchmarks import run
+
+    monkeypatch.setenv("TPU_PREMAPPED_BUFFER_SIZE", "1")
+    for name in ("PYTHONPATH", "RAY_TPU_TMPDIR"):   # session() sets them
+        monkeypatch.setenv(name, os.environ.get(name, ""))
+    monkeypatch.setattr(ray_tpu, "init", lambda **kw: {"session_dir": ""})
+    monkeypatch.setattr(ray_tpu, "shutdown", lambda: None)
+    monkeypatch.setattr(run, "wait_until_ended", lambda started: None)
+    monkeypatch.setattr(run, "adopt_orphans", lambda: None)
+    with run.session(1, asked):
+        assert os.environ["TPU_PREMAPPED_BUFFER_SIZE"] == str(bytes_)
+
+
+@pytest.mark.parametrize("seed", [5, 2**31 + 7])
+def test_the_batch_program_does_not_hold_the_seed(seed):
+    """One program for every seed (else each new seed compiles it anew
+    in set-up), and the batches the seed itself gives."""
+    import functools
+
+    import jax
+    import numpy as np
+
+    from benchmarks import reference
+
+    sizes = dict(batch=4, seq=32, vocab=512)
+    program = jax.jit(functools.partial(traffic.train_batch, **sizes))
+    texts = {program.lower(reference.seed_key(s), 3).as_text()
+             for s in (seed, 11)}
+    assert len(texts) == 1
+    got = traffic.batch_maker(seed, **sizes)(3)
+    want = traffic.train_batch(seed, 3, **sizes)
+    assert all(np.array_equal(got[k], want[k]) for k in want)
+    assert not np.array_equal(got["tokens"][0], got["tokens"][1])

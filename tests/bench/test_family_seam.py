@@ -9,6 +9,7 @@ module of the benchmark knows a block.
 """
 
 import ast
+import copy
 import glob
 import hashlib
 import json
@@ -19,9 +20,12 @@ import sys
 import cloudpickle
 import pytest
 from test_bench_run import (MIXES, TINY, AlteredTokenLM,  # noqa: F401
-                            TracedOnCpuLM, compile_cache, cpu_tpu_workers)
+                            TracedOnCpuLM, build_tiny_bench, check_line,
+                            compile_cache, cpu_tpu_workers)
+from test_bench_units import (every_cell_reports_what_the_contract_asks,
+                              keeps_the_contract)
 
-from benchmarks import loader, run
+from benchmarks import loader, peaks, run
 
 cloudpickle.register_pickle_by_value(sys.modules[__name__])
 
@@ -33,14 +37,11 @@ TOY = {"model_type": "toy", "hidden_size": 32, "norm_eps": 1e-6,
 
 # --------------------------------------------- a second family, as files
 
-@pytest.fixture(scope="module")
-def toy_bench(tmp_path_factory):
+def build_toy_bench(root: str, real: dict) -> dict:
     """A benchmark root of its own. The shared harness is the repo's
     (``benchmarks`` on the path, its metric readers copied); the toy
     family, its configuration, its mix and its cell are new files under
-    a new path and new entries."""
-    root = str(tmp_path_factory.mktemp("toy_bench"))
-    real = loader.load_benchmark()
+    a new path and new entries beside whatever ``real`` holds."""
     shutil.copytree(os.path.join(BENCHMARKS, "metrics"),
                     os.path.join(root, "benchmarks", "metrics"))
     shutil.copytree(os.path.join(HERE, "toy"), os.path.join(root, "toy"),
@@ -65,6 +66,12 @@ def toy_bench(tmp_path_factory):
     return bench
 
 
+@pytest.fixture(scope="module")
+def toy_bench(tmp_path_factory):
+    return build_toy_bench(str(tmp_path_factory.mktemp("toy_bench")),
+                           loader.load_benchmark())
+
+
 def test_a_second_family_is_served_as_files_and_entries_alone(
         toy_bench, cpu_tpu_workers):
     family = loader.find_family(toy_bench, TOY)
@@ -77,13 +84,12 @@ def test_a_second_family_is_served_as_files_and_entries_alone(
     assert line["attempted"] > 0 and line["failed"] == 0
     gap = line["compared"]["served_logit_gap"]
     assert 0.0 <= gap["value"] <= gap["limit"]
-    # the shared readers price the toy's steps by the toy's own costs;
-    # its engine records no phases of its own, so those readers find
-    # nothing and their metrics are left out
+    # the shared readers price the toy's steps by the toy's own costs
+    # and read the phases its engine times under the program's names
     got = line["metrics"]
     assert got["serve_mfu_pct.closed"]["value"] > 0.0
     assert got["decode_occupancy_pct.closed"]["value"] > 0.0
-    assert "decode_device_wait_ms.closed" not in got
+    assert got["decode_device_wait_ms.closed"]["value"] > 0.0
 
 
 def test_the_second_family_with_a_token_altered_is_not_correct(
@@ -93,6 +99,134 @@ def test_the_second_family_with_a_token_altered_is_not_correct(
     assert not line["correct"]
     gap = line["compared"]["served_logit_gap"]
     assert gap["value"] > gap["limit"]
+
+
+# ------------------------- the real file, grown by such a cell as data
+
+GROWN_CELL = "toy-d1.decode-closed32"
+GROWN_READERS = {       # per-layer entries that list the new cell alone
+    "decode_occupancy_pct.closed32": (
+        "readers", "decode_occupancy_pct", "program_counter",
+        "replica and serve/decode_scheduler.py"),
+    "decode_device_wait_ms.closed32": (
+        "inside", "decode_device_wait_ms", "program_span",
+        "engine: JaxSlotEngine, models/decode.py"),
+    "decode_host_ms.closed32": (
+        "inside", "decode_host_ms", "program_span",
+        "engine: JaxSlotEngine, models/decode.py"),
+    "prefill_stall_pct.closed32": (
+        "inside", "prefill_stall_pct", "program_span",
+        "replica and serve/decode_scheduler.py"),
+    "device_idle_pct.closed32": (
+        "readers", "device_idle_pct", "device_trace", "device"),
+    "serve_mfu_pct.closed32": (
+        "readers", "serve_mfu_pct", "program_span",
+        "model step: models/transformer.py, models/decode.py"),
+    "decode_roofline_pct.closed32": (
+        "inside", "decode_roofline_pct", "device_trace",
+        "model step: models/transformer.py, models/decode.py")}
+
+
+def grow_by_a_cell(root: str, real: dict) -> dict:
+    """What the next ``model_config`` PR does to the real file: a family
+    (the toy's files), a configuration, a mix and one-line readers under
+    a path of its own, one cell, its name appended to
+    ``serve_tokens_per_s``'s ``workloads`` (the one change to an entry
+    that is there), and per-layer entries that list it alone. The
+    benchmark's own files stand where they stand in the repo."""
+    shutil.copytree(BENCHMARKS, os.path.join(root, "benchmarks"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copytree(os.path.join(HERE, "toy"), os.path.join(root, "grown"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for sub, name, data in (("configs", "toy-d1", TOY),
+                            ("workloads", "decode-closed32",
+                             MIXES["tiny-closed"])):
+        os.makedirs(os.path.join(root, "grown", sub))
+        with open(os.path.join(root, "grown", sub, name + ".json"),
+                  "w") as f:
+            json.dump(data, f)
+    os.makedirs(os.path.join(root, "grown", "metrics"))
+    for name, (module, read, _, _) in GROWN_READERS.items():
+        with open(os.path.join(root, "grown", "metrics", name + ".py"),
+                  "w") as f:
+            f.write(f"from benchmarks.{module} import {read} as read"
+                    "  # noqa: F401\n")
+    grown = copy.deepcopy(dict(real, root=root))
+    grown["paths"].append("grown")
+    grown["configs"].append({
+        "name": "toy-d1", "source": "tests/bench/toy (no public model)",
+        "file": "grown/configs/toy-d1.json", "reduced": [],
+        "why": "a second family: an embedding, a norm, a tied head"})
+    grown["workloads"].append({
+        "name": GROWN_CELL, "config": "toy-d1",
+        "traffic": "decode-closed32", "chips": 1,
+        "why": "closed loop on the second family's own engine"})
+    for m in grown["end_to_end"]:
+        if m["name"] == "serve_tokens_per_s":
+            m["workloads"].append(GROWN_CELL)
+    grown["per_layer"] += [
+        {"name": name, "unit": "ms" if name.split(".")[0].endswith("_ms")
+         else "%", "better": "lower", "source": source, "layer": layer,
+         "moves": "serve_tokens_per_s", "workloads": [GROWN_CELL]}
+        for name, (_, _, source, layer) in GROWN_READERS.items()]
+    return grown
+
+
+def reported(bench, cells):
+    """{(cell, traced): the names of the metrics it reports}."""
+    return {(cell, traced): [m["name"] for m in loader.cell_metrics(
+        bench, cell, traced)] for cell in cells for traced in (False, True)}
+
+
+def test_the_real_benchmark_grows_by_a_cell_of_a_second_family(
+        tmp_path, cpu_tpu_workers):
+    real = loader.load_benchmark()
+    grown = grow_by_a_cell(str(tmp_path / "grown"), real)
+    keeps_the_contract(grown)
+    every_cell_reports_what_the_contract_asks(grown)
+    # the cells that were there report exactly what they report today
+    cells = [w["name"] for w in real["workloads"]]
+    assert reported(grown, cells) == reported(real, cells)
+    assert reported(grown, [GROWN_CELL]) == {
+        (GROWN_CELL, False): ["serve_tokens_per_s", "setup_s"],
+        (GROWN_CELL, True): list(GROWN_READERS)}
+    # the other tests' benchmarks are built from the grown file as from
+    # the real one
+    for build, made in ((build_tiny_bench, ["tiny.closed", "tiny.open",
+                                            "tiny.train"]),
+                        (build_toy_bench, cells + ["toy.closed"])):
+        small = build(str(tmp_path / (build.__name__ + "_grown")), grown)
+        same = build(str(tmp_path / (build.__name__ + "_real")), real)
+        assert reported(small, made) == reported(same, made)
+    # the new cell, served through the front door on the CPU
+    line = run.run_cell(grown, GROWN_CELL, seed=2**31 + 23, seconds=3.0,
+                        trace=True, platform="cpu", lm_class=TracedOnCpuLM)
+    assert line["correct"], line["faults"]
+    check_line(grown, GROWN_CELL, line, True)
+    # every metric but the roofline, whose time is the device's
+    # operations by name: the CPU's stand-in plane names none
+    assert set(line["metrics"]) == set(GROWN_READERS) - {
+        "decode_roofline_pct.closed32"}
+    assert line["slice"]["steps"] > 0
+    # ... which reads, through the same entry and file, where a trace
+    # names the family's decode program (a TPU's does): two steps of 2
+    # and 1 rows begun in the slice, 1.5 rows looked up a step
+    vocab, hidden = TOY["vocab_size"], TOY["hidden_size"]
+    step_bytes = 4.0 * ((vocab + 1) * hidden + 1.5 * hidden)
+    obs = {"run": {"config": TOY, "family": loader.find_family(grown, TOY)},
+           "device": {"kind": "TPU v5 lite"},
+           "steps": [[10.1, 10.2, 2, 0], [10.3, 10.4, 1, 0],
+                     [11.2, 11.3, 2, 0]],
+           "decode_before": {"steps": 4, "phases": {}},
+           "decode_after": {"steps": 8, "phases": {
+               "serve.engine.wait": [4, 0.1],
+               "serve.engine.rows_looked_up": [4, 6.0]}},
+           "trace": {"window_s": 1.0, "slice": [10.0, 11.0],
+                     "program_seconds": {"_greedy": 1e-6, "other": 9.0}}}
+    read = loader.load_reader(grown, "decode_roofline_pct.closed32")
+    assert read(obs) == pytest.approx(
+        100.0 * 2 * step_bytes / peaks.PEAKS["TPU v5 lite"][
+            "hbm_bytes_per_s"] / 1e-6)
 
 
 # -------------------------------------- the weights are the parent's

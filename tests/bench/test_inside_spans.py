@@ -18,6 +18,7 @@ SERVING = ("decode_device_wait_ms.closed", "decode_host_ms.closed",
            "decode_slot_reads_ms.closed", "scheduler_overhead_ms.closed",
            "prefill_stall_pct.closed")
 ROOFLINES = ("flash_fwd_roofline_pct.train", "flash_bwd_roofline_pct.train")
+DECODE_ROOFLINE = "decode_roofline_pct.closed"
 LOOP_SPANS = ("serve.admit", "serve.prefill", "serve.step", "serve.emit")
 STEP_SPANS = tuple("serve.engine." + n for n in (
     "check", "put", "dispatch", "wait", "read"))
@@ -187,7 +188,8 @@ def training_obs(op_totals):
             "trace": {"window_s": 1.0, "op_totals": op_totals}}
 
 
-def test_the_roofline_readers_against_a_hand_worked_shape(bench):
+def test_the_roofline_readers_against_a_hand_worked_shape(bench,
+                                                         monkeypatch):
     chip = peaks.PEAKS["TPU v5 lite"]
     # 3 x 2 heads x 8 x 9 / 2 = 216 unmasked pairs; forward 4 x 4 FLOPs a
     # pair = 3456, q k v o at 3 x 8 x 2 x 4 x 2 bytes each + the logsumexp
@@ -219,6 +221,86 @@ def test_the_roofline_readers_against_a_hand_worked_shape(bench):
         assert reader(bench, name)(untraced) is None
     with pytest.raises(ValueError, match="no peaks on record"):
         reader(bench, ROOFLINES[0])(dict(obs, device={"kind": "cpu"}))
+    # a family whose K and V have a head of their own gives it sixth:
+    # k, v (and dk, dv) cost half the bytes, q, o (do, dq) what they did
+    costs = loader.family_module(obs["run"]["family"], "costs")
+    monkeypatch.setattr(costs, "flash_shape",
+                        lambda config, mix: (3, 8, 2, 4, 2, 1))
+    assert reader(bench, ROOFLINES[0])(obs) == pytest.approx(
+        100.0 * 6 * ((1536 * 3 / 4 + 192) / 819e9) / 3e-6)
+    assert reader(bench, ROOFLINES[1])(obs) == pytest.approx(
+        100.0 * 3 * ((3072 * 3 / 4 + 384) / 819e9) / 5e-6)
+
+
+def decode_obs(program_seconds, config=None):
+    """Three decode steps, two of them begun in the slice [10, 11): 2
+    rows that attend 7 positions between them, then 1 row at 5."""
+    config = config or {
+        "model_type": "ouro", "hidden_size": 8, "intermediate_size": 16,
+        "head_dim": 4, "num_attention_heads": 2, "num_hidden_layers": 3,
+        "vocab_size": 10, "torch_dtype": "bfloat16"}
+    obs = serving_obs()
+    obs.update(
+        run={"config": config, "traffic": {"slots": 2},
+             "family": loader.find_family(loader.load_benchmark(), config)},
+        device={"kind": "TPU v5 lite"},
+        steps=[[9.5, 9.9, 2, 5], [10.1, 10.4, 2, 7], [10.6, 10.9, 1, 5]],
+        prefills=[[10.45, 10.55, 4]],
+        trace={"window_s": 1.0, "busy_s": 0.9, "slice": [10.0, 11.0],
+               "program_seconds": program_seconds})
+    return obs
+
+
+def test_the_decode_roofline_against_a_hand_worked_step(bench, monkeypatch):
+    chip = peaks.PEAKS["TPU v5 lite"]
+    # 2056 parameters at 2 bytes; a position's K and V in 3 layers x 2
+    # heads x 4 are 96 bytes: (7 + 2) and (5 + 1) positions read or
+    # written. The bytes bound both steps at this size.
+    step_bytes = (2056 * 2 + 9 * 96, 2056 * 2 + 6 * 96)
+    assert step_bytes == (4976, 4688)
+    read = reader(bench, DECODE_ROOFLINE)
+    obs = decode_obs({"slot_decode_step": 4e-6, "slot_prefill": 9.0,
+                      "argmax": 9.0})
+    assert read(obs) == pytest.approx(
+        100.0 * sum(step_bytes) / chip["hbm_bytes_per_s"] / 4e-6)
+    # where a step's FLOPs take the chip longer than its bytes, they
+    # price it: a chip with 1e-3 of the FLOP/s
+    costs = loader.family_module(obs["run"]["family"], "costs")
+    flops = (costs.forward_flops(obs["run"]["config"], 2, 7, logit_rows=2)
+             + costs.forward_flops(obs["run"]["config"], 1, 5, logit_rows=1))
+    slow = dict(chip, bf16_flops_per_s=chip["bf16_flops_per_s"] * 1e-3)
+    assert flops / slow["bf16_flops_per_s"] > sum(step_bytes) / 819e9
+    monkeypatch.setitem(peaks.PEAKS, "slow chip", slow)
+    assert read(dict(obs, device={"kind": "slow chip"})) == pytest.approx(
+        100.0 * flops / slow["bf16_flops_per_s"] / 4e-6)
+    # nothing to read: an untraced run, a trace in which the program
+    # ran no operation (the CPU's stand-in plane), no step in the slice
+    assert read(dict(obs, trace=None)) is None
+    assert read(decode_obs({"slot_prefill": 9.0})) is None
+    assert read(decode_obs({})) is None
+    assert read(dict(obs, steps=[[9.5, 9.9, 2, 5]])) is None
+    # a family that prices no decode step in bytes reads nothing
+    for name in ("DECODE_PROGRAM", "decode_step_bytes"):
+        with monkeypatch.context() as patch:
+            patch.delattr(costs, name)
+            assert read(obs) is None
+    assert read(obs) is not None
+
+
+def test_an_engines_own_counters_reach_its_familys_costs():
+    """What an engine records under ``serve.engine.`` beside the timed
+    phases is a counter: its mean a step of the window."""
+    obs = serving_obs()
+    assert inside.engine_counts(obs) == {}
+    obs["decode_before"]["phases"]["serve.engine.experts_touched"] = [10, 90]
+    obs["decode_after"]["phases"]["serve.engine.experts_touched"] = [30, 330]
+    assert inside.engine_counts(obs) == {
+        "serve.engine.experts_touched": pytest.approx(12.0)}
+    # a counter that began inside the window counts from nothing
+    del obs["decode_before"]["phases"]["serve.engine.experts_touched"]
+    assert inside.engine_counts(obs) == {
+        "serve.engine.experts_touched": pytest.approx(16.5)}
+    assert inside.engine_counts(serving_obs(steps=(10, 10))) == {}
 
 
 def test_a_tpu_line_must_carry_the_rooflines_and_a_cpu_line_need_not(bench):
@@ -252,11 +334,42 @@ def test_a_tpu_line_must_carry_the_rooflines_and_a_cpu_line_need_not(bench):
         check_line(bench, cell, without, True)
     check_line(bench, cell, dict(without, device=dict(
         without["device"], platform="cpu")), True)
-    # only a kernel's metric may be absent, also off the TPU
+    # only a roofline may be absent, also off the TPU
     del without["metrics"]["train_step_ms"]
     with pytest.raises(AssertionError):
         check_line(bench, cell, dict(without, device=dict(
             without["device"], platform="cpu")), True)
+
+
+def test_a_tpu_line_must_carry_the_decode_roofline(bench):
+    """The same for the serving cell's whole-step share: a TPU's trace
+    names the decode program's operations, the CPU's stand-in does not."""
+    cell = "ouro-2.6b.decode-closed"
+
+    def line_of(platform, program_seconds):
+        obs = decode_obs(program_seconds)
+        for when, slot_steps in (("decode_before", 15), ("decode_after", 50)):
+            obs[when]["slot_steps"] = slot_steps
+        return {"correct": True, "attempted": 2, "failed": 0,
+                "metrics": loader.read_metrics(bench, cell, True, obs),
+                "device": {"platform": platform, "kind": "any", "count": 1,
+                           "memory_peak_bytes": 1, "busy_s": 0.9,
+                           "window_s": 1.0},
+                "breakdown": {"device_ops": [], "idle_gaps": []},
+                "compared": {}}
+
+    on_tpu = line_of("tpu", {"slot_decode_step": 0.5})
+    assert {DECODE_ROOFLINE, "serve_mfu_pct.closed"} <= set(on_tpu["metrics"])
+    check_line(bench, cell, on_tpu, True)
+    without = line_of("tpu", {})
+    assert DECODE_ROOFLINE not in without["metrics"]
+    with pytest.raises(AssertionError):
+        check_line(bench, cell, without, True)
+    on_cpu = dict(without, device=dict(without["device"], platform="cpu"))
+    check_line(bench, cell, on_cpu, True)
+    del on_cpu["metrics"]["device_idle_pct.closed"]   # device_trace too
+    with pytest.raises(AssertionError):
+        check_line(bench, cell, on_cpu, True)
 
 
 @pytest.mark.parametrize("instruction,kernel", [
@@ -277,19 +390,28 @@ def test_the_rooflines_are_entered_beside_their_readers(bench):
     assert not os.path.exists(os.path.join(
         bench["root"], "benchmarks", "put_off", "kernel-rooflines.json"))
     entered = {m["name"]: m for m in bench["per_layer"]}
-    assert set(SERVING) | set(ROOFLINES) <= set(entered)
+    assert set(SERVING) | set(ROOFLINES) | {DECODE_ROOFLINE} <= set(entered)
+    # one clock on the step (PR 28): the wrapper's median went, the
+    # engine's own wait and host phases say the same from inside
+    assert "decode_step_ms.closed" not in entered
+    whole = entered[DECODE_ROOFLINE]
+    assert "ouro-2.6b.decode-closed" in whole["workloads"]
+    assert (whole["moves"], whole["unit"], whole["source"]) == (
+        "serve_tokens_per_s", "%", "device_trace")
+    assert whole["layer"] == entered["serve_mfu_pct.closed"]["layer"]
     for name in ROOFLINES:
         m = entered[name]
-        assert m["workloads"] == ["ouro-2.6b-d12.train-2k"]
+        assert "ouro-2.6b-d12.train-2k" in m["workloads"]
         assert m["layer"] == "kernels: ops/attention.py"
         assert (m["moves"], m["unit"], m["source"]) == (
             "train_tokens_per_s", "%", "device_trace")
         assert callable(reader(bench, name))
-    names = [m["name"] for m in loader.cell_metrics(
-        bench, "ouro-2.6b-d12.train-2k", True)]
-    assert names[-2:] == list(ROOFLINES)
-    assert not set(ROOFLINES) & {m["name"] for m in loader.cell_metrics(
+    train = {m["name"] for m in loader.cell_metrics(
+        bench, "ouro-2.6b-d12.train-2k", True)}
+    serve = {m["name"] for m in loader.cell_metrics(
         bench, "ouro-2.6b.decode-closed", True)}
+    assert set(ROOFLINES) <= train and DECODE_ROOFLINE not in train
+    assert DECODE_ROOFLINE in serve and not set(ROOFLINES) & serve
 
 
 # -------------------------------------------- in the tiny cell's line
@@ -304,9 +426,14 @@ def test_the_traced_closed_cell_prints_the_inside_metrics(
     got = {n: line["metrics"][n]["value"] for n in SERVING}
     assert 0.0 < got["prefill_stall_pct.closed"] < 100.0
     assert got["decode_slot_reads_ms.closed"] < got["decode_host_ms.closed"]
-    # the inside pair is the step the benchmark's own wrapper times
-    # (means against a median, on a shared CPU: loosely)
+    # the inside pair is the step's one clock: the steps begun in the
+    # traced slice fit into it at that length (a mean over the window
+    # against a count in the slice, on a shared CPU: loosely)
     inside_ms = (got["decode_host_ms.closed"]
                  + got["decode_device_wait_ms.closed"])
-    outside_ms = line["metrics"]["decode_step_ms.closed"]["value"]
-    assert 0.5 * outside_ms < inside_ms < 2.0 * outside_ms
+    assert line["slice"]["steps"] > 0 and line["slice"]["prefills"] >= 0
+    assert 0.0 < inside_ms * line["slice"]["steps"] < \
+        2.0 * 1e3 * line["device"]["window_s"]
+    # the whole-step roofline's time is the decode program's operations
+    # by name, which the CPU's stand-in plane does not hold
+    assert DECODE_ROOFLINE not in line["metrics"]
