@@ -139,8 +139,9 @@ def _attend(q, k, v, pcfg: ParallelConfig):
         impl = "ring" if pcfg.sp else "local"
     if impl == "local" or not pcfg.sp:
         # Pallas blocked online-softmax kernel when the default backend
-        # is the TPU and T is a multiple of the 128 block; the XLA
-        # reference otherwise (ops.attention.flash_attention).
+        # is the TPU and T is a multiple of 128 (the kernel chooses its
+        # blocks from T and multiplies at q's dtype); the XLA reference
+        # otherwise (ops.attention.flash_attention).
         return flash_attention(q, k, v, causal=True)
     if impl == "ring":
         return ring_attention(q, k, v, axis=pcfg.sp, causal=True)

@@ -13,6 +13,16 @@ accumulating dK/dV (q-blocks innermost), one accumulating dQ
 either. ``delta = rowsum(dO * O)`` is precomputed by XLA (one fused
 elementwise reduce). Shapes everywhere: [batch, seq, heads, head_dim].
 
+What one grid step is given is read from the input: the products take
+their operands at the dtype they come in (probabilities and score
+gradients are cast to it) and accumulate in float32, with the softmax
+between them in float32; each kernel's blocks come from
+``flash_blocks`` (multiples of 128 that divide the sequence, up to
+caps swept on the chip), so every sequence that is a multiple of 128
+takes the kernel; and under ``causal`` a block above the diagonal is
+neither computed nor fetched (its index map names the block of the
+nearest live step), a block below it skips the mask.
+
 Reference-parity note: the reference snapshot has no attention kernels
 at all (SURVEY.md §5.7 — absent); this op underpins the TPU-native
 long-context capability layered on the runtime.
@@ -26,7 +36,14 @@ import jax
 import jax.numpy as jnp
 
 _NEG_INF = -1e30
-_LANES = 128  # f32 VMEM lane width; m/l scratch rows are lane-replicated
+_LANES = 128  # f32 VMEM lane width; the m/l scratch rows are as wide
+_BLOCK = 128  # granule of a block along the sequence
+# The three pallas_calls, by their ``name=`` (a device trace names them
+# so), in the order ``_flash`` carries their blocks.
+KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+# Largest (block_q, block_k) of each: flash_blocks.
+_BLOCK_CAPS = {"flash_fwd": (1024, 1024), "flash_bwd_dkv": (512, 512),
+               "flash_bwd_dq": (1024, 1024)}
 
 
 def attention(q, k, v, *, causal: bool = True,
@@ -55,6 +72,60 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _on_live_tiles(tile, causal, qi, ki, block_q, block_k):
+    """Run ``tile(masked)`` for the (q-block ``qi``, k-block ``ki``) grid
+    step unless ``causal`` masks the whole of it: ``masked`` False for a
+    block wholly on or below the diagonal (no compare, no select), True
+    for one the diagonal crosses."""
+    import jax.experimental.pallas as pl
+
+    if not causal:
+        tile(False)
+        return
+    first_q, first_k = qi * block_q, ki * block_k
+    last_q, last_k = first_q + block_q - 1, first_k + block_k - 1
+    pl.when(last_k <= first_q)(functools.partial(tile, False))
+    pl.when((first_k <= last_q) & (last_k > first_q))(
+        functools.partial(tile, True))
+
+
+def _causal_mask(s, first_q, first_k, q_axis):
+    """The score tile ``s`` with every pair q < k at _NEG_INF; q runs
+    from ``first_q`` along ``q_axis`` of the tile, k from ``first_k``
+    along the other."""
+    qpos = first_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    kpos = first_k + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                              1 - q_axis)
+    return jnp.where(qpos >= kpos, s, _NEG_INF)
+
+
+def _kv_index(causal, block_q, block_k):
+    """Index map of a K or V block on a (b, h, qi, ki) grid. Under
+    ``causal`` ki is clamped to the last k-block the q-block attends to,
+    so that a masked grid step names the block the last live step named
+    and the pipeline issues no copy for it."""
+    def index(b, h, i, j):
+        if causal:
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        return (b, h, j, 0)
+    return index
+
+
+def _lanes(x, n):
+    """A lane-replicated [rows, w] value at n lanes, or as one column to
+    broadcast where n is not a multiple of w."""
+    w = x.shape[1]
+    if n <= w:
+        return x[:, :n]
+    return jnp.tile(x, (1, n // w)) if n % w == 0 else x[:, :1]
+
+
+def _scratch_lanes(block_k):
+    """Width of the forward's m/l scratch rows: a vreg's lanes where the
+    k-block is made of whole ones, else the k-block."""
+    return block_k if block_k % _LANES else _LANES
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                   acc_ref, *, sm_scale, causal, block_q, block_k, num_k):
     """One (b, h, qi, ki) grid step of online-softmax attention.
@@ -63,13 +134,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     lse_ref [1,1,bq,1] per-row logsumexp (the backward's softmax key;
     the trailing singleton keeps the block's last-two dims Mosaic-legal:
     (bq, 1) = sublane-divisible x whole-array lane dim).
-    Scratch (VMEM, persists across the innermost ki axis):
-      m_ref/l_ref [bq, _LANES] lane-replicated running max / denom,
+    Scratch (VMEM, persists across the innermost ki axis), w lanes wide
+    (_scratch_lanes: a vreg's 128, or bk where that is no multiple):
+      m_ref [bq, w] the running max, the same in every lane,
+      l_ref [bq, w] the running denominator in w partial sums, lane j
+        holding the columns j, j + w, ...: a step adds to it lane by
+        lane and only _finish sums across the lanes,
       acc_ref [bq, D] running numerator.
+    So a step reduces across lanes once (the max) and broadcasts along
+    them once. The two products take q, k, v as they come (p is cast to
+    v's dtype) and accumulate in float32; all between them is float32.
     """
     import jax.experimental.pallas as pl
 
     qi, ki = pl.program_id(2), pl.program_id(3)
+    w = m_ref.shape[1]
 
     @pl.when(ki == 0)
     def _init():
@@ -77,42 +156,34 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Causal: blocks strictly above the diagonal contribute nothing.
-    run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)          # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)          # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
+    def _tile(masked):
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            kpos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
+        if masked:
+            s = _causal_mask(s, qi * block_q, ki * block_k, q_axis=0)
 
-        m_prev = m_ref[:, :1]                         # [bq, 1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)    # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
+        m_prev = m_ref[...]                           # [bq, w]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)               # rescale old state
-        p = jnp.exp(s - m_new)                        # [bq, bk]
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        p = jnp.exp(s - _lanes(m_new, block_k))       # [bq, bk]
+        l_ref[...] = alpha * l_ref[...] + sum(
+            p[:, c:c + w] for c in range(0, block_k, w))
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    # Causal: blocks strictly above the diagonal contribute nothing.
+    _on_live_tiles(_tile, causal, qi, ki, block_q, block_k)
 
     @pl.when(ki == num_k - 1)
     def _finish():
         # Fully masked rows (can't happen under causal) would have l=0;
         # guard the divide anyway so the kernel never emits NaN.
-        l = l_ref[:, :1]
+        l = jnp.sum(l_ref[...], axis=-1, keepdims=True)
         l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l)
@@ -133,6 +204,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
     kernel = functools.partial(
         _flash_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, num_k=num_k)
+    kv_block = _kv_index(causal, block_q, block_k)
     out, lse = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(qt.shape, q.dtype),
@@ -141,18 +213,16 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D),
                          lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k, D), kv_block),
+            pl.BlockSpec((1, 1, block_k, D), kv_block),
         ],
         out_specs=(pl.BlockSpec((1, 1, block_q, D),
                                 lambda b, h, i, j: (b, h, i, 0)),
                    pl.BlockSpec((1, 1, block_q, 1),
                                 lambda b, h, i, j: (b, h, i, 0))),
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _scratch_lanes(block_k)), jnp.float32),
+            pltpu.VMEM((block_q, _scratch_lanes(block_k)), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
@@ -161,38 +231,16 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
     return out.transpose(0, 2, 1, 3), lse
 
 
-def _bwd_tiles(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *,
-               sm_scale, causal, block_q, block_k, qi, ki):
-    """Shared recompute for one (q-block, k-block) tile of the backward:
-    returns (p, ds) — the probability tile and the score gradient tile
-    (sm_scale folded into ds)."""
-    q = q_ref[0, 0].astype(jnp.float32)               # [bq, D]
-    k = k_ref[0, 0].astype(jnp.float32)               # [bk, D]
-    v = v_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                               # [bq, 1]
-    delta = dl_ref[0, 0]                              # [bq, 1]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale     # [bq, bk]
-    if causal:
-        qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                       s.shape, 0)
-        kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                       s.shape, 1)
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
-    p = jnp.exp(s - lse)                              # exact softmax tile
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # [bq, bk]
-    ds = p * (dp - delta) * sm_scale
-    return q, k, do, p, ds
-
-
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale,
                           causal, block_q, block_k, num_q):
-    """Grid (b, h, ki, qi), qi innermost: dK/dV accumulate over q."""
+    """Grid (b, h, ki, qi), qi innermost: dK/dV accumulate over q.
+
+    The tile is computed transposed, [bk, bq] (k along the sublanes, q
+    along the lanes), so that all four products are plain row-by-column
+    or row-by-row ones and no score tile is ever transposed; lse_ref and
+    dl_ref are lane-dense rows [1,1,1,bq] for the same reason.
+    """
     import jax.experimental.pallas as pl
 
     ki, qi = pl.program_id(2), pl.program_id(3)
@@ -202,20 +250,26 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-
-    @pl.when(run)
-    def _step():
-        q, _k, do, p, ds = _bwd_tiles(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-            sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, qi=qi, ki=ki)
+    def _tile(masked):
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        st = jax.lax.dot_general(                      # k @ q^T  [bk, bq]
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        if masked:
+            st = _causal_mask(st, qi * block_q, ki * block_k, q_axis=1)
+        pt = jnp.exp(st - lse_ref[0, 0])               # exact softmax tile
+        dpt = jax.lax.dot_general(                     # v @ do^T  [bk, bq]
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dst = pt * (dpt - dl_ref[0, 0]) * sm_scale
         dv_acc[...] += jax.lax.dot_general(            # p^T @ do  [bk, D]
-            p, do, (((0,), (0,)), ((), ())),
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_acc[...] += jax.lax.dot_general(            # ds^T @ q  [bk, D]
-            ds, q, (((0,), (0,)), ((), ())),
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _on_live_tiles(_tile, causal, qi, ki, block_q, block_k)
 
     @pl.when(qi == num_q - 1)
     def _finish():
@@ -226,7 +280,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                          dq_ref, dq_acc, *, sm_scale, causal, block_q,
                          block_k, num_k):
-    """Grid (b, h, qi, ki), ki innermost: dQ accumulates over k."""
+    """Grid (b, h, qi, ki), ki innermost: dQ accumulates over k.
+    lse_ref/dl_ref are columns [1,1,bq,1], as the forward writes lse."""
     import jax.experimental.pallas as pl
 
     qi, ki = pl.program_id(2), pl.program_id(3)
@@ -235,122 +290,192 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-
-    @pl.when(run)
-    def _step():
-        _q, k, _do, _p, ds = _bwd_tiles(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-            sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, qi=qi, ki=ki)
-        dq_acc[...] += jax.lax.dot_general(            # ds @ k  [bq, D]
-            ds, k, (((1,), (0,)), ((), ())),
+    def _tile(masked):
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        s = jax.lax.dot_general(                       # q @ k^T  [bq, bk]
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        if masked:
+            s = _causal_mask(s, qi * block_q, ki * block_k, q_axis=0)
+        p = jnp.exp(s - lse_ref[0, 0])                 # exact softmax tile
+        dp = jax.lax.dot_general(                      # do @ v^T  [bq, bk]
+            do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
+        ds = p * (dp - dl_ref[0, 0]) * sm_scale
+        dq_acc[...] += jax.lax.dot_general(            # ds @ k  [bq, D]
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _on_live_tiles(_tile, causal, qi, ki, block_q, block_k)
 
     @pl.when(ki == num_k - 1)
     def _finish():
         dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                    block_k, interpret):
+def _flash_dkv(qt, kt, vt, dot, lse, delta, causal, sm_scale, block_q,
+               block_k, interpret):
+    """dK, dV of [B,H,T,D] operands; lse, delta as rows [B,H,1,T]."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    B, T, H, D = q.shape
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    dot = g.transpose(0, 2, 1, 3)
-    # delta_i = rowsum(dO_i * O_i): one fused XLA reduce, [B, H, T, 1]
-    # (trailing singleton matches the lse layout; see _flash_kernel doc).
-    delta = jnp.einsum("bqhd,bqhd->bhq", g.astype(jnp.float32),
-                       out.astype(jnp.float32))[..., None]
-    num_q, num_k = T // block_q, T // block_k
+    B, H, T, D = qt.shape
+    num_q = T // block_q
 
-    qspec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, j, i: (b, h, i, 0))
+    def q_index(i, j):
+        # the masked steps of a k-block come first: clamped to the first
+        # q-block that attends to it, they name the block the first live
+        # step needs, fetched once
+        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+
+    qspec = pl.BlockSpec((1, 1, block_q, D),
+                         lambda b, h, j, i: (b, h, q_index(i, j), 0))
     kspec = pl.BlockSpec((1, 1, block_k, D), lambda b, h, j, i: (b, h, j, 0))
-    rowq = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, j, i: (b, h, i, 0))
-    dk, dv = pl.pallas_call(
+    rowq = pl.BlockSpec((1, 1, 1, block_q),
+                        lambda b, h, j, i: (b, h, 0, q_index(i, j)))
+    return pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
                           block_k=block_k, num_q=num_q),
-        out_shape=(jax.ShapeDtypeStruct(kt.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vt.shape, v.dtype)),
-        grid=(B, H, num_k, num_q),
+        out_shape=(jax.ShapeDtypeStruct(kt.shape, kt.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)),
+        grid=(B, H, T // block_k, num_q),
         in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
-        out_specs=(pl.BlockSpec((1, 1, block_k, D),
-                                lambda b, h, j, i: (b, h, j, 0)),) * 2,
+        out_specs=(kspec, kspec),
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32)] * 2,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
-    qspec2 = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    kspec2 = pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h, j, 0))
-    rowq2 = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
-    dq = pl.pallas_call(
+
+def _flash_dq(qt, kt, vt, dot, lse, delta, causal, sm_scale, block_q,
+              block_k, interpret):
+    """dQ of [B,H,T,D] operands; lse, delta as columns [B,H,T,1]."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    B, H, T, D = qt.shape
+    num_k = T // block_k
+    qspec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    kspec = pl.BlockSpec((1, 1, block_k, D),
+                         _kv_index(causal, block_q, block_k))
+    colq = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
+    return pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q,
                           block_k=block_k, num_k=num_k),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        grid=(B, H, num_q, num_k),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
-        out_specs=qspec2,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        grid=(B, H, T // block_q, num_k),
+        in_specs=[qspec, kspec, kspec, qspec, colq, colq],
+        out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
+
+
+def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
+                    interpret):
+    B, T, H, D = q.shape
+    qt, kt, vt, dot = (x.transpose(0, 2, 1, 3) for x in (q, k, v, g))
+    # delta_i = rowsum(dO_i * O_i): one fused XLA reduce, [B, H, T].
+    delta = jnp.einsum("bqhd,bqhd->bhq", g.astype(jnp.float32),
+                       out.astype(jnp.float32))
+    dk, dv = _flash_dkv(qt, kt, vt, dot, lse.reshape(B, H, 1, T),
+                        delta[:, :, None, :], causal, sm_scale,
+                        *blocks[1], interpret)
+    dq = _flash_dq(qt, kt, vt, dot, lse, delta[..., None], causal,
+                   sm_scale, *blocks[2], interpret)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    out, _ = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, blocks, interpret):
+    out, _ = _flash_forward(q, k, v, causal, sm_scale, *blocks[0],
                             interpret)
     return out
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q,
-                              block_k, interpret)
+def _flash_fwd(q, k, v, causal, sm_scale, blocks, interpret):
+    out, lse = _flash_forward(q, k, v, causal, sm_scale, *blocks[0],
+                              interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, sm_scale, blocks, interpret, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                           block_q, block_k, interpret)
+                           blocks, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def flash_blocks(T: int, head_dim: int, itemsize: int,
+                 kernel: str) -> tuple[int, int]:
+    """(block_q, block_k) of one of the three ``KERNELS`` for sequences
+    of ``T`` (a multiple of 128): the largest multiples of 128 that
+    divide ``T`` up to the kernel's caps in ``_BLOCK_CAPS``, so a ``T``
+    below a cap is taken whole. The caps were swept on a TPU v5e at head
+    dimension 128 in bfloat16 and hold for rows of up to 512 bytes
+    (float32 at 128 and bfloat16 at 256 are faster with them than with
+    smaller blocks: PERF.md section 6, PR 29); a wider row overflows
+    the scoped VMEM with them and gets proportionally fewer rows a
+    block."""
+    shrink = max(1, head_dim * itemsize // 512)
+
+    def largest(cap):
+        cap = max(_BLOCK, cap // shrink)
+        return max(b for b in range(_BLOCK, min(cap, T) + 1, _BLOCK)
+                   if T % b == 0)
+
+    cap_q, cap_k = _BLOCK_CAPS[kernel]
+    return largest(cap_q), largest(cap_k)
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
-                    sm_scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
+                    sm_scale: float | None = None,
+                    block_q: int | None = None,
+                    block_k: int | None = None, interpret: bool = False):
     """Blockwise online-softmax attention (Pallas on TPU).
 
     Which implementation runs is decided by two things the caller can
     see: the platform — the Mosaic kernel exists only for the TPU, so a
     process whose default backend is anything else runs ``attention``
     unless it asks for the kernel under ``interpret`` — and the shape:
-    decode steps (Tq != Tk) and sequences not divisible by the block
-    sizes run ``attention`` on every platform. A caller that must know
-    the kernel ran looks for the Mosaic custom call in its compiled
-    program (chip_smoke.py does).
+    decode steps (Tq != Tk) and sequences that are not a multiple of
+    128 (with explicit blocks: of those blocks) run ``attention`` on
+    every platform; ``interpret`` alone takes such a sequence as one
+    block. A caller that must know the kernel ran looks for the Mosaic
+    custom call in its compiled program (chip_smoke.py does).
+
+    The kernels read the rest from their input as well: the products run
+    at the operands' dtype and accumulate in float32, and each of the
+    three kernels takes its blocks from ``flash_blocks``. An explicit
+    ``block_q`` and ``block_k`` (both, or neither) win, for all three.
     """
     B, T, H, D = q.shape
     sm_scale = sm_scale if sm_scale is not None else D ** -0.5
-    if interpret:
-        # interpret mode exists to exercise the kernel: clamp blocks so
-        # it runs even at small T (no Mosaic tiling constraints on CPU).
-        block_q = min(block_q, T)
-        block_k = min(block_k, T)
-    # Shape: short / unaligned sequences use the XLA reference — Mosaic
-    # blocks come in sublane 8 / lane 128 granules.
-    unaligned = (T < block_q or T % block_q or T % block_k
-                 or k.shape[1] != T)
-    if unaligned or not (interpret or _on_tpu()):
+    if (block_q is None) != (block_k is None):
+        raise ValueError("give both block_q and block_k, or neither")
+    if k.shape[1] != T or not (interpret or _on_tpu()):
         return attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    return _flash(q, k, v, causal, sm_scale, block_q, block_k,
-                  interpret)
+    if block_q is None and T % _BLOCK == 0:
+        blocks = tuple(flash_blocks(T, D, q.dtype.itemsize, kernel)
+                       for kernel in KERNELS)
+    elif block_q is None and not interpret:
+        return attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    else:
+        if interpret:
+            # interpret mode exists to exercise the kernel: blocks are
+            # clamped so it runs even at small T, and a T the table has
+            # no blocks for is taken whole (no Mosaic tiling constraints
+            # on CPU).
+            block_q, block_k = min(block_q or T, T), min(block_k or T, T)
+        blocks = ((block_q, block_k),) * len(KERNELS)
+    # Unaligned sequences use the XLA reference — Mosaic blocks come in
+    # sublane 8 / lane 128 granules.
+    if any(T % b for pair in blocks for b in pair):
+        return attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    return _flash(q, k, v, causal, sm_scale, blocks, interpret)

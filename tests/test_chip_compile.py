@@ -1,9 +1,11 @@
-"""The flash kernels at the benchmark's training shape, compiled for a
-TPU v5e that is described and not attached (libtpu's compiler runs on
-this CPU box; nothing executes): Mosaic accepts them, and the compiled
-program names its three custom calls ``flash_fwd``, ``flash_bwd_dkv``
-and ``flash_bwd_dq``, which is what the benchmark's kernel readers look
-up in a device trace.
+"""The flash kernels at the shapes the benchmark's cells run, compiled
+for a TPU v5e that is described and not attached (libtpu's compiler runs
+on this CPU box; nothing executes): Mosaic accepts them at the blocks
+``flash_blocks`` chooses (a table that overflows VMEM fails here), every
+shape takes the kernel (a prompt of 128 too), and the compiled program
+names its three custom calls ``flash_fwd``, ``flash_bwd_dkv`` and
+``flash_bwd_dq``, which is what the benchmark's kernel readers look up
+in a device trace.
 
 The topology is described inside a fixture, by the one xdist worker
 that is given this file: libtpu loads in one process at a time, so no
@@ -15,8 +17,23 @@ import re
 
 import pytest
 
-SHAPE = (4, 2048, 16, 128)      # cell ouro-2.6b-d12.train-2k: B, T, H, Dh
 KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+# B, T, H, Dh, the dtype, and whether the gradient is compiled too
+SHAPES = {
+    # ouro-2.6b-d12.train-2k
+    "train-2k": ((4, 2048, 16, 128), "bfloat16", True),
+    # ouro-2.6b.decode-closed
+    "prefill-128": ((1, 128, 16, 128), "bfloat16", False),
+    "prefill-256": ((1, 256, 16, 128), "bfloat16", False),
+    # chip_smoke.py: its train leg, and the longer of its kernel checks
+    "dense-168m": ((16, 1024, 16, 64), "bfloat16", True),
+    "smoke-4k": ((4, 4096, 8, 128), "bfloat16", True),
+    # rows of 512 bytes, two ways, which keep the caps swept at 256, and
+    # of 1024, which halve them: each has to fit VMEM as well
+    "train-2k-f32": ((4, 2048, 16, 128), "float32", True),
+    "head-256": ((2, 2048, 8, 256), "bfloat16", True),
+    "head-256-f32": ((2, 2048, 8, 256), "float32", True),
+}
 
 
 @pytest.fixture(scope="module")
@@ -62,8 +79,9 @@ def mosaic_calls(compiled_text):
     return sorted(kernel_of(n, KERNELS) or n for n in names)
 
 
+@pytest.mark.parametrize("cell", sorted(SHAPES))
 def test_flash_kernels_compile_for_v5e_under_their_own_names(
-        one_chip, no_compile_cache, monkeypatch):
+        cell, one_chip, no_compile_cache, monkeypatch):
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -74,7 +92,8 @@ def test_flash_kernels_compile_for_v5e_under_their_own_names(
     # this process's default backend is the CPU; the program is for the
     # described chip, so take the branch a TPU process takes
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    arg = jax.ShapeDtypeStruct(SHAPE, jnp.bfloat16, sharding=one_chip)
+    shape, dtype, with_gradient = SHAPES[cell]
+    arg = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
 
     def loss(q, k, v):
         # as models/transformer.py runs its layers under remat: a scan
@@ -90,6 +109,8 @@ def test_flash_kernels_compile_for_v5e_under_their_own_names(
         arg, arg, arg).compile().as_text()
     assert "%flash_fwd.1 = " in forward
     assert mosaic_calls(forward) == ["flash_fwd"]
+    if not with_gradient:
+        return
     both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         arg, arg, arg).compile().as_text()
     # the forward, remat's second forward, and the backward's two

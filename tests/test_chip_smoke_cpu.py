@@ -58,8 +58,10 @@ def test_smoke_legs_compose_on_cpu(tpu_session, monkeypatch, tmp_path):
     assert trained["device"]["pid"] != served["device"]["pid"]
     assert len(trained["losses"]) == 5
     row, = trained["kernels"]
-    assert row["finite"] and max(
-        row[k] for k in ("fwd_err", "dq_err", "dk_err", "dv_err")) < 1e-4
+    errors = [row[k] for k in ("fwd_err", "dq_err", "dk_err", "dv_err")]
+    # above 0: the kernels ran (interpreted), not attention() against
+    # itself
+    assert row["finite"] and 0 < min(errors) and max(errors) < 1e-4
 
 
 def test_on_chip_checks_reject_a_cpu_run():

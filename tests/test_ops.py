@@ -98,6 +98,142 @@ def test_flash_grad_bf16():
                                    atol=1e-1, rtol=1e-1)
 
 
+def _loss(attn):
+    return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("T,block_q,block_k", [
+    (512, 256, 256), (1024, 256, 512), (1024, 512, 256)])
+def test_flash_bf16_large_blocks_match_oracle(causal, T, block_q, block_k):
+    # bfloat16 operands go into the products as they are; blocks above
+    # 128 put a wholly masked block, a diagonal block and a wholly live
+    # one (which skips the mask) into one call, with several k blocks a
+    # row so that the rescale fires, and with block_q != block_k the
+    # clamped index maps name other blocks than the grid's own.
+    q, k, v = _qkv(jax.random.key(6), B=1, T=T, H=2, D=128,
+                   dtype=jnp.bfloat16)
+    flash = functools.partial(flash_attention, causal=causal,
+                              block_q=block_q, block_k=block_k,
+                              interpret=True)
+    ref = functools.partial(attention, causal=causal)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v), np.float32),
+        np.asarray(ref(q, k, v), np.float32), atol=3e-2, rtol=3e-2)
+    g_flash = jax.grad(_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(_loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(gf, np.float32),
+                                   np.asarray(gr, np.float32),
+                                   atol=1e-1, rtol=1e-1)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_float32_keeps_float32_products_at_its_own_blocks(causal):
+    # no blocks given: the table's (384 whole); a float32 input is
+    # multiplied in float32, so the tolerances of the explicit-block
+    # cases above hold
+    q, k, v = _qkv(jax.random.key(7), B=1, T=384)
+    flash = functools.partial(flash_attention, causal=causal,
+                              interpret=True)
+    ref = functools.partial(attention, causal=causal)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    g_flash = jax.grad(_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(_loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   atol=2e-4, rtol=2e-4)
+
+
+# (block_q, block_k) of flash_fwd, flash_bwd_dkv, flash_bwd_dq at two
+# bytes an element, as swept on the chip (PERF.md section 6, PR 29)
+BLOCK_TABLE = {
+    128: ((128, 128), (128, 128), (128, 128)),
+    256: ((256, 256), (256, 256), (256, 256)),
+    384: ((384, 384), (384, 384), (384, 384)),
+    640: ((640, 640), (128, 128), (640, 640)),
+    896: ((896, 896), (128, 128), (896, 896)),
+    1024: ((1024, 1024), (512, 512), (1024, 1024)),
+    2048: ((1024, 1024), (512, 512), (1024, 1024)),
+}
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("T", sorted(BLOCK_TABLE))
+def test_flash_blocks_table(T, head_dim):
+    from ray_tpu.ops.attention import KERNELS, flash_blocks
+
+    got = tuple(flash_blocks(T, head_dim, 2, kernel) for kernel in KERNELS)
+    assert got == BLOCK_TABLE[T]
+    # up to 512 bytes a row (float32 at 128) the caps hold; at twice
+    # that, half the rows a block
+    assert got == tuple(flash_blocks(T, 128, 4, kernel)
+                        for kernel in KERNELS)
+    wide = tuple(flash_blocks(T, 256, 4, kernel) for kernel in KERNELS)
+    for pair, narrow in zip(wide, got):
+        for block, was in zip(pair, narrow):
+            assert block % 128 == 0 and T % block == 0
+            assert block <= max(128, was)
+    assert flash_blocks(2048, 256, 4, "flash_fwd") == (512, 512)
+    assert flash_blocks(2048, 256, 4, "flash_bwd_dkv") == (256, 256)
+
+
+def _program(q, k, v, **kwargs):
+    return str(jax.make_jaxpr(functools.partial(
+        flash_attention, **kwargs))(q, k, v))
+
+
+@pytest.mark.parametrize("T,kernel_runs", [(128, True), (640, True),
+                                           (192, False)])
+def test_flash_takes_every_multiple_of_128(T, kernel_runs, monkeypatch):
+    # the rule of a TPU process (traced here, not run): a prompt of 128
+    # is a prefill program of the serving cell and must hold the kernel;
+    # 192 is no multiple and runs the reference
+    import importlib
+
+    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"),
+                        "_on_tpu", lambda: True)
+    q, k, v = _qkv(jax.random.key(8), B=1, T=T, H=1)
+    assert ("pallas_call" in _program(q, k, v)) == kernel_runs
+
+
+@pytest.mark.parametrize("T,D", [(32, 16), (128, 64), (192, 64), (640, 64),
+                                 (32, 48), (256, 192)])
+def test_flash_interpret_runs_the_kernel_at_its_own_blocks(T, D):
+    # interpret mode exists to exercise the kernel: with no blocks given
+    # it runs at every T, one the table has no blocks for taken whole
+    # (chip_smoke's CPU composition checks the kernels at T=32), and at
+    # head dimensions the scratch rows' width neither divides nor is
+    # divided by
+    q, k, v = _qkv(jax.random.key(8), B=1, T=T, H=1, D=D)
+    flash = functools.partial(flash_attention, interpret=True)
+    assert "pallas_call" in _program(q, k, v, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(attention(q, k, v)),
+        atol=2e-5, rtol=2e-5)
+    g_flash = jax.grad(_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    assert "pallas_call" in str(jax.make_jaxpr(jax.grad(
+        _loss(flash), argnums=(0, 1, 2)))(q, k, v))
+    g_ref = jax.grad(_loss(attention), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   atol=2e-4, rtol=2e-4)
+
+
+def test_flash_explicit_blocks_come_as_a_pair():
+    q, k, v = _qkv(jax.random.key(9), B=1, T=384, H=1)
+    with pytest.raises(ValueError, match="both block_q and block_k"):
+        flash_attention(q, k, v, block_q=128, interpret=True)
+    # a k-block that is no multiple of a vreg's lanes, nor a divisor
+    got = flash_attention(q, k, v, block_q=128, block_k=192,
+                          interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(attention(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+
+
 def test_flash_fallback_paths():
     # Non-block-aligned T and decode (Tq != Tk) fall back to the
     # reference — results must still be exact.
