@@ -6,12 +6,7 @@ zoo is part of the framework, built on ``ray_tpu.ops`` kernels and
 over a dp/pp/sp/tp mesh.
 """
 
-from ray_tpu.models.decode import (  # noqa: F401
-    decode_step,
-    generate,
-    init_kv_cache,
-    prefill,
-)
+from ray_tpu.models.decode import generate  # noqa: F401
 from ray_tpu.models.transformer import (  # noqa: F401
     DENSE_168M,
     ParallelConfig,

@@ -1,20 +1,41 @@
 """KV-cached autoregressive decoding for the flagship transformer.
 
-Parity target: the serving half of the reference's model families
-(reference: the generation utilities its RLlib/serve examples lean on;
-the training side lives in models/transformer.py). TPU-first design:
-the KV cache is a preallocated [L, B, max_len, H, Dh] pytree so every
-decode step is ONE jitted program of static shapes — `prefill` runs
-the prompt through the full-sequence layers (flash/XLA attention)
-while writing the cache, and `decode_step` attends the new token
-against the cache with a position mask (no recompute, no dynamic
-shapes). `generate` wraps both in a `lax.scan`, so an N-token
-generation is exactly two compiled programs.
+One cache form: ``[L, slots, max_len, H, Dh]`` K and V, preallocated,
+with a decode offset per row (``pos: [slots]``). Continuous batching
+(serve/decode_scheduler.py) needs exactly that: one sequence prefills
+into an open row while the other rows keep stepping, and a finished row
+frees at once. Whole-batch generation (``generate``) is its
+all-rows-active case. Every shape is static, so serving is two compiled
+programs, each a ``lax.scan`` of ``transformer.block`` (the one
+definition of the layer) over the stacked layers and their cache rows:
 
-Oracle: greedy generate() must match per-step argmax of the FULL
-forward() on the growing prefix — tests/test_ops.py asserts this
-exactly, which pins the cache bookkeeping (rope offsets, masking,
-update slices) to the training forward's semantics.
+* ``slot_prefill`` runs a prompt through the block with the training
+  forward's rope and attention (flash kernel on TPU, XLA off it) and
+  keeps the roped K/V in the slot's rows;
+* ``slot_decode_step`` ropes each row's new token at the row's own
+  position, writes its K/V there and attends the cache under a per-row
+  mask (no recompute, no dynamic shapes).
+
+Invariants the scheduler relies on:
+
+* ``slot_prefill`` rewrites rows [0, T0) of its slot and resets that
+  slot's pos, so a reused slot never sees its predecessor's K/V — the
+  stale tail beyond T0 is always overwritten (step s writes position
+  pos BEFORE attending it) and never attended.
+* ``slot_decode_step`` writes every row's K/V unconditionally (a
+  masked write would cost a gather per layer) but advances ``pos``
+  only where ``active``: an inactive row's cache may take garbage at
+  its frozen pos, which is sound because inactive rows are only ever
+  re-entered through ``slot_prefill``.
+* A row at ``pos == max_len`` would have its write clamped onto the
+  last position; the callers refuse before that (``generate``'s
+  ``T0 + steps > max_len``, ``JaxSlotEngine.step``'s host mirror).
+
+Oracle: greedy decoding must match the per-step argmax of the FULL
+forward() on the growing prefix, which shares no cache code —
+tests/test_ops.py and tests/test_decode_scheduler.py assert it exactly,
+which pins the cache bookkeeping (rope offsets, masking, row writes) to
+the training forward's semantics.
 """
 
 from __future__ import annotations
@@ -26,152 +47,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.models.transformer import TransformerConfig, block, unembed
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.norms import rmsnorm
-from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
-
-
-def init_kv_cache(cfg: TransformerConfig, batch: int,
-                  max_len: int) -> Dict:
-    shape = (cfg.n_layers, batch, max_len, cfg.n_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "pos": jnp.zeros((), jnp.int32)}
-
-
-def _qkv(lp, h, Dh):
-    B, T = h.shape[:2]
-    q = (h @ lp["wq"]).reshape(B, T, -1, Dh)
-    k = (h @ lp["wk"]).reshape(B, T, -1, Dh)
-    v = (h @ lp["wv"]).reshape(B, T, -1, Dh)
-    return q, k, v
-
-
-def _mlp(lp, x):
-    h = rmsnorm(x, lp["mlp_norm"])
-    g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
-    u = (h @ lp["w_up"]).astype(jnp.float32)
-    return x + ((g * u).astype(x.dtype) @ lp["w_down"]).astype(x.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def prefill(params, tokens, cache: Dict,
-            cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
-    """Run the prompt [B, T0] through the stack, writing each layer's
-    K/V into the cache. Returns (last-token logits [B, V], cache)."""
-    B, T0 = tokens.shape
-    max_len = cache["k"].shape[2]
-    cos, sin = rope_frequencies(cfg.head_dim, max_len,
-                                theta=cfg.rope_theta)
-    positions = jnp.arange(T0)
-    x = params["embed"][tokens]
-
-    def body(x, layer_in):
-        lp, ck, cv = layer_in
-        h = rmsnorm(x, lp["attn_norm"])
-        q, k, v = _qkv(lp, h, cfg.head_dim)
-        q = apply_rotary(q, cos, sin, positions=positions)
-        k = apply_rotary(k, cos, sin, positions=positions)
-        # same kernel as the training forward's local path (Pallas on
-        # TPU, XLA fallback off-TPU) so prefill logits match forward()
-        # bit for bit and long prompts keep the blocked-VMEM property
-        o = flash_attention(q, k, v, causal=True).reshape(B, T0, -1)
-        x = x + (o @ lp["wo"]).astype(x.dtype)
-        x = _mlp(lp, x)
-        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                      (0, 0, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                      (0, 0, 0, 0))
-        return x, (ck, cv)
-
-    x, (ck, cv) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rmsnorm(x, params["final_norm"])
-    # bf16 matmul then f32, bit-matching the training forward's
-    # unembed so greedy decode agrees with full-forward argmax exactly
-    logits = (x[:, -1] @ params["embed"].T.astype(x.dtype)
-              ).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv,
-                    "pos": jnp.asarray(T0, jnp.int32)}
-
-
-def decode_step(params, cache: Dict, token,
-                cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
-    """One token [B] in, next-token logits [B, V] out; cache advances.
-    Eager-call entry with a capacity check — dynamic_update_slice
-    CLAMPS out-of-range writes, so stepping past max_len would
-    silently overwrite the last slot instead of failing."""
-    if int(cache["pos"]) >= cache["k"].shape[2]:
-        raise ValueError(
-            f"KV cache full (pos {int(cache['pos'])} of "
-            f"{cache['k'].shape[2]}); allocate a larger max_len")
-    return _decode_step_jit(params, cache, token, cfg)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _decode_step_jit(params, cache: Dict, token,
-                     cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
-    """Jitted body: a single fused device program per step (attention
-    against the full static-shape cache with a position mask)."""
-    B = token.shape[0]
-    max_len = cache["k"].shape[2]
-    pos = cache["pos"]
-    cos, sin = rope_frequencies(cfg.head_dim, max_len,
-                                theta=cfg.rope_theta)
-    positions = pos[None]  # [1]
-    x = params["embed"][token][:, None, :]  # [B, 1, D]
-    sm_scale = cfg.head_dim ** -0.5
-    valid = (jnp.arange(max_len) <= pos)[None, None, :]  # [1,1,Tmax]
-
-    def body(x, layer_in):
-        lp, ck, cv = layer_in
-        h = rmsnorm(x, lp["attn_norm"])
-        q, k, v = _qkv(lp, h, cfg.head_dim)
-        q = apply_rotary(q, cos, sin, positions=positions)
-        k = apply_rotary(k, cos, sin, positions=positions)
-        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                      (0, pos, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                      (0, pos, 0, 0))
-        s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], ck,
-                       preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(valid, s, -jnp.inf)
-        # accumulation dtypes bit-match ops.attention (softmax fp32,
-        # p cast to the value dtype, p@v accumulated in fp32)
-        p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
-        o = jnp.einsum("bhk,bkhd->bhd", p, cv,
-                       preferred_element_type=jnp.float32
-                       ).astype(q.dtype)
-        x = x + (o.reshape(B, 1, -1) @ lp["wo"]).astype(x.dtype)
-        x = _mlp(lp, x)
-        return x, (ck, cv)
-
-    x, (ck, cv) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rmsnorm(x[:, 0], params["final_norm"])
-    logits = (x @ params["embed"].T.astype(x.dtype)
-              ).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv, "pos": pos + 1}
-
-
-# ------------------------------------------------------------- slot cache
-# Continuous batching (serve/decode_scheduler.py) needs per-SLOT decode
-# offsets: one sequence prefills into an open batch row while the other
-# rows keep stepping, and a finished row frees immediately. The whole-
-# batch cache above carries a single scalar ``pos``; these variants
-# carry ``pos: [slots]`` and mask per row. Invariants the scheduler
-# relies on:
-#
-# * ``slot_prefill`` rewrites rows [0, T0) of its slot and resets that
-#   slot's pos, so a reused slot never sees its predecessor's K/V — the
-#   stale tail beyond T0 is always overwritten (step s writes position
-#   pos BEFORE attending it) and never attended.
-# * ``slot_decode_step`` writes every row's K/V unconditionally (a
-#   masked write would cost a gather per layer) but advances ``pos``
-#   only where ``active``: an inactive row's cache may take garbage at
-#   its frozen pos, which is sound because inactive rows are only ever
-#   re-entered through ``slot_prefill``.
+from ray_tpu.ops.rotary import apply_rotary, rope_frequencies, rotate
 
 
 def init_slot_cache(cfg: TransformerConfig, slots: int,
@@ -195,18 +73,19 @@ def slot_prefill(params, tokens, cache: Dict, slot,
     max_len = cache["k"].shape[2]
     cos, sin = rope_frequencies(cfg.head_dim, max_len,
                                 theta=cfg.rope_theta)
-    positions = jnp.arange(T0)
+    rope = functools.partial(apply_rotary, cos=cos, sin=sin,
+                             positions=jnp.arange(T0))
     x = params["embed"][tokens]
+
+    def attend(q, k, v):
+        # the training forward's local attention, so the last token's
+        # logits are forward()'s; the roped k and v are what a later
+        # step attends
+        return flash_attention(q, k, v, causal=True), (k, v)
 
     def body(x, layer_in):
         lp, ck, cv = layer_in  # ck/cv: [slots, max_len, H, Dh]
-        h = rmsnorm(x, lp["attn_norm"])
-        q, k, v = _qkv(lp, h, cfg.head_dim)
-        q = apply_rotary(q, cos, sin, positions=positions)
-        k = apply_rotary(k, cos, sin, positions=positions)
-        o = flash_attention(q, k, v, causal=True).reshape(1, T0, -1)
-        x = x + (o @ lp["wo"]).astype(x.dtype)
-        x = _mlp(lp, x)
+        x, (k, v) = block(lp, x, rope, attend, cfg)
         ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
                                       (slot, 0, 0, 0))
         cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
@@ -215,22 +94,8 @@ def slot_prefill(params, tokens, cache: Dict, slot,
 
     x, (ck, cv) = lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rmsnorm(x, params["final_norm"])
-    logits = (x[:, -1] @ params["embed"].T.astype(x.dtype)
-              ).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv,
-                    "pos": cache["pos"].at[slot].set(T0)}
-
-
-def _rotary_rows(x, cos, sin, pos):
-    """apply_rotary for per-ROW positions: x [B, 1, H, D], pos [B].
-    (ops.rotary broadcasts one [T] position vector over the batch; a
-    continuous batch has every row at a different offset.)"""
-    c = cos[pos][:, None, None, :]
-    s = sin[pos][:, None, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    return out.astype(x.dtype)
+    return unembed(params, x, last=True), {
+        "k": ck, "v": cv, "pos": cache["pos"].at[slot].set(T0)}
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -252,34 +117,33 @@ def slot_decode_step(params, cache: Dict, token, active,
              <= pos[:, None, None])  # [B, 1, Tmax]
     rows = jnp.arange(B)
 
+    def rope(t):  # every row at its own position
+        return rotate(t, cos[pos][:, None, None, :],
+                      sin[pos][:, None, None, :])
+
     def body(x, layer_in):
         lp, ck, cv = layer_in
-        h = rmsnorm(x, lp["attn_norm"])
-        q, k, v = _qkv(lp, h, cfg.head_dim)
-        q = _rotary_rows(q, cos, sin, pos)
-        k = _rotary_rows(k, cos, sin, pos)
-        ck = ck.at[rows, pos].set(k[:, 0].astype(ck.dtype))
-        cv = cv.at[rows, pos].set(v[:, 0].astype(cv.dtype))
-        s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], ck,
-                       preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(valid, s, -jnp.inf)
-        # accumulation dtypes bit-match _decode_step_jit so a batch of
-        # one slot reproduces the whole-batch decode exactly
-        p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
-        o = jnp.einsum("bhk,bkhd->bhd", p, cv,
-                       preferred_element_type=jnp.float32
-                       ).astype(q.dtype)
-        x = x + (o.reshape(B, 1, -1) @ lp["wo"]).astype(x.dtype)
-        x = _mlp(lp, x)
-        return x, (ck, cv)
+
+        def attend(q, k, v):
+            nk = ck.at[rows, pos].set(k[:, 0].astype(ck.dtype))
+            nv = cv.at[rows, pos].set(v[:, 0].astype(cv.dtype))
+            s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], nk,
+                           preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(valid, s, -jnp.inf)
+            # accumulation dtypes as ops.attention's: softmax fp32, p
+            # cast to the value dtype, p@v accumulated in fp32
+            p = jax.nn.softmax(s, axis=-1).astype(nv.dtype)
+            o = jnp.einsum("bhk,bkhd->bhd", p, nv,
+                           preferred_element_type=jnp.float32
+                           ).astype(q.dtype)
+            return o, (nk, nv)
+
+        return block(lp, x, rope, attend, cfg)
 
     x, (ck, cv) = lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rmsnorm(x[:, 0], params["final_norm"])
-    logits = (x @ params["embed"].T.astype(x.dtype)
-              ).astype(jnp.float32)
-    new_pos = jnp.where(active, pos + 1, pos)
-    return logits, {"k": ck, "v": cv, "pos": new_pos}
+    return unembed(params, x[:, 0]), {
+        "k": ck, "v": cv, "pos": jnp.where(active, pos + 1, pos)}
 
 
 @functools.partial(jax.jit,
@@ -291,6 +155,8 @@ def _decode_loop(params, logits, cache, key, temperature, *, cfg,
     closure would retrace every invocation, and a static temperature
     would recompile per distinct float, so only the greedy/sampling
     BRANCH is static and the magnitude is a traced operand."""
+    active = jnp.ones(logits.shape[0], bool)
+
     def pick(logits, k):
         if not sample:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -302,11 +168,11 @@ def _decode_loop(params, logits, cache, key, temperature, *, cfg,
         key, sub = jax.random.split(key)
         tok = pick(logits, sub)
         # the token sampled on the LAST iteration needs no successor
-        # logits: skip its decode_step (at steps=1 this halves the
+        # logits: skip its decode step (at steps=1 this halves the
         # per-generation device work)
         logits, cache = lax.cond(
             i < steps - 1,
-            lambda: _decode_step_jit(params, cache, tok, cfg),
+            lambda: slot_decode_step(params, cache, tok, active, cfg),
             lambda: (logits, cache))
         return (logits, cache, key), tok
 
@@ -321,8 +187,9 @@ def generate(params, prompt, cfg: TransformerConfig, *, steps: int,
     """Autoregressive sampling: greedy at temperature 0, categorical
     otherwise (an explicit ``key`` is required then — a silent fixed
     seed would make every call return the same completion). Returns
-    generated tokens [B, steps]. Two compiled programs total, cached
-    across calls: prefill + the scanned decode loop."""
+    generated tokens [B, steps]. The slot cache with every row active:
+    two compiled programs total, cached across calls — ``slot_prefill``
+    (dispatched once a prompt row) and the scanned decode loop."""
     B, T0 = prompt.shape
     max_len = max_len or min(cfg.max_seq, T0 + steps)
     if T0 + steps > max_len:
@@ -330,11 +197,15 @@ def generate(params, prompt, cfg: TransformerConfig, *, steps: int,
                          f"max_len ({max_len})")
     if temperature > 0.0 and key is None:
         raise ValueError("temperature > 0 requires an explicit key")
-    cache = init_kv_cache(cfg, B, max_len)
-    logits, cache = prefill(params, prompt, cache, cfg)
+    cache = init_slot_cache(cfg, B, max_len)
+    first = []
+    for row in range(B):
+        logits, cache = slot_prefill(params, prompt[row:row + 1], cache,
+                                     jnp.int32(row), cfg)
+        first.append(logits)
     if key is None:
         key = jax.random.key(0)  # unused by the greedy path
-    return _decode_loop(params, logits, cache, key,
+    return _decode_loop(params, jnp.concatenate(first), cache, key,
                         jnp.asarray(max(temperature, 1e-8),
                                     jnp.float32),
                         cfg=cfg, steps=steps,

@@ -1,6 +1,15 @@
 """Decoder-only transformer (Llama-style) over a dp/pp/sp/tp mesh.
 
-One model definition, two execution modes sharing every line of math:
+**One block.** ``block`` is the only definition of the layer: norms,
+projections, feed-forward and residuals. What differs between training,
+prefill and decode is handed to it: ``rope`` (how q and k are rotated)
+and ``attend`` (what the queries attend, and the state that comes
+back). ``forward`` here and ``slot_prefill`` / ``slot_decode_step`` in
+models/decode.py each scan it over the stacked layers; ``unembed`` is
+their shared final norm and tied head. A change to the architecture is
+a change to ``block``, ``init_params`` and ``param_specs``.
+
+``forward`` runs in two modes sharing every line of math:
 
 * **oracle** — ``ParallelConfig()`` with all axes ``None``: plain
   single-device forward (the differential-test reference).
@@ -72,13 +81,7 @@ class ParallelConfig:
     sp: Optional[str] = None
     tp: Optional[str] = None
     attn: str = "auto"          # auto | local | ring | ulysses
-    remat: bool = False
-    # Rematerialization policy when remat is on (jax.checkpoint
-    # policies): "full" recomputes the whole layer (minimum HBM,
-    # maximum recompute); "dots" / "dots_no_batch" save the MXU matmul
-    # outputs and recompute only the cheap elementwise work — the
-    # standard MFU/HBM middle ground on TPU.
-    remat_policy: str = "full"
+    remat: bool = False         # jax.checkpoint around each block
     num_microbatches: Optional[int] = None
 
     def data_axes(self):
@@ -150,9 +153,15 @@ def _attend(q, k, v, pcfg: ParallelConfig):
     raise ValueError(f"unknown attn impl {impl!r}")
 
 
-def _layer(lp, x, cos, sin, positions, cfg: TransformerConfig,
-           pcfg: ParallelConfig):
-    """One block on local shards. x: [B_l, T_l, D] (tp-replicated)."""
+def block(lp, x, rope, attend, cfg: TransformerConfig,
+          pcfg: ParallelConfig = ParallelConfig()):
+    """The transformer block, on local shards. x: [B_l, T_l, D]
+    (tp-replicated); ``lp`` one layer's weights. ``rope(t)`` rotates q
+    and k; ``attend(q, k, v) -> (o, state)`` takes them as [B, T,
+    H_local, Dh] and gives the attention output, which is flattened
+    here to [B, T, H_local * Dh], and whatever the caller keeps of the
+    layer (the K/V a cache holds; None in training). Returns
+    (x, state)."""
     B, T, D = x.shape
     Dh = cfg.head_dim
 
@@ -162,10 +171,8 @@ def _layer(lp, x, cos, sin, positions, cfg: TransformerConfig,
     q = (h @ lp["wq"]).reshape(B, T, -1, Dh)      # H_local heads
     k = (h @ lp["wk"]).reshape(B, T, -1, Dh)
     v = (h @ lp["wv"]).reshape(B, T, -1, Dh)
-    q = apply_rotary(q, cos, sin, positions=positions)
-    k = apply_rotary(k, cos, sin, positions=positions)
-    o = _attend(q, k, v, pcfg).reshape(B, T, -1)
-    o = o @ lp["wo"]                               # row-parallel
+    o, state = attend(rope(q), rope(k), v)
+    o = o.reshape(B, T, -1) @ lp["wo"]             # row-parallel
     if pcfg.tp:
         o = tp_allreduce(o, pcfg.tp)
     x = x + o.astype(x.dtype)
@@ -178,29 +185,33 @@ def _layer(lp, x, cos, sin, positions, cfg: TransformerConfig,
     d = (g * u).astype(x.dtype) @ lp["w_down"]     # row-parallel
     if pcfg.tp:
         d = tp_allreduce(d, pcfg.tp)
-    return x + d.astype(x.dtype)
+    return x + d.astype(x.dtype), state
 
 
-def _stack_fn(cfg, pcfg, cos, sin, positions):
+def unembed(params, x, *, last=False):
+    """Final norm and the tied unembed of x [..., D], or with ``last``
+    of the last position alone of x [B, T, D] (a prefill). The matmul
+    runs at x's dtype and the logits are float32: a stable
+    softmax-xent, and one rounding for every caller, so that a greedy
+    decode agrees with the full forward's argmax."""
+    x = rmsnorm(x, params["final_norm"])
+    if last:
+        x = x[:, -1]
+    return (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
+
+
+def _stack_fn(cfg, pcfg, rope):
     """Scan the (locally held) layer stack over one activation."""
+    def layer(lp, x):
+        return block(lp, x, rope,
+                     lambda q, k, v: (_attend(q, k, v, pcfg), None),
+                     cfg, pcfg)
+
+    if pcfg.remat:
+        layer = jax.checkpoint(layer)
+
     def run(layers, x):
-        layer = functools.partial(_layer, cos=cos, sin=sin,
-                                  positions=positions, cfg=cfg, pcfg=pcfg)
-        if pcfg.remat:
-            policy = {
-                "full": None,
-                "dots": jax.checkpoint_policies.checkpoint_dots,
-                "dots_no_batch":
-                    jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            }[pcfg.remat_policy]
-            layer = jax.checkpoint(layer, policy=policy) if policy \
-                else jax.checkpoint(layer)
-
-        def body(h, lp):
-            return layer(lp, h), None
-
-        out, _ = lax.scan(body, x, layers)
-        return out
+        return lax.scan(lambda h, lp: layer(lp, h), x, layers)[0]
     return run
 
 
@@ -219,15 +230,14 @@ def forward(params, tokens, cfg: TransformerConfig,
         positions = jnp.arange(T)
 
     x = params["embed"][tokens]                    # [B,T,D]
-    stack = _stack_fn(cfg, pcfg, cos, sin, positions)
+    stack = _stack_fn(cfg, pcfg, functools.partial(
+        apply_rotary, cos=cos, sin=sin, positions=positions))
     if pcfg.pp:
         x = pipeline_spmd(stack, params["layers"], x, axis=pcfg.pp,
                           num_microbatches=pcfg.num_microbatches)
     else:
         x = stack(params["layers"], x)
-    x = rmsnorm(x, params["final_norm"])
-    # tied unembed; logits fp32 for a stable softmax-xent
-    return (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
+    return unembed(params, x)
 
 
 def loss_fn(params, batch, cfg: TransformerConfig,

@@ -15,16 +15,23 @@ def rope_frequencies(head_dim: int, max_seq: int, *,
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def rotate(x, c, s):
+    """x: [B, T, H, D] rotated pairwise (first half against second) by
+    the angles whose cosines and sines ``c``, ``s`` broadcast against
+    [B, T, H, D//2]. Arithmetic in float32, result at x's dtype."""
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return out.astype(x.dtype)
+
+
 def apply_rotary(x, cos, sin, *, positions=None):
     """x: [B, T, H, D]; cos/sin: [max_seq, D//2]. positions: [T] global
-    token positions (for sequence-parallel shards / decode offsets)."""
+    token positions, shared by the batch (for sequence-parallel shards /
+    prefill); a batch whose rows stand at different positions gathers
+    its own rows of the tables and calls ``rotate``."""
     T = x.shape[1]
     if positions is None:
         c, s = cos[:T], sin[:T]
     else:
         c, s = cos[positions], sin[positions]
-    c = c[None, :, None, :]
-    s = s[None, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    return out.astype(x.dtype)
+    return rotate(x, c[None, :, None, :], s[None, :, None, :])

@@ -27,6 +27,11 @@ _CURRENT = os.path.join(_BASE, "ray_current_cluster")
 _PIDS = os.path.join(_BASE, "cli_node_pids")
 
 
+def _node_log(pid: int) -> str:
+    """Where a CLI-started node's stderr is kept until ``stop``."""
+    return os.path.join(_BASE, f"cli_node_{pid}.err")
+
+
 def _read_current_address() -> str:
     try:
         with open(_CURRENT) as f:
@@ -68,18 +73,30 @@ def cmd_start(args) -> None:
     if args.resources:
         cmd += ["--resources", args.resources]
 
-    proc = subprocess.Popen(cmd, start_new_session=True,
-                            stdout=subprocess.DEVNULL,
-                            stderr=subprocess.DEVNULL)
+    # the node's stderr (its log, and the reason when it cannot start)
+    # goes to a file: a pipe would block or break once this CLI exits
+    err_path = addr_file + ".err"
+    with open(err_path, "wb") as err:
+        proc = subprocess.Popen(cmd, start_new_session=True,
+                                stdout=subprocess.DEVNULL, stderr=err)
+
+    def give_up(why: str):
+        with open(err_path, errors="replace") as f:
+            tail = f.read()[-4000:].strip()
+        os.unlink(err_path)
+        sys.exit(f"{why}; the node's last output:\n{tail}" if tail
+                 else why)
+
     deadline = time.monotonic() + 60
     while time.monotonic() < deadline and not os.path.exists(addr_file):
         if proc.poll() is not None:
-            sys.exit(f"node process exited early (rc={proc.returncode})")
+            give_up(f"node process exited early (rc={proc.returncode})")
         # raylint: disable=async-blocking — CLI process waiting on a child daemon's address file; no loop here
         time.sleep(0.1)
     if not os.path.exists(addr_file):
         proc.terminate()
-        sys.exit("timed out waiting for the node to come up")
+        give_up("timed out waiting for the node to come up")
+    os.replace(err_path, _node_log(proc.pid))
     with open(addr_file) as f:
         gcs_address, raylet_address, session_dir = \
             f.read().strip().splitlines()
@@ -120,7 +137,7 @@ def cmd_stop(args) -> None:
             stopped += 1
         except (ProcessLookupError, PermissionError):
             pass
-    for path in (_PIDS, _CURRENT):
+    for path in (_PIDS, _CURRENT, *map(_node_log, pids)):
         try:
             os.unlink(path)
         except FileNotFoundError:

@@ -5,8 +5,10 @@ Mirrors the reference's CLI smoke coverage
 """
 
 import os
+import shutil
 import subprocess
 import sys
+import tempfile
 
 import pytest
 
@@ -20,8 +22,11 @@ def _run(tmpbase, *argv, timeout=90):
         capture_output=True, text=True, timeout=timeout, env=env)
 
 
-def test_cli_lifecycle(tmp_path):
-    base = str(tmp_path)
+def test_cli_lifecycle():
+    # a short base of its own: under xdist pytest's tmp_path is so deep
+    # that <base>/session_*/sockets/<name>.sock passes the 108 bytes a
+    # sockaddr_un holds
+    base = tempfile.mkdtemp(dir="/tmp")
     try:
         r = _run(base, "start", "--head", "--num-cpus", "2")
         assert r.returncode == 0, r.stderr
@@ -56,7 +61,7 @@ def test_cli_lifecycle(tmp_path):
             env={**os.environ, "PYTHONPATH": REPO,
                  "RAY_TPU_TMPDIR": base})
         assert r.returncode == 0, r.stderr
-        out_json = str(tmp_path / "timeline.json")
+        out_json = os.path.join(base, "timeline.json")
         r = _run(base, "timeline", "--output", out_json)
         assert r.returncode == 0, r.stderr
         assert "wrote" in r.stdout
@@ -82,7 +87,25 @@ def test_cli_lifecycle(tmp_path):
         assert "node" in r.stdout
     finally:
         r = _run(base, "stop")
+        shutil.rmtree(base, ignore_errors=True)
     assert "stopped" in r.stdout
+
+
+def test_cli_start_says_why_the_node_could_not_start():
+    """A base too long for a unix socket's address: ``start --head``
+    fails with the node's own reason in its stderr, not only an exit
+    code."""
+    base = tempfile.mkdtemp(dir="/tmp")
+    long_base = os.path.join(base, "d" * (100 - len(base) - 1))
+    assert len(long_base) == 100
+    try:
+        r = _run(long_base, "start", "--head", "--num-cpus", "1")
+        assert r.returncode != 0
+        assert "node process exited early" in r.stderr
+        assert "AF_UNIX path too long" in r.stderr, r.stderr
+    finally:
+        _run(long_base, "stop")
+        shutil.rmtree(base, ignore_errors=True)
 
 
 def test_cli_microbenchmark(tmp_path):

@@ -247,6 +247,84 @@ def test_flash_fallback_paths():
         np.asarray(attention(*qd)), atol=1e-6)
 
 
+_TINY = {
+    "f32": dict(vocab=97, d_model=64, n_heads=4, n_layers=3, d_ff=128,
+                max_seq=64, dtype=jnp.float32),
+    "bf16": dict(vocab=61, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+                 max_seq=32, dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_TINY))
+def test_train_prefill_and_decode_are_one_body(name):
+    """``transformer.block`` under its three callers: ``slot_prefill``
+    of each of B prompts into its slot, then ``slot_decode_step`` with
+    every row active, against ``forward()`` on the growing prefix (no
+    cache code at all). At every step, the prefill's included, the
+    argmax is forward()'s exactly and the logits are forward()'s to
+    float tolerance (the same products summed in another order: a
+    one-row unembed, a cache read back)."""
+    from ray_tpu.models import TransformerConfig, forward, init_params
+    from ray_tpu.models import decode
+
+    cfg = TransformerConfig(**_TINY[name])
+    params = init_params(jax.random.key(0), cfg)
+    B, T0, steps = 3, 5, 6
+    prompt = jax.random.randint(jax.random.key(1), (B, T0), 0, cfg.vocab)
+    tol = 2e-5 if cfg.dtype == jnp.float32 else 3e-2
+
+    cache = decode.init_slot_cache(cfg, B, T0 + steps)
+    rows = []
+    for b in range(B):
+        logits, cache = decode.slot_prefill(
+            params, prompt[b:b + 1], cache, jnp.int32(b), cfg)
+        rows.append(logits)
+    logits = jnp.concatenate(rows)
+    prefix = np.asarray(prompt)
+    full = forward(params, jnp.asarray(prefix), cfg)[:, -1]
+    active = jnp.ones(B, bool)
+    for t in range(steps):
+        nxt = np.asarray(jnp.argmax(full, axis=-1))
+        np.testing.assert_array_equal(
+            np.asarray(jnp.argmax(logits, axis=-1)), nxt,
+            err_msg=f"step {t}")
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(full),
+                                   atol=tol, err_msg=f"step {t}")
+        prefix = np.concatenate([prefix, nxt[:, None]], axis=1)
+        logits, cache = decode.slot_decode_step(
+            params, cache, jnp.asarray(nxt, jnp.int32), active, cfg)
+        full = forward(params, jnp.asarray(prefix), cfg)[:, -1]
+    np.testing.assert_array_equal(np.asarray(cache["pos"]),
+                                  [T0 + steps] * B)
+
+
+@pytest.mark.parametrize("name", sorted(_TINY))
+def test_slot_prefill_leaves_the_other_rows_untouched(name):
+    """``slot_prefill`` of one prompt into slot 1 of 3 writes rows
+    [0, T0) of that slot and its pos; rows 0 and 2 keep every byte."""
+    from ray_tpu.models import TransformerConfig, init_params
+    from ray_tpu.models import decode
+
+    cfg = TransformerConfig(**_TINY[name])
+    params = init_params(jax.random.key(0), cfg)
+    T0, max_len = 4, 8
+    before = decode.init_slot_cache(cfg, 3, max_len)
+    marks = jax.random.normal(jax.random.key(2), before["k"].shape,
+                              cfg.dtype)
+    before = {"k": marks, "v": -marks, "pos": jnp.asarray([7, 2, 5])}
+    prompt = jax.random.randint(jax.random.key(3), (1, T0), 0, cfg.vocab)
+    _, after = decode.slot_prefill(params, prompt, before, jnp.int32(1),
+                                   cfg)
+    np.testing.assert_array_equal(np.asarray(after["pos"]), [7, T0, 5])
+    for kv in ("k", "v"):
+        was, now = (np.asarray(c[kv], np.float32)
+                    for c in (before, after))
+        np.testing.assert_array_equal(now[:, [0, 2]], was[:, [0, 2]])
+        np.testing.assert_array_equal(now[:, 1, T0:], was[:, 1, T0:])
+        # every (layer, position, head) of the prompt's rows is new
+        assert (now[:, 1, :T0] != was[:, 1, :T0]).any(axis=-1).all()
+
+
 def test_kv_cached_decode_matches_full_forward():
     """Serving path (models/decode.py): greedy KV-cached generation
     must match per-step argmax of the FULL training forward on the
