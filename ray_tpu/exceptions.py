@@ -207,6 +207,24 @@ class ServeOverloadedError(RayTpuError):
         super().__init__(reason)
 
 
+class SlotStateLostError(RayTpuError):
+    """A serving program failed after it had taken the slot cache, so
+    the state of EVERY slot is lost, not one request's.
+
+    ``models/decode.py``'s two programs consume the cache they are
+    given (it is donated: the result is the same memory, written in
+    place), so a call that raises after dispatch leaves no cache to go
+    on from. ``JaxSlotEngine`` then starts over from an empty cache and
+    raises this, with the program's own error as ``__cause__``; the
+    decode scheduler fails every in-flight request with it and frees
+    every slot, and its queue and loop go on. A request that fails with
+    it was not served wrong: it can be sent again as it was."""
+
+    def __init__(self, reason: str = "slot cache lost"):
+        self.reason = reason
+        super().__init__(reason)
+
+
 class AsyncioActorExit(RayTpuError):
     """Raised inside an async actor to exit it gracefully."""
 

@@ -7,17 +7,34 @@ into an open row while the other rows keep stepping, and a finished row
 frees at once. Whole-batch generation (``generate``) is its
 all-rows-active case. Every shape is static, so serving is two compiled
 programs, each a ``lax.scan`` of ``transformer.block`` (the one
-definition of the layer) over the stacked layers and their cache rows:
+definition of the layer) over the stacked layers and their index, with
+the whole K and V as the scan's carry:
 
 * ``slot_prefill`` runs a prompt through the block with the training
   forward's rope and attention (flash kernel on TPU, XLA off it) and
-  keeps the roped K/V in the slot's rows;
+  writes the roped K/V into the carry at ``(layer, slot, 0, 0, 0)``;
 * ``slot_decode_step`` ropes each row's new token at the row's own
-  position, writes its K/V there and attends the cache under a per-row
+  position, scatters its K/V into the carry at ``[layer, rows, pos]``
+  and attends the layer's K/V, read out of the carry, under a per-row
   mask (no recompute, no dynamic shapes).
+
+The cache is one buffer, written in place. Both programs take it
+donated, and K and V are carried through the layers' scan, not scanned
+over: XLA then aliases the result to the argument and the only
+operations that produce K or V are the in-place writes of the new rows.
+Either half alone leaves a copy (a carry not donated is copied whole at
+entry; a donated cache that is a scanned input is sliced out and
+stacked again, layer by layer): tests/test_chip_compile.py holds the
+compiled programs to it.
 
 Invariants the scheduler relies on:
 
+* A call consumes the cache it is given: after ``slot_prefill`` or
+  ``slot_decode_step`` the argument's arrays are deleted (on every
+  backend) and the returned cache is the one to go on with. A caller
+  that wants the old state afterwards passes a copy. Inside another jit
+  (``_decode_loop``) the inner donation does nothing and the cache is
+  the outer carry.
 * ``slot_prefill`` rewrites rows [0, T0) of its slot and resets that
   slot's pos, so a reused slot never sees its predecessor's K/V — the
   stale tail beyond T0 is always overwritten (step s writes position
@@ -61,14 +78,16 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
             "pos": jnp.zeros((slots,), jnp.int32)}
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@functools.partial(jax.jit, static_argnames=("cfg",),
+                   donate_argnames=("cache",))
 def slot_prefill(params, tokens, cache: Dict, slot,
                  cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
     """Run one prompt [1, T0] through the stack, writing each layer's
     K/V into cache row ``slot`` (a traced index: one compiled program
-    serves every slot). Returns (last-token logits [1, V], cache).
-    Compiles once per distinct T0 — serving callers should bucket or
-    pad prompt lengths if retrace cost matters."""
+    serves every slot). Returns (last-token logits [1, V], cache); the
+    cache given is consumed and the one returned is its memory, updated
+    in place. Compiles once per distinct T0 — serving callers should
+    bucket or pad prompt lengths if retrace cost matters."""
     _, T0 = tokens.shape
     max_len = cache["k"].shape[2]
     cos, sin = rope_frequencies(cfg.head_dim, max_len,
@@ -83,28 +102,33 @@ def slot_prefill(params, tokens, cache: Dict, slot,
         # step attends
         return flash_attention(q, k, v, causal=True), (k, v)
 
-    def body(x, layer_in):
-        lp, ck, cv = layer_in  # ck/cv: [slots, max_len, H, Dh]
+    def body(carry, layer_in):
+        x, ck, cv = carry  # ck/cv: the whole [L, slots, max_len, H, Dh]
+        lp, i = layer_in
         x, (k, v) = block(lp, x, rope, attend, cfg)
-        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                      (slot, 0, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                      (slot, 0, 0, 0))
-        return x, (ck, cv)
+        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype)[None],
+                                      (i, slot, 0, 0, 0))
+        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype)[None],
+                                      (i, slot, 0, 0, 0))
+        return (x, ck, cv), None
 
-    x, (ck, cv) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, ck, cv), _ = lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers)))
     return unembed(params, x, last=True), {
         "k": ck, "v": cv, "pos": cache["pos"].at[slot].set(T0)}
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@functools.partial(jax.jit, static_argnames=("cfg",),
+                   donate_argnames=("cache",))
 def slot_decode_step(params, cache: Dict, token, active,
                      cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
     """One continuous-batching step: token [B] in, next-token logits
     [B, V] out; each ACTIVE row attends its own prefix (per-row
     position mask) and advances its own pos. Inactive rows are free
-    riders — their logits are garbage and their pos is frozen."""
+    riders — their logits are garbage and their pos is frozen. The
+    cache given is consumed and the one returned is its memory, with
+    one position a row and layer written in place."""
     B = token.shape[0]
     max_len = cache["k"].shape[2]
     pos = cache["pos"]  # [B]
@@ -121,27 +145,34 @@ def slot_decode_step(params, cache: Dict, token, active,
         return rotate(t, cos[pos][:, None, None, :],
                       sin[pos][:, None, None, :])
 
-    def body(x, layer_in):
-        lp, ck, cv = layer_in
+    def body(carry, layer_in):
+        x, ck, cv = carry  # ck/cv: the whole [L, B, max_len, H, Dh]
+        lp, i = layer_in
 
         def attend(q, k, v):
-            nk = ck.at[rows, pos].set(k[:, 0].astype(ck.dtype))
-            nv = cv.at[rows, pos].set(v[:, 0].astype(cv.dtype))
-            s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], nk,
+            # write, then attend: the layer's K/V are read out of the
+            # carry after the rows' new token is in it
+            nk = ck.at[i, rows, pos].set(k[:, 0].astype(ck.dtype))
+            nv = cv.at[i, rows, pos].set(v[:, 0].astype(cv.dtype))
+            lk = lax.dynamic_index_in_dim(nk, i, keepdims=False)
+            lv = lax.dynamic_index_in_dim(nv, i, keepdims=False)
+            s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], lk,
                            preferred_element_type=jnp.float32) * sm_scale
             s = jnp.where(valid, s, -jnp.inf)
             # accumulation dtypes as ops.attention's: softmax fp32, p
             # cast to the value dtype, p@v accumulated in fp32
-            p = jax.nn.softmax(s, axis=-1).astype(nv.dtype)
-            o = jnp.einsum("bhk,bkhd->bhd", p, nv,
+            p = jax.nn.softmax(s, axis=-1).astype(lv.dtype)
+            o = jnp.einsum("bhk,bkhd->bhd", p, lv,
                            preferred_element_type=jnp.float32
                            ).astype(q.dtype)
             return o, (nk, nv)
 
-        return block(lp, x, rope, attend, cfg)
+        x, (ck, cv) = block(lp, x, rope, attend, cfg)
+        return (x, ck, cv), None
 
-    x, (ck, cv) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, ck, cv), _ = lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers)))
     return unembed(params, x[:, 0]), {
         "k": ck, "v": cv, "pos": jnp.where(active, pos + 1, pos)}
 

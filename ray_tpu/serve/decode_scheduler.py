@@ -65,6 +65,7 @@ name, to be read as deltas:
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
 import time
 from collections import deque
@@ -72,7 +73,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 from ray_tpu._private import rpc
-from ray_tpu.exceptions import ServeOverloadedError
+from ray_tpu.exceptions import ServeOverloadedError, SlotStateLostError
 from ray_tpu.util.phases import phase, phase_add, phase_totals, recording
 
 logger = logging.getLogger(__name__)
@@ -246,6 +247,15 @@ class DecodeScheduler:
         if not req.future.done():
             req.future.set_result(req.tokens)
 
+    def _fail_active(self, e: Exception, what: str) -> None:
+        """Fail every in-flight request with ``e`` and free its slot."""
+        logger.error("%s failed: %r", what, e, exc_info=e)
+        for slot, req in list(self._active.items()):
+            del self._active[slot]
+            self._free.append(slot)
+            if not req.future.done():
+                req.future.set_exception(e)
+
     def _done(self, req: _Request) -> bool:
         return (len(req.tokens) >= req.max_tokens or
                 (req.eos_token is not None and req.tokens and
@@ -272,10 +282,20 @@ class DecodeScheduler:
                     await self._prefill(slot, req)
                 except Exception as e:  # noqa: BLE001 — one bad prompt
                     # must not kill the batch: fail ITS future, free the
-                    # slot, keep decoding everyone else
+                    # slot, keep decoding everyone else. That holds for
+                    # whatever is refused before the program is
+                    # dispatched (a prompt too long, an error while
+                    # tracing or compiling): the cache is untouched.
                     self._free.append(slot)
                     if not req.future.done():
                         req.future.set_exception(e)
+                    if isinstance(e, SlotStateLostError):
+                        # the program took the cache and then failed:
+                        # the engine starts from an empty one, so what
+                        # every active slot held is gone with it. As a
+                        # failed step: they fail typed, the queue and
+                        # the loop go on. Never a token off a zeroed row.
+                        self._fail_active(e, "prefill")
                     continue
                 finally:
                     if req.joined_mid_batch:
@@ -306,12 +326,7 @@ class DecodeScheduler:
             except Exception as e:  # noqa: BLE001 — a failed device
                 # step fails the IN-FLIGHT requests typed; the loop and
                 # the queue survive (shed at the door, never collapse)
-                logger.error("decode step failed: %r", e, exc_info=e)
-                for slot, req in list(self._active.items()):
-                    del self._active[slot]
-                    self._free.append(slot)
-                    if not req.future.done():
-                        req.future.set_exception(e)
+                self._fail_active(e, "decode step")
                 continue
             with phase("serve.emit"):
                 self.steps += 1
@@ -344,7 +359,18 @@ class JaxSlotEngine:
     that host array. No ``block_until_ready`` here or in ``prefill``:
     the fetch waits for the device, and its own enqueue overlaps the
     step (an explicit wait before it cost 0.9 ms a step on a v5e:
-    PERF.md, PR 25)."""
+    PERF.md, PR 25).
+
+    The cache is one device buffer for the engine's life: both programs
+    take it donated and return it written in place, so ``_cache`` is
+    reassigned to the same memory and the array handed in is dead
+    after the call. What a failed call leaves: one refused before
+    dispatch (the prompt's length, a full slot, an error while tracing
+    or compiling) raises as it is and leaves cache and mirror as they
+    were; one that raises after the buffers are gone (the program, or
+    the fetch of its result) leaves an EMPTY cache, a zeroed mirror and
+    :class:`~ray_tpu.exceptions.SlotStateLostError` — every slot has to
+    be prefilled again, and the scheduler fails what was in flight."""
 
     def __init__(self, params, cfg, *, slots: int, max_len: int):
         import jax  # deferred: scheduler users without a
@@ -361,6 +387,29 @@ class JaxSlotEngine:
         self._cache = decode_mod.init_slot_cache(cfg, slots, max_len)
         self._pos = [0] * self.slots    # host mirror of _cache["pos"]
 
+    @contextlib.contextmanager
+    def _giving_the_cache(self):
+        """Yields the cache for a program to consume. Where the program
+        or the fetch of its result raises: the error passes as it is if
+        the cache still lives (nothing was taken: a refusal while
+        tracing or compiling; donation takes the buffers at dispatch).
+        Else every slot's K/V went with it: start over from an empty
+        cache and say so, typed."""
+        given = self._cache
+        try:
+            yield given
+        except Exception as e:  # noqa: BLE001 — typed if the cache went
+            if not given["k"].is_deleted():
+                raise
+            self._cache = None      # a result half made goes first
+            self._cache = self._decode.init_slot_cache(
+                self._cfg, self.slots, self.max_len)
+            self._pos = [0] * self.slots
+            raise SlotStateLostError(
+                f"the slot cache was consumed by a call that failed "
+                f"({e!r}): the state of all {self.slots} slots is lost"
+            ) from e
+
     def prefill(self, slot: int, prompt) -> int:
         jnp = self._jnp
         tokens = jnp.asarray(prompt, jnp.int32)[None, :]
@@ -368,11 +417,11 @@ class JaxSlotEngine:
             raise ValueError(
                 f"prompt ({tokens.shape[1]}) >= slot max_len "
                 f"({self.max_len})")
-        logits, self._cache = self._decode.slot_prefill(
-            self._params, tokens, self._cache, jnp.int32(slot),
-            self._cfg)
-        self._pos[slot] = tokens.shape[1]
-        return int(jnp.argmax(logits[0]))
+        with self._giving_the_cache() as cache:
+            logits, self._cache = self._decode.slot_prefill(
+                self._params, tokens, cache, jnp.int32(slot), self._cfg)
+            self._pos[slot] = tokens.shape[1]
+            return int(jnp.argmax(logits[0]))
 
     def step(self, tokens: Dict[int, int]) -> Dict[int, int]:
         jnp = self._jnp
@@ -390,16 +439,17 @@ class JaxSlotEngine:
         with phase("serve.engine.put"):
             tok = jnp.asarray(tok, jnp.int32)
             act = jnp.asarray(act)
-        with phase("serve.engine.dispatch"):
-            logits, self._cache = self._decode.slot_decode_step(
-                self._params, self._cache, tok, act, self._cfg)
-            for slot in tokens:
-                self._pos[slot] += 1
-            nxt = jnp.argmax(logits, axis=-1)
-        with phase("serve.engine.wait"):
-            # the step's one transfer: waits for the device, then brings
-            # the whole int32[slots] row
-            row = self._jax.device_get(nxt)
+        with self._giving_the_cache() as cache:
+            with phase("serve.engine.dispatch"):
+                logits, self._cache = self._decode.slot_decode_step(
+                    self._params, cache, tok, act, self._cfg)
+                for slot in tokens:
+                    self._pos[slot] += 1
+                nxt = jnp.argmax(logits, axis=-1)
+            with phase("serve.engine.wait"):
+                # the step's one transfer: waits for the device, then
+                # brings the whole int32[slots] row
+                row = self._jax.device_get(nxt)
         with phase("serve.engine.read"):
             row = row.tolist()
             return {slot: row[slot] for slot in tokens}

@@ -7,6 +7,11 @@ names its three custom calls ``flash_fwd``, ``flash_bwd_dkv`` and
 ``flash_bwd_dq``, which is what the benchmark's kernel readers look up
 in a device trace.
 
+The serving cell's two programs are compiled the same way, at the
+cell's own shapes, and held to what keeps the slot cache one buffer:
+the cache aliased to the result, no temporary the size of a layer's K,
+and no operation that produces K or V but the in-place writes.
+
 The topology is described inside a fixture, by the one xdist worker
 that is given this file: libtpu loads in one process at a time, so no
 other test file may do the same and nothing here runs at import.
@@ -115,3 +120,151 @@ def test_flash_kernels_compile_for_v5e_under_their_own_names(
         arg, arg, arg).compile().as_text()
     # the forward, remat's second forward, and the backward's two
     assert mosaic_calls(both) == sorted(KERNELS + ("flash_fwd",))
+
+
+# ------------------------------------- the slot cache, written in place
+
+SERVING_CELL = "ouro-2.6b.decode-closed"
+INSTRUCTION = re.compile(
+    r"^\s*(ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\](?:\{[^}]*\})? "
+    r"([\w\-]+)\((.*)$")
+IN_PLACE = ("scatter", "dynamic-update-slice")
+
+
+def computations(compiled_text):
+    """{computation: [(is_root, name, shape, opcode, rest)]} of the
+    instructions with an array result (a tuple's holds no new array)."""
+    out, current = {}, None
+    for line in compiled_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            current = out.setdefault(head.group(1), [])
+        elif current is not None and (m := INSTRUCTION.match(line)):
+            root, name, shape, opcode, rest = m.groups()
+            current.append((bool(root), name,
+                            tuple(int(n) for n in shape.split(",") if n),
+                            opcode, rest))
+    return out
+
+
+def cache_producers(compiled_text, shapes):
+    """The instructions outside fused computations whose result has one
+    of ``shapes``, as (name, what it does): parameters and tuple
+    elements name memory and are left out; a fusion does what the root
+    of the computation it calls does."""
+    comps = computations(compiled_text)
+
+    def called(rest):
+        return re.search(r"calls=%([\w.\-]+)", rest).group(1)
+
+    root_of = {c: next(op for root, _, _, op, _ in ins if root)
+               for c, ins in comps.items() if any(i[0] for i in ins)}
+    fused = {called(rest) for ins in comps.values()
+             for *_, opcode, rest in ins if opcode == "fusion"}
+    found = []
+    for comp, ins in comps.items():
+        if comp in fused:
+            continue
+        for _, name, shape, opcode, rest in ins:
+            if shape not in shapes or opcode in ("parameter",
+                                                 "get-tuple-element"):
+                continue
+            found.append((name, root_of[called(rest)]
+                          if opcode == "fusion" else opcode))
+    return found
+
+
+@pytest.fixture(scope="module")
+def serving_cell():
+    """Cell 1 as the benchmark's worker builds it: the program's config
+    object, slots and slot length, the prompt lengths."""
+    from benchmarks import loader
+
+    bench = loader.load_benchmark()
+    cell = loader.find_cell(bench, SERVING_CELL)
+    config = loader.load_config(bench, cell["config"])
+    mix = loader.load_traffic(bench, cell["traffic"])
+    program = loader.family_module(loader.find_family(bench, config),
+                                   "program")
+    return (program.program_config(config, mix["slot_len"]),
+            int(mix["slots"]), int(mix["slot_len"]),
+            sorted(mix["prompt_lengths"]))
+
+
+def test_the_serving_cells_shapes_are_the_ones_compiled_here(serving_cell):
+    cfg, slots, slot_len, lengths = serving_cell
+    assert (cfg.n_layers, slots, slot_len, cfg.n_heads, cfg.head_dim,
+            lengths) == (48, 8, 1024, 16, 128, [128, 256])
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-128",
+                                     "prefill-256"])
+def test_the_slot_cache_is_one_buffer_written_in_place(
+        program, serving_cell, one_chip, no_compile_cache, monkeypatch):
+    """``slot_decode_step`` and ``slot_prefill`` at cell 1's shapes, for
+    the described v5e: the cache's K, V and pos are aliased to the
+    result (3.22 GB, all of it), the temporaries stay under one layer's
+    K (33.5 MB: no copy of a layer, let alone of the cache, has room),
+    and the only operations whose result is a layer's or the whole
+    cache's K or V are the two in-place writes. Neither half does it
+    alone: the parent's program (the cache a scanned input, nothing
+    donated) fails every one of these."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode, init_params
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg, slots, slot_len, _ = serving_cell
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len)))
+    if program == "decode":
+        lowered = decode.slot_decode_step.lower(
+            params, cache, array((slots,), jnp.int32),
+            array((slots,), jnp.bool_), cfg)
+    else:
+        length = int(program.split("-")[1])
+        lowered = decode.slot_prefill.lower(
+            params, array((1, length), jnp.int32), cache,
+            array((), jnp.int32), cfg)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+
+    # the cache's three leaves, and nothing else, alias the result
+    parameter = {leaf: int(n) for n, leaf in re.findall(
+        r"parameter\((\d+)\)[^\n]*op_name=\"cache\[\\'(\w+)\\'\]\"",
+        text)}
+    assert sorted(parameter) == ["k", "pos", "v"]
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliased, "nothing is aliased: the cache is not donated"
+    assert sorted(int(n) for n in re.findall(
+        r"\((\d+), \{\}, may-alias\)", aliased.group(1))
+    ) == sorted(parameter.values())
+
+    layer = (slots, slot_len, cfg.n_heads, cfg.head_dim)
+    whole = (cfg.n_layers,) + layer
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    layer_bytes = itemsize * slots * slot_len * cfg.n_heads * cfg.head_dim
+    memory = compiled.memory_analysis()
+    # K and V whole, and pos padded to a tile
+    assert 0 <= (memory.alias_size_in_bytes
+                 - 2 * cfg.n_layers * layer_bytes) <= 4096
+    assert memory.temp_size_in_bytes < layer_bytes
+
+    produced = cache_producers(text, {layer, whole})
+    assert len(produced) == 2, produced         # K's write and V's
+    assert {op for _, op in produced} <= set(IN_PLACE), produced
+    if program != "decode":
+        assert mosaic_calls(text) == ["flash_fwd"]

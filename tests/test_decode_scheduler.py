@@ -19,7 +19,7 @@ import time
 
 import pytest
 
-from ray_tpu.exceptions import ServeOverloadedError
+from ray_tpu.exceptions import ServeOverloadedError, SlotStateLostError
 from ray_tpu.serve.decode_scheduler import DecodeScheduler
 
 
@@ -649,6 +649,197 @@ def test_a_raising_program_leaves_the_mirror_where_the_cache_is(call):
     assert eng._pos == _device_pos(eng) == [3, 0]
 
 
+# ----------------------------- one cache, donated and written in place
+
+
+def _consuming(eng, fail):
+    """The engine's programs, each of which runs for real (so the cache
+    it is given is consumed) and then raises where ``fail`` names it
+    and ``fail`` is armed: the error of a device that gave out after
+    the dispatch."""
+    import types
+
+    real = eng._decode
+
+    def program(name):
+        def call(*args, **kwargs):
+            out = getattr(real, name)(*args, **kwargs)
+            if fail.get(name):
+                fail[name] -= 1
+                raise RuntimeError(f"device lost in {name}")
+            return out
+        return call
+
+    return types.SimpleNamespace(
+        init_slot_cache=real.init_slot_cache,
+        slot_prefill=program("slot_prefill"),
+        slot_decode_step=program("slot_decode_step"))
+
+
+@pytest.mark.parametrize("call", ["prefill", "step"])
+def test_a_call_consumes_the_engines_cache_and_leaves_one_to_go_on(call):
+    """Either program takes the engine's cache donated: the arrays it
+    was given are deleted after the call, ``_cache`` is the result and
+    is whole (its positions read back, the next call runs on it)."""
+    eng = _tiny_engine(slots=2, max_len=16)
+    last = {0: eng.prefill(0, [5, 11, 23])}
+    given = eng._cache
+    if call == "prefill":
+        eng.prefill(1, [40, 2])
+        want = [3, 2]
+    else:
+        last = eng.step(last)
+        want = [4, 0]
+    assert eng._cache is not given
+    assert all(given[name].is_deleted() for name in ("k", "v", "pos"))
+    assert not any(a.is_deleted() for a in eng._cache.values())
+    assert eng._pos == _device_pos(eng) == want
+    assert sorted(eng.step(last)) == [0]
+
+
+def test_a_script_of_prefills_and_steps_equals_forward_on_the_prefix():
+    """Prefills and steps through the engine, some slots sitting a step
+    out and one slot freed and taken again: every token the engine
+    gives is the argmax of ``forward()`` on that slot's growing prefix
+    (no cache code), so writing the cache in place changed no value."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import forward
+
+    eng = _tiny_engine(slots=3, max_len=32)
+
+    def oracle(prefix):
+        logits = forward(eng._params, jnp.asarray([prefix], jnp.int32),
+                         eng._cfg)
+        return int(jnp.argmax(logits[0, -1]))
+
+    prefix = {}
+    script = [("prefill", 0, [5, 11, 23]), ("prefill", 2, [40, 2]),
+              ("step", (0, 2)), ("step", (0,)),
+              ("prefill", 1, [88, 17, 3, 9, 1]), ("step", (0, 1, 2)),
+              ("step", (1, 2)),
+              ("prefill", 0, [7]),          # slot 0 taken again
+              ("step", (0, 1, 2)), ("step", (2,)), ("step", (0, 1, 2))]
+    for kind, *args in script:
+        if kind == "prefill":
+            slot, prompt = args
+            prefix[slot] = list(prompt)
+            got = {slot: eng.prefill(slot, prompt)}
+        else:
+            got = eng.step({s: prefix[s][-1] for s in args[0]})
+            assert sorted(got) == sorted(args[0])
+        for slot, tok in got.items():
+            assert tok == oracle(prefix[slot]), (kind, args, slot)
+            prefix[slot].append(tok)
+
+
+@pytest.mark.parametrize("call", ["prefill", "step"])
+def test_a_program_that_raises_with_the_cache_gone_starts_over(call):
+    """A program (or the fetch of its result) that raises after it has
+    consumed the cache: the engine raises the typed error with the
+    program's own as its cause, holds a fresh empty cache and a zeroed
+    mirror, and serves the next prompt from there."""
+    eng = _tiny_engine(slots=2, max_len=16)
+    first = eng.prefill(0, [5, 11, 23])
+    fail = {"slot_prefill" if call == "prefill"
+            else "slot_decode_step": 1}
+    eng._decode = _consuming(eng, fail)
+    with pytest.raises(SlotStateLostError,
+                       match="all 2 slots") as raised:
+        if call == "prefill":
+            eng.prefill(1, [40, 2])
+        else:
+            eng.step({0: first})
+    assert isinstance(raised.value.__cause__, RuntimeError)
+    assert eng._pos == _device_pos(eng) == [0, 0]
+    assert not any(a.is_deleted() for a in eng._cache.values())
+    assert not eng._cache["k"].any() and not eng._cache["v"].any()
+    assert eng.prefill(0, [5, 11, 23]) == first
+
+
+def _served_alone(prompt, n):
+    """What a fresh engine serves for one prompt, token by token."""
+    eng = _tiny_engine(slots=2, max_len=32)
+    out = [eng.prefill(0, prompt)]
+    while len(out) < n:
+        out.append(eng.step({0: out[-1]})[0])
+    return out
+
+
+@pytest.mark.parametrize("call", ["prefill", "step"])
+def test_lost_slot_state_fails_all_in_flight_and_the_next_is_right(call):
+    """The scheduler over an engine whose program raises after
+    consuming the cache, in a prefill that joins a running batch or in
+    a step: every request in flight fails with the typed error (none is
+    answered from a zeroed row), every slot is free again, the loop
+    lives, and the next request is served token for token what a fresh
+    engine serves."""
+    eng = _tiny_engine(slots=2, max_len=32)
+    fail = {}
+    eng._decode = _consuming(eng, fail)
+    real_step = eng.step
+
+    async def run():
+        armed = asyncio.Event()
+        loop = asyncio.get_running_loop()
+
+        def step(tokens):       # on the executor's thread
+            out = real_step(tokens)
+            loop.call_soon_threadsafe(armed.set)
+            return out
+
+        eng.step = step
+        sched = DecodeScheduler(eng)
+        running = asyncio.ensure_future(
+            sched.submit([5, 11, 23], max_tokens=20))
+        await armed.wait()          # the first request is decoding
+        if call == "prefill":
+            fail["slot_prefill"] = 1
+            late = [sched.submit([40, 2], max_tokens=4)]
+        else:
+            fail["slot_decode_step"] = 1
+            late = []
+        results = await asyncio.gather(running, *late,
+                                       return_exceptions=True)
+        stats = sched.stats()
+        after = await sched.submit([88, 17, 3], max_tokens=5)
+        await sched.aclose()
+        return results, stats, after
+
+    results, stats, after = asyncio.run(run())
+    assert all(isinstance(r, SlotStateLostError) for r in results), results
+    assert (stats["active_slots"], stats["free_slots"]) == (0, 2)
+    assert stats["completed"] == 0
+    assert after == _served_alone([88, 17, 3], 5)
+
+
+def test_a_prompt_refused_before_dispatch_still_fails_alone():
+    """A prompt as long as the slot is refused by the engine before any
+    program runs: the cache is not consumed, so its request alone fails
+    (with the engine's own error) and the one decoding beside it is
+    served in full and right."""
+    eng = _tiny_engine(slots=2, max_len=16)
+
+    async def run():
+        sched = DecodeScheduler(eng)
+        good = asyncio.ensure_future(
+            sched.submit([5, 11, 23], max_tokens=8))
+        await asyncio.sleep(0)
+        bad = sched.submit(list(range(16)), max_tokens=2)
+        results = await asyncio.gather(good, bad, return_exceptions=True)
+        stats = sched.stats()
+        await sched.aclose()
+        return results, stats
+
+    (good, bad), stats = asyncio.run(run())
+    assert isinstance(bad, ValueError)
+    assert not isinstance(bad, SlotStateLostError)
+    assert "slot max_len" in str(bad)
+    assert good == _served_alone([5, 11, 23], 8)
+    assert (stats["completed"], stats["free_slots"]) == (1, 2)
+
+
 class _HostReads:
     """Counts what reaches for a device array from the host while it is
     open: ``indexed`` (``x[i]``, a slice program each) and ``fetched``
@@ -716,14 +907,15 @@ def test_a_step_indexes_no_device_array_and_fetches_one(monkeypatch,
     thing: the argmax row, whole. The tokens it returns are those the
     row held."""
     eng = _tiny_engine(slots=3, max_len=32)
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
     last = {0: eng.prefill(0, [5, 11, 23]), 1: eng.prefill(1, [40, 2]),
             2: eng.prefill(2, [88])}
     last = eng.step(last)                   # compiles
-    logits, _ = eng._decode.slot_decode_step(   # pure: nothing is donated
-        eng._params, eng._cache,
+    logits, _ = eng._decode.slot_decode_step(   # consumes what it is given
+        eng._params, jax.tree.map(jnp.copy, eng._cache),
         jnp.asarray([last[s] for s in range(3)], jnp.int32),
         jnp.asarray([s in active for s in range(3)]), eng._cfg)
     row = np.asarray(jnp.argmax(logits, axis=-1)).tolist()

@@ -313,8 +313,10 @@ def test_slot_prefill_leaves_the_other_rows_untouched(name):
                               cfg.dtype)
     before = {"k": marks, "v": -marks, "pos": jnp.asarray([7, 2, 5])}
     prompt = jax.random.randint(jax.random.key(3), (1, T0), 0, cfg.vocab)
-    _, after = decode.slot_prefill(params, prompt, before, jnp.int32(1),
-                                   cfg)
+    # the program consumes the cache it is given: it gets a copy
+    _, after = decode.slot_prefill(params, prompt,
+                                   jax.tree.map(jnp.copy, before),
+                                   jnp.int32(1), cfg)
     np.testing.assert_array_equal(np.asarray(after["pos"]), [7, T0, 5])
     for kv in ("k", "v"):
         was, now = (np.asarray(c[kv], np.float32)
@@ -323,6 +325,50 @@ def test_slot_prefill_leaves_the_other_rows_untouched(name):
         np.testing.assert_array_equal(now[:, 1, T0:], was[:, 1, T0:])
         # every (layer, position, head) of the prompt's rows is new
         assert (now[:, 1, :T0] != was[:, 1, :T0]).any(axis=-1).all()
+
+
+@pytest.mark.parametrize("program", ["slot_prefill", "slot_decode_step"])
+@pytest.mark.parametrize("name", sorted(_TINY))
+def test_a_serving_program_consumes_the_cache_it_is_given(name, program):
+    """Both programs take the cache donated: K, V and pos of the
+    argument are deleted after the call (on the CPU backend too), the
+    result is whole, and it equals what the same call makes of a copy,
+    so the donation changes where the result lives and not what it is."""
+    from ray_tpu.models import TransformerConfig, init_params
+    from ray_tpu.models import decode
+
+    cfg = TransformerConfig(**_TINY[name])
+    params = init_params(jax.random.key(0), cfg)
+    marks = jax.random.normal(jax.random.key(2), (cfg.n_layers, 3, 8,
+                                                  cfg.n_heads,
+                                                  cfg.head_dim), cfg.dtype)
+    given = {"k": marks, "v": -marks, "pos": jnp.asarray([3, 2, 5])}
+    if program == "slot_prefill":
+        args = (jax.random.randint(jax.random.key(3), (1, 4), 0,
+                                   cfg.vocab),)
+
+        def call(cache):
+            return decode.slot_prefill(params, *args, cache,
+                                       jnp.int32(1), cfg)
+    else:
+        def call(cache):
+            return decode.slot_decode_step(
+                params, cache, jnp.asarray([5, 9, 2], jnp.int32),
+                jnp.asarray([True, False, True]), cfg)
+
+    want_logits, want = call(jax.tree.map(jnp.copy, given))
+    logits, after = call(given)
+    assert all(given[leaf].is_deleted() for leaf in ("k", "v", "pos"))
+    assert not any(a.is_deleted() for a in after.values())
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))
+    for leaf in ("k", "v", "pos"):
+        np.testing.assert_array_equal(
+            np.asarray(after[leaf], np.float32),
+            np.asarray(want[leaf], np.float32))
+    # ... and it is a cache to go on from
+    decode.slot_decode_step(params, after, jnp.zeros(3, jnp.int32),
+                            jnp.ones(3, bool), cfg)
 
 
 def test_kv_cached_decode_matches_full_forward():
