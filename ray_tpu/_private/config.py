@@ -300,6 +300,14 @@ class RayTpuConfig:
     # bound. Also the default queue cap of a DecodeScheduler built by
     # a replica that doesn't pass its own.
     serve_max_queue_depth: int = 16
+    # How long a replica's constructor may take before its deployment
+    # fails (the controller kills the replica and ``deploy()`` raises):
+    # the bound on a constructor that hangs. A replica that leases a
+    # TPU gets ten times it: it starts a device client and makes or
+    # loads its model's weights there (9 GB of weights drawn from a
+    # seed took over a minute on a v5e chip whose programs were not
+    # compiled yet).
+    serve_replica_startup_timeout_s: float = 60.0
     # The proxy's admission-controller queue budget, as a multiple of
     # the deployment's dispatch capacity (replicas x
     # max_concurrent_queries): once waiting + in-flight requests reach

@@ -1,14 +1,19 @@
 """KV-cached autoregressive decoding for the flagship transformer.
 
-One cache form: ``[L, slots, max_len, H, Dh]`` K and V, preallocated,
-with a decode offset per row (``pos: [slots]``). Continuous batching
-(serve/decode_scheduler.py) needs exactly that: one sequence prefills
-into an open row while the other rows keep stepping, and a finished row
-frees at once. Whole-batch generation (``generate``) is its
-all-rows-active case. Every shape is static, so serving is two compiled
-programs, each a ``lax.scan`` of ``transformer.block`` (the one
-definition of the layer) over the stacked layers and their index, with
-the whole K and V as the scan's carry:
+One cache form: ``cache["k"]`` and ``cache["v"]`` are tuples of arrays,
+one for each run of alike layers (``transformer.layer_runs``; a model
+whose layers are all alike has one run): ``[L, slots, rows, H, Dh]`` K
+and ``[.., Dv]`` V (``[L, slots, rows, G * Dh]``, the K/V heads side by
+side in a row, where G of them serve more query heads each:
+``init_slot_cache``), preallocated, with a decode offset per row
+(``pos: [slots]``).
+Continuous batching (serve/decode_scheduler.py) needs exactly that: one
+sequence prefills into an open row while the other rows keep stepping,
+and a finished row frees at once. Whole-batch generation (``generate``)
+is its all-rows-active case. Every shape is static, so serving is two
+compiled programs, each a ``lax.scan`` of ``transformer.block`` (the
+one definition of the layer) over the stacked layers and their index,
+with the whole K and V as the scan's carry:
 
 * ``slot_prefill`` runs a prompt through the block with the training
   forward's rope and attention (flash kernel on TPU, XLA off it) and
@@ -18,9 +23,23 @@ the whole K and V as the scan's carry:
   and attends the layer's K/V, read out of the carry, under a per-row
   mask (no recompute, no dynamic shapes).
 
-The cache is one buffer, written in place. Both programs take it
-donated, and K and V are carried through the layers' scan, not scanned
-over: XLA then aliases the result to the argument and the only
+**Layers of several kinds** (``TransformerConfig.layer_kinds``) are
+scanned run by run, and the cache is allocated by kind: each run's
+array at the run's own K/V heads. A run of full-attention
+layers has ``max_len`` rows. A run of window layers has ``window`` rows,
+a ring: position p lives in row ``p % window``, ``slot_prefill`` writes
+a prompt's last ``window`` positions there, and ``slot_decode_step``
+writes row ``pos % window`` and attends the rows filled so far (rope is
+applied before the write, so the order of the rows does not matter),
+with the layer's sink logit in the denominator where it has one. A
+model with expert layers carries ``cache["load"]``, int32 [3]: the held
+experts that got a row, the rows routed to held experts and the fullest
+expert's rows in the last decode step, each summed over the expert
+layers, for the engine to fetch with the step's tokens.
+
+The cache is one set of buffers, written in place. Both programs take
+it donated, and K and V are carried through the layers' scan, not
+scanned over: XLA then aliases the result to the argument and the only
 operations that produce K or V are the in-place writes of the new rows.
 Either half alone leaves a copy (a carry not donated is copied whole at
 entry; a donated cache that is a scanned input is sliced out and
@@ -35,10 +54,11 @@ Invariants the scheduler relies on:
   that wants the old state afterwards passes a copy. Inside another jit
   (``_decode_loop``) the inner donation does nothing and the cache is
   the outer carry.
-* ``slot_prefill`` rewrites rows [0, T0) of its slot and resets that
-  slot's pos, so a reused slot never sees its predecessor's K/V — the
-  stale tail beyond T0 is always overwritten (step s writes position
-  pos BEFORE attending it) and never attended.
+* ``slot_prefill`` rewrites rows [0, T0) of its slot (of a ring, the
+  rows its last ``window`` positions fall on) and resets that slot's
+  pos, so a reused slot never sees its predecessor's K/V — the stale
+  tail beyond T0 is always overwritten (step s writes position pos
+  BEFORE attending it) and never attended.
 * ``slot_decode_step`` writes every row's K/V unconditionally (a
   masked write would cost a gather per layer) but advances ``pos``
   only where ``active``: an inactive row's cache may take garbage at
@@ -64,18 +84,71 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import TransformerConfig, block, unembed
+from ray_tpu.models.transformer import (EXPERTS, WINDOW, TransformerConfig,
+                                        block, kind_rope, layer_runs,
+                                        layer_stacks, scan_run, unembed)
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.rotary import apply_rotary, rope_frequencies, rotate
+from ray_tpu.ops.rotary import apply_rotary, rotate
 
 
 def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> Dict:
-    """KV cache with an independent decode offset per batch row."""
-    shape = (cfg.n_layers, slots, max_len, cfg.n_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "pos": jnp.zeros((slots,), jnp.int32)}
+    """KV cache with an independent decode offset per batch row: a
+    tuple of arrays of K and one of V, one array a run of alike layers
+    (one in all where the layers are all alike), a window run at
+    ``cfg.window`` rows. Where every query head has a K/V head of its
+    own a row is [H, Dh]; where G K/V heads serve more query heads each
+    it is flat, [G * Dh], the heads side by side: rows of 768 or 1536
+    values tile the chip's memory as they are, which [4, 192] or
+    [8, 192] do not (the compiler's own layouts for those cost two
+    copies of K a step)."""
+    runs = layer_runs(cfg)
+
+    def leaves(width):
+        made = []
+        for (attention, _), n in runs:
+            G = cfg.kv_heads(attention)
+            row = (G, width) if G == cfg.n_heads else (G * width,)
+            made.append(jnp.zeros(
+                (n, slots, cfg.window if attention == WINDOW else max_len)
+                + row, cfg.dtype))
+        return tuple(made)
+
+    cache = {"k": leaves(cfg.head_dim), "v": leaves(cfg.v_dim),
+             "pos": jnp.zeros((slots,), jnp.int32)}
+    if any(ffn == EXPERTS for (_, ffn), _ in runs):
+        cache["load"] = jnp.zeros((3,), jnp.int32)
+    return cache
+
+
+def _cache_runs(cache: Dict, runs):
+    """The cache's K and V arrays, one a run of alike layers."""
+    ks, vs = cache["k"], cache["v"]
+    if not (isinstance(ks, tuple) and isinstance(vs, tuple)
+            and len(ks) == len(vs) == len(runs)):
+        raise ValueError(
+            f"the cache holds a tuple of K arrays and one of V, one "
+            f"array for each of the model's {len(runs)} runs of alike "
+            f"layers (init_slot_cache)")
+    return ks, vs
+
+
+def _max_len(cfg: TransformerConfig, runs, ks) -> int:
+    """Rows of a full-attention run's cache: the longest sequence a slot
+    holds (``cfg.max_seq`` where every layer has a window)."""
+    return next((ck.shape[2] for ((attention, _), _), ck in zip(runs, ks)
+                 if attention != WINDOW), cfg.max_seq)
+
+
+def _cache_rows(t, like, window: Optional[int]):
+    """A prompt's K or V, t [1, T0, G, D], as the rows the cache
+    ``like`` holds: flat where its rows are, and of a ring of
+    ``window`` the last ``window`` positions, position p in row
+    p % window."""
+    T0 = t.shape[1]
+    if window is not None and T0 > window:
+        t = jnp.roll(t[:, T0 - window:], (T0 - window) % window, axis=1)
+    return t.reshape(t.shape[:2] + like.shape[3:]).astype(like.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -89,34 +162,51 @@ def slot_prefill(params, tokens, cache: Dict, slot,
     in place. Compiles once per distinct T0 — serving callers should
     bucket or pad prompt lengths if retrace cost matters."""
     _, T0 = tokens.shape
-    max_len = cache["k"].shape[2]
-    cos, sin = rope_frequencies(cfg.head_dim, max_len,
-                                theta=cfg.rope_theta)
-    rope = functools.partial(apply_rotary, cos=cos, sin=sin,
-                             positions=jnp.arange(T0))
+    runs = layer_runs(cfg)
+    ks, vs = _cache_runs(cache, runs)
+    max_len = _max_len(cfg, runs, ks)
+    ropes = {}
+    for attention in dict.fromkeys(attention for (attention, _), _ in runs):
+        cos, sin = kind_rope(cfg, attention, max_len)
+        ropes[attention] = functools.partial(
+            apply_rotary, cos=cos, sin=sin, positions=jnp.arange(T0))
     x = params["embed"][tokens]
 
-    def attend(q, k, v):
-        # the training forward's local attention, so the last token's
-        # logits are forward()'s; the roped k and v are what a later
-        # step attends
-        return flash_attention(q, k, v, causal=True), (k, v)
+    def one_run(x, layers, ck, cv, attention):
+        window = cfg.window if attention == WINDOW else None
 
-    def body(carry, layer_in):
-        x, ck, cv = carry  # ck/cv: the whole [L, slots, max_len, H, Dh]
-        lp, i = layer_in
-        x, (k, v) = block(lp, x, rope, attend, cfg)
-        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype)[None],
-                                      (i, slot, 0, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype)[None],
-                                      (i, slot, 0, 0, 0))
-        return (x, ck, cv), None
+        def body(carry, lp, i):
+            x, ck, cv = carry  # ck/cv: the whole [L, slots, rows, G, Dh]
 
-    (x, ck, cv), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
-    return unembed(params, x, last=True), {
-        "k": ck, "v": cv, "pos": cache["pos"].at[slot].set(T0)}
+            def attend(q, k, v):
+                # the training forward's local attention, so the last
+                # token's logits are forward()'s; the roped k and v are
+                # what a later step attends
+                with jax.named_scope(f"{attention}_attention"):
+                    return flash_attention(
+                        q, k, v, causal=True, window=window,
+                        sink=lp.get("sink")), (k, v)
+
+            x, (k, v), _ = block(lp, x, ropes[attention], attend, cfg)
+            ck = lax.dynamic_update_slice(
+                ck, _cache_rows(k, ck, window)[None],
+                (i, slot) + (0,) * (ck.ndim - 2))
+            cv = lax.dynamic_update_slice(
+                cv, _cache_rows(v, cv, window)[None],
+                (i, slot) + (0,) * (cv.ndim - 2))
+            return x, ck, cv
+
+        return scan_run(body, (x, ck, cv), layers)
+
+    new_k, new_v = [], []
+    for ((attention, _), layers), ck, cv in zip(
+            layer_stacks(params, cfg), ks, vs):
+        x, ck, cv = one_run(x, layers, ck, cv, attention)
+        new_k.append(ck)
+        new_v.append(cv)
+    return unembed(params, x, last=True, eps=cfg.norm_eps), dict(
+        cache, k=tuple(new_k), v=tuple(new_v),
+        pos=cache["pos"].at[slot].set(T0))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -125,56 +215,117 @@ def slot_decode_step(params, cache: Dict, token, active,
                      cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
     """One continuous-batching step: token [B] in, next-token logits
     [B, V] out; each ACTIVE row attends its own prefix (per-row
-    position mask) and advances its own pos. Inactive rows are free
-    riders — their logits are garbage and their pos is frozen. The
-    cache given is consumed and the one returned is its memory, with
-    one position a row and layer written in place."""
+    position mask; in a window layer the last ``window`` positions of
+    it) and advances its own pos. Inactive rows are free riders — their
+    logits are garbage and their pos is frozen. The cache given is
+    consumed and the one returned is its memory, with one position a
+    row and layer written in place."""
     B = token.shape[0]
-    max_len = cache["k"].shape[2]
+    runs = layer_runs(cfg)
+    ks, vs = _cache_runs(cache, runs)
+    max_len = _max_len(cfg, runs, ks)
     pos = cache["pos"]  # [B]
-    cos, sin = rope_frequencies(cfg.head_dim, max_len,
-                                theta=cfg.rope_theta)
+    kinds = list(dict.fromkeys(attention for (attention, _), _ in runs))
+    tables = {a: kind_rope(cfg, a, max_len) for a in kinds}
     x = params["embed"][token][:, None, :]  # [B, 1, D]
     sm_scale = cfg.head_dim ** -0.5
-    # row r attends positions [0, pos[r]] (pos[r] is written this step)
-    valid = (jnp.arange(max_len)[None, None, :]
-             <= pos[:, None, None])  # [B, 1, Tmax]
+    # row r attends positions [0, pos[r]] (pos[r] is written this
+    # step); of a ring, the rows filled so far, all once pos[r] has
+    # passed the window
+    valid = {a: (jnp.arange(cfg.window if a == WINDOW else max_len)
+                 [None, None, :] <= pos[:, None, None])  # [B, 1, rows]
+             for a in kinds}
     rows = jnp.arange(B)
 
-    def rope(t):  # every row at its own position
-        return rotate(t, cos[pos][:, None, None, :],
-                      sin[pos][:, None, None, :])
+    def one_run(x, load, layers, ck, cv, attention):
+        cos, sin = tables[attention]
+        window = cfg.window if attention == WINDOW else None
+        at = pos if window is None else pos % window
 
-    def body(carry, layer_in):
-        x, ck, cv = carry  # ck/cv: the whole [L, B, max_len, H, Dh]
-        lp, i = layer_in
+        def rope(t):  # every row at its own position
+            return rotate(t, cos[pos][:, None, None, :],
+                          sin[pos][:, None, None, :])
 
-        def attend(q, k, v):
-            # write, then attend: the layer's K/V are read out of the
-            # carry after the rows' new token is in it
-            nk = ck.at[i, rows, pos].set(k[:, 0].astype(ck.dtype))
-            nv = cv.at[i, rows, pos].set(v[:, 0].astype(cv.dtype))
-            lk = lax.dynamic_index_in_dim(nk, i, keepdims=False)
-            lv = lax.dynamic_index_in_dim(nv, i, keepdims=False)
-            s = jnp.einsum("bhd,bkhd->bhk", q[:, 0], lk,
-                           preferred_element_type=jnp.float32) * sm_scale
-            s = jnp.where(valid, s, -jnp.inf)
-            # accumulation dtypes as ops.attention's: softmax fp32, p
-            # cast to the value dtype, p@v accumulated in fp32
-            p = jax.nn.softmax(s, axis=-1).astype(lv.dtype)
-            o = jnp.einsum("bhk,bkhd->bhd", p, lv,
-                           preferred_element_type=jnp.float32
-                           ).astype(q.dtype)
-            return o, (nk, nv)
+        def body(carry, lp, i):
+            x, ck, cv, load = carry  # ck/cv: the whole [L, B, rows, G, Dh]
 
-        x, (ck, cv) = block(lp, x, rope, attend, cfg)
-        return (x, ck, cv), None
+            def attend(q, k, v):
+                # write, then attend: the layer's K/V are read out of the
+                # carry after the rows' new token is in it
+                nk = ck.at[i, rows, at].set(
+                    k[:, 0].reshape((B,) + ck.shape[3:]).astype(ck.dtype))
+                nv = cv.at[i, rows, at].set(
+                    v[:, 0].reshape((B,) + cv.shape[3:]).astype(cv.dtype))
+                lk = lax.dynamic_index_in_dim(nk, i, keepdims=False)
+                lv = lax.dynamic_index_in_dim(nv, i, keepdims=False)
+                with jax.named_scope(f"{attention}_attention"):
+                    return _attend_cached(q[:, 0], lk, lv, valid[attention],
+                                          sm_scale, lp.get("sink")), (nk, nv)
 
-    (x, ck, cv), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
-    return unembed(params, x[:, 0]), {
-        "k": ck, "v": cv, "pos": jnp.where(active, pos + 1, pos)}
+            x, (ck, cv), got = block(lp, x, rope, attend, cfg)
+            if got is not None:
+                load = load + jnp.stack(
+                    [jnp.sum(got > 0), jnp.sum(got), jnp.max(got)])
+            return x, ck, cv, load
+
+        return scan_run(body, (x, ck, cv, load), layers)
+
+    load = jnp.zeros_like(cache["load"]) if "load" in cache else None
+    new_k, new_v = [], []
+    for ((attention, _), layers), ck, cv in zip(
+            layer_stacks(params, cfg), ks, vs):
+        x, ck, cv, load = one_run(x, load, layers, ck, cv, attention)
+        new_k.append(ck)
+        new_v.append(cv)
+    logits = unembed(params, x[:, 0], eps=cfg.norm_eps)
+    cache = dict(cache, k=tuple(new_k), v=tuple(new_v),
+                 pos=jnp.where(active, pos + 1, pos))
+    if load is not None:
+        cache["load"] = load
+    return logits, cache
+
+
+def _attend_cached(q, lk, lv, valid, sm_scale, sink=None):
+    """One new token a row against a layer's cached K/V: q [B, H, Dh],
+    ``valid`` [B, 1, rows] the rows each batch row may attend, ``sink``
+    [H] one more logit a head in the denominator. lk [B, rows, H, Dh]
+    and lv [B, rows, H, Dv] where every query head has its own K/V
+    head. Where G K/V heads serve H / G query heads each the rows are
+    flat, lk [B, rows, G * Dh] and lv [B, rows, G * Dv]: each query is
+    widened to a whole row, zero outside its own K/V head's part, so
+    that both products are plain ones over rows as they lie in memory
+    (G times the multiplications of the heads taken apart, which stay
+    under the time the rows take to read), and the output keeps its own
+    head's part. Accumulation dtypes as ops.attention's: softmax fp32,
+    p cast to the value dtype, p@v accumulated in fp32."""
+    B, H, D = q.shape
+    grouped = lk.ndim == 3
+    if grouped:
+        G = lk.shape[2] // D
+        own = (jnp.arange(H)[:, None] // (H // G)
+               == jnp.arange(G)[None, :])[None, :, :, None]   # [1, H, G, 1]
+        wide = jnp.where(own, q[:, :, None, :], 0).reshape(B, H, G * D)
+        s = jnp.einsum("bhc,bkc->bhk", wide, lk,
+                       preferred_element_type=jnp.float32) * sm_scale
+    else:
+        s = jnp.einsum("bhd,bkhd->bhk", q, lk,
+                       preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(valid, s, -jnp.inf)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1).astype(lv.dtype)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None], (B, H, 1))
+        p = jax.nn.softmax(jnp.concatenate([s, column], axis=-1),
+                           axis=-1)[..., :-1].astype(lv.dtype)
+    if grouped:
+        o = jnp.einsum("bhk,bkc->bhc", p, lv,
+                       preferred_element_type=jnp.float32)
+        o = jnp.sum(jnp.where(own, o.reshape(B, H, G, -1), 0), axis=2)
+    else:
+        o = jnp.einsum("bhk,bkhd->bhd", p, lv,
+                       preferred_element_type=jnp.float32)
+    return o.astype(q.dtype)
 
 
 @functools.partial(jax.jit,

@@ -1,13 +1,30 @@
-"""Decoder-only transformer (Llama-style) over a dp/pp/sp/tp mesh.
+"""Decoder-only transformer over a dp/pp/sp/tp mesh: pre-norm blocks of
+attention (all heads alike, or fewer K/V heads than query heads; rope
+on the whole head or on its first dimensions) and a feed-forward (dense
+SwiGLU, or a chip's share of sparse experts), whose layers may be of
+several kinds in one model: full or windowed attention, each with its
+own K/V heads and rope base, with or without a sink logit.
 
 **One block.** ``block`` is the only definition of the layer: norms,
 projections, feed-forward and residuals. What differs between training,
 prefill and decode is handed to it: ``rope`` (how q and k are rotated)
 and ``attend`` (what the queries attend, and the state that comes
-back). ``forward`` here and ``slot_prefill`` / ``slot_decode_step`` in
-models/decode.py each scan it over the stacked layers; ``unembed`` is
-their shared final norm and tied head. A change to the architecture is
-a change to ``block``, ``init_params`` and ``param_specs``.
+back); what differs between layers is in the layer's own weights (a
+router makes it an expert layer) and in the closures its caller builds
+for its kind. ``forward`` here and ``slot_prefill`` /
+``slot_decode_step`` in models/decode.py each scan it over the stacked
+layers; ``unembed`` is their shared final norm and head. A change to
+the architecture is a change to ``block``, ``init_params`` and
+``param_specs``.
+
+**Layers of several kinds.** Layers of unlike shape cannot share one
+scan. ``TransformerConfig.layer_kinds`` gives each layer's kind; the
+published order is cut into runs of alike layers (``layer_runs``), each
+run's weights are stacked on a leading dimension of their own
+(``params["layers"]`` is then a tuple of such stacks, one a run, where
+a model of one kind keeps the one stack), and every caller scans run
+after run. Unrolling instead would compile one body a layer where this
+compiles one a run.
 
 ``forward`` runs in two modes sharing every line of math:
 
@@ -31,7 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,11 +59,18 @@ from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
 from ray_tpu.parallel.collectives import (axis_size, shard_map,
                                            tp_allreduce, tp_copy)
+from ray_tpu.parallel.experts import expert_ffn, expert_tile, route
 from ray_tpu.parallel.pipeline import pipeline_spmd
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.ulysses import ulysses_attention
 
 from jax.sharding import PartitionSpec as P
+
+
+# A layer's kind: (attention, feed-forward).
+FULL, WINDOW = "full", "window"
+DENSE, EXPERTS = "dense", "experts"
+LayerKind = Tuple[str, str]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,10 +83,120 @@ class TransformerConfig:
     max_seq: int = 256
     rope_theta: float = 10000.0
     dtype: jnp.dtype = jnp.bfloat16
+    norm_eps: float = 1e-6
+    tie_embeddings: bool = True
+    # attention: None means "as the field above it says" (all heads
+    # alike, head_dim = d_model / n_heads, rope on the whole head)
+    n_kv_heads: Optional[int] = None
+    qk_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rotary_dim: Optional[int] = None    # leading dims of a head rotated
+    value_scale: float = 1.0            # on v, before the product
+    # each layer's (attention, feed-forward) kind; None: all full, dense
+    layer_kinds: Optional[Tuple[LayerKind, ...]] = None
+    # window layers: position t attends (t - window, t]
+    window: Optional[int] = None
+    window_kv_heads: Optional[int] = None
+    window_rope_theta: Optional[float] = None
+    # attention kinds whose heads have a learned sink logit
+    sink_kinds: Tuple[str, ...] = ()
+    # expert layers: the router scores n_experts, a row goes to
+    # experts_per_token of them, and this chip holds the contiguous
+    # range [experts_first, experts_first + experts_held) at width
+    # d_expert
+    n_experts: int = 0
+    experts_per_token: int = 0
+    experts_first: int = 0
+    experts_held: int = 0
+    d_expert: int = 0
+
+    def __post_init__(self):
+        def refuse(key, why):
+            raise ValueError(f"TransformerConfig.{key}: {why}")
+
+        kinds = self.layer_kinds
+        if kinds is not None:
+            if len(kinds) != self.n_layers:
+                refuse("layer_kinds", f"{len(kinds)} kinds for "
+                       f"{self.n_layers} layers")
+            for attention, ffn in kinds:
+                if attention not in (FULL, WINDOW) or ffn not in (
+                        DENSE, EXPERTS):
+                    refuse("layer_kinds", f"unknown kind "
+                           f"{(attention, ffn)!r}")
+        if any(a == WINDOW for a, _ in kinds or ()) and not self.window:
+            refuse("window", "window layers need a window")
+        for key in ("n_kv_heads", "window_kv_heads"):
+            heads = getattr(self, key)
+            if heads is not None and self.n_heads % heads:
+                refuse(key, f"{heads} K/V heads do not divide "
+                       f"{self.n_heads} query heads")
+        if self.rotary_dim is not None and (
+                self.rotary_dim % 2 or self.rotary_dim > self.head_dim):
+            refuse("rotary_dim", f"{self.rotary_dim} is odd or wider "
+                   f"than the head ({self.head_dim})")
+        if any(f == EXPERTS for _, f in kinds or ()):
+            if not (0 < self.experts_per_token <= self.n_experts
+                    and self.d_expert > 0 and self.experts_held > 0
+                    and 0 <= self.experts_first
+                    and self.experts_first + self.experts_held
+                    <= self.n_experts):
+                refuse("n_experts", "expert layers need n_experts, "
+                       "experts_per_token, d_expert and a held range "
+                       "inside the router's width")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        """Width of a head in q and k."""
+        return self.qk_head_dim or self.d_model // self.n_heads
+
+    @property
+    def v_dim(self) -> int:
+        """Width of a head in v and in the attention output."""
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        return self.rotary_dim or self.head_dim
+
+    def kv_heads(self, attention: str = FULL) -> int:
+        heads = self.n_kv_heads or self.n_heads
+        if attention == WINDOW:
+            heads = self.window_kv_heads or heads
+        return heads
+
+    def theta(self, attention: str = FULL) -> float:
+        if attention == WINDOW and self.window_rope_theta is not None:
+            return self.window_rope_theta
+        return self.rope_theta
+
+
+def layer_runs(cfg: TransformerConfig) -> Tuple[Tuple[LayerKind, int], ...]:
+    """The layers in their order as runs of alike ones: ((kind, how
+    many), ...). Each run is one stack of weights and one scan."""
+    kinds = cfg.layer_kinds or ((FULL, DENSE),) * cfg.n_layers
+    runs = []
+    for kind in kinds:
+        if runs and runs[-1][0] == tuple(kind):
+            runs[-1][1] += 1
+        else:
+            runs.append([tuple(kind), 1])
+    return tuple((kind, n) for kind, n in runs)
+
+
+def layer_stacks(params, cfg: TransformerConfig):
+    """[(kind, that run's stacked weights), ...] in the layers' order:
+    the one place that reads ``params["layers"]``. A model of one kind
+    keeps it as the one stack, the form its checkpoints, its callers
+    and the benchmark's ``ouro`` family hold; one with ``layer_kinds``
+    holds a tuple of stacks, one a run."""
+    runs, layers = layer_runs(cfg), params["layers"]
+    if cfg.layer_kinds is None:
+        layers = (layers,)
+    if len(layers) != len(runs):
+        raise ValueError(f"{len(layers)} stacks of layers for "
+                         f"{len(runs)} runs of alike layers")
+    return [(kind, stack) for (kind, _), stack in zip(runs, layers)]
 
 
 # The dense decoder the repo serves and trains at full width on one
@@ -88,55 +222,116 @@ class ParallelConfig:
         return tuple(a for a in (self.dp, self.sp) if a)
 
 
-def init_params(key, cfg: TransformerConfig):
-    """Pytree of params; layer weights stacked on a leading L dim."""
-    k = jax.random.split(key, 8)
-    D, H, Dh, F, L, V = (cfg.d_model, cfg.n_heads, cfg.head_dim,
-                         cfg.d_ff, cfg.n_layers, cfg.vocab)
-    dt = cfg.dtype
+def _init_run(keys, cfg: TransformerConfig, kind: LayerKind, n: int):
+    """One run of ``n`` alike layers, stacked on a leading dim. ``keys``
+    holds ten keys, the first seven in the order of a dense layer's
+    matrices."""
+    attention, ffn = kind
+    D, H, G = cfg.d_model, cfg.n_heads, cfg.kv_heads(attention)
+    Dh, Dv, dt = cfg.head_dim, cfg.v_dim, cfg.dtype
     init = jax.nn.initializers.normal(0.02)
 
     def w(kk, shape):
         return init(kk, shape, jnp.float32).astype(dt)
 
-    return {
-        "embed": w(k[0], (V, D)),
-        "layers": {
-            "attn_norm": jnp.ones((L, D), dt),
-            "wq": w(k[1], (L, D, H * Dh)),
-            "wk": w(k[2], (L, D, H * Dh)),
-            "wv": w(k[3], (L, D, H * Dh)),
-            "wo": w(k[4], (L, H * Dh, D)),
-            "mlp_norm": jnp.ones((L, D), dt),
-            "w_gate": w(k[5], (L, D, F)),
-            "w_up": w(k[6], (L, D, F)),
-            "w_down": w(k[7], (L, F, D)),
-        },
-        "final_norm": jnp.ones((D,), dt),
+    run = {
+        "attn_norm": jnp.ones((n, D), dt),
+        "wq": w(keys[0], (n, D, H * Dh)),
+        "wk": w(keys[1], (n, D, G * Dh)),
+        "wv": w(keys[2], (n, D, G * Dv)),
+        "wo": w(keys[3], (n, H * Dv, D)),
+        "mlp_norm": jnp.ones((n, D), dt),
     }
+    if attention in cfg.sink_kinds:
+        run["sink"] = init(keys[7], (n, H), jnp.float32)
+    if ffn == EXPERTS:
+        E, F = cfg.experts_held, cfg.d_expert
+        run.update(
+            router=w(keys[8], (n, D, cfg.n_experts)),
+            router_bias=init(keys[9], (n, cfg.n_experts), jnp.float32),
+            w_gate=w(keys[4], (n, E, D, F)), w_up=w(keys[5], (n, E, D, F)),
+            w_down=w(keys[6], (n, E, F, D)))
+    else:
+        F = cfg.d_ff
+        run.update(w_gate=w(keys[4], (n, D, F)), w_up=w(keys[5], (n, D, F)),
+                   w_down=w(keys[6], (n, F, D)))
+    return run
 
 
-def param_specs(pcfg: ParallelConfig):
-    """PartitionSpec pytree matching ``init_params`` output."""
+def init_params(key, cfg: TransformerConfig):
+    """Pytree of params; layer weights stacked on a leading L dim, or,
+    with ``layer_kinds``, a tuple of such stacks, one for each run of
+    alike layers (``layer_runs``)."""
+    k = jax.random.split(key, 8)
+    runs = layer_runs(cfg)
+    if cfg.layer_kinds is None:
+        (kind, n), = runs
+        layers = _init_run(list(k[1:]) + [None] * 3, cfg, kind, n)
+    else:
+        layers = tuple(
+            _init_run(jax.random.split(jax.random.fold_in(key, 1 + r), 10),
+                      cfg, kind, n)
+            for r, (kind, n) in enumerate(runs))
+    init = jax.nn.initializers.normal(0.02)
+    params = {
+        "embed": init(k[0], (cfg.vocab, cfg.d_model),
+                      jnp.float32).astype(cfg.dtype),
+        "layers": layers,
+        "final_norm": jnp.ones((cfg.d_model,), cfg.dtype),
+    }
+    if not cfg.tie_embeddings:
+        params["head"] = init(jax.random.fold_in(key, 0),
+                              (cfg.d_model, cfg.vocab),
+                              jnp.float32).astype(cfg.dtype)
+    return params
+
+
+def param_specs(pcfg: ParallelConfig,
+                cfg: Optional[TransformerConfig] = None):
+    """PartitionSpec pytree matching ``init_params`` output: of a model
+    of one kind without ``cfg``, else of ``cfg``'s. Heads and the
+    feed-forward's width (an expert's own, inside each expert) go over
+    ``tp``; a stack's layers over ``pp``, which only a model of one
+    kind can have."""
     pp, tp = pcfg.pp, pcfg.tp
-    return {
-        "embed": P(None, None),
-        "layers": {
+
+    def run_specs(kind):
+        attention, ffn = kind
+        specs = {
             "attn_norm": P(pp, None),
             "wq": P(pp, None, tp),
             "wk": P(pp, None, tp),
             "wv": P(pp, None, tp),
             "wo": P(pp, tp, None),
             "mlp_norm": P(pp, None),
-            "w_gate": P(pp, None, tp),
-            "w_up": P(pp, None, tp),
-            "w_down": P(pp, tp, None),
-        },
-        "final_norm": P(None),
-    }
+        }
+        if cfg is not None and attention in cfg.sink_kinds:
+            specs["sink"] = P(pp, tp)
+        if ffn == EXPERTS:
+            specs.update(router=P(pp, None, None), router_bias=P(pp, None),
+                         w_gate=P(pp, None, None, tp),
+                         w_up=P(pp, None, None, tp),
+                         w_down=P(pp, None, tp, None))
+        else:
+            specs.update(w_gate=P(pp, None, tp), w_up=P(pp, None, tp),
+                         w_down=P(pp, tp, None))
+        return specs
+
+    specs = {"embed": P(None, None), "final_norm": P(None)}
+    if cfg is None or cfg.layer_kinds is None:
+        specs["layers"] = run_specs((FULL, DENSE))
+    else:
+        if pp:
+            raise ValueError("a pipeline over layers of several kinds is "
+                             "not expressed: pp needs one stack")
+        specs["layers"] = tuple(run_specs(kind)
+                                for kind, _ in layer_runs(cfg))
+    if cfg is not None and not cfg.tie_embeddings:
+        specs["head"] = P(None, None)
+    return specs
 
 
-def _attend(q, k, v, pcfg: ParallelConfig):
+def _attend(q, k, v, pcfg: ParallelConfig, window=None, sink=None):
     impl = pcfg.attn
     if impl == "auto":
         impl = "ring" if pcfg.sp else "local"
@@ -145,7 +340,12 @@ def _attend(q, k, v, pcfg: ParallelConfig):
         # is the TPU and T is a multiple of 128 (the kernel chooses its
         # blocks from T and multiplies at q's dtype); the XLA reference
         # otherwise (ops.attention.flash_attention).
-        return flash_attention(q, k, v, causal=True)
+        return flash_attention(q, k, v, causal=True, window=window,
+                               sink=sink)
+    if window is not None or sink is not None or k.shape[2] != q.shape[2] \
+            or v.shape[3] != q.shape[3]:
+        raise ValueError("sequence-parallel attention takes heads all "
+                         "alike, one width, no window and no sink")
     if impl == "ring":
         return ring_attention(q, k, v, axis=pcfg.sp, causal=True)
     if impl == "ulysses":
@@ -158,60 +358,127 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
     """The transformer block, on local shards. x: [B_l, T_l, D]
     (tp-replicated); ``lp`` one layer's weights. ``rope(t)`` rotates q
     and k; ``attend(q, k, v) -> (o, state)`` takes them as [B, T,
-    H_local, Dh] and gives the attention output, which is flattened
-    here to [B, T, H_local * Dh], and whatever the caller keeps of the
-    layer (the K/V a cache holds; None in training). Returns
-    (x, state)."""
+    H_local, Dh] (k and v at the layer's K/V heads, v at the value
+    width) and gives the attention output, which is flattened here to
+    [B, T, H_local * Dv], and whatever the caller keeps of the layer
+    (the K/V a cache holds; None in training). A layer that has a
+    router is an expert layer: its feed-forward is the part the experts
+    held here give (parallel/experts.py). Returns (x, state, load):
+    ``load`` the rows each held expert got, int32 [experts_held], None
+    for a dense layer."""
     B, T, D = x.shape
-    Dh = cfg.head_dim
 
-    h = rmsnorm(x, lp["attn_norm"])
+    h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
     if pcfg.tp:
         h = tp_copy(h, pcfg.tp)
-    q = (h @ lp["wq"]).reshape(B, T, -1, Dh)      # H_local heads
-    k = (h @ lp["wk"]).reshape(B, T, -1, Dh)
-    v = (h @ lp["wv"]).reshape(B, T, -1, Dh)
+    q = (h @ lp["wq"]).reshape(B, T, -1, cfg.head_dim)  # H_local heads
+    k = (h @ lp["wk"]).reshape(B, T, -1, cfg.head_dim)
+    v = (h @ lp["wv"]).reshape(B, T, -1, cfg.v_dim)
+    if cfg.value_scale != 1.0:
+        v = v * cfg.value_scale
     o, state = attend(rope(q), rope(k), v)
     o = o.reshape(B, T, -1) @ lp["wo"]             # row-parallel
     if pcfg.tp:
         o = tp_allreduce(o, pcfg.tp)
     x = x + o.astype(x.dtype)
 
-    h = rmsnorm(x, lp["mlp_norm"])
+    h = rmsnorm(x, lp["mlp_norm"], eps=cfg.norm_eps)
     if pcfg.tp:
         h = tp_copy(h, pcfg.tp)
-    g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
-    u = (h @ lp["w_up"]).astype(jnp.float32)
-    d = (g * u).astype(x.dtype) @ lp["w_down"]     # row-parallel
+    load = None
+    if "router" in lp:
+        with jax.named_scope("router"):
+            chosen, weights = route(h.reshape(B * T, D), lp["router"],
+                                    lp["router_bias"],
+                                    cfg.experts_per_token)
+        with jax.named_scope("experts"):
+            d, load = expert_ffn(
+                h.reshape(B * T, D), chosen, weights, lp["w_gate"],
+                lp["w_up"], lp["w_down"], first=cfg.experts_first,
+                held=cfg.experts_held, base=lp.get("expert_base", 0),
+                tile=expert_tile(B * T, cfg.experts_per_token,
+                                 cfg.n_experts))
+        d = d.reshape(B, T, D)
+    else:
+        g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
+        u = (h @ lp["w_up"]).astype(jnp.float32)
+        d = (g * u).astype(x.dtype) @ lp["w_down"]     # row-parallel
     if pcfg.tp:
         d = tp_allreduce(d, pcfg.tp)
-    return x + d.astype(x.dtype), state
+    return x + d.astype(x.dtype), state, load
 
 
-def unembed(params, x, *, last=False):
-    """Final norm and the tied unembed of x [..., D], or with ``last``
-    of the last position alone of x [B, T, D] (a prefill). The matmul
-    runs at x's dtype and the logits are float32: a stable
-    softmax-xent, and one rounding for every caller, so that a greedy
-    decode agrees with the full forward's argmax."""
-    x = rmsnorm(x, params["final_norm"])
+EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+
+def scan_run(body, carry, layers, index: bool = True):
+    """``lax.scan`` of ``body(carry, lp, i) -> carry`` over one run's
+    stacked layers: ``lp`` layer i's weights. (``index`` False scans the
+    layers alone and gives ``i`` None.) An expert run's matrices are
+    not sliced a layer at a time: the slice would be a copy of every
+    held expert (0.8 GB a layer at 16 experts of 4096 x 2048), made so
+    that the loop over the tiles could index it. ``lp`` holds them
+    whole, the layers' experts flattened to one leading dimension, and
+    ``expert_base``, where layer i's begin (``block`` hands both to
+    ``expert_ffn``)."""
+    n = layers["attn_norm"].shape[0]
+    if "router" not in layers:
+        if not index:
+            return lax.scan(lambda c, lp: (body(c, lp, None), None), carry,
+                            layers)[0]
+        return lax.scan(lambda c, xs: (body(c, *xs), None), carry,
+                        (layers, jnp.arange(n)))[0]
+    sliced = {k: v for k, v in layers.items() if k not in EXPERT_MATRICES}
+    held = layers["w_gate"].shape[1]
+    whole = {k: layers[k].reshape((n * held,) + layers[k].shape[2:])
+             for k in EXPERT_MATRICES}
+
+    def step(c, xs):
+        lp, i = xs
+        return body(c, dict(lp, **whole, expert_base=i * held), i), None
+
+    return lax.scan(step, carry, (sliced, jnp.arange(n)))[0]
+
+
+def unembed(params, x, *, last=False, eps: float = 1e-6):
+    """Final norm and the unembed of x [..., D] (the head where the
+    model has one, else the embedding, tied), or with ``last`` of the
+    last position alone of x [B, T, D] (a prefill). The matmul runs at
+    x's dtype and the logits are float32: a stable softmax-xent, and
+    one rounding for every caller, so that a greedy decode agrees with
+    the full forward's argmax."""
+    x = rmsnorm(x, params["final_norm"], eps=eps)
     if last:
         x = x[:, -1]
-    return (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
+    head = params["head"] if "head" in params else params["embed"].T
+    return (x @ head.astype(x.dtype)).astype(jnp.float32)
 
 
-def _stack_fn(cfg, pcfg, rope):
-    """Scan the (locally held) layer stack over one activation."""
+def kind_rope(cfg: TransformerConfig, attention: str, max_seq: int):
+    """(cos, sin) tables [max_seq, rope_dim / 2] of an attention kind:
+    each kind has its own base."""
+    return rope_frequencies(cfg.rope_dim, max_seq,
+                            theta=cfg.theta(attention))
+
+
+def _stack_fn(cfg, pcfg, rope, kind: LayerKind = (FULL, DENSE)):
+    """Scan one run of (locally held) alike layers over one
+    activation."""
+    window = cfg.window if kind[0] == WINDOW else None
+
     def layer(lp, x):
-        return block(lp, x, rope,
-                     lambda q, k, v: (_attend(q, k, v, pcfg), None),
-                     cfg, pcfg)
+        def attend(q, k, v):
+            with jax.named_scope(f"{kind[0]}_attention"):
+                return _attend(q, k, v, pcfg, window, lp.get("sink")), None
+
+        return block(lp, x, rope, attend, cfg, pcfg)[0]
 
     if pcfg.remat:
         layer = jax.checkpoint(layer)
 
     def run(layers, x):
-        return lax.scan(lambda h, lp: layer(lp, h), x, layers)[0]
+        return scan_run(lambda h, lp, _: layer(lp, h), x, layers,
+                        index=False)
     return run
 
 
@@ -222,22 +489,28 @@ def forward(params, tokens, cfg: TransformerConfig,
     Call directly for the oracle, or inside shard_map for SPMD.
     """
     T = tokens.shape[1]
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq,
-                                theta=cfg.rope_theta)
+    stacks = layer_stacks(params, cfg)
+    if pcfg.pp and len(stacks) > 1:
+        raise ValueError("a pipeline over layers of several kinds is not "
+                         "expressed: pp needs one stack")
+    tables = {a: kind_rope(cfg, a, cfg.max_seq)
+              for a in dict.fromkeys(kind[0] for kind, _ in stacks)}
     if pcfg.sp:
         positions = lax.axis_index(pcfg.sp) * T + jnp.arange(T)
     else:
         positions = jnp.arange(T)
 
     x = params["embed"][tokens]                    # [B,T,D]
-    stack = _stack_fn(cfg, pcfg, functools.partial(
-        apply_rotary, cos=cos, sin=sin, positions=positions))
-    if pcfg.pp:
-        x = pipeline_spmd(stack, params["layers"], x, axis=pcfg.pp,
-                          num_microbatches=pcfg.num_microbatches)
-    else:
-        x = stack(params["layers"], x)
-    return unembed(params, x)
+    for kind, layers in stacks:
+        cos, sin = tables[kind[0]]
+        stack = _stack_fn(cfg, pcfg, functools.partial(
+            apply_rotary, cos=cos, sin=sin, positions=positions), kind)
+        if pcfg.pp:
+            x = pipeline_spmd(stack, layers, x, axis=pcfg.pp,
+                              num_microbatches=pcfg.num_microbatches)
+        else:
+            x = stack(layers, x)
+    return unembed(params, x, eps=cfg.norm_eps)
 
 
 def loss_fn(params, batch, cfg: TransformerConfig,
@@ -263,7 +536,7 @@ def make_train_step(cfg: TransformerConfig, pcfg: ParallelConfig,
 
     optimizer = optimizer or optax.adamw(3e-4)
 
-    pspecs_for_grads = param_specs(pcfg)
+    pspecs_for_grads = param_specs(pcfg, cfg)
 
     def local_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch, cfg,
@@ -301,7 +574,7 @@ def make_train_step(cfg: TransformerConfig, pcfg: ParallelConfig,
     if mesh is None:
         return jax.jit(local_step), optimizer
 
-    pspecs = param_specs(pcfg)
+    pspecs = param_specs(pcfg, cfg)
     opt_specs = _opt_state_specs(optimizer, cfg, pspecs)
     batch_spec = {"tokens": P(pcfg.dp, pcfg.sp),
                   "targets": P(pcfg.dp, pcfg.sp)}
@@ -324,7 +597,7 @@ def init_train_state(key, cfg: TransformerConfig, pcfg: ParallelConfig,
         return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                             is_leaf=lambda x: isinstance(x, P))
 
-    pspecs = param_specs(pcfg)
+    pspecs = param_specs(pcfg, cfg)
     params = jax.jit(functools.partial(init_params, cfg=cfg),
                      out_shardings=named(pspecs))(key)
     opt_state = jax.jit(

@@ -18,14 +18,21 @@ def rope_frequencies(head_dim: int, max_seq: int, *,
 def rotate(x, c, s):
     """x: [B, T, H, D] rotated pairwise (first half against second) by
     the angles whose cosines and sines ``c``, ``s`` broadcast against
-    [B, T, H, D//2]. Arithmetic in float32, result at x's dtype."""
+    [B, T, H, R//2]. R = D rotates the whole head; a narrower table
+    rotates the first R dimensions so and passes the rest through.
+    Arithmetic in float32, result at x's dtype."""
+    rotary = 2 * c.shape[-1]
+    if rotary < x.shape[-1]:
+        return jnp.concatenate(
+            [rotate(x[..., :rotary], c, s), x[..., rotary:]], axis=-1)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)
 
 
 def apply_rotary(x, cos, sin, *, positions=None):
-    """x: [B, T, H, D]; cos/sin: [max_seq, D//2]. positions: [T] global
+    """x: [B, T, H, D]; cos/sin: [max_seq, R//2], R <= D the leading
+    dimensions rotated (``rotate``). positions: [T] global
     token positions, shared by the batch (for sequence-parallel shards /
     prefill); a batch whose rows stand at different positions gathers
     its own rows of the tables and calls ``rotate``."""

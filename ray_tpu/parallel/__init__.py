@@ -11,7 +11,7 @@ Axes (by convention, any subset may be size 1):
   dp — data parallel (batch)
   pp — pipeline parallel (layer stages)
   sp — sequence/context parallel (ring attention / Ulysses)
-  tp — tensor parallel (MXU-dim sharding; also used for experts)
+  tp — tensor parallel (MXU-dim sharding; inside each held expert too)
 """
 
 from ray_tpu.parallel.mesh import (  # noqa: F401
@@ -39,4 +39,4 @@ from ray_tpu.parallel.sharding import (  # noqa: F401
 from ray_tpu.parallel.ring_attention import ring_attention  # noqa: F401
 from ray_tpu.parallel.ulysses import ulysses_attention  # noqa: F401
 from ray_tpu.parallel.pipeline import pipeline_spmd  # noqa: F401
-from ray_tpu.parallel.moe import moe_dispatch_combine  # noqa: F401
+from ray_tpu.parallel.experts import expert_ffn, route  # noqa: F401

@@ -216,7 +216,11 @@ def deployment(_func_or_class=None, *, name: Optional[str] = None,
                ray_actor_options: Optional[Dict] = None,
                route_prefix: Optional[str] = "__default__",
                autoscaling_config: Optional[Dict] = None):
-    """``@serve.deployment`` decorator (bare or with options)."""
+    """``@serve.deployment`` decorator (bare or with options).
+    ``max_concurrent_queries`` caps what a router keeps in flight at one
+    replica. A replica that hosts a continuous-batching decode loop
+    (``self.decode_scheduler``) states its own cap where that is larger:
+    the loop's slots and its queue (serve/replica.py)."""
     def wrap(func_or_class):
         return Deployment(
             func_or_class,

@@ -21,7 +21,6 @@ logger = logging.getLogger(__name__)
 CONTROLLER_NAME = "SERVE_CONTROLLER"
 SNAPSHOT_KEY = "replicas:{name}"  # long-poll key per deployment
 ROUTES_KEY = "routes"             # long-poll key for the HTTP route table
-REPLICA_STARTUP_TIMEOUT_S = 60.0
 # Cadence of the replica health loop (a crashed replica is detected,
 # dropped from router membership, and replaced within ~one period).
 HEALTH_CHECK_PERIOD_S = 0.5
@@ -29,6 +28,26 @@ HEALTH_CHECK_PERIOD_S = 0.5
 # view under, so the dashboard's /api/serve renders without an RPC to
 # this actor (the GCS process has no worker to call actors with).
 SERVE_STATE_KEY = b"serve:state"
+# A replica that leases a TPU may take this many times the start-up
+# limit of one that does not (device client, weights, first compiles).
+TPU_STARTUP_FACTOR = 10.0
+
+
+def _startup_timeout_s(actor_options: dict) -> float:
+    """How long a replica's constructor may take before its deployment
+    fails: the session's ``serve_replica_startup_timeout_s``, and ten
+    times it for a replica that leases a TPU (_private/config.py)."""
+    from ray_tpu._private.config import get_config
+
+    try:
+        import ray_tpu.worker as worker_mod
+        cfg = worker_mod.global_worker.core.config
+    except Exception:  # noqa: BLE001 — unit harness without a worker
+        cfg = get_config()
+    leases_tpu = actor_options.get("num_tpus") or (
+        actor_options.get("resources") or {}).get("TPU")
+    return float(cfg.serve_replica_startup_timeout_s) * (
+        TPU_STARTUP_FACTOR if leases_tpu else 1.0)
 
 
 async def _as_coro(ref):
@@ -199,12 +218,19 @@ class ServeController:
 
     def _snapshot(self, name: str) -> dict:
         cfg = self._configs.get(name)
+        replicas = self._replicas.get(name, [])
+        # what a router may keep in flight at one replica: the
+        # deployment's cap, or the replicas' own where they state a
+        # larger one (a decode loop's slots and queue: replica.py)
+        cap = cfg["max_concurrent_queries"] if cfg else 1
+        if cfg and replicas:
+            cap = max(cap, min(r.get("concurrency", cap)
+                               for r in replicas))
         return {
-            "max_concurrent_queries":
-                cfg["max_concurrent_queries"] if cfg else 1,
+            "max_concurrent_queries": cap,
             "replicas": [
                 {"id": r["id"], "handle": r["handle"]}
-                for r in self._replicas.get(name, [])
+                for r in replicas
             ],
         }
 
@@ -245,8 +271,10 @@ class ServeController:
             self._next_replica_id += 1
             rid = f"{name}#{version}#{self._next_replica_id}"
             opts = dict(cfg["ray_actor_options"])
-            opts.setdefault("max_concurrency",
-                            max(cfg["max_concurrent_queries"], 100))
+            # the replica sheds above its own cap (replica.py); the
+            # actor's limit only has to stay out of its way and leave
+            # ready/stats/drain room beside the requests
+            opts.setdefault("max_concurrency", 1000)
             handle = ray_tpu.remote(Replica).options(**opts).remote(
                 cfg["callable_def"], cfg["init_args"], cfg["init_kwargs"],
                 max_concurrent_queries=cfg["max_concurrent_queries"])
@@ -256,10 +284,12 @@ class ServeController:
         # A failing/hanging constructor must not leak the batch or
         # wedge the reconcile lock forever.
         try:
+            timeout = _startup_timeout_s(cfg["ray_actor_options"])
             for r in starting:
                 await asyncio.wait_for(
-                    _as_coro(r["handle"].ready.remote()),
-                    timeout=REPLICA_STARTUP_TIMEOUT_S)
+                    _as_coro(r["handle"].ready.remote()), timeout=timeout)
+                r["concurrency"] = int(
+                    await r["handle"].concurrency.remote())
                 current.append(r)
         except BaseException:
             for r in starting:

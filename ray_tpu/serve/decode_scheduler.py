@@ -59,7 +59,12 @@ name, to be read as deltas:
   argmax row fetched at once: it waits for the device and carries the
   transfer. ``read`` builds the returned dict from that host array.
   The engine's ``prefill`` has no spans of its own: ``serve.prefill``
-  less its hop is the call.
+  less its hop is the call. Of a model with expert layers the same
+  fetch brings three counts of the step, each summed over its expert
+  layers, kept as sums under ``serve.engine.experts_hit`` (held experts
+  that got a row), ``serve.engine.expert_rows`` (rows routed to held
+  experts) and ``serve.engine.expert_rows_max`` (the fullest expert's
+  rows): ``[steps, sum]``, not seconds.
 """
 
 from __future__ import annotations
@@ -77,6 +82,11 @@ from ray_tpu.exceptions import ServeOverloadedError, SlotStateLostError
 from ray_tpu.util.phases import phase, phase_add, phase_totals, recording
 
 logger = logging.getLogger(__name__)
+
+# what a step of a model with expert layers counts, in the order of
+# ``cache["load"]`` (models/decode.py): sums, kept beside the phases
+EXPERT_COUNTS = ("serve.engine.experts_hit", "serve.engine.expert_rows",
+                 "serve.engine.expert_rows_max")
 
 
 @dataclass
@@ -98,10 +108,15 @@ class DecodeScheduler:
     and parks (zero cycles) whenever queue and batch are both empty.
     """
 
-    def __init__(self, engine, *, max_queue_depth: int = 64,
+    def __init__(self, engine, *, max_queue_depth: Optional[int] = None,
                  retry_after_s: float = 1.0):
         if int(engine.slots) <= 0:
             raise ValueError("engine must expose at least one slot")
+        if max_queue_depth is None:
+            # room for a whole batch of waiters and as many again: a
+            # closed loop of more callers than slots queues that many
+            # at once when it starts
+            max_queue_depth = max(64, 2 * int(engine.slots))
         self._engine = engine
         self._free: List[int] = list(range(engine.slots))
         self._queue: deque[_Request] = deque()
@@ -162,6 +177,13 @@ class DecodeScheduler:
 
     def queue_depth(self) -> int:
         return len(self._queue)
+
+    @property
+    def capacity(self) -> int:
+        """Requests this loop holds at once before ``submit`` sheds:
+        one a slot and ``max_queue_depth`` waiting. The replica that
+        hosts the loop states it to the routers as its own cap."""
+        return int(self._engine.slots) + self._max_queue_depth
 
     def stats(self) -> dict:
         return {
@@ -399,7 +421,7 @@ class JaxSlotEngine:
         try:
             yield given
         except Exception as e:  # noqa: BLE001 — typed if the cache went
-            if not given["k"].is_deleted():
+            if not given["k"][0].is_deleted():
                 raise
             self._cache = None      # a result half made goes first
             self._cache = self._decode.init_slot_cache(
@@ -446,10 +468,17 @@ class JaxSlotEngine:
                 for slot in tokens:
                     self._pos[slot] += 1
                 nxt = jnp.argmax(logits, axis=-1)
+                load = self._cache.get("load")
+                if load is not None:
+                    # the expert layers' counts ride in the same row
+                    nxt = jnp.concatenate([nxt.astype(load.dtype), load])
             with phase("serve.engine.wait"):
                 # the step's one transfer: waits for the device, then
                 # brings the whole int32[slots] row
                 row = self._jax.device_get(nxt)
         with phase("serve.engine.read"):
             row = row.tolist()
+            if load is not None:
+                for name, n in zip(EXPERT_COUNTS, row[self.slots:]):
+                    phase_add(name, n)
             return {slot: row[slot] for slot in tokens}

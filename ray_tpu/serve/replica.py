@@ -8,7 +8,8 @@ counter exists for draining — plus a hard overload cap: multiple
 routers each honor max_concurrent_queries LOCALLY, so their sum can
 oversubscribe one replica. Past
 ``max_concurrent_queries + serve_max_queue_depth`` concurrent requests
-the replica sheds with the typed
+(a replica that hosts a decode loop counts from the loop's own capacity
+where that is larger: ``concurrency``) the replica sheds with the typed
 :class:`~ray_tpu.exceptions.ServeOverloadedError`, which the proxy
 renders as ``503 + Retry-After``).
 
@@ -22,9 +23,15 @@ from __future__ import annotations
 
 import asyncio
 import inspect
+import time
 from typing import Any
 
 from ray_tpu.exceptions import ServeOverloadedError
+
+
+# How long a draining replica must have seen no new request before it
+# counts as drained.
+DRAIN_QUIET_S = 0.1
 
 
 class Replica:
@@ -37,6 +44,7 @@ class Replica:
         else:
             self._obj = callable_def  # plain function deployment
         self._inflight = 0
+        self._last_request_at = time.monotonic()
         self._shed = 0
         self._draining = False
         queue_depth = 16
@@ -48,12 +56,27 @@ class Replica:
             retry_after = float(cfg.serve_retry_after_s)
         except Exception:  # noqa: BLE001 — unit harness without a
             pass           # worker: keep the defaults
-        self._max_inflight = int(max_concurrent_queries) + max(0, queue_depth)
+        # A continuous-batching decode loop bounds itself: so many
+        # requests decode at once, so many wait, and its own submit
+        # sheds the rest. Where that is more than the deployment's cap,
+        # the loop's bound is this replica's, or the routers would hold
+        # back requests its batch has slots for.
+        sched = getattr(self._obj, "decode_scheduler", None)
+        self._concurrency = max(int(max_concurrent_queries),
+                                int(getattr(sched, "capacity", 0)))
+        self._max_inflight = self._concurrency + max(0, queue_depth)
         self._retry_after_s = max(0.0, retry_after)
 
     async def ready(self) -> str:
         """Health check the controller awaits before routing traffic."""
         return "ok"
+
+    async def concurrency(self) -> int:
+        """What a router may keep in flight here: the deployment's
+        ``max_concurrent_queries``, or the hosted decode loop's own
+        capacity where that is larger. The controller publishes it to
+        the routers with the replica set."""
+        return self._concurrency
 
     async def stats(self) -> dict:
         """Load signal for the controller's autoscaler (reference:
@@ -85,6 +108,7 @@ class Replica:
                 f"{self._max_inflight})",
                 retry_after_s=self._retry_after_s)
         self._inflight += 1
+        self._last_request_at = time.monotonic()
         try:
             # Zero-copy ingress: resolve a by-reference body before the
             # user's callable sees the request.
@@ -111,7 +135,13 @@ class Replica:
         """
         self._draining = True
         started_with = self._inflight
-        while self._inflight > 0:
+        # Drained is idle for a moment from here on, not idle at this
+        # instant: a router that has not seen the controller's new
+        # snapshot yet may send a straggler, and the controller kills
+        # this replica as soon as drain returns.
+        self._last_request_at = time.monotonic()
+        while self._inflight > 0 or (time.monotonic() - self._last_request_at
+                                     < DRAIN_QUIET_S):
             await asyncio.sleep(0.005)
         sched = getattr(self._obj, "decode_scheduler", None)
         if sched is not None:

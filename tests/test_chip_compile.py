@@ -12,12 +12,19 @@ cell's own shapes, and held to what keeps the slot cache one buffer:
 the cache aliased to the result, no temporary the size of a layer's K,
 and no operation that produces K or V but the in-place writes.
 
+The second serving cell's programs (``mimo-v2-flash-ep16-d7``: layers of
+several kinds, a cache allocated by kind, a chip's share of the
+experts) are held to the same, at that cell's shapes; and Ouro's three
+programs still lower to the text on record from before layers had
+kinds.
+
 The topology is described inside a fixture, by the one xdist worker
 that is given this file: libtpu loads in one process at a time, so no
 other test file may do the same and nothing here runs at import.
 """
 
 import importlib
+import math
 import re
 
 import pytest
@@ -243,9 +250,10 @@ def test_the_slot_cache_is_one_buffer_written_in_place(
     text = compiled.as_text()
 
     # the cache's three leaves, and nothing else, alias the result
+    # (a model of one kind holds one run: ``cache['k'][0]``)
     parameter = {leaf: int(n) for n, leaf in re.findall(
-        r"parameter\((\d+)\)[^\n]*op_name=\"cache\[\\'(\w+)\\'\]\"",
-        text)}
+        r"parameter\((\d+)\)[^\n]*op_name=\"cache\[\\'(\w+)\\'\]"
+        r"(?:\[0\])?\"", text)}
     assert sorted(parameter) == ["k", "pos", "v"]
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
     assert aliased, "nothing is aliased: the cache is not donated"
@@ -268,3 +276,225 @@ def test_the_slot_cache_is_one_buffer_written_in_place(
     assert {op for _, op in produced} <= set(IN_PLACE), produced
     if program != "decode":
         assert mosaic_calls(text) == ["flash_fwd"]
+
+
+# --------------------- layers of several kinds, a cache by layer kind
+
+KINDS_CELL = "mimo-v2-flash-ep16-d7.reason-closed"
+HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def kinds_cell():
+    """The cell of the model with window and full layers and sparse
+    experts, as the benchmark's worker builds it."""
+    from benchmarks import loader
+
+    bench = loader.load_benchmark()
+    cell = loader.find_cell(bench, KINDS_CELL)
+    config = loader.load_config(bench, cell["config"])
+    mix = loader.load_traffic(bench, cell["traffic"])
+    program = loader.family_module(loader.find_family(bench, config),
+                                   "program")
+    return (program.program_config(config, mix["slot_len"]),
+            int(mix["slots"]), int(mix["slot_len"]),
+            sorted(mix["prompt_lengths"]))
+
+
+def test_the_second_serving_cells_shapes_are_the_ones_compiled_here(
+        kinds_cell):
+    from ray_tpu.models.transformer import layer_runs
+
+    cfg, slots, slot_len, lengths = kinds_cell
+    assert (slots, slot_len, lengths) == (128, 3200, [512, 1024, 2048])
+    assert (cfg.n_heads, cfg.head_dim, cfg.v_dim, cfg.rope_dim,
+            cfg.kv_heads("full"), cfg.kv_heads("window"), cfg.window,
+            cfg.n_experts, cfg.experts_held, cfg.experts_per_token,
+            cfg.d_expert, cfg.d_ff, cfg.vocab) == (
+        64, 192, 128, 64, 4, 8, 128, 256, 16, 8, 2048, 16384, 152576)
+    assert layer_runs(cfg) == (
+        (("full", "dense"), 1), (("window", "experts"), 4),
+        (("full", "experts"), 1), (("window", "experts"), 1))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-512",
+                                     "prefill-1024", "prefill-2048"])
+def test_a_cache_by_layer_kind_is_still_written_in_place(
+        program, kinds_cell, one_chip, no_compile_cache, monkeypatch):
+    """``slot_decode_step`` and ``slot_prefill`` of the model with
+    layers of several kinds, at its cell's shapes, for the described
+    v5e. Every leaf of the cache is aliased to the result (2.52 GB: the
+    full runs' 3200 rows a slot, the window runs' rings of 128); the
+    only operations that produce an array of a run's or a layer's K or
+    V are the in-place writes; the temporaries stay under the smallest
+    full layer's V (0.42 GB: no copy of one has room; the expert
+    layers' matrices are indexed where they lie, not sliced a layer at
+    a time); the arguments and temporaries fit the chip; and each
+    prefill holds its Mosaic calls, one a run of layers."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode, init_params
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg, slots, slot_len, _ = kinds_cell
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len)))
+    # full runs hold slot_len rows a slot, window runs a ring of 128;
+    # a row is the layer's K/V heads side by side
+    assert [k.shape for k in cache["k"]] == [
+        (1, 128, 3200, 768), (4, 128, 128, 1536), (1, 128, 3200, 768),
+        (1, 128, 128, 1536)]
+    assert [v.shape for v in cache["v"]] == [
+        (1, 128, 3200, 512), (4, 128, 128, 1024), (1, 128, 3200, 512),
+        (1, 128, 128, 1024)]
+    if program == "decode":
+        lowered = decode.slot_decode_step.lower(
+            params, cache, array((slots,), jnp.int32),
+            array((slots,), jnp.bool_), cfg)
+    else:
+        length = int(program.split("-")[1])
+        lowered = decode.slot_prefill.lower(
+            params, array((1, length), jnp.int32), cache,
+            array((), jnp.int32), cfg)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+
+    # every leaf of the cache, and nothing else, aliases the result
+    leaves = re.findall(
+        r"parameter\((\d+)\)[^\n]*op_name=\"cache\[([^\"]*)\]\"", text)
+    # K and V of the four runs, pos, and load where the program reads
+    # it (a decode step writes its three counts anew)
+    assert len(leaves) == 4 + 4 + 1 + (program != "decode")
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliased, "nothing is aliased: the cache is not donated"
+    assert sorted(int(n) for n in re.findall(
+        r"\((\d+), \{\}, may-alias\)", aliased.group(1))
+    ) == sorted(int(n) for n, _ in leaves)
+
+    kv_bytes = sum(2 * math.prod(leaf.shape)
+                   for leaf in cache["k"] + cache["v"])
+    memory = compiled.memory_analysis()
+    assert 0 <= memory.alias_size_in_bytes - kv_bytes <= 8192
+    assert memory.temp_size_in_bytes < 2 * 128 * 3200 * 512
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < HBM_BYTES)
+
+    shapes = {leaf.shape for leaf in cache["k"] + cache["v"]} | {
+        leaf.shape[1:] for leaf in cache["k"] + cache["v"]}
+    # a bitcast names memory; a copy-start/copy-done pair is the
+    # compiler's prefetch of one window layer's ring (50 MB) into the
+    # chip's fast memory, which is that layer's read and no second one
+    produced = [(name, op) for name, op in cache_producers(text, shapes)
+                if op not in ("bitcast", "copy-start", "copy-done")]
+    assert {op for _, op in produced} <= set(IN_PLACE), produced
+    assert len(produced) == 8, produced     # K's and V's write, a run
+    if program != "decode":
+        assert mosaic_calls(text) == ["flash_fwd"] * 4
+
+
+# digests of the parent's lowered programs (commit 0b4d871, before
+# layers had kinds), made from that commit's tree by this file's own
+# normaliser. Left out: the Mosaic kernels' serialized bodies, which
+# hold the line numbers of ops/attention.py, and the results' labels,
+# which name the cache's place in the result's tree (``['k']`` there,
+# ``['k'][0]`` now that a cache holds a tuple of runs)
+LOWERED_BEFORE_KINDS = {
+    "decode": "41cb2b708fbc51ce",
+    "prefill-128": "1cea9e4f9e135812",
+    "prefill-256": "5157d8e76e2ef739",
+    "train": "4ee3f3d6f707d1e7",
+}
+KERNEL_JAXPRS_BEFORE_KINDS = "345359b76e414d9d"
+
+
+def without_kernel_bodies(lowered_text):
+    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", lowered_text)
+    return re.sub(r' \{jax\.result_info = "[^"]*"\}', "", text)
+
+
+def digest(text):
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def test_ouros_three_programs_lower_to_the_text_on_record(
+        serving_cell, one_chip, monkeypatch):
+    """A model of one kind goes through the code that runs layers of
+    several kinds and comes out as it went in: ``slot_decode_step``,
+    ``slot_prefill`` (128, 256) and the train step of the two Ouro
+    cells lower to the parent's StableHLO, operation for operation, and
+    the three flash kernels trace to the parent's jaxprs. A change that
+    means to alter these programs records its own digests here."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import loader
+    from ray_tpu.models import decode, init_params
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg, slots, slot_len, lengths = serving_cell
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len)))
+    lowered = {"decode": decode.slot_decode_step.lower(
+        params, cache, array((slots,), jnp.int32),
+        array((slots,), jnp.bool_), cfg)}
+    for length in lengths:
+        lowered[f"prefill-{length}"] = decode.slot_prefill.lower(
+            params, array((1, length), jnp.int32), cache,
+            array((), jnp.int32), cfg)
+
+    bench = loader.load_benchmark()
+    cell = loader.find_cell(bench, "ouro-2.6b-d12.train-2k")
+    config = loader.load_config(bench, cell["config"])
+    mix = loader.load_traffic(bench, cell["traffic"])
+    program = loader.family_module(loader.find_family(bench, config),
+                                   "program")
+    train_cfg = program.program_config(config, mix["seq"])
+    step, optimizer = program.make_train_step(train_cfg, mix)
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), train_cfg)))
+    batch = array((mix["batch"], mix["seq"]), jnp.int32)
+    lowered["train"] = step.lower(
+        params, described(jax.eval_shape(optimizer.init, params)),
+        {"tokens": batch, "targets": batch})
+    assert {name: digest(without_kernel_bodies(low.as_text()))
+            for name, low in lowered.items()} == LOWERED_BEFORE_KINDS
+
+    jaxprs = []
+    for shape in ((4, 2048, 16, 128), (1, 128, 16, 128), (1, 256, 16, 128)):
+        arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+        def forward(q, k, v):
+            return attention.flash_attention(q, k, v, causal=True)
+
+        jaxprs.append(str(jax.make_jaxpr(forward)(arg, arg, arg)))
+        jaxprs.append(str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(forward(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(arg, arg, arg)))
+    assert digest("\n".join(jaxprs)) == KERNEL_JAXPRS_BEFORE_KINDS

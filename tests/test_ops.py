@@ -309,9 +309,9 @@ def test_slot_prefill_leaves_the_other_rows_untouched(name):
     params = init_params(jax.random.key(0), cfg)
     T0, max_len = 4, 8
     before = decode.init_slot_cache(cfg, 3, max_len)
-    marks = jax.random.normal(jax.random.key(2), before["k"].shape,
-                              cfg.dtype)
-    before = {"k": marks, "v": -marks, "pos": jnp.asarray([7, 2, 5])}
+    (k,) = before["k"]              # layers all alike: one run
+    marks = jax.random.normal(jax.random.key(2), k.shape, cfg.dtype)
+    before = {"k": (marks,), "v": (-marks,), "pos": jnp.asarray([7, 2, 5])}
     prompt = jax.random.randint(jax.random.key(3), (1, T0), 0, cfg.vocab)
     # the program consumes the cache it is given: it gets a copy
     _, after = decode.slot_prefill(params, prompt,
@@ -319,7 +319,7 @@ def test_slot_prefill_leaves_the_other_rows_untouched(name):
                                    jnp.int32(1), cfg)
     np.testing.assert_array_equal(np.asarray(after["pos"]), [7, T0, 5])
     for kv in ("k", "v"):
-        was, now = (np.asarray(c[kv], np.float32)
+        was, now = (np.asarray(c[kv][0], np.float32)
                     for c in (before, after))
         np.testing.assert_array_equal(now[:, [0, 2]], was[:, [0, 2]])
         np.testing.assert_array_equal(now[:, 1, T0:], was[:, 1, T0:])
@@ -342,7 +342,8 @@ def test_a_serving_program_consumes_the_cache_it_is_given(name, program):
     marks = jax.random.normal(jax.random.key(2), (cfg.n_layers, 3, 8,
                                                   cfg.n_heads,
                                                   cfg.head_dim), cfg.dtype)
-    given = {"k": marks, "v": -marks, "pos": jnp.asarray([3, 2, 5])}
+    # the layers are all alike: one run, so one array of K and of V
+    given = {"k": (marks,), "v": (-marks,), "pos": jnp.asarray([3, 2, 5])}
     if program == "slot_prefill":
         args = (jax.random.randint(jax.random.key(3), (1, 4), 0,
                                    cfg.vocab),)
@@ -358,14 +359,17 @@ def test_a_serving_program_consumes_the_cache_it_is_given(name, program):
 
     want_logits, want = call(jax.tree.map(jnp.copy, given))
     logits, after = call(given)
-    assert all(given[leaf].is_deleted() for leaf in ("k", "v", "pos"))
-    assert not any(a.is_deleted() for a in after.values())
+    assert all(a.is_deleted() for a in jax.tree.leaves(given))
+    assert not any(a.is_deleted() for a in jax.tree.leaves(after))
     np.testing.assert_array_equal(np.asarray(logits),
                                   np.asarray(want_logits))
-    for leaf in ("k", "v", "pos"):
-        np.testing.assert_array_equal(
-            np.asarray(after[leaf], np.float32),
-            np.asarray(want[leaf], np.float32))
+    for got, wanted in zip(jax.tree.leaves(after), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(wanted, np.float32))
+    # a bare array is no cache: it would be read as a run a layer
+    whole = jnp.zeros(marks.shape, cfg.dtype)
+    with pytest.raises(ValueError, match="one array for each"):
+        call({"k": whole, "v": whole, "pos": jnp.asarray([3, 2, 5])})
     # ... and it is a cache to go on from
     decode.slot_decode_step(params, after, jnp.zeros(3, jnp.int32),
                             jnp.ones(3, bool), cfg)

@@ -18,7 +18,6 @@ from ray_tpu.ops.attention import attention
 from ray_tpu.parallel import (
     build_mesh,
     default_mesh_shape,
-    moe_dispatch_combine,
     pipeline_spmd,
     ring_attention,
     shard_map,
@@ -132,59 +131,6 @@ def test_pipeline_grads_match_sequential():
     got = jax.jit(fn)(w, x)
     want = jax.grad(seq_loss)(w)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-
-
-def test_moe_scaled_experts_route_correctly():
-    """Per-expert scaling experts: output reveals WHICH expert ran, so
-    a dispatch/combine routing bug cannot pass."""
-    n = 2
-    mesh = Mesh(np.array(cpus(n)), ("tp",))
-    T, D, E = 16, 8, 4
-    ks = jax.random.split(jax.random.key(7), 2)
-    x = jax.random.normal(ks[0], (n * T, D), jnp.float32)
-    logits = jax.random.normal(ks[1], (n * T, E), jnp.float32)
-    scales = jnp.arange(1.0, E + 1.0)          # expert e multiplies by e+1
-
-    def expert_fn(params, xs):
-        # params: [E_local] scales; xs: [E_local, cap_total, D]
-        return xs * params[:, None, None]
-
-    def body(x_, l_, p_):
-        return moe_dispatch_combine(x_, l_, expert_fn, p_, axis="tp",
-                                    capacity_factor=8.0)
-
-    fn = shard_map(
-        body, mesh=mesh, in_specs=(P("tp"), P("tp"), P("tp")),
-        out_specs=P("tp"), check_vma=False)
-    got = jax.jit(lambda a, b, c: fn(a, b, c))(x, logits, scales)
-    gates = jax.nn.softmax(logits, axis=-1)
-    top = jnp.argmax(gates, axis=-1)
-    want = x * jnp.max(gates, -1, keepdims=True) * scales[top][:, None]
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
-
-
-def test_moe_identity_experts_roundtrip():
-    n = 2
-    mesh = Mesh(np.array(cpus(n)), ("tp",))
-    T, D, E = 16, 8, 4
-    ks = jax.random.split(jax.random.key(4), 2)
-    x = jax.random.normal(ks[0], (n * T, D), jnp.float32)
-    logits = jax.random.normal(ks[1], (n * T, E), jnp.float32)
-
-    def expert_fn(params, xs):
-        del params
-        return xs  # identity experts
-
-    fn = shard_map(
-        functools.partial(moe_dispatch_combine, expert_fn=expert_fn,
-                          expert_params=None, axis="tp",
-                          capacity_factor=8.0),
-        mesh=mesh, in_specs=(P("tp"), P("tp")), out_specs=P("tp"),
-        check_vma=False)
-    got = jax.jit(lambda a, b: fn(a, b))(x, logits)
-    gates = jax.nn.softmax(logits, axis=-1)
-    want = x * jnp.max(gates, axis=-1, keepdims=True)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("mcfg", [
