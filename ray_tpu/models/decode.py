@@ -37,6 +37,17 @@ experts that got a row, the rows routed to held experts and the fullest
 expert's rows in the last decode step, each summed over the expert
 layers, for the engine to fetch with the step's tokens.
 
+**The picked token stays on the device.** ``cache["tok"]``, int32
+[slots], is each row's last pick (:func:`pick`, the one place a token
+is chosen: ``slot_prefill`` picks a prompt's first token into its
+slot's entry, ``slot_decode_step`` each active row's next one,
+``generate`` draws through it). A step is told what to feed a row by
+one int32 a row: a token id, ``CARRY`` for the row's own last pick, or
+``IDLE``. So the serving engine dispatches step n + 1 before the host
+has seen step n's tokens: they are read where they were made, and what
+the step hands back for the host is the row of picks (with the three
+counts behind it), never ``[slots, vocab]`` logits.
+
 The cache is one set of buffers, written in place. Both programs take
 it donated, and K and V are carried through the layers' scan, not
 scanned over: XLA then aliases the result to the argument and the only
@@ -61,9 +72,9 @@ Invariants the scheduler relies on:
   BEFORE attending it) and never attended.
 * ``slot_decode_step`` writes every row's K/V unconditionally (a
   masked write would cost a gather per layer) but advances ``pos``
-  only where ``active``: an inactive row's cache may take garbage at
-  its frozen pos, which is sound because inactive rows are only ever
-  re-entered through ``slot_prefill``.
+  and replaces ``tok`` only where ``active``: an inactive row's cache
+  may take garbage at its frozen pos, which is sound because inactive
+  rows are only ever re-entered through ``slot_prefill``.
 * A row at ``pos == max_len`` would have its write clamped onto the
   last position; the callers refuse before that (``generate``'s
   ``T0 + steps > max_len``, ``JaxSlotEngine.step``'s host mirror).
@@ -91,6 +102,20 @@ from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.rotary import apply_rotary, rotate
 
 
+# in a step's ``token`` row, in place of a token id (which is never
+# negative): feed the row its own last pick, ``cache["tok"]``; and, where
+# no ``active`` is given beside it, leave the row out of the step
+CARRY, IDLE = -1, -2
+
+
+def pick(logits, key=None, temperature=None):
+    """The next token of each row of ``logits`` [.., V], int32: the
+    likeliest, or one drawn at ``temperature`` where a key is given."""
+    if key is None:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jax.random.categorical(key, logits / temperature).astype(jnp.int32)
+
+
 def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> Dict:
     """KV cache with an independent decode offset per batch row: a
@@ -115,7 +140,8 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
         return tuple(made)
 
     cache = {"k": leaves(cfg.head_dim), "v": leaves(cfg.v_dim),
-             "pos": jnp.zeros((slots,), jnp.int32)}
+             "pos": jnp.zeros((slots,), jnp.int32),
+             "tok": jnp.zeros((slots,), jnp.int32)}
     if any(ffn == EXPERTS for (_, ffn), _ in runs):
         cache["load"] = jnp.zeros((3,), jnp.int32)
     return cache
@@ -157,10 +183,11 @@ def slot_prefill(params, tokens, cache: Dict, slot,
                  cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
     """Run one prompt [1, T0] through the stack, writing each layer's
     K/V into cache row ``slot`` (a traced index: one compiled program
-    serves every slot). Returns (last-token logits [1, V], cache); the
-    cache given is consumed and the one returned is its memory, updated
-    in place. Compiles once per distinct T0 — serving callers should
-    bucket or pad prompt lengths if retrace cost matters."""
+    serves every slot). Returns (last-token logits [1, V], cache), the
+    token picked from them in ``cache["tok"][slot]``; the cache given is
+    consumed and the one returned is its memory, updated in place.
+    Compiles once per distinct T0 — serving callers should bucket or
+    pad prompt lengths if retrace cost matters."""
     _, T0 = tokens.shape
     runs = layer_runs(cfg)
     ks, vs = _cache_runs(cache, runs)
@@ -204,23 +231,40 @@ def slot_prefill(params, tokens, cache: Dict, slot,
         x, ck, cv = one_run(x, layers, ck, cv, attention)
         new_k.append(ck)
         new_v.append(cv)
-    return unembed(params, x, last=True, eps=cfg.norm_eps), dict(
+    logits = unembed(params, x, last=True, eps=cfg.norm_eps)
+    return logits, dict(
         cache, k=tuple(new_k), v=tuple(new_v),
-        pos=cache["pos"].at[slot].set(T0))
+        pos=cache["pos"].at[slot].set(T0),
+        tok=cache["tok"].at[slot].set(pick(logits)[0]))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache",))
 def slot_decode_step(params, cache: Dict, token, active,
                      cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
-    """One continuous-batching step: token [B] in, next-token logits
-    [B, V] out; each ACTIVE row attends its own prefix (per-row
-    position mask; in a window layer the last ``window`` positions of
-    it) and advances its own pos. Inactive rows are free riders — their
-    logits are garbage and their pos is frozen. The cache given is
+    """One continuous-batching step: each ACTIVE row is fed one token,
+    attends its own prefix (per-row position mask; in a window layer the
+    last ``window`` positions of it), advances its own pos and picks its
+    next token into ``cache["tok"]``. Inactive rows are free riders —
+    their logits are garbage, their pos and tok frozen. ``token`` int32
+    [B] says what a row is fed: a token id, or ``CARRY`` for the row's
+    own last pick, which never left the device. The cache given is
     consumed and the one returned is its memory, with one position a
-    row and layer written in place."""
+    row and layer written in place.
+
+    Two callers, two forms. With ``active`` bool [B]: (next-token
+    logits [B, V], cache), for a caller that draws the token itself
+    (``generate``) or compares the logits. With ``active`` None, as
+    served: ``token`` alone steers the rows (``IDLE`` leaves one out)
+    and what comes back is (the row of picks, int32 [B], with the three
+    counts of ``cache["load"]`` behind it where the model has expert
+    layers, cache): a few bytes for the host to fetch whenever it gets
+    to it, while the next step already runs on ``cache["tok"]``."""
     B = token.shape[0]
+    served = active is None
+    if served:
+        active = token != IDLE
+    token = jnp.where(token >= 0, token, cache["tok"])
     runs = layer_runs(cfg)
     ks, vs = _cache_runs(cache, runs)
     max_len = _max_len(cfg, runs, ks)
@@ -278,11 +322,14 @@ def slot_decode_step(params, cache: Dict, token, active,
         new_k.append(ck)
         new_v.append(cv)
     logits = unembed(params, x[:, 0], eps=cfg.norm_eps)
+    tok = jnp.where(active, pick(logits), cache["tok"])
     cache = dict(cache, k=tuple(new_k), v=tuple(new_v),
-                 pos=jnp.where(active, pos + 1, pos))
+                 pos=jnp.where(active, pos + 1, pos), tok=tok)
     if load is not None:
         cache["load"] = load
-    return logits, cache
+    if not served:
+        return logits, cache
+    return (tok if load is None else jnp.concatenate([tok, load])), cache
 
 
 def _attend_cached(q, lk, lv, valid, sm_scale, sink=None):
@@ -339,16 +386,10 @@ def _decode_loop(params, logits, cache, key, temperature, *, cfg,
     BRANCH is static and the magnitude is a traced operand."""
     active = jnp.ones(logits.shape[0], bool)
 
-    def pick(logits, k):
-        if not sample:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jax.random.categorical(
-            k, logits / temperature).astype(jnp.int32)
-
     def body(carry, i):
         logits, cache, key = carry
         key, sub = jax.random.split(key)
-        tok = pick(logits, sub)
+        tok = pick(logits, sub if sample else None, temperature)
         # the token sampled on the LAST iteration needs no successor
         # logits: skip its decode step (at steps=1 this halves the
         # per-generation device work)

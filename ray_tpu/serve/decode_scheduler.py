@@ -32,7 +32,11 @@ The scheduler is ENGINE-AGNOSTIC: anything with ``slots``,
 ``step({slot: last_token}) -> {slot: next_token}`` drives it, so the
 admission policy is unit-testable without jax (tests/
 test_decode_scheduler.py uses a fake engine); :class:`JaxSlotEngine`
-adapts the real per-slot cache. Engine calls run in the default
+adapts the real per-slot cache. ``step`` may answer fewer slots than
+it was given: an engine that keeps a step in flight, as
+:class:`JaxSlotEngine` does, answers a slot that joined with a call in
+the next one, and is handed that slot's last token again until it has.
+Engine calls run in the default
 executor — a jitted decode step must not block the replica's asyncio
 loop, which keeps accepting/queueing requests mid-step.
 
@@ -54,17 +58,23 @@ name, to be read as deltas:
 * executor thread, inside :class:`JaxSlotEngine`'s ``step``:
   ``serve.engine.check`` / ``.put`` / ``.dispatch`` / ``.wait`` /
   ``.read``. ``check`` is host only: the capacity check against the
-  engine's own mirror of the slots' positions, and the ``tok``/``act``
-  lists. ``wait`` is the step's one device-to-host transfer, the whole
-  argmax row fetched at once: it waits for the device and carries the
+  engine's own mirror of the slots' positions, and the one int32 row
+  that steers the step. ``put`` sends that row, ``dispatch`` enqueues
+  the step. ``wait`` is the call's one device-to-host transfer, the
+  whole row of picks of the step dispatched a call EARLIER: it waits
+  for what is left of that step, which has run since, and carries the
   transfer. ``read`` builds the returned dict from that host array.
   The engine's ``prefill`` has no spans of its own: ``serve.prefill``
-  less its hop is the call. Of a model with expert layers the same
-  fetch brings three counts of the step, each summed over its expert
-  layers, kept as sums under ``serve.engine.experts_hit`` (held experts
-  that got a row), ``serve.engine.expert_rows`` (rows routed to held
-  experts) and ``serve.engine.expert_rows_max`` (the fullest expert's
-  rows): ``[steps, sum]``, not seconds.
+  less its hop is the call. Sums beside the phases, ``[calls, sum]``
+  and not seconds: ``serve.engine.ahead`` (1 for a call answered from
+  a step already in flight, 0 for one that had to dispatch and wait)
+  and ``serve.engine.rows_wasted`` (rows stepped that no request was
+  owed: a finished request's one step more). Of a model with expert
+  layers the same fetch brings three counts of the step it fetched
+  (wasted rows included), each summed over its expert layers:
+  ``serve.engine.experts_hit`` (held experts that got a row),
+  ``serve.engine.expert_rows`` (rows routed to held experts) and
+  ``serve.engine.expert_rows_max`` (the fullest expert's rows).
 """
 
 from __future__ import annotations
@@ -75,7 +85,7 @@ import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Set
 
 from ray_tpu._private import rpc
 from ray_tpu.exceptions import ServeOverloadedError, SlotStateLostError
@@ -352,7 +362,9 @@ class DecodeScheduler:
                 continue
             with phase("serve.emit"):
                 self.steps += 1
-                self.slot_steps += len(tokens)
+                # a slot that joined with this call is answered by the
+                # next (JaxSlotEngine keeps one step in flight)
+                self.slot_steps += len(out)
                 for slot, tok in out.items():
                     req = self._active.get(slot)
                     if req is None:
@@ -363,36 +375,71 @@ class DecodeScheduler:
                         self._finish(slot, req)
 
 
+class _Flight(NamedTuple):
+    """A decode step dispatched and not yet fetched."""
+    row: Any            # its picks (and counts), still on the device
+    owed: Set[int]      # the slots it owes an answer; prefill takes its own out
+    rode: int           # the rows in it
+
+
 class JaxSlotEngine:
     """Adapts the per-slot KV cache (models/decode.py) to the
     scheduler's engine protocol. Greedy decoding; prompts are int
     token-id sequences. One compiled prefill program per distinct
     prompt length, one compiled step program total.
 
-    A decode step makes one device-to-host transfer, and none before
-    its dispatch. The slots' positions are mirrored on the host
-    (``_pos``: ``prefill`` sets a slot's entry to the prompt's length,
-    ``step`` adds one per active slot, each once the program has
-    returned and ``_cache`` is reassigned, so a call that raises leaves
-    the mirror where the cache is); the device's ``cache["pos"]`` stays
-    what the programs compute from and the mirror never writes it.
-    ``step``'s phases: ``check`` reads the mirror (host only), ``wait``
-    fetches the whole argmax row once, ``read`` builds the dict from
-    that host array. No ``block_until_ready`` here or in ``prefill``:
-    the fetch waits for the device, and its own enqueue overlaps the
-    step (an explicit wait before it cost 0.9 ms a step on a v5e:
-    PERF.md, PR 25).
+    **One decode step is in flight.** ``step(tokens)`` dispatches the
+    step for ``tokens``' slots first and only then fetches the row of
+    the step that the call before dispatched, and answers from that: a
+    call's answer is one step behind what the device is running, and
+    between two calls (emit, executor hops, check, put) the device
+    works on a step that is already queued. The token a row picked
+    stays on the device (``cache["tok"]``) and the next step reads it
+    there; only a slot that is not in the step in flight (just
+    prefilled) is fed the host's token. So:
+
+    * ``out`` holds the slots of ``tokens`` that were in the step in
+      flight. A slot that joins with this call gets its first decode
+      token from the next call; a call that finds no slot of its own in
+      flight (the first ever, after an idle spell) dispatches twice and
+      fetches the first, so it never answers nothing.
+    * For a slot that continues, ``tokens`` must hand back the engine's
+      own last answer (it is not sent up again): another value is
+      refused before dispatch. A slot in the step in flight that a call
+      leaves out has left: its row there is waste (a finished request
+      rides one step more: one K/V position in a dead slot, no time),
+      and it steps again only after a ``prefill``.
+    * ``prefill`` forgets its slot's place in the step in flight, so a
+      new request is answered only from steps dispatched after it: the
+      old row's token never reaches it.
+
+    A call makes one device-to-host transfer, and none before its
+    dispatch. The slots' positions are mirrored on the host: ``_pos``
+    is what has been dispatched (``prefill`` sets a slot's entry to the
+    prompt's length, every dispatched step adds one for each row in it,
+    wasted rows too), which is what the device's ``cache["pos"]`` reads
+    once it has run what is queued; the mirror never writes it. A slot
+    whose mirror stands at ``max_len`` is left out of a step dispatched
+    ahead of its answer; asked for in earnest it raises before
+    dispatch. ``step``'s phases: ``check`` reads the mirror and builds
+    the row that steers the step (host only), ``put`` sends that one
+    int32 row, ``wait`` fetches the row of picks of the step before,
+    ``read`` builds the dict from that host array; ``serve.engine.ahead``
+    and ``serve.engine.rows_wasted`` count beside them (module
+    docstring). No ``block_until_ready``: the fetch waits for the
+    device.
 
     The cache is one device buffer for the engine's life: both programs
-    take it donated and return it written in place, so ``_cache`` is
-    reassigned to the same memory and the array handed in is dead
-    after the call. What a failed call leaves: one refused before
-    dispatch (the prompt's length, a full slot, an error while tracing
-    or compiling) raises as it is and leaves cache and mirror as they
-    were; one that raises after the buffers are gone (the program, or
-    the fetch of its result) leaves an EMPTY cache, a zeroed mirror and
-    :class:`~ray_tpu.exceptions.SlotStateLostError` — every slot has to
-    be prefilled again, and the scheduler fails what was in flight."""
+    take it donated and return it written in place, so the step in
+    flight holds the only cache. What a failed call leaves: one refused
+    before dispatch (the prompt's length, a full slot, a forced token,
+    an error while tracing or compiling) raises as it is and leaves
+    cache, mirror and the step in flight as they were; an error after
+    the buffers are gone (a program, the fetch of the step before, the
+    prefill that follows a step that failed) is found up to one call
+    late and leaves an EMPTY cache, a zeroed mirror, nothing in flight
+    and :class:`~ray_tpu.exceptions.SlotStateLostError` — every slot has
+    to be prefilled again, and the scheduler fails what was in flight."""
 
     def __init__(self, params, cfg, *, slots: int, max_len: int):
         import jax  # deferred: scheduler users without a
@@ -406,27 +453,33 @@ class JaxSlotEngine:
         self._cfg = cfg
         self.slots = int(slots)
         self.max_len = int(max_len)
-        self._cache = decode_mod.init_slot_cache(cfg, slots, max_len)
-        self._pos = [0] * self.slots    # host mirror of _cache["pos"]
+        self._start_over()
+
+    def _start_over(self) -> None:
+        self._cache = self._flight = None   # a result half made goes first
+        self._cache = self._decode.init_slot_cache(
+            self._cfg, self.slots, self.max_len)
+        self._pos = [0] * self.slots    # dispatched: _cache["pos"] to come
+        # the token a slot's caller holds (the last answered, or fed to
+        # a slot just prefilled) and hands back with the next call; None
+        # once its row went on without it (prefill it again)
+        self._last: List[Optional[int]] = [0] * self.slots
 
     @contextlib.contextmanager
     def _giving_the_cache(self):
-        """Yields the cache for a program to consume. Where the program
-        or the fetch of its result raises: the error passes as it is if
-        the cache still lives (nothing was taken: a refusal while
-        tracing or compiling; donation takes the buffers at dispatch).
-        Else every slot's K/V went with it: start over from an empty
-        cache and say so, typed."""
+        """Around what consumes the cache. Where a program or a fetch
+        raises: the error passes as it is if the cache still lives
+        (nothing was taken: a refusal while tracing or compiling;
+        donation takes the buffers at dispatch). Else every slot's K/V
+        went with it, and the step in flight too: start over from an
+        empty cache and say so, typed."""
         given = self._cache
         try:
-            yield given
+            yield
         except Exception as e:  # noqa: BLE001 — typed if the cache went
             if not given["k"][0].is_deleted():
                 raise
-            self._cache = None      # a result half made goes first
-            self._cache = self._decode.init_slot_cache(
-                self._cfg, self.slots, self.max_len)
-            self._pos = [0] * self.slots
+            self._start_over()
             raise SlotStateLostError(
                 f"the slot cache was consumed by a call that failed "
                 f"({e!r}): the state of all {self.slots} slots is lost"
@@ -439,46 +492,104 @@ class JaxSlotEngine:
             raise ValueError(
                 f"prompt ({tokens.shape[1]}) >= slot max_len "
                 f"({self.max_len})")
-        with self._giving_the_cache() as cache:
-            logits, self._cache = self._decode.slot_prefill(
-                self._params, tokens, cache, jnp.int32(slot), self._cfg)
+        if self._flight is not None:    # its old row answers no one
+            self._flight.owed.discard(slot)
+        with self._giving_the_cache():
+            _, self._cache = self._decode.slot_prefill(
+                self._params, tokens, self._cache, jnp.int32(slot),
+                self._cfg)
             self._pos[slot] = tokens.shape[1]
-            return int(jnp.argmax(logits[0]))
+            # queued behind the step in flight; the whole row of picks
+            self._last[slot] = int(self._jax.device_get(
+                self._cache["tok"])[slot])
+            return self._last[slot]
 
-    def step(self, tokens: Dict[int, int]) -> Dict[int, int]:
-        jnp = self._jnp
-        with phase("serve.engine.check"):
-            tok = [0] * self.slots
-            act = [False] * self.slots
-            for slot, t in tokens.items():
+    def _steer(self, tokens: Dict[int, int], owed) -> List[int]:
+        """What the next step feeds each row (models/decode.py): CARRY
+        for a slot the step in flight owes an answer, the host's token
+        for one it does not, IDLE for the rest."""
+        CARRY, IDLE = self._decode.CARRY, self._decode.IDLE
+        steer = [IDLE] * self.slots
+        for slot, t in tokens.items():
+            full = self._pos[slot] >= self.max_len
+            if slot in owed:
+                if t != self._last[slot]:
+                    raise ValueError(
+                        f"slot {slot} continues from its own last token "
+                        f"{self._last[slot]}, which is on the device: "
+                        f"{t} cannot be fed in its place")
+                # ahead of its answer: a full slot just sits it out
+                steer[slot] = IDLE if full else CARRY
+            elif self._last[slot] is None:
+                raise ValueError(
+                    f"slot {slot} left a step out and its row went on "
+                    f"without it: prefill it again")
+            elif full:
                 # a slot at capacity would silently clamp its cache
                 # write; refuse loudly (the scheduler's max_tokens bound
                 # plus the engine's prompt-length check make this
                 # unreachable)
-                if self._pos[slot] >= self.max_len:
-                    raise ValueError(f"slot {slot} KV cache full")
-                tok[slot], act[slot] = int(t), True
+                raise ValueError(f"slot {slot} KV cache full")
+            elif t < 0:
+                raise ValueError(f"slot {slot}: token {t} is negative")
+            else:
+                steer[slot] = self._last[slot] = int(t)
+        return steer
+
+    def _dispatch(self, steer: List[int], fed) -> _Flight:
+        row, self._cache = self._decode.slot_decode_step(
+            self._params, self._cache, fed, None, self._cfg)
+        rode = {slot for slot, t in enumerate(steer)
+                if t != self._decode.IDLE}
+        for slot in rode:
+            self._pos[slot] += 1
+        return _Flight(row, rode, len(rode))
+
+    def step(self, tokens: Dict[int, int]) -> Dict[int, int]:
+        if not tokens:
+            return {}
+        IDLE = self._decode.IDLE
+        with phase("serve.engine.check"):
+            before = self._flight or _Flight(None, set(), 0)
+            ahead = any(slot in before.owed for slot in tokens)
+            steers = [self._steer(tokens, before.owed)]
+            if not ahead:
+                # nothing in flight answers this call: the step for
+                # ``tokens`` will, and one more goes out behind it (made
+                # here, before the first is dispatched, so that each
+                # phase is entered once a call: the mirror is one short)
+                steers.append([
+                    IDLE if t == IDLE or self._pos[slot] + 1 >= self.max_len
+                    else self._decode.CARRY
+                    for slot, t in enumerate(steers[0])])
         with phase("serve.engine.put"):
-            tok = jnp.asarray(tok, jnp.int32)
-            act = jnp.asarray(act)
-        with self._giving_the_cache() as cache:
+            fed = [self._jnp.asarray(steer, self._jnp.int32)
+                   for steer in steers]
+        wasted = 0
+        with self._giving_the_cache():
             with phase("serve.engine.dispatch"):
-                logits, self._cache = self._decode.slot_decode_step(
-                    self._params, cache, tok, act, self._cfg)
-                for slot in tokens:
-                    self._pos[slot] += 1
-                nxt = jnp.argmax(logits, axis=-1)
-                load = self._cache.get("load")
-                if load is not None:
-                    # the expert layers' counts ride in the same row
-                    nxt = jnp.concatenate([nxt.astype(load.dtype), load])
+                flights = [self._dispatch(steer, row)
+                           for steer, row in zip(steers, fed)]
+                if not ahead:
+                    # the old step in flight is dropped unfetched (its
+                    # expert counts with it): its slots have all left
+                    wasted = before.rode
+                    for slot in before.owed:
+                        self._last[slot] = None
+                    before = flights[0]
+                self._flight = flights[-1]
             with phase("serve.engine.wait"):
-                # the step's one transfer: waits for the device, then
-                # brings the whole int32[slots] row
-                row = self._jax.device_get(nxt)
+                # the call's one transfer: waits for the step before,
+                # then brings its whole int32 row
+                row = self._jax.device_get(before.row)
         with phase("serve.engine.read"):
             row = row.tolist()
-            if load is not None:
-                for name, n in zip(EXPERT_COUNTS, row[self.slots:]):
-                    phase_add(name, n)
-            return {slot: row[slot] for slot in tokens}
+            for name, n in zip(EXPERT_COUNTS, row[self.slots:]):
+                phase_add(name, n)
+            out = {slot: row[slot] for slot in tokens if slot in before.owed}
+            for slot in before.owed:
+                self._last[slot] = out.get(slot)    # None: it has left
+            phase_add("serve.engine.ahead", int(ahead))
+            phase_add("serve.engine.rows_wasted",
+                      wasted + before.rode - len(out))
+            return out

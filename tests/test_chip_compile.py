@@ -14,9 +14,10 @@ and no operation that produces K or V but the in-place writes.
 
 The second serving cell's programs (``mimo-v2-flash-ep16-d7``: layers of
 several kinds, a cache allocated by kind, a chip's share of the
-experts) are held to the same, at that cell's shapes; and Ouro's three
-programs still lower to the text on record from before layers had
-kinds.
+experts) are held to the same, at that cell's shapes. Both cells'
+decode steps hand the host a row of picked tokens and nothing of
+``[slots, vocab]``; the serving programs of both cells and Ouro's train
+step lower to the text on record.
 
 The topology is described inside a fixture, by the one xdist worker
 that is given this file: libtpu loads in one process at a time, so no
@@ -181,6 +182,33 @@ def cache_producers(compiled_text, shapes):
     return found
 
 
+# the two cells' decode steps as compiled for the described chip, kept
+# for the test that reads them again (a compile each, a minute together)
+COMPILED_DECODE = {}
+
+
+def compiled_decode(cell, cfg, slots, slot_len, one_chip):
+    """The served form of ``slot_decode_step`` (steered by one int32
+    row, ``active`` None) at a cell's shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode, init_params
+
+    if cell not in COMPILED_DECODE:
+        described = lambda tree: jax.tree.map(          # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+        COMPILED_DECODE[cell] = decode.slot_decode_step.lower(
+            described(jax.eval_shape(
+                lambda: init_params(jax.random.key(0), cfg))),
+            described(jax.eval_shape(
+                lambda: decode.init_slot_cache(cfg, slots, slot_len))),
+            jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+            None, cfg).compile()
+    return COMPILED_DECODE[cell]
+
+
 @pytest.fixture(scope="module")
 def serving_cell():
     """Cell 1 as the benchmark's worker builds it: the program's config
@@ -209,7 +237,7 @@ def test_the_serving_cells_shapes_are_the_ones_compiled_here(serving_cell):
 def test_the_slot_cache_is_one_buffer_written_in_place(
         program, serving_cell, one_chip, no_compile_cache, monkeypatch):
     """``slot_decode_step`` and ``slot_prefill`` at cell 1's shapes, for
-    the described v5e: the cache's K, V and pos are aliased to the
+    the described v5e: the cache's K, V, pos and tok are aliased to the
     result (3.22 GB, all of it), the temporaries stay under one layer's
     K (33.5 MB: no copy of a layer, let alone of the cache, has room),
     and the only operations whose result is a layer's or the whole
@@ -238,23 +266,21 @@ def test_the_slot_cache_is_one_buffer_written_in_place(
     cache = described(jax.eval_shape(
         lambda: decode.init_slot_cache(cfg, slots, slot_len)))
     if program == "decode":
-        lowered = decode.slot_decode_step.lower(
-            params, cache, array((slots,), jnp.int32),
-            array((slots,), jnp.bool_), cfg)
+        compiled = compiled_decode(SERVING_CELL, cfg, slots, slot_len,
+                                   one_chip)
     else:
         length = int(program.split("-")[1])
-        lowered = decode.slot_prefill.lower(
+        compiled = decode.slot_prefill.lower(
             params, array((1, length), jnp.int32), cache,
-            array((), jnp.int32), cfg)
-    compiled = lowered.compile()
+            array((), jnp.int32), cfg).compile()
     text = compiled.as_text()
 
-    # the cache's three leaves, and nothing else, alias the result
+    # the cache's four leaves, and nothing else, alias the result
     # (a model of one kind holds one run: ``cache['k'][0]``)
     parameter = {leaf: int(n) for n, leaf in re.findall(
         r"parameter\((\d+)\)[^\n]*op_name=\"cache\[\\'(\w+)\\'\]"
         r"(?:\[0\])?\"", text)}
-    assert sorted(parameter) == ["k", "pos", "v"]
+    assert sorted(parameter) == ["k", "pos", "tok", "v"]
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
     assert aliased, "nothing is aliased: the cache is not donated"
     assert sorted(int(n) for n in re.findall(
@@ -266,9 +292,9 @@ def test_the_slot_cache_is_one_buffer_written_in_place(
     itemsize = jnp.dtype(cfg.dtype).itemsize
     layer_bytes = itemsize * slots * slot_len * cfg.n_heads * cfg.head_dim
     memory = compiled.memory_analysis()
-    # K and V whole, and pos padded to a tile
+    # K and V whole, and pos and tok padded to a tile each
     assert 0 <= (memory.alias_size_in_bytes
-                 - 2 * cfg.n_layers * layer_bytes) <= 4096
+                 - 2 * cfg.n_layers * layer_bytes) <= 8192
     assert memory.temp_size_in_bytes < layer_bytes
 
     produced = cache_producers(text, {layer, whole})
@@ -361,23 +387,21 @@ def test_a_cache_by_layer_kind_is_still_written_in_place(
         (1, 128, 3200, 512), (4, 128, 128, 1024), (1, 128, 3200, 512),
         (1, 128, 128, 1024)]
     if program == "decode":
-        lowered = decode.slot_decode_step.lower(
-            params, cache, array((slots,), jnp.int32),
-            array((slots,), jnp.bool_), cfg)
+        compiled = compiled_decode(KINDS_CELL, cfg, slots, slot_len,
+                                   one_chip)
     else:
         length = int(program.split("-")[1])
-        lowered = decode.slot_prefill.lower(
+        compiled = decode.slot_prefill.lower(
             params, array((1, length), jnp.int32), cache,
-            array((), jnp.int32), cfg)
-    compiled = lowered.compile()
+            array((), jnp.int32), cfg).compile()
     text = compiled.as_text()
 
     # every leaf of the cache, and nothing else, aliases the result
     leaves = re.findall(
         r"parameter\((\d+)\)[^\n]*op_name=\"cache\[([^\"]*)\]\"", text)
-    # K and V of the four runs, pos, and load where the program reads
-    # it (a decode step writes its three counts anew)
-    assert len(leaves) == 4 + 4 + 1 + (program != "decode")
+    # K and V of the four runs, pos and tok, and load where the program
+    # reads it (a decode step writes its three counts anew)
+    assert len(leaves) == 4 + 4 + 2 + (program != "decode")
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
     assert aliased, "nothing is aliased: the cache is not donated"
     assert sorted(int(n) for n in re.findall(
@@ -405,17 +429,72 @@ def test_a_cache_by_layer_kind_is_still_written_in_place(
         assert mosaic_calls(text) == ["flash_fwd"] * 4
 
 
-# digests of the parent's lowered programs (commit 0b4d871, before
-# layers had kinds), made from that commit's tree by this file's own
-# normaliser. Left out: the Mosaic kernels' serialized bodies, which
-# hold the line numbers of ops/attention.py, and the results' labels,
-# which name the cache's place in the result's tree (``['k']`` there,
-# ``['k'][0]`` now that a cache holds a tuple of runs)
-LOWERED_BEFORE_KINDS = {
-    "decode": "41cb2b708fbc51ce",
-    "prefill-128": "1cea9e4f9e135812",
-    "prefill-256": "5157d8e76e2ef739",
+@pytest.mark.parametrize("cell", [SERVING_CELL, KINDS_CELL])
+def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
+        cell, serving_cell, kinds_cell, one_chip, no_compile_cache,
+        monkeypatch):
+    """The served ``slot_decode_step`` of both cells, compiled for the
+    described v5e: what it returns beside the cache is one int32 row
+    (the picks, with the three expert counts behind them where the
+    model has expert layers), a few hundred bytes; the only array of
+    ``[slots, vocab]`` in the program is the head's own product, which
+    the pick reads where it lies (no second one, no copy, nothing of
+    that size among the results); and the whole cache still aliases
+    the result."""
+    import jax.numpy as jnp
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg, slots, slot_len, _ = (serving_cell if cell == SERVING_CELL
+                               else kinds_cell)
+    compiled = compiled_decode(cell, cfg, slots, slot_len, one_chip)
+    text = compiled.as_text()
+    row = slots + (3 if cfg.n_experts else 0)
+    root = re.search(r"ROOT %[\w.\-]+ = \(([^\n]*?)\) tuple\(",
+                     text[text.index("\nENTRY "):]).group(1)
+    results = re.findall(r"(\w+)\[([\d,]*)\]", root)
+    # the row; beside it the cache's pos and tok are int32 [slots] too
+    assert results.count(("s32", str(row))) == (1 if cfg.n_experts else 3)
+    assert not [r for r in results
+                if r[1] == f"{slots},{cfg.vocab}"], results
+    memory = compiled.memory_analysis()
+    # beside the aliased cache: the row, padded to a tile
+    assert 4 * row <= (memory.output_size_in_bytes
+                       - memory.alias_size_in_bytes) <= 4096
+    # what lies inside a fusion (of whatever result: the head's product
+    # fused with the pick yields a pair of rows) never reaches memory
+    fused = set(re.findall(r" fusion\([^\n]*calls=%([\w.\-]+)", text))
+    logits = [(comp, name, op)
+              for comp, ins in computations(text).items()
+              if comp not in fused
+              for _, name, shape, op, _ in ins
+              if shape == (slots, cfg.vocab)]
+    assert len(logits) <= 1, logits
+    assert memory.temp_size_in_bytes < 2 * 4 * slots * cfg.vocab + (
+        jnp.dtype(cfg.dtype).itemsize * slots * slot_len * cfg.n_heads
+        * cfg.head_dim if cell == SERVING_CELL else 2 * 128 * 3200 * 512)
+
+
+# digests of the lowered programs, made by this file's own normaliser.
+# ``train`` is the parent's of before layers had kinds (commit 0b4d871),
+# and so were cell 1's serving programs until a step kept its picks on
+# the device (``cache["tok"]``, the pick inside both programs, a decode
+# step steered by one int32 row and returning the row of picks): those
+# three are on record anew, and the second serving cell's four beside
+# them. Left out: the Mosaic kernels' serialized bodies, which hold the
+# line numbers of ops/attention.py, and the results' labels, which name
+# the cache's place in the result's tree
+LOWERED = {
+    "decode": "2fc4dddef25cce21",
+    "prefill-128": "73d2ed7d30dcc38c",
+    "prefill-256": "dbd7cc3a5ea8d517",
     "train": "4ee3f3d6f707d1e7",
+}
+LOWERED_KINDS = {
+    "decode": "8eaabf43f891f8f9",
+    "prefill-512": "8bfb8e890cd61412",
+    "prefill-1024": "486ec1e4b8f1528b",
+    "prefill-2048": "282e4d2871f6e016",
 }
 KERNEL_JAXPRS_BEFORE_KINDS = "345359b76e414d9d"
 
@@ -431,14 +510,53 @@ def digest(text):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
+def serving_programs_lowered(cell, one_chip):
+    """{name: the lowered text's digest} of a serving cell's decode
+    step, as served, and of its prefills."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode, init_params
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg, slots, slot_len, lengths = cell
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len)))
+    lowered = {"decode": decode.slot_decode_step.lower(
+        params, cache, array((slots,), jnp.int32), None, cfg)}
+    for length in lengths:
+        lowered[f"prefill-{length}"] = decode.slot_prefill.lower(
+            params, array((1, length), jnp.int32), cache,
+            array((), jnp.int32), cfg)
+    return {name: digest(without_kernel_bodies(low.as_text()))
+            for name, low in lowered.items()}
+
+
+def test_the_second_cells_serving_programs_lower_to_the_text_on_record(
+        kinds_cell, one_chip, monkeypatch):
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert serving_programs_lowered(kinds_cell, one_chip) == LOWERED_KINDS
+
+
 def test_ouros_three_programs_lower_to_the_text_on_record(
         serving_cell, one_chip, monkeypatch):
-    """A model of one kind goes through the code that runs layers of
-    several kinds and comes out as it went in: ``slot_decode_step``,
-    ``slot_prefill`` (128, 256) and the train step of the two Ouro
-    cells lower to the parent's StableHLO, operation for operation, and
-    the three flash kernels trace to the parent's jaxprs. A change that
-    means to alter these programs records its own digests here."""
+    """``slot_decode_step`` as served, ``slot_prefill`` (128, 256) and
+    the train step of the two Ouro cells lower to the StableHLO on
+    record, operation for operation (the train step to the parent's of
+    before layers had kinds: a model of one kind goes through the code
+    that runs several and comes out as it went in), and the three flash
+    kernels trace to that parent's jaxprs. A change that means to alter
+    these programs records its own digests here."""
     import jax
     import jax.numpy as jnp
 
@@ -456,18 +574,7 @@ def test_ouros_three_programs_lower_to_the_text_on_record(
     def array(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    cfg, slots, slot_len, lengths = serving_cell
-    params = described(jax.eval_shape(
-        lambda: init_params(jax.random.key(0), cfg)))
-    cache = described(jax.eval_shape(
-        lambda: decode.init_slot_cache(cfg, slots, slot_len)))
-    lowered = {"decode": decode.slot_decode_step.lower(
-        params, cache, array((slots,), jnp.int32),
-        array((slots,), jnp.bool_), cfg)}
-    for length in lengths:
-        lowered[f"prefill-{length}"] = decode.slot_prefill.lower(
-            params, array((1, length), jnp.int32), cache,
-            array((), jnp.int32), cfg)
+    digests = serving_programs_lowered(serving_cell, one_chip)
 
     bench = loader.load_benchmark()
     cell = loader.find_cell(bench, "ouro-2.6b-d12.train-2k")
@@ -480,11 +587,10 @@ def test_ouros_three_programs_lower_to_the_text_on_record(
     params = described(jax.eval_shape(
         lambda: init_params(jax.random.key(0), train_cfg)))
     batch = array((mix["batch"], mix["seq"]), jnp.int32)
-    lowered["train"] = step.lower(
+    digests["train"] = digest(without_kernel_bodies(step.lower(
         params, described(jax.eval_shape(optimizer.init, params)),
-        {"tokens": batch, "targets": batch})
-    assert {name: digest(without_kernel_bodies(low.as_text()))
-            for name, low in lowered.items()} == LOWERED_BEFORE_KINDS
+        {"tokens": batch, "targets": batch}).as_text()))
+    assert digests == LOWERED
 
     jaxprs = []
     for shape in ((4, 2048, 16, 128), (1, 128, 16, 128), (1, 256, 16, 128)):

@@ -10,6 +10,11 @@ Oracle half: the per-slot KV cache (models/decode.py slot_prefill /
 slot_decode_step) must produce, token for token, the argmax of the full
 ``forward()`` on the growing prefix, including through a slot freed and
 re-prefilled mid-flight.
+
+Engine half: :class:`JaxSlotEngine` keeps one decode step in flight
+(dispatch, then fetch the step before): what a call answers and when,
+what a finished or re-prefilled slot's row in flight comes to, the
+position mirror, one transfer a call, and a failure found a call late.
 """
 
 import asyncio
@@ -54,6 +59,53 @@ class GatedEngine(FreeRunEngine):
         await self.gate.acquire()
         self.step_slots.append(sorted(tokens))
         return {s: t + 1 for s, t in tokens.items()}
+
+
+class LaggingEngine(FreeRunEngine):
+    """FreeRunEngine's tokens, answered one call behind as by an engine
+    that keeps a step in flight: a call computes its slots' next tokens
+    (from the token in flight where there is one, else from the one it
+    is handed) and answers with what the call before computed; with
+    nothing in flight for its slots it computes twice."""
+
+    def __init__(self, slots):
+        super().__init__(slots)
+        self.flying = {}
+
+    def prefill(self, slot, prompt):
+        self.flying.pop(slot, None)
+        return super().prefill(slot, prompt)
+
+    def step(self, tokens):
+        self.step_slots.append(sorted(tokens))
+        out = {s: self.flying[s] for s in tokens if s in self.flying}
+        self.flying = {s: out.get(s, t) + 1 for s, t in tokens.items()}
+        if not out:
+            out, self.flying = self.flying, {
+                s: t + 1 for s, t in self.flying.items()}
+        return out
+
+
+def test_an_engine_that_answers_a_call_late_is_served_in_full():
+    """``step`` may answer fewer slots than it was given (a slot that
+    joined with the call): the scheduler hands that slot's token back
+    until it is answered, every request gets its own tokens in full,
+    and ``slot_steps`` counts each decode token once."""
+    async def run():
+        eng = LaggingEngine(slots=3)
+        sched = DecodeScheduler(eng)
+        outs = await asyncio.gather(
+            *[sched.submit([i], max_tokens=2 + i % 4) for i in range(10)])
+        for i, toks in enumerate(outs):
+            assert toks == [i + 100 + k for k in range(2 + i % 4)]
+        st = sched.stats()
+        assert st["completed"] == 10
+        assert st["slot_steps"] == st["tokens_generated"] - 10
+        # the joining call's row and a finished slot's one step more are
+        # rows the engine stepped and the scheduler did not count
+        assert st["slot_steps"] < sum(len(s) for s in eng.step_slots)
+        await sched.aclose()
+    asyncio.run(run())
 
 
 def test_single_request_generates_max_tokens():
@@ -480,34 +532,59 @@ def test_a_raising_step_still_closes_and_counts_its_span():
 # ------------------------------------------------------------- jax oracle
 
 
+def _tiny_engine(slots, max_len):
+    """The real engine over a model of two layers whose head is its
+    own, so that a greedy sequence wanders (a tied one repeats its last
+    token, and a stale token would pass for the right one)."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.decode_scheduler import JaxSlotEngine
+
+    cfg = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
+                            d_ff=128, max_seq=64, dtype=jnp.float32,
+                            tie_embeddings=False)
+    return JaxSlotEngine(init_params(jax.random.key(0), cfg), cfg,
+                         slots=slots, max_len=max_len)
+
+
+def _oracle(eng, prompt, n, eos=None):
+    """``n`` greedy tokens (fewer after ``eos``) by the full forward()
+    on the growing prefix, which shares no cache code."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import forward
+
+    prefix = list(prompt)
+    while len(prefix) < len(prompt) + n:
+        logits = forward(eng._params, jnp.asarray([prefix], jnp.int32),
+                         eng._cfg)
+        prefix.append(int(jnp.argmax(logits[0, -1])))
+        if prefix[-1] == eos:
+            break
+    return prefix[len(prompt):]
+
+
+def test_the_tiny_models_greedy_sequences_wander():
+    """The oracle is worth its name: neighbouring tokens of a greedy
+    sequence differ, so a token one step stale is a wrong token."""
+    eng = _tiny_engine(slots=1, max_len=8)
+    for prompt in ([5, 11, 23], [40, 2], [7]):
+        seq = _oracle(eng, prompt, 8)
+        assert sum(a != b for a, b in zip(seq, seq[1:])) >= 5, seq
+
+
 def test_slot_cache_matches_full_forward_on_the_growing_prefix():
     """Greedy tokens through the per-slot cache — including a slot
     freed by one sequence and re-prefilled by another mid-flight —
     are, request by request, the per-step argmax of the full forward()
     on the growing prefix, which shares no cache code."""
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import (TransformerConfig, forward,
-                                            init_params)
-
-    cfg = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
-                            d_ff=128, max_seq=64, dtype=jnp.float32)
-    params = init_params(jax.random.key(0), cfg)
-    from ray_tpu.serve.decode_scheduler import JaxSlotEngine
-
+    eng = _tiny_engine(slots=2, max_len=32)
     prompts = [[5, 11, 23], [40, 2, 9], [88, 17, 3]]
     steps = [6, 3, 4]   # seq1 finishes early; seq2 takes its slot
 
-    def oracle(prompt, n):
-        prefix = list(prompt)
-        for _ in range(n):
-            logits = forward(params, jnp.asarray([prefix], jnp.int32), cfg)
-            prefix.append(int(jnp.argmax(logits[0, -1])))
-        return prefix[len(prompt):]
-
     async def run():
-        eng = JaxSlotEngine(params, cfg, slots=2, max_len=32)
         sched = DecodeScheduler(eng)
         outs = await asyncio.gather(
             *[sched.submit(p, max_tokens=n)
@@ -517,114 +594,307 @@ def test_slot_cache_matches_full_forward_on_the_growing_prefix():
 
     outs = asyncio.run(run())
     for prompt, n, got in zip(prompts, steps, outs):
-        assert got == oracle(prompt, n), (prompt, n)
+        assert got == _oracle(eng, prompt, n), (prompt, n)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_a_seeded_load_through_the_scheduler_equals_forward(seed):
+    """Twelve requests of seeded prompts and lengths on three slots,
+    one of them ended by an EOS it really meets, arriving in two waves:
+    slots finish at different calls, ride their wasted step and are
+    prefilled again while other rows are in flight. Every request gets
+    exactly forward()'s greedy tokens on its own growing prefix."""
+    import random
+
+    eng = _tiny_engine(slots=3, max_len=40)
+    rng = random.Random(seed)
+    asks = [([rng.randrange(97) for _ in range(rng.randint(1, 9))],
+             rng.randint(1, 14)) for _ in range(12)]
+    # an EOS that the fourth request meets half way
+    long = _oracle(eng, asks[3][0], 14)
+    eos = long[6]
+    asks[3] = (asks[3][0], 14)
+
+    async def run():
+        sched = DecodeScheduler(eng)
+        first = [asyncio.ensure_future(sched.submit(
+            p, max_tokens=n, eos_token=eos if i == 3 else None))
+            for i, (p, n) in enumerate(asks[:7])]
+        await asyncio.wait(first, return_when=asyncio.FIRST_COMPLETED)
+        rest = [sched.submit(p, max_tokens=n) for p, n in asks[7:]]
+        outs = await asyncio.gather(*first, *rest)
+        stats = sched.stats()
+        await sched.aclose()
+        return outs, stats
+
+    outs, stats = asyncio.run(run())
+    for i, ((prompt, n), got) in enumerate(zip(asks, outs)):
+        assert got == _oracle(eng, prompt, n, eos if i == 3 else None), i
+    assert len(outs[3]) == long.index(eos) + 1 < 14
+    # every decode token was counted in one step's occupancy, once
+    assert stats["slot_steps"] == stats["tokens_generated"] - 12
+    wasted = stats["phases"]["serve.engine.rows_wasted"][1]
+    assert 0 < wasted <= sum(n > 1 for _, n in asks) + 1
+
+
+ENGINE_COUNTS = ("serve.engine.ahead", "serve.engine.rows_wasted")
 
 
 def test_engine_phases_cover_the_step():
-    """Every step of the real engine records its five phases once, a
-    prefill none of its own, and the step's phases are the step: their
-    sum is 90 to 100 % of its wall time (the median step's, so that one
-    stall of a shared box between two spans does not decide it)."""
-    jax = pytest.importorskip("jax")
+    """Every step of the real engine records its five phases once (and
+    its two counts beside them), a prefill none of its own, and the
+    step's phases are the step: their sum is 90 to 100 % of its wall
+    time (the median step's, so that one stall of a shared box between
+    two spans does not decide it)."""
     import statistics
 
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import TransformerConfig, init_params
-    from ray_tpu.serve.decode_scheduler import JaxSlotEngine
     from ray_tpu.util.phases import recording
 
-    cfg = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
-                            d_ff=128, max_seq=64, dtype=jnp.float32)
-    eng = JaxSlotEngine(init_params(jax.random.key(0), cfg), cfg,
-                        slots=2, max_len=32)
+    eng = _tiny_engine(slots=2, max_len=32)
     with recording({}) as table:
         last = {0: eng.prefill(0, [5, 11, 23]), 1: eng.prefill(1, [40, 2])}
     assert table == {}
-    last = eng.step(last)                   # compiles
+    with recording({}) as table:
+        last = eng.step(last)               # compiles; two dispatches
+    assert [table[p][0] for p in ENGINE_STEP_PHASES] == [1] * 5
     shares = []
     for _ in range(15):
         with recording({}) as table:
             t0 = time.perf_counter()
             last = eng.step(last)
             wall = time.perf_counter() - t0
-        assert sorted(table) == sorted(ENGINE_STEP_PHASES)
+        assert sorted(table) == sorted(ENGINE_STEP_PHASES + ENGINE_COUNTS)
         assert [table[p][0] for p in ENGINE_STEP_PHASES] == [1] * 5
+        assert table["serve.engine.ahead"] == [1, 1]
+        assert table["serve.engine.rows_wasted"] == [1, 0]
         shares.append(sum(table[p][1] for p in ENGINE_STEP_PHASES) / wall)
     assert max(shares) <= 1.0
     assert statistics.median(shares) >= 0.9
 
 
-# ------------------------------------ one transfer a step, positions on host
-
-
-def _tiny_engine(slots, max_len):
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import TransformerConfig, init_params
-    from ray_tpu.serve.decode_scheduler import JaxSlotEngine
-
-    cfg = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
-                            d_ff=128, max_seq=64, dtype=jnp.float32)
-    return JaxSlotEngine(init_params(jax.random.key(0), cfg), cfg,
-                         slots=slots, max_len=max_len)
+# --------------------------- one step in flight, one transfer a call
 
 
 def _device_pos(eng):
+    """``cache["pos"]`` once the device has run what is queued (the
+    read waits for it)."""
     import numpy as np
 
     return np.asarray(eng._cache["pos"]).tolist()
 
 
-def test_position_mirror_equals_the_device_after_every_call():
-    """Prefills, steps with some slots inactive, a slot freed and
-    prefilled again: after each call the host's positions are the
-    device's ``cache["pos"]`` in every row, the frozen ones too."""
+class _Client:
+    """A caller that keeps the engine's protocol: hands each slot's last
+    token back, takes an answer when it comes (a slot that joined is
+    answered a call late), and holds every token to the oracle."""
+
+    def __init__(self, eng):
+        self.eng, self.prefix, self.done = eng, {}, []
+
+    def prefill(self, slot, prompt):
+        if slot in self.prefix:
+            self.done.append(self.prefix[slot])
+        self.prefix[slot] = (len(prompt), list(prompt) + [
+            self.eng.prefill(slot, prompt)])
+
+    def step(self, *slots):
+        out = self.eng.step({s: self.last(s) for s in slots})
+        for slot, tok in out.items():
+            self.prefix[slot][1].append(tok)
+        return sorted(out)
+
+    def last(self, slot):
+        return self.prefix[slot][1][-1]
+
+    def check(self):
+        """Every request, the finished ones too; gives how many tokens
+        each got."""
+        served = self.done + list(self.prefix.values())
+        for n, prefix in served:
+            assert prefix[n:] == _oracle(self.eng, prefix[:n],
+                                         len(prefix) - n), prefix[:n]
+        return [len(prefix) - n for n, prefix in served]
+
+
+# a slot finishes (0 leaves after the fifth call) and is taken again
+# while the others' rows are in flight; 1 joins a running batch.
+# (call, its arguments, the slots it answers, the mirror after it)
+SCRIPT = [
+    ("prefill", (0, [5, 11, 23]), None, [3, 0, 0]),
+    ("prefill", (2, [40, 2]), None, [3, 0, 2]),
+    ("step", (0, 2), [0, 2], [5, 0, 4]),        # the first: two dispatches
+    ("step", (0, 2), [0, 2], [6, 0, 5]),
+    ("prefill", (1, [88, 17, 3, 9, 1]), None, [6, 5, 5]),
+    ("step", (0, 1, 2), [0, 2], [7, 6, 6]),     # 1 is answered a call late
+    ("step", (1, 2), [1, 2], [7, 7, 7]),        # 0 is done: its row is waste
+    ("prefill", (0, [7]), None, [1, 7, 7]),     # ... and the slot taken again
+    ("step", (0, 1, 2), [1, 2], [2, 8, 8]),
+    ("step", (0, 1, 2), [0, 1, 2], [3, 9, 9]),
+    ("step", (2,), [2], [3, 9, 10]),            # 0 and 1 leave together
+    ("prefill", (1, [30, 31]), None, [3, 2, 10]),
+    ("step", (1, 2), [2], [3, 3, 11]),
+    ("step", (1, 2), [1, 2], [3, 4, 12]),
+]
+
+
+def test_position_mirror_is_what_was_dispatched_and_the_device_follows():
+    """Prefills, steps, a slot that finishes and is prefilled again, a
+    slot that joins: after each call the host's positions are what has
+    been dispatched, every row of every step counted (the second
+    dispatch of a call that found nothing in flight, the wasted step of
+    a slot that left), and the device's ``cache["pos"]`` reads the same
+    in every row once it has run what is queued."""
     eng = _tiny_engine(slots=3, max_len=32)
     assert eng._pos == _device_pos(eng) == [0, 0, 0]
-    last = {}
-    script = [("prefill", 0, [5, 11, 23]), ("prefill", 2, [40, 2]),
-              ("step", (0, 2)), ("step", (0,)),      # slot 2 sits one out
-              ("prefill", 1, [88, 17, 3, 9, 1]), ("step", (0, 1, 2)),
-              ("step", (1, 2)),                      # slot 0 is done
-              ("prefill", 0, [7]),                   # ... and taken again
-              ("step", (0, 1, 2)), ("step", (2,))]
-    want = [0, 0, 0]
-    for kind, *args in script:
-        if kind == "prefill":
-            slot, prompt = args
-            last[slot] = eng.prefill(slot, prompt)
-            want[slot] = len(prompt)
-        else:
-            out = eng.step({s: last[s] for s in args[0]})
-            assert sorted(out) == sorted(args[0])
-            last.update(out)
-            for s in args[0]:
-                want[s] += 1
+    client = _Client(eng)
+    for kind, args, answered, want in SCRIPT:
+        got = getattr(client, kind)(*args)
+        assert got == answered, (kind, args)
         assert eng._pos == want == _device_pos(eng), (kind, args)
 
 
-def test_full_slot_is_refused_before_anything_is_dispatched():
-    """A slot whose position has reached ``max_len`` makes ``step``
-    raise from the host-side check: no put, no dispatch (the cache is
-    the object it was), and no slot of the call advances."""
+def test_a_script_of_prefills_and_steps_equals_forward_on_the_prefix():
+    """The same script: every token the engine gives, one call behind
+    the step that made it, is the argmax of ``forward()`` on that
+    slot's growing prefix (no cache code); the slot taken again is
+    answered from its new prompt alone."""
+    client = _Client(_tiny_engine(slots=3, max_len=32))
+    for kind, args, _, _ in SCRIPT:
+        getattr(client, kind)(*args)
+    # the two requests that ended (slot 0's, slot 1's), then slot 0's
+    # second, slot 2's only one and slot 1's second
+    assert client.check() == [4, 4, 2, 10, 2]
+
+
+def test_the_counters_count_what_the_script_did():
+    """``serve.engine.ahead``: of the script's nine steps all but the
+    first were answered from a step already in flight.
+    ``rows_wasted``: slot 0's row after its request was done, and slots
+    0 and 1 when they left together; a slot prefilled while its row was
+    in flight (none here) would count too."""
+    from ray_tpu.util.phases import recording
+
+    client = _Client(_tiny_engine(slots=3, max_len=32))
+    with recording({}) as table:
+        for kind, args, _, _ in SCRIPT:
+            getattr(client, kind)(*args)
+    assert table["serve.engine.ahead"] == [9, 8]
+    assert table["serve.engine.rows_wasted"] == [9, 3]
+
+
+def test_a_slot_taken_again_is_never_answered_from_its_old_row():
+    """Slot 0's request ends while its next row is in flight; a new
+    prompt is prefilled into the slot before the next call. The old
+    row's token, which the fetch of that step brings to the host, is a
+    real and different token: the new request gets its own, a call
+    later, and its neighbour is answered without a break."""
+    import numpy as np
+
+    eng = _tiny_engine(slots=2, max_len=32)
+    client = _Client(eng)
+    client.prefill(0, [5, 11, 23])
+    client.prefill(1, [40, 2])
+    assert client.step(0, 1) == [0, 1] == client.step(0, 1)
+    stale = np.asarray(eng._flight.row).tolist()[0]
+    client.prefill(0, [7])                      # slot 0 ended, taken again
+    assert eng._flight.owed == {1}
+    assert client.step(0, 1) == [1]             # nothing for 0 from that row
+    assert client.step(0, 1) == [0, 1]
+    client.check()
+    assert client.prefix[0][1][2] != stale
+    # ... and alone: nothing in flight is owed to anyone, so the call
+    # dispatches twice and answers from the first
+    client.prefill(0, [9, 9])
+    client.prefill(1, [1])
+    assert eng._flight.owed == set()
+    assert client.step(0, 1) == [0, 1]
+    client.check()
+
+
+def test_a_full_slot_rides_no_step_ahead_and_is_refused_in_earnest():
+    """A slot whose position has reached ``max_len`` is left out of the
+    step dispatched ahead of its last answer (no write past the row's
+    end, no error: the answer it is owed still comes). Asked to step
+    again it raises from the host-side check: no put, no dispatch (the
+    cache is the object it was), and no slot of the call advances."""
     from ray_tpu.util.phases import recording
 
     eng = _tiny_engine(slots=2, max_len=8)
-    last = {0: eng.prefill(0, [5, 11, 23, 4, 9]), 1: eng.prefill(1, [40])}
-    for _ in range(3):
-        last = eng.step(last)               # the last one fills row 7
+    client = _Client(eng)
+    client.prefill(0, [5, 11, 23, 4, 9])
+    client.prefill(1, [40])
+    assert client.step(0, 1) == [0, 1]
+    assert eng._pos == [7, 3]
+    assert client.step(0, 1) == [0, 1]          # dispatched: row 7, the last
     assert eng._pos == _device_pos(eng) == [8, 4]
-    cache = eng._cache
+    assert client.step(0, 1) == [0, 1]          # 0 sits the step ahead out
+    assert eng._pos == _device_pos(eng) == [8, 5]
+    assert eng._flight.owed == {1}
+    assert client.check()[0] == 4       # the prefill's and rows 5, 6, 7's
+    cache, flight = eng._cache, eng._flight
     with recording({}) as table:
         with pytest.raises(ValueError, match="^slot 0 KV cache full$"):
-            eng.step({1: last[1], 0: last[0]})
-    assert eng._cache is cache
-    assert eng._pos == _device_pos(eng) == [8, 4]
-    assert sorted(table) == ["serve.engine.check"]
-    assert sorted(eng.step({1: last[1]})) == [1]    # the other decodes on
+            client.step(1, 0)
+    assert eng._cache is cache and eng._flight is flight
     assert eng._pos == _device_pos(eng) == [8, 5]
+    assert sorted(table) == ["serve.engine.check"]
+    assert client.step(1) == [1]                # the other decodes on
+    assert eng._pos == _device_pos(eng) == [8, 6]
+    client.check()
+
+
+@pytest.mark.parametrize("what,match", [
+    ("forced", "continues from its own last token"),
+    ("sat_out", "left a step out"),
+    ("negative", "is negative")])
+def test_a_token_the_engine_cannot_honour_is_refused_before_dispatch(
+        what, match):
+    """The token of a slot that continues is on the device: a caller
+    that hands back another is refused, not ignored. So is a slot that
+    comes back after a call it was left out of (its row went on without
+    it), and a token that would read as a steering mark. Each before
+    anything is dispatched: the step in flight still answers."""
+    eng = _tiny_engine(slots=2, max_len=32)
+    client = _Client(eng)
+    client.prefill(0, [5, 11, 23])
+    client.prefill(1, [40, 2])
+    client.step(0, 1)
+    held = {s: client.last(s) for s in (0, 1)}
+    if what == "forced":
+        bad = {0: held[0], 1: (held[1] + 1) % 97}
+    elif what == "sat_out":
+        client.step(0)
+        held[0] = client.last(0)
+        bad = dict(held)
+    else:
+        client.prefill(1, [3])
+        bad = {0: held[0], 1: -1}
+    cache, flight, pos = eng._cache, eng._flight, list(eng._pos)
+    with pytest.raises(ValueError, match=match):
+        eng.step(bad)
+    assert eng._cache is cache and eng._flight is flight
+    assert eng._pos == pos
+    assert client.step(0) == [0]
+    client.check()
+
+
+def test_a_fresh_slot_is_fed_the_token_its_caller_hands_it():
+    """A slot just prefilled is in no step in flight: what it is fed is
+    the host's token, whatever the prefill picked, and the answers
+    follow from that token."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import forward
+
+    eng = _tiny_engine(slots=1, max_len=16)
+    picked = eng.prefill(0, [5, 11, 23])
+    forced = (picked + 1) % 97
+    prefix = [5, 11, 23, forced]
+    for _ in range(3):
+        prefix.append(eng.step({0: prefix[-1]})[0])
+        want = forward(eng._params, jnp.asarray([prefix[:-1]], jnp.int32),
+                       eng._cfg)
+        assert prefix[-1] == int(jnp.argmax(want[0, -1]))
 
 
 @pytest.mark.parametrize("call", ["prefill", "step"])
@@ -633,47 +903,64 @@ def test_a_raising_program_leaves_the_mirror_where_the_cache_is(call):
 
     eng = _tiny_engine(slots=2, max_len=16)
     last = {0: eng.prefill(0, [5, 11, 23])}
+    last = eng.step(last)
 
     def boom(*a, **k):
         raise RuntimeError("device lost")
 
-    cache = eng._cache
-    eng._decode = types.SimpleNamespace(slot_prefill=boom,
-                                        slot_decode_step=boom)
+    cache, flight = eng._cache, eng._flight
+    real = eng._decode
+    eng._decode = types.SimpleNamespace(
+        slot_prefill=boom, slot_decode_step=boom, CARRY=real.CARRY,
+        IDLE=real.IDLE)
     with pytest.raises(RuntimeError):
         if call == "prefill":
             eng.prefill(1, [40, 2])
         else:
             eng.step(last)
-    assert eng._cache is cache
-    assert eng._pos == _device_pos(eng) == [3, 0]
+    assert eng._cache is cache and eng._flight is flight
+    assert eng._pos == _device_pos(eng) == [5, 0]
+    eng._decode = real
+    assert sorted(eng.step(last)) == [0]    # the step in flight answers
 
 
 # ----------------------------- one cache, donated and written in place
 
 
 def _consuming(eng, fail):
-    """The engine's programs, each of which runs for real (so the cache
-    it is given is consumed) and then raises where ``fail`` names it
-    and ``fail`` is armed: the error of a device that gave out after
-    the dispatch."""
+    """The engine's programs and its fetch, each of which runs for real
+    (so the cache a program is given is consumed) and then raises where
+    ``fail`` names it and ``fail`` is armed: the error of a device that
+    gave out after the dispatch, found at once (``slot_prefill``,
+    ``slot_decode_step``) or by the next transfer (``fetch``: in a step
+    that of the step dispatched a call earlier, in a prefill the first
+    read behind a step that failed in flight)."""
     import types
 
-    real = eng._decode
+    real, jax = eng._decode, eng._jax
 
-    def program(name):
+    def program(name, of):
         def call(*args, **kwargs):
-            out = getattr(real, name)(*args, **kwargs)
+            out = getattr(of, name)(*args, **kwargs)
             if fail.get(name):
                 fail[name] -= 1
                 raise RuntimeError(f"device lost in {name}")
             return out
         return call
 
+    eng._jax = types.SimpleNamespace(
+        device_get=program("device_get", jax))
     return types.SimpleNamespace(
-        init_slot_cache=real.init_slot_cache,
-        slot_prefill=program("slot_prefill"),
-        slot_decode_step=program("slot_decode_step"))
+        init_slot_cache=real.init_slot_cache, CARRY=real.CARRY,
+        IDLE=real.IDLE, slot_prefill=program("slot_prefill", real),
+        slot_decode_step=program("slot_decode_step", real))
+
+
+# where the error surfaces: (the call that raises, the name armed)
+LOST_AT = {"prefill": ("prefill", "slot_prefill"),
+           "step": ("step", "slot_decode_step"),
+           "late_fetch": ("step", "device_get"),
+           "prefill_after_failed_step": ("prefill", "device_get")}
 
 
 @pytest.mark.parametrize("call", ["prefill", "step"])
@@ -689,7 +976,7 @@ def test_a_call_consumes_the_engines_cache_and_leaves_one_to_go_on(call):
         want = [3, 2]
     else:
         last = eng.step(last)
-        want = [4, 0]
+        want = [5, 0]
     assert eng._cache is not given
     import jax
 
@@ -699,61 +986,28 @@ def test_a_call_consumes_the_engines_cache_and_leaves_one_to_go_on(call):
     assert sorted(eng.step(last)) == [0]
 
 
-def test_a_script_of_prefills_and_steps_equals_forward_on_the_prefix():
-    """Prefills and steps through the engine, some slots sitting a step
-    out and one slot freed and taken again: every token the engine
-    gives is the argmax of ``forward()`` on that slot's growing prefix
-    (no cache code), so writing the cache in place changed no value."""
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import forward
-
-    eng = _tiny_engine(slots=3, max_len=32)
-
-    def oracle(prefix):
-        logits = forward(eng._params, jnp.asarray([prefix], jnp.int32),
-                         eng._cfg)
-        return int(jnp.argmax(logits[0, -1]))
-
-    prefix = {}
-    script = [("prefill", 0, [5, 11, 23]), ("prefill", 2, [40, 2]),
-              ("step", (0, 2)), ("step", (0,)),
-              ("prefill", 1, [88, 17, 3, 9, 1]), ("step", (0, 1, 2)),
-              ("step", (1, 2)),
-              ("prefill", 0, [7]),          # slot 0 taken again
-              ("step", (0, 1, 2)), ("step", (2,)), ("step", (0, 1, 2))]
-    for kind, *args in script:
-        if kind == "prefill":
-            slot, prompt = args
-            prefix[slot] = list(prompt)
-            got = {slot: eng.prefill(slot, prompt)}
-        else:
-            got = eng.step({s: prefix[s][-1] for s in args[0]})
-            assert sorted(got) == sorted(args[0])
-        for slot, tok in got.items():
-            assert tok == oracle(prefix[slot]), (kind, args, slot)
-            prefix[slot].append(tok)
-
-
-@pytest.mark.parametrize("call", ["prefill", "step"])
-def test_a_program_that_raises_with_the_cache_gone_starts_over(call):
-    """A program (or the fetch of its result) that raises after it has
-    consumed the cache: the engine raises the typed error with the
-    program's own as its cause, holds a fresh empty cache and a zeroed
-    mirror, and serves the next prompt from there."""
+@pytest.mark.parametrize("where", sorted(LOST_AT))
+def test_a_program_that_raises_with_the_cache_gone_starts_over(where):
+    """A program that raises after it has consumed the cache, or a
+    fetch that finds the step in flight failed (in the step after it,
+    or in the prefill queued behind it): the engine raises the typed
+    error with the device's own as its cause, holds a fresh empty
+    cache, a zeroed mirror and nothing in flight, and serves the next
+    prompt from there."""
     eng = _tiny_engine(slots=2, max_len=16)
     first = eng.prefill(0, [5, 11, 23])
-    fail = {"slot_prefill" if call == "prefill"
-            else "slot_decode_step": 1}
-    eng._decode = _consuming(eng, fail)
+    last = eng.step({0: first})             # a step is in flight
+    assert eng._flight is not None
+    call, armed = LOST_AT[where]
+    eng._decode = _consuming(eng, {armed: 1})
     with pytest.raises(SlotStateLostError,
                        match="all 2 slots") as raised:
         if call == "prefill":
             eng.prefill(1, [40, 2])
         else:
-            eng.step({0: first})
+            eng.step(last)
     assert isinstance(raised.value.__cause__, RuntimeError)
+    assert eng._flight is None
     assert eng._pos == _device_pos(eng) == [0, 0]
     import jax
 
@@ -761,6 +1015,7 @@ def test_a_program_that_raises_with_the_cache_gone_starts_over(call):
     (k,), (v,) = eng._cache["k"], eng._cache["v"]   # one run of layers
     assert not k.any() and not v.any()
     assert eng.prefill(0, [5, 11, 23]) == first
+    assert eng.step({0: first}) == last
 
 
 def _served_alone(prompt, n):
@@ -772,38 +1027,47 @@ def _served_alone(prompt, n):
     return out
 
 
-@pytest.mark.parametrize("call", ["prefill", "step"])
-def test_lost_slot_state_fails_all_in_flight_and_the_next_is_right(call):
-    """The scheduler over an engine whose program raises after
-    consuming the cache, in a prefill that joins a running batch or in
-    a step: every request in flight fails with the typed error (none is
-    answered from a zeroed row), every slot is free again, the loop
-    lives, and the next request is served token for token what a fresh
-    engine serves."""
+@pytest.mark.parametrize("where", sorted(LOST_AT))
+def test_lost_slot_state_fails_all_in_flight_and_the_next_is_right(where):
+    """The scheduler over an engine whose device gives out after the
+    cache was consumed: in a prefill that joins a running batch, in a
+    step, at the fetch of the step before (the failure found one call
+    late), or in the prefill queued behind a step that failed. Every
+    request in flight fails with the typed error (none is answered from
+    a zeroed row), every slot is free again, the loop lives, and the
+    next request is served token for token what a fresh engine
+    serves."""
     eng = _tiny_engine(slots=2, max_len=32)
     fail = {}
     eng._decode = _consuming(eng, fail)
-    real_step = eng.step
+    call, armed = LOST_AT[where]
+    real_step, real_prefill = eng.step, eng.prefill
 
     async def run():
-        armed = asyncio.Event()
+        decoding = asyncio.Event()
         loop = asyncio.get_running_loop()
 
         def step(tokens):       # on the executor's thread
             out = real_step(tokens)
-            loop.call_soon_threadsafe(armed.set)
+            loop.call_soon_threadsafe(decoding.set)
             return out
 
+        def prefill(slot, prompt):
+            if decoding.is_set() and not fail.get("done"):
+                fail[armed] = fail["done"] = 1
+            return real_prefill(slot, prompt)
+
         eng.step = step
+        if call == "prefill":       # armed as the late prefill begins
+            eng.prefill = prefill
         sched = DecodeScheduler(eng)
         running = asyncio.ensure_future(
             sched.submit([5, 11, 23], max_tokens=20))
-        await armed.wait()          # the first request is decoding
+        await decoding.wait()       # the first request is decoding
         if call == "prefill":
-            fail["slot_prefill"] = 1
             late = [sched.submit([40, 2], max_tokens=4)]
         else:
-            fail["slot_decode_step"] = 1
+            fail[armed] = 1
             late = []
         results = await asyncio.gather(running, *late,
                                        return_exceptions=True)
@@ -816,7 +1080,9 @@ def test_lost_slot_state_fails_all_in_flight_and_the_next_is_right(call):
     assert all(isinstance(r, SlotStateLostError) for r in results), results
     assert (stats["active_slots"], stats["free_slots"]) == (0, 2)
     assert stats["completed"] == 0
-    assert after == _served_alone([88, 17, 3], 5)
+    assert eng._flight is not None      # ... of the request served after
+    assert after == _served_alone([88, 17, 3], 5) == _oracle(
+        eng, [88, 17, 3], 5)
 
 
 def test_a_prompt_refused_before_dispatch_still_fails_alone():
@@ -906,27 +1172,59 @@ def test_the_seam_counts_every_way_to_read_a_device_array(monkeypatch):
 
 
 @pytest.mark.parametrize("active", [(1,), (0, 2), (0, 1, 2)])
-def test_a_step_indexes_no_device_array_and_fetches_one(monkeypatch,
-                                                        active):
-    """However many slots are active, a step asks the device for one
-    thing: the argmax row, whole. The tokens it returns are those the
-    row held."""
+def test_a_call_dispatches_then_fetches_one_row_and_indexes_nothing(
+        monkeypatch, active):
+    """However many slots go on, a call asks the device for one thing,
+    the row of picks of the step dispatched a call earlier, whole, and
+    only after it has dispatched its own step: nothing is read before
+    the dispatch, and nothing of the new step at all. The tokens it
+    returns are those the old row held."""
     eng = _tiny_engine(slots=3, max_len=32)
-    import jax
-    import jax.numpy as jnp
     import numpy as np
 
     last = {0: eng.prefill(0, [5, 11, 23]), 1: eng.prefill(1, [40, 2]),
             2: eng.prefill(2, [88])}
     last = eng.step(last)                   # compiles
-    logits, _ = eng._decode.slot_decode_step(   # consumes what it is given
-        eng._params, jax.tree.map(jnp.copy, eng._cache),
-        jnp.asarray([last[s] for s in range(3)], jnp.int32),
-        jnp.asarray([s in active for s in range(3)]), eng._cfg)
-    row = np.asarray(jnp.argmax(logits, axis=-1)).tolist()
+    row = np.asarray(eng._flight.row).tolist()
+    order = []
+    real = eng._decode.slot_decode_step
+
+    def dispatched(*args, **kwargs):
+        order.append(("dispatch", seen.fetched))
+        return real(*args, **kwargs)
+
     with monkeypatch.context() as m:
         seen = _HostReads(m)
+        m.setattr(eng._decode, "slot_decode_step", dispatched)
         out = eng.step({s: last[s] for s in active})
+        order.append(("returned", seen.fetched))
     assert (seen.indexed, seen.fetched) == (0, 1)
+    assert order == [("dispatch", 0), ("returned", 1)]
     assert out == {s: row[s] for s in active}
     assert all(type(t) is int for t in out.values())
+    assert eng._flight.owed == set(active)
+
+
+def test_a_call_with_nothing_in_flight_dispatches_twice_and_fetches_once(
+        monkeypatch):
+    """The first call ever, and one after every slot has been prefilled
+    anew: two dispatches, then the one fetch, of the first of them."""
+    eng = _tiny_engine(slots=2, max_len=32)
+    last = {0: eng.prefill(0, [5, 11, 23]), 1: eng.prefill(1, [40, 2])}
+    eng.step(last)                          # compiles
+    last = {0: eng.prefill(0, [7]), 1: eng.prefill(1, [30, 31])}
+    order = []
+    real = eng._decode.slot_decode_step
+
+    def dispatched(*args, **kwargs):
+        order.append(("dispatch", seen.fetched))
+        return real(*args, **kwargs)
+
+    with monkeypatch.context() as m:
+        seen = _HostReads(m)
+        m.setattr(eng._decode, "slot_decode_step", dispatched)
+        out = eng.step(last)
+    assert (seen.indexed, seen.fetched) == (0, 1)
+    assert order == [("dispatch", 0), ("dispatch", 0)]
+    assert out == {0: _oracle(eng, [7], 2)[1],
+                   1: _oracle(eng, [30, 31], 2)[1]}

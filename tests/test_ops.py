@@ -311,7 +311,8 @@ def test_slot_prefill_leaves_the_other_rows_untouched(name):
     before = decode.init_slot_cache(cfg, 3, max_len)
     (k,) = before["k"]              # layers all alike: one run
     marks = jax.random.normal(jax.random.key(2), k.shape, cfg.dtype)
-    before = {"k": (marks,), "v": (-marks,), "pos": jnp.asarray([7, 2, 5])}
+    before = {"k": (marks,), "v": (-marks,), "pos": jnp.asarray([7, 2, 5]),
+              "tok": jnp.asarray([1, 2, 3])}
     prompt = jax.random.randint(jax.random.key(3), (1, T0), 0, cfg.vocab)
     # the program consumes the cache it is given: it gets a copy
     _, after = decode.slot_prefill(params, prompt,
@@ -343,7 +344,8 @@ def test_a_serving_program_consumes_the_cache_it_is_given(name, program):
                                                   cfg.n_heads,
                                                   cfg.head_dim), cfg.dtype)
     # the layers are all alike: one run, so one array of K and of V
-    given = {"k": (marks,), "v": (-marks,), "pos": jnp.asarray([3, 2, 5])}
+    given = {"k": (marks,), "v": (-marks,), "pos": jnp.asarray([3, 2, 5]),
+             "tok": jnp.asarray([1, 2, 3])}
     if program == "slot_prefill":
         args = (jax.random.randint(jax.random.key(3), (1, 4), 0,
                                    cfg.vocab),)
@@ -369,7 +371,8 @@ def test_a_serving_program_consumes_the_cache_it_is_given(name, program):
     # a bare array is no cache: it would be read as a run a layer
     whole = jnp.zeros(marks.shape, cfg.dtype)
     with pytest.raises(ValueError, match="one array for each"):
-        call({"k": whole, "v": whole, "pos": jnp.asarray([3, 2, 5])})
+        call({"k": whole, "v": whole, "pos": jnp.asarray([3, 2, 5]),
+              "tok": jnp.asarray([1, 2, 3])})
     # ... and it is a cache to go on from
     decode.slot_decode_step(params, after, jnp.zeros(3, jnp.int32),
                             jnp.ones(3, bool), cfg)
