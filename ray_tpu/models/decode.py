@@ -1,41 +1,58 @@
-"""KV-cached autoregressive decoding for the flagship transformer.
+"""Cached autoregressive decoding for the flagship transformer: a
+slot's state between tokens, of the two kinds its layers keep.
 
-One cache form: ``cache["k"]`` and ``cache["v"]`` are tuples of arrays,
-one for each run of alike layers (``transformer.layer_runs``; a model
-whose layers are all alike has one run): ``[L, slots, rows, H, Dh]`` K
-and ``[.., Dv]`` V (``[L, slots, rows, G * Dh]``, the K/V heads side by
-side in a row, where G of them serve more query heads each:
-``init_slot_cache``), preallocated, with a decode offset per row
-(``pos: [slots]``).
-Continuous batching (serve/decode_scheduler.py) needs exactly that: one
-sequence prefills into an open row while the other rows keep stepping,
-and a finished row frees at once. Whole-batch generation (``generate``)
-is its all-rows-active case. Every shape is static, so serving is two
-compiled programs, each a ``lax.scan`` of ``transformer.block`` (the
-one definition of the layer) over the stacked layers and their index,
-with the whole K and V as the scan's carry:
+**Two kinds of state, one cache.** The cache is a dict of tuples, each
+with one entry for each run of alike layers (``transformer.layer_runs``;
+a model whose layers are all alike has one run), and a run's entry is
+an array in the tuples of its own kind of state and ``None`` in the
+others:
+
+* an attention run keeps *rows*: ``cache["k"][r]`` ``[L, slots, rows,
+  H, Dh]`` and ``cache["v"][r]`` ``[.., Dv]`` (``[L, slots, rows,
+  G * Dh]``, the K/V heads side by side in a row, where G of them serve
+  more query heads each: ``init_slot_cache``), one row a position, read
+  up to the slot's decode offset ``cache["pos"]`` ``[slots]``. A run of
+  full-attention layers has ``max_len`` rows. A run of window layers
+  has ``window`` rows, a ring: position p lives in row ``p % window``,
+  ``slot_prefill`` writes a prompt's last ``window`` positions there,
+  and ``slot_decode_step`` writes row ``pos % window`` and attends the
+  rows filled so far (rope is applied before the write, so the order of
+  the rows does not matter), with the layer's sink logit in the
+  denominator where it has one;
+* a Mamba run keeps a *summary*: ``cache["ssm"][r]`` ``[L, slots, N,
+  C]`` float32, the selective scan's state after the slot's last token,
+  and ``cache["conv"][r]`` ``[L, K - 1, slots, C]`` at the model's
+  dtype, the last K - 1 inputs of its causal convolution (ops/ssm.py;
+  the slots beside the channels, so that the two dimensions the chip
+  tiles are whole multiples of a tile and not K - 1 = 3 rows). It has no rows and no position: it does not grow, a
+  token's step replaces it whole, and what it was before cannot be read
+  back. A model without Mamba layers has neither tuple.
+
+A model with expert layers carries ``cache["load"]``, int32 [3]: the
+held experts that got a row, the rows routed to held experts and the
+fullest expert's rows in the last decode step, each summed over the
+expert layers, for the engine to fetch with the step's tokens.
+
+Continuous batching (serve/decode_scheduler.py) needs exactly this
+form: one sequence prefills into an open slot while the other slots
+keep stepping, and a finished slot frees at once. Whole-batch
+generation (``generate``) is its all-rows-active case. Every shape is
+static, so serving is two compiled programs, each a ``lax.scan`` of
+``transformer.block`` (the one definition of the layer) over each run's
+stacked layers and their index, with the run's whole state as the
+scan's carry:
 
 * ``slot_prefill`` runs a prompt through the block with the training
-  forward's rope and attention (flash kernel on TPU, XLA off it) and
-  writes the roped K/V into the carry at ``(layer, slot, 0, 0, 0)``;
-* ``slot_decode_step`` ropes each row's new token at the row's own
-  position, scatters its K/V into the carry at ``[layer, rows, pos]``
-  and attends the layer's K/V, read out of the carry, under a per-row
-  mask (no recompute, no dynamic shapes).
-
-**Layers of several kinds** (``TransformerConfig.layer_kinds``) are
-scanned run by run, and the cache is allocated by kind: each run's
-array at the run's own K/V heads. A run of full-attention
-layers has ``max_len`` rows. A run of window layers has ``window`` rows,
-a ring: position p lives in row ``p % window``, ``slot_prefill`` writes
-a prompt's last ``window`` positions there, and ``slot_decode_step``
-writes row ``pos % window`` and attends the rows filled so far (rope is
-applied before the write, so the order of the rows does not matter),
-with the layer's sink logit in the denominator where it has one. A
-model with expert layers carries ``cache["load"]``, int32 [3]: the held
-experts that got a row, the rows routed to held experts and the fullest
-expert's rows in the last decode step, each summed over the expert
-layers, for the engine to fetch with the step's tokens.
+  forward's rope, attention and scan (the flash and ``ssm_scan``
+  kernels on TPU, XLA off it) and writes into the carry at ``(layer,
+  slot)`` the roped K/V of the prompt's positions, or the state and the
+  convolution's tail after its last one;
+* ``slot_decode_step`` feeds each row one token: an attention layer
+  ropes it at the row's own position, scatters its K/V into the carry
+  at ``[layer, rows, pos]`` and attends the layer's K/V, read out of
+  the carry, under a per-row mask; a Mamba layer reads the rows' state
+  out of the carry, advances it by one position and writes it back (no
+  recompute, no dynamic shapes).
 
 **The picked token stays on the device.** ``cache["tok"]``, int32
 [slots], is each row's last pick (:func:`pick`, the one place a token
@@ -49,9 +66,10 @@ the step hands back for the host is the row of picks (with the three
 counts behind it), never ``[slots, vocab]`` logits.
 
 The cache is one set of buffers, written in place. Both programs take
-it donated, and K and V are carried through the layers' scan, not
-scanned over: XLA then aliases the result to the argument and the only
-operations that produce K or V are the in-place writes of the new rows.
+it donated, and every run's state is carried through the layers' scan,
+not scanned over: XLA then aliases the result to the argument, the
+only operations that produce K or V are the in-place writes of the new
+rows, and a Mamba layer's state is read and written where it lies.
 Either half alone leaves a copy (a carry not donated is copied whole at
 entry; a donated cache that is a scanned input is sliced out and
 stacked again, layer by layer): tests/test_chip_compile.py holds the
@@ -65,25 +83,39 @@ Invariants the scheduler relies on:
   that wants the old state afterwards passes a copy. Inside another jit
   (``_decode_loop``) the inner donation does nothing and the cache is
   the outer carry.
-* ``slot_prefill`` rewrites rows [0, T0) of its slot (of a ring, the
-  rows its last ``window`` positions fall on) and resets that slot's
-  pos, so a reused slot never sees its predecessor's K/V — the stale
-  tail beyond T0 is always overwritten (step s writes position pos
-  BEFORE attending it) and never attended.
-* ``slot_decode_step`` writes every row's K/V unconditionally (a
-  masked write would cost a gather per layer) but advances ``pos``
-  and replaces ``tok`` only where ``active``: an inactive row's cache
-  may take garbage at its frozen pos, which is sound because inactive
-  rows are only ever re-entered through ``slot_prefill``.
+* ``slot_prefill`` makes its slot's state the prompt's and nothing
+  else's, so a reused slot never sees its predecessor. Of rows: it
+  rewrites rows [0, T0) (of a ring, the rows its last ``window``
+  positions fall on) and resets the slot's pos; the stale tail beyond
+  T0 is always overwritten (step s writes position pos BEFORE attending
+  it) and never attended. Of a summary: it replaces the slot's state
+  and tail whole, computed from zeros.
+* ``slot_decode_step`` advances ``pos``, replaces ``tok`` and advances
+  a Mamba layer's state and tail only where ``active``: **an inactive
+  row's summary is bit for bit what it was** (the step computes a new
+  one for every row and writes back the old one there: the select
+  costs no traffic, the layer's state is read and written whole either
+  way). Rows are not so guarded, and need not be: a step writes every
+  row's K/V unconditionally (a masked write would cost a gather per
+  layer), so an inactive row's cache may take garbage at its frozen
+  pos, which no one reads before the next write there. A summary has
+  no such place: garbage folded into it would stay. A row that is
+  stepped when no one is owed its token (the engine's one step more
+  for a finished request) does advance, rows and summary alike, which
+  is sound because such a slot is only ever re-entered through
+  ``slot_prefill``.
 * A row at ``pos == max_len`` would have its write clamped onto the
   last position; the callers refuse before that (``generate``'s
   ``T0 + steps > max_len``, ``JaxSlotEngine.step``'s host mirror).
+  ``max_len`` bounds a slot by its attention runs' rows; a model of
+  Mamba layers alone is bounded by ``cfg.max_seq``.
 
 Oracle: greedy decoding must match the per-step argmax of the FULL
 forward() on the growing prefix, which shares no cache code —
-tests/test_ops.py and tests/test_decode_scheduler.py assert it exactly,
-which pins the cache bookkeeping (rope offsets, masking, row writes) to
-the training forward's semantics.
+tests/test_ops.py, tests/test_decode_scheduler.py and
+tests/test_mamba_block.py assert it, which pins the cache bookkeeping
+(rope offsets, masking, row writes, the state's hand-over from prefill
+to step) to the training forward's semantics.
 """
 
 from __future__ import annotations
@@ -95,9 +127,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (EXPERTS, WINDOW, TransformerConfig,
-                                        block, kind_rope, layer_runs,
-                                        layer_stacks, scan_run, unembed)
+from ray_tpu.models.transformer import (EXPERTS, FROM_THE_START, MAMBA,
+                                        WINDOW, TransformerConfig, block,
+                                        kind_rope, layer_runs, layer_stacks,
+                                        no_rotation, roped_kinds, scan_run,
+                                        unembed)
+from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.rotary import apply_rotary, rotate
 
@@ -118,52 +153,89 @@ def pick(logits, key=None, temperature=None):
 
 def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> Dict:
-    """KV cache with an independent decode offset per batch row: a
-    tuple of arrays of K and one of V, one array a run of alike layers
-    (one in all where the layers are all alike), a window run at
-    ``cfg.window`` rows. Where every query head has a K/V head of its
-    own a row is [H, Dh]; where G K/V heads serve more query heads each
-    it is flat, [G * Dh], the heads side by side: rows of 768 or 1536
-    values tile the chip's memory as they are, which [4, 192] or
-    [8, 192] do not (the compiler's own layouts for those cost two
-    copies of K a step)."""
+    """The cache of ``slots`` sequences, each with a decode offset of
+    its own: tuples with one entry a run of alike layers (one in all
+    where the layers are all alike), ``None`` where the run keeps no
+    state of that kind. ``k`` and ``v``: an attention run's rows, a
+    window run at ``cfg.window`` of them. Where every query head has a
+    K/V head of its own a row is [H, Dh]; where G K/V heads serve more
+    query heads each it is flat, [G * Dh], the heads side by side: rows
+    of 768 or 1536 values tile the chip's memory as they are, which
+    [4, 192] or [8, 192] do not (the compiler's own layouts for those
+    cost two copies of K a step). ``ssm`` and ``conv``, in a model with
+    Mamba layers: a Mamba run's state [n, slots, N, C] float32 and its
+    convolution's tail [n, K - 1, slots, C]."""
     runs = layer_runs(cfg)
 
-    def leaves(width):
+    def rows(width):
         made = []
-        for (attention, _), n in runs:
-            G = cfg.kv_heads(attention)
+        for (mixer, _), n in runs:
+            if mixer == MAMBA:
+                made.append(None)
+                continue
+            G = cfg.kv_heads(mixer)
             row = (G, width) if G == cfg.n_heads else (G * width,)
             made.append(jnp.zeros(
-                (n, slots, cfg.window if attention == WINDOW else max_len)
+                (n, slots, cfg.window if mixer == WINDOW else max_len)
                 + row, cfg.dtype))
         return tuple(made)
 
-    cache = {"k": leaves(cfg.head_dim), "v": leaves(cfg.v_dim),
+    def summaries(shape, dtype):
+        return tuple(jnp.zeros((n,) + shape, dtype)
+                     if mixer == MAMBA else None for (mixer, _), n in runs)
+
+    cache = {"k": rows(cfg.head_dim), "v": rows(cfg.v_dim),
              "pos": jnp.zeros((slots,), jnp.int32),
              "tok": jnp.zeros((slots,), jnp.int32)}
+    if cfg.has_mamba:
+        cache["ssm"] = summaries((slots, cfg.ssm_state, cfg.ssm_inner),
+                                 jnp.float32)
+        cache["conv"] = summaries((cfg.ssm_conv - 1, slots, cfg.ssm_inner),
+                                  cfg.dtype)
     if any(ffn == EXPERTS for (_, ffn), _ in runs):
         cache["load"] = jnp.zeros((3,), jnp.int32)
     return cache
 
 
+# which two tuples of the cache hold a run's state, by its mixer
+ROWS, SUMMARY = ("k", "v"), ("ssm", "conv")
+
+
+def _state_names(mixer: str):
+    return SUMMARY if mixer == MAMBA else ROWS
+
+
 def _cache_runs(cache: Dict, runs):
-    """The cache's K and V arrays, one a run of alike layers."""
-    ks, vs = cache["k"], cache["v"]
-    if not (isinstance(ks, tuple) and isinstance(vs, tuple)
-            and len(ks) == len(vs) == len(runs)):
-        raise ValueError(
-            f"the cache holds a tuple of K arrays and one of V, one "
-            f"array for each of the model's {len(runs)} runs of alike "
-            f"layers (init_slot_cache)")
-    return ks, vs
+    """Each run's pair of state arrays: (K, V) of an attention run,
+    (state, tail) of a Mamba run."""
+    names = {name for (mixer, _), _ in runs for name in _state_names(mixer)}
+    for name in sorted(names):
+        if not (isinstance(cache.get(name), tuple)
+                and len(cache[name]) == len(runs)):
+            raise ValueError(
+                f"the cache holds a tuple cache[{name!r}], one array for "
+                f"each of the model's {len(runs)} runs of alike layers "
+                f"(None where a run keeps no such state: init_slot_cache)")
+    return [tuple(cache[name][r] for name in _state_names(mixer))
+            for r, ((mixer, _), _) in enumerate(runs)]
 
 
-def _max_len(cfg: TransformerConfig, runs, ks) -> int:
+def _with_states(cache: Dict, runs, states, **more) -> Dict:
+    """``cache`` with each run's pair of state arrays replaced."""
+    new = {name: [None] * len(runs) for name in ROWS + SUMMARY
+           if name in cache}
+    for r, (((mixer, _), _), pair) in enumerate(zip(runs, states)):
+        for name, array in zip(_state_names(mixer), pair):
+            new[name][r] = array
+    return dict(cache, **{name: tuple(held) for name, held in new.items()},
+                **more)
+
+
+def _max_len(cfg: TransformerConfig, runs, states) -> int:
     """Rows of a full-attention run's cache: the longest sequence a slot
-    holds (``cfg.max_seq`` where every layer has a window)."""
-    return next((ck.shape[2] for ((attention, _), _), ck in zip(runs, ks)
-                 if attention != WINDOW), cfg.max_seq)
+    holds (``cfg.max_seq`` where no layer keeps all its rows)."""
+    return next((ck.shape[2] for ((mixer, _), _), (ck, _) in zip(runs, states)
+                 if mixer not in (WINDOW, MAMBA)), cfg.max_seq)
 
 
 def _cache_rows(t, like, window: Optional[int]):
@@ -177,29 +249,39 @@ def _cache_rows(t, like, window: Optional[int]):
     return t.reshape(t.shape[:2] + like.shape[3:]).astype(like.dtype)
 
 
+def _tally(load, got):
+    """``load`` with an expert layer's counts added: held experts that
+    got a row, rows routed to them, the fullest one's rows."""
+    if got is None:
+        return load
+    return load + jnp.stack([jnp.sum(got > 0), jnp.sum(got), jnp.max(got)])
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache",))
 def slot_prefill(params, tokens, cache: Dict, slot,
                  cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
-    """Run one prompt [1, T0] through the stack, writing each layer's
-    K/V into cache row ``slot`` (a traced index: one compiled program
-    serves every slot). Returns (last-token logits [1, V], cache), the
-    token picked from them in ``cache["tok"][slot]``; the cache given is
-    consumed and the one returned is its memory, updated in place.
-    Compiles once per distinct T0 — serving callers should bucket or
-    pad prompt lengths if retrace cost matters."""
+    """Run one prompt [1, T0] through the stack, leaving in cache row
+    ``slot`` (a traced index: one compiled program serves every slot)
+    each attention layer's K/V and each Mamba layer's state and tail
+    after the prompt's last position. Returns (last-token logits
+    [1, V], cache), the token picked from them in
+    ``cache["tok"][slot]``; the cache given is consumed and the one
+    returned is its memory, updated in place. Compiles once per
+    distinct T0 — serving callers should bucket or pad prompt lengths
+    if retrace cost matters."""
     _, T0 = tokens.shape
     runs = layer_runs(cfg)
-    ks, vs = _cache_runs(cache, runs)
-    max_len = _max_len(cfg, runs, ks)
+    states = _cache_runs(cache, runs)
+    max_len = _max_len(cfg, runs, states)
     ropes = {}
-    for attention in dict.fromkeys(attention for (attention, _), _ in runs):
+    for attention in roped_kinds(cfg, runs):
         cos, sin = kind_rope(cfg, attention, max_len)
         ropes[attention] = functools.partial(
             apply_rotary, cos=cos, sin=sin, positions=jnp.arange(T0))
     x = params["embed"][tokens]
 
-    def one_run(x, layers, ck, cv, attention):
+    def attention_run(x, layers, ck, cv, attention):
         window = cfg.window if attention == WINDOW else None
 
         def body(carry, lp, i):
@@ -214,7 +296,8 @@ def slot_prefill(params, tokens, cache: Dict, slot,
                         q, k, v, causal=True, window=window,
                         sink=lp.get("sink")), (k, v)
 
-            x, (k, v), _ = block(lp, x, ropes[attention], attend, cfg)
+            x, (k, v), _ = block(lp, x, ropes.get(attention, no_rotation),
+                                 attend, cfg)
             ck = lax.dynamic_update_slice(
                 ck, _cache_rows(k, ck, window)[None],
                 (i, slot) + (0,) * (ck.ndim - 2))
@@ -225,16 +308,38 @@ def slot_prefill(params, tokens, cache: Dict, slot,
 
         return scan_run(body, (x, ck, cv), layers)
 
-    new_k, new_v = [], []
-    for ((attention, _), layers), ck, cv in zip(
-            layer_stacks(params, cfg), ks, vs):
-        x, ck, cv = one_run(x, layers, ck, cv, attention)
-        new_k.append(ck)
-        new_v.append(cv)
+    def mamba_run(x, layers, cs, cc):
+        def body(carry, lp, i):
+            x, cs, tails = carry    # cs: the whole [L, slots, N, C]
+            # the training forward's convolution and scan
+            x, (tail, state), _ = block(lp, x, None, FROM_THE_START, cfg)
+            cs = lax.dynamic_update_slice(
+                cs, state[None].astype(cs.dtype), (i, slot, 0, 0))
+            tails = lax.dynamic_update_slice(
+                tails, tail.swapaxes(0, 1)[None].astype(tails.dtype),
+                (i, 0, 0, 0))
+            return x, cs, tails
+
+        # the layers' tails are gathered [L, K - 1, 1, C] and written
+        # into the slot once, behind the scan: carried through it, the
+        # tails' array (rows of the model's dtype, written at a slot
+        # that is no multiple of a tile) is copied whole on the way in
+        # and on the way out
+        tails = jnp.zeros(cc.shape[:2] + (1,) + cc.shape[3:], cc.dtype)
+        x, cs, tails = scan_run(body, (x, cs, tails), layers)
+        return x, cs, lax.dynamic_update_slice(cc, tails, (0, 0, slot, 0))
+
+    new = []
+    for ((mixer, _), layers), (first, second) in zip(
+            layer_stacks(params, cfg), states):
+        if mixer == MAMBA:
+            x, first, second = mamba_run(x, layers, first, second)
+        else:
+            x, first, second = attention_run(x, layers, first, second, mixer)
+        new.append((first, second))
     logits = unembed(params, x, last=True, eps=cfg.norm_eps)
-    return logits, dict(
-        cache, k=tuple(new_k), v=tuple(new_v),
-        pos=cache["pos"].at[slot].set(T0),
+    return logits, _with_states(
+        cache, runs, new, pos=cache["pos"].at[slot].set(T0),
         tok=cache["tok"].at[slot].set(pick(logits)[0]))
 
 
@@ -244,13 +349,15 @@ def slot_decode_step(params, cache: Dict, token, active,
                      cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
     """One continuous-batching step: each ACTIVE row is fed one token,
     attends its own prefix (per-row position mask; in a window layer the
-    last ``window`` positions of it), advances its own pos and picks its
-    next token into ``cache["tok"]``. Inactive rows are free riders —
-    their logits are garbage, their pos and tok frozen. ``token`` int32
-    [B] says what a row is fed: a token id, or ``CARRY`` for the row's
-    own last pick, which never left the device. The cache given is
-    consumed and the one returned is its memory, with one position a
-    row and layer written in place.
+    last ``window`` positions of it) and advances its Mamba layers'
+    state by it, advances its own pos and picks its next token into
+    ``cache["tok"]``. Inactive rows are free riders — their logits are
+    garbage, their pos, tok and Mamba state frozen. ``token`` int32 [B]
+    says what a row is fed: a token id, or ``CARRY`` for the row's own
+    last pick, which never left the device. The cache given is consumed
+    and the one returned is its memory, with one position a row and
+    attention layer written in place and every Mamba layer's state
+    replaced where it lay.
 
     Two callers, two forms. With ``active`` bool [B]: (next-token
     logits [B, V], cache), for a caller that draws the token itself
@@ -266,11 +373,12 @@ def slot_decode_step(params, cache: Dict, token, active,
         active = token != IDLE
     token = jnp.where(token >= 0, token, cache["tok"])
     runs = layer_runs(cfg)
-    ks, vs = _cache_runs(cache, runs)
-    max_len = _max_len(cfg, runs, ks)
+    states = _cache_runs(cache, runs)
+    max_len = _max_len(cfg, runs, states)
     pos = cache["pos"]  # [B]
-    kinds = list(dict.fromkeys(attention for (attention, _), _ in runs))
-    tables = {a: kind_rope(cfg, a, max_len) for a in kinds}
+    kinds = list(dict.fromkeys(mixer for (mixer, _), _ in runs
+                               if mixer != MAMBA))
+    tables = {a: kind_rope(cfg, a, max_len) for a in roped_kinds(cfg, runs)}
     x = params["embed"][token][:, None, :]  # [B, 1, D]
     sm_scale = cfg.head_dim ** -0.5
     # row r attends positions [0, pos[r]] (pos[r] is written this
@@ -281,14 +389,16 @@ def slot_decode_step(params, cache: Dict, token, active,
              for a in kinds}
     rows = jnp.arange(B)
 
-    def one_run(x, load, layers, ck, cv, attention):
-        cos, sin = tables[attention]
+    def attention_run(x, load, layers, ck, cv, attention):
         window = cfg.window if attention == WINDOW else None
         at = pos if window is None else pos % window
+        rope = no_rotation
+        if attention in tables:
+            cos, sin = tables[attention]
 
-        def rope(t):  # every row at its own position
-            return rotate(t, cos[pos][:, None, None, :],
-                          sin[pos][:, None, None, :])
+            def rope(t):  # every row at its own position
+                return rotate(t, cos[pos][:, None, None, :],
+                              sin[pos][:, None, None, :])
 
         def body(carry, lp, i):
             x, ck, cv, load = carry  # ck/cv: the whole [L, B, rows, G, Dh]
@@ -307,24 +417,53 @@ def slot_decode_step(params, cache: Dict, token, active,
                                           sm_scale, lp.get("sink")), (nk, nv)
 
             x, (ck, cv), got = block(lp, x, rope, attend, cfg)
-            if got is not None:
-                load = load + jnp.stack(
-                    [jnp.sum(got > 0), jnp.sum(got), jnp.max(got)])
-            return x, ck, cv, load
+            return x, ck, cv, _tally(load, got)
 
         return scan_run(body, (x, ck, cv, load), layers)
 
+    def mamba_run(x, load, layers, cs, cc):
+        def body(carry, lp, i):
+            x, cs, cc, load = carry     # [L, B, N, C] and [L, K-1, B, C]
+            state = lax.dynamic_index_in_dim(cs, i, keepdims=False)
+            tail = lax.dynamic_index_in_dim(cc, i, keepdims=False).swapaxes(
+                0, 1)                   # [B, K-1, C]
+
+            def conv(u, w, b):
+                return ssm.causal_conv(u, w, b, tail)
+
+            def step(u, dt, A, b, c, D):
+                with jax.named_scope("ssm_step"):
+                    y, new = ssm.selective_step(
+                        u[:, 0], dt[:, 0], A, b[:, 0], c[:, 0], D, state)
+                return y[:, None], new
+
+            x, (new_tail, new_state), got = block(
+                lp, x, None, ssm.Recurrence(conv, step), cfg)
+            # an inactive row's summary stays bit for bit what it was
+            cs = lax.dynamic_update_slice(cs, jnp.where(
+                active[:, None, None], new_state, state)[None],
+                (i, 0, 0, 0))
+            cc = lax.dynamic_update_slice(cc, jnp.where(
+                active[:, None, None], new_tail.astype(cc.dtype),
+                tail).swapaxes(0, 1)[None], (i, 0, 0, 0))
+            return x, cs, cc, _tally(load, got)
+
+        return scan_run(body, (x, cs, cc, load), layers)
+
     load = jnp.zeros_like(cache["load"]) if "load" in cache else None
-    new_k, new_v = [], []
-    for ((attention, _), layers), ck, cv in zip(
-            layer_stacks(params, cfg), ks, vs):
-        x, ck, cv, load = one_run(x, load, layers, ck, cv, attention)
-        new_k.append(ck)
-        new_v.append(cv)
+    new = []
+    for ((mixer, _), layers), (first, second) in zip(
+            layer_stacks(params, cfg), states):
+        if mixer == MAMBA:
+            x, first, second, load = mamba_run(x, load, layers, first, second)
+        else:
+            x, first, second, load = attention_run(x, load, layers, first,
+                                                   second, mixer)
+        new.append((first, second))
     logits = unembed(params, x[:, 0], eps=cfg.norm_eps)
     tok = jnp.where(active, pick(logits), cache["tok"])
-    cache = dict(cache, k=tuple(new_k), v=tuple(new_v),
-                 pos=jnp.where(active, pos + 1, pos), tok=tok)
+    cache = _with_states(cache, runs, new,
+                         pos=jnp.where(active, pos + 1, pos), tok=tok)
     if load is not None:
         cache["load"] = load
     if not served:

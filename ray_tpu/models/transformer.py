@@ -1,17 +1,21 @@
 """Decoder-only transformer over a dp/pp/sp/tp mesh: pre-norm blocks of
-attention (all heads alike, or fewer K/V heads than query heads; rope
-on the whole head or on its first dimensions) and a feed-forward (dense
-SwiGLU, or a chip's share of sparse experts), whose layers may be of
-several kinds in one model: full or windowed attention, each with its
-own K/V heads and rope base, with or without a sink logit.
+a mixer over the sequence and a feed-forward (dense SwiGLU, or a chip's
+share of sparse experts), whose layers may be of several kinds in one
+model. The mixer is attention (all heads alike, or fewer K/V heads than
+query heads; rope on the whole head, on its first dimensions or not at
+all; full or windowed, each with its own K/V heads and rope base, with
+or without a sink logit) or a Mamba layer: a gated selective
+state-space recurrence behind a short causal convolution
+(ops/ssm.py), which carries the order of the sequence itself.
 
 **One block.** ``block`` is the only definition of the layer: norms,
 projections, feed-forward and residuals. What differs between training,
 prefill and decode is handed to it: ``rope`` (how q and k are rotated)
-and ``attend`` (what the queries attend, and the state that comes
-back); what differs between layers is in the layer's own weights (a
-router makes it an expert layer) and in the closures its caller builds
-for its kind. ``forward`` here and ``slot_prefill`` /
+and ``attend`` (what the queries attend, or how a Mamba layer's
+convolution and scan run, and the state that comes back); what differs
+between layers is in the layer's own weights (a router makes it an
+expert layer, ``w_in`` a Mamba layer) and in the closures its caller
+builds for its kind. ``forward`` here and ``slot_prefill`` /
 ``slot_decode_step`` in models/decode.py each scan it over the stacked
 layers; ``unembed`` is their shared final norm and head. A change to
 the architecture is a change to ``block``, ``init_params`` and
@@ -54,6 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
@@ -67,8 +72,9 @@ from ray_tpu.parallel.ulysses import ulysses_attention
 from jax.sharding import PartitionSpec as P
 
 
-# A layer's kind: (attention, feed-forward).
-FULL, WINDOW = "full", "window"
+# A layer's kind: (mixer, feed-forward). The mixer is one of the two
+# kinds of attention or a Mamba layer.
+FULL, WINDOW, MAMBA = "full", "window", "mamba"
 DENSE, EXPERTS = "dense", "experts"
 LayerKind = Tuple[str, str]
 
@@ -91,8 +97,11 @@ class TransformerConfig:
     qk_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
     rotary_dim: Optional[int] = None    # leading dims of a head rotated
+    # False: q and k are not rotated and attention has no positional
+    # term at all (a model whose Mamba layers carry the order)
+    rope: bool = True
     value_scale: float = 1.0            # on v, before the product
-    # each layer's (attention, feed-forward) kind; None: all full, dense
+    # each layer's (mixer, feed-forward) kind; None: all full, dense
     layer_kinds: Optional[Tuple[LayerKind, ...]] = None
     # window layers: position t attends (t - window, t]
     window: Optional[int] = None
@@ -109,6 +118,14 @@ class TransformerConfig:
     experts_first: int = 0
     experts_held: int = 0
     d_expert: int = 0
+    # Mamba layers: ssm_inner channels (the published expansion times
+    # d_model), each with a state of ssm_state values; dt is projected
+    # through ssm_dt_rank; the causal convolution spans ssm_conv
+    # positions
+    ssm_inner: int = 0
+    ssm_state: int = 0
+    ssm_dt_rank: int = 0
+    ssm_conv: int = 0
 
     def __post_init__(self):
         def refuse(key, why):
@@ -119,11 +136,11 @@ class TransformerConfig:
             if len(kinds) != self.n_layers:
                 refuse("layer_kinds", f"{len(kinds)} kinds for "
                        f"{self.n_layers} layers")
-            for attention, ffn in kinds:
-                if attention not in (FULL, WINDOW) or ffn not in (
+            for mixer, ffn in kinds:
+                if mixer not in (FULL, WINDOW, MAMBA) or ffn not in (
                         DENSE, EXPERTS):
                     refuse("layer_kinds", f"unknown kind "
-                           f"{(attention, ffn)!r}")
+                           f"{(mixer, ffn)!r}")
         if any(a == WINDOW for a, _ in kinds or ()) and not self.window:
             refuse("window", "window layers need a window")
         for key in ("n_kv_heads", "window_kv_heads"):
@@ -131,6 +148,9 @@ class TransformerConfig:
             if heads is not None and self.n_heads % heads:
                 refuse(key, f"{heads} K/V heads do not divide "
                        f"{self.n_heads} query heads")
+        if self.rotary_dim == 0:
+            refuse("rotary_dim", "0 dimensions rotated is said with "
+                   "rope=False, not with a width of nothing")
         if self.rotary_dim is not None and (
                 self.rotary_dim % 2 or self.rotary_dim > self.head_dim):
             refuse("rotary_dim", f"{self.rotary_dim} is odd or wider "
@@ -144,6 +164,11 @@ class TransformerConfig:
                 refuse("n_experts", "expert layers need n_experts, "
                        "experts_per_token, d_expert and a held range "
                        "inside the router's width")
+        if self.has_mamba and not (
+                self.ssm_inner > 0 and self.ssm_state > 0
+                and self.ssm_dt_rank > 0 and self.ssm_conv > 1):
+            refuse("ssm_inner", "Mamba layers need ssm_inner, ssm_state, "
+                   "ssm_dt_rank and a convolution of at least 2 positions")
 
     @property
     def head_dim(self) -> int:
@@ -157,7 +182,11 @@ class TransformerConfig:
 
     @property
     def rope_dim(self) -> int:
-        return self.rotary_dim or self.head_dim
+        return self.head_dim if self.rotary_dim is None else self.rotary_dim
+
+    @property
+    def has_mamba(self) -> bool:
+        return any(mixer == MAMBA for mixer, _ in self.layer_kinds or ())
 
     def kv_heads(self, attention: str = FULL) -> int:
         heads = self.n_kv_heads or self.n_heads
@@ -222,6 +251,47 @@ class ParallelConfig:
         return tuple(a for a in (self.dp, self.sp) if a)
 
 
+def mamba_dt_bias(key, shape, low: float = 1e-3, high: float = 1e-1):
+    """The Mamba family's own start for the bias of dt's projection:
+    such that softplus(bias) is log-uniform in [low, high], so that a
+    channel's decay exp(dt A) neither dies at once nor stands still
+    under fresh weights. float32."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                 * (jnp.log(high) - jnp.log(low)) + jnp.log(low))
+    return dt + jnp.log(-jnp.expm1(-dt))       # softplus's inverse
+
+
+def _init_mamba(keys, cfg: TransformerConfig, n: int):
+    """The mixer of ``n`` stacked Mamba layers. Matrices normal(0.02)
+    at the model's dtype; what feeds the recurrence is float32 and
+    starts as the family starts it: ``a_log`` log(1..N) down every
+    channel ([N, C], the state's layout: ops/ssm.py), ``d_skip`` ones,
+    ``dt_bias`` by :func:`mamba_dt_bias`."""
+    D, C, N = cfg.d_model, cfg.ssm_inner, cfg.ssm_state
+    R, K, dt = cfg.ssm_dt_rank, cfg.ssm_conv, cfg.dtype
+    init = jax.nn.initializers.normal(0.02)
+
+    def w(kk, shape):
+        return init(kk, shape, jnp.float32).astype(dt)
+
+    return {
+        "w_in": w(keys[0], (n, D, 2 * C)),
+        "conv_w": w(keys[7], (n, K, C)),
+        "conv_b": w(keys[8], (n, C)),
+        "w_x": w(keys[1], (n, C, R + 2 * N)),
+        "dt_norm": jnp.ones((n, R), dt),
+        "b_norm": jnp.ones((n, N), dt),
+        "c_norm": jnp.ones((n, N), dt),
+        "w_dt": w(keys[2], (n, R, C)),
+        "dt_bias": mamba_dt_bias(keys[9], (n, C)),
+        "a_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None],
+            (n, N, C)),
+        "d_skip": jnp.ones((n, C), jnp.float32),
+        "w_out": w(keys[3], (n, C, D)),
+    }
+
+
 def _init_run(keys, cfg: TransformerConfig, kind: LayerKind, n: int):
     """One run of ``n`` alike layers, stacked on a leading dim. ``keys``
     holds ten keys, the first seven in the order of a dense layer's
@@ -234,14 +304,19 @@ def _init_run(keys, cfg: TransformerConfig, kind: LayerKind, n: int):
     def w(kk, shape):
         return init(kk, shape, jnp.float32).astype(dt)
 
-    run = {
-        "attn_norm": jnp.ones((n, D), dt),
-        "wq": w(keys[0], (n, D, H * Dh)),
-        "wk": w(keys[1], (n, D, G * Dh)),
-        "wv": w(keys[2], (n, D, G * Dv)),
-        "wo": w(keys[3], (n, H * Dv, D)),
-        "mlp_norm": jnp.ones((n, D), dt),
-    }
+    if attention == MAMBA:
+        run = dict(_init_mamba(keys, cfg, n),
+                   attn_norm=jnp.ones((n, D), dt),
+                   mlp_norm=jnp.ones((n, D), dt))
+    else:
+        run = {
+            "attn_norm": jnp.ones((n, D), dt),
+            "wq": w(keys[0], (n, D, H * Dh)),
+            "wk": w(keys[1], (n, D, G * Dh)),
+            "wv": w(keys[2], (n, D, G * Dv)),
+            "wo": w(keys[3], (n, H * Dv, D)),
+            "mlp_norm": jnp.ones((n, D), dt),
+        }
     if attention in cfg.sink_kinds:
         run["sink"] = init(keys[7], (n, H), jnp.float32)
     if ffn == EXPERTS:
@@ -292,19 +367,29 @@ def param_specs(pcfg: ParallelConfig,
     of one kind without ``cfg``, else of ``cfg``'s. Heads and the
     feed-forward's width (an expert's own, inside each expert) go over
     ``tp``; a stack's layers over ``pp``, which only a model of one
-    kind can have."""
+    kind can have. A model with Mamba layers is refused ``tp``, ``sp``
+    and ``pp``: no sharding of that mixer is expressed."""
     pp, tp = pcfg.pp, pcfg.tp
+    if cfg is not None:
+        _refuse_a_sharded_mamba(cfg, pcfg)
 
     def run_specs(kind):
         attention, ffn = kind
-        specs = {
-            "attn_norm": P(pp, None),
-            "wq": P(pp, None, tp),
-            "wk": P(pp, None, tp),
-            "wv": P(pp, None, tp),
-            "wo": P(pp, tp, None),
-            "mlp_norm": P(pp, None),
-        }
+        if attention == MAMBA:
+            specs = {name: P(*(None,) * rank) for name, rank in (
+                ("attn_norm", 2), ("mlp_norm", 2), ("w_in", 3),
+                ("conv_w", 3), ("conv_b", 2), ("w_x", 3), ("dt_norm", 2),
+                ("b_norm", 2), ("c_norm", 2), ("w_dt", 3), ("dt_bias", 2),
+                ("a_log", 3), ("d_skip", 2), ("w_out", 3))}
+        else:
+            specs = {
+                "attn_norm": P(pp, None),
+                "wq": P(pp, None, tp),
+                "wk": P(pp, None, tp),
+                "wv": P(pp, None, tp),
+                "wo": P(pp, tp, None),
+                "mlp_norm": P(pp, None),
+            }
         if cfg is not None and attention in cfg.sink_kinds:
             specs["sink"] = P(pp, tp)
         if ffn == EXPERTS:
@@ -329,6 +414,17 @@ def param_specs(pcfg: ParallelConfig,
     if cfg is not None and not cfg.tie_embeddings:
         specs["head"] = P(None, None)
     return specs
+
+
+def _refuse_a_sharded_mamba(cfg: TransformerConfig,
+                            pcfg: ParallelConfig) -> None:
+    sharded = [axis for axis in ("tp", "sp", "pp") if getattr(pcfg, axis)]
+    if cfg.has_mamba and sharded:
+        raise ValueError(
+            f"a model with Mamba layers runs on one device or under dp "
+            f"alone: no sharding of that mixer over {', '.join(sharded)} "
+            f"is expressed (its channels for tp, its scan for sp, its "
+            f"runs of unlike layers for pp)")
 
 
 def _attend(q, k, v, pcfg: ParallelConfig, window=None, sink=None):
@@ -361,7 +457,11 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
     H_local, Dh] (k and v at the layer's K/V heads, v at the value
     width) and gives the attention output, which is flattened here to
     [B, T, H_local * Dv], and whatever the caller keeps of the layer
-    (the K/V a cache holds; None in training). A layer that has a
+    (the K/V a cache holds; None in training). A layer that has
+    ``w_in`` is a Mamba layer: ``rope`` is not called and ``attend`` is
+    an ``ops.ssm.Recurrence``, the convolution and the scan as its
+    caller runs them (:func:`mamba_mixer`); the state that comes back
+    is (the convolution's tail, the scan's state). A layer that has a
     router is an expert layer: its feed-forward is the part the experts
     held here give (parallel/experts.py). Returns (x, state, load):
     ``load`` the rows each held expert got, int32 [experts_held], None
@@ -369,17 +469,21 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
     B, T, D = x.shape
 
     h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
-    if pcfg.tp:
-        h = tp_copy(h, pcfg.tp)
-    q = (h @ lp["wq"]).reshape(B, T, -1, cfg.head_dim)  # H_local heads
-    k = (h @ lp["wk"]).reshape(B, T, -1, cfg.head_dim)
-    v = (h @ lp["wv"]).reshape(B, T, -1, cfg.v_dim)
-    if cfg.value_scale != 1.0:
-        v = v * cfg.value_scale
-    o, state = attend(rope(q), rope(k), v)
-    o = o.reshape(B, T, -1) @ lp["wo"]             # row-parallel
-    if pcfg.tp:
-        o = tp_allreduce(o, pcfg.tp)
+    if "w_in" in lp:
+        with jax.named_scope("mamba_mixer"):
+            o, state = mamba_mixer(lp, h, attend, cfg)
+    else:
+        if pcfg.tp:
+            h = tp_copy(h, pcfg.tp)
+        q = (h @ lp["wq"]).reshape(B, T, -1, cfg.head_dim)  # H_local heads
+        k = (h @ lp["wk"]).reshape(B, T, -1, cfg.head_dim)
+        v = (h @ lp["wv"]).reshape(B, T, -1, cfg.v_dim)
+        if cfg.value_scale != 1.0:
+            v = v * cfg.value_scale
+        o, state = attend(rope(q), rope(k), v)
+        o = o.reshape(B, T, -1) @ lp["wo"]             # row-parallel
+        if pcfg.tp:
+            o = tp_allreduce(o, pcfg.tp)
     x = x + o.astype(x.dtype)
 
     h = rmsnorm(x, lp["mlp_norm"], eps=cfg.norm_eps)
@@ -406,6 +510,32 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
     if pcfg.tp:
         d = tp_allreduce(d, pcfg.tp)
     return x + d.astype(x.dtype), state, load
+
+
+def mamba_mixer(lp, h, recur: ssm.Recurrence, cfg: TransformerConfig):
+    """A Mamba layer's mixer over h [B, T, D], the normed input: the
+    projection to the channels u and their gate z; the causal
+    convolution and its silu (``recur.conv``); dt, B and C projected
+    from the result, each through its own RMSNorm, dt widened to the
+    channels and through a softplus; the selective scan
+    (``recur.scan``); the gate; the projection back. The matrix
+    products run at h's dtype; dt, A and the state are float32. Returns
+    (the mixer's output [B, T, D], (the convolution's tail [B, K - 1,
+    C], the scan's state [B, N, C]))."""
+    C, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    uz = h @ lp["w_in"]
+    z = uz[..., C:]
+    u, tail = recur.conv(uz[..., :C], lp["conv_w"], lp["conv_b"])
+    low = u @ lp["w_x"]
+    dt, b, c = (rmsnorm(low[..., a:z_], lp[name], eps=cfg.norm_eps)
+                for name, a, z_ in (("dt_norm", 0, R), ("b_norm", R, R + N),
+                                    ("c_norm", R + N, R + 2 * N)))
+    dt = jax.nn.softplus(
+        jnp.matmul(dt, lp["w_dt"], preferred_element_type=jnp.float32)
+        + lp["dt_bias"])
+    y, state = recur.scan(u, dt, -jnp.exp(lp["a_log"]), b, c, lp["d_skip"])
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return gated.astype(h.dtype) @ lp["w_out"], (tail, state)
 
 
 EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
@@ -461,6 +591,31 @@ def kind_rope(cfg: TransformerConfig, attention: str, max_seq: int):
                             theta=cfg.theta(attention))
 
 
+def roped_kinds(cfg: TransformerConfig, runs) -> list:
+    """The kinds of mixer among ``runs`` whose q and k are rotated: the
+    attention kinds, unless the model has no rotation at all."""
+    if not cfg.rope:
+        return []
+    return list(dict.fromkeys(mixer for (mixer, _), *_ in runs
+                              if mixer != MAMBA))
+
+
+def no_rotation(t):
+    """``rope`` of a model whose attention has no positional term."""
+    return t
+
+
+def _scan_from_the_start(*args):
+    with jax.named_scope("ssm_scan"):
+        return ssm.selective_scan(*args)
+
+
+# a Mamba layer's recurrence over a whole sequence from nothing before
+# it: training's, which drops what comes back, and a prefill's, which
+# keeps it
+FROM_THE_START = ssm.Recurrence(ssm.causal_conv, _scan_from_the_start)
+
+
 def _stack_fn(cfg, pcfg, rope, kind: LayerKind = (FULL, DENSE)):
     """Scan one run of (locally held) alike layers over one
     activation."""
@@ -471,7 +626,9 @@ def _stack_fn(cfg, pcfg, rope, kind: LayerKind = (FULL, DENSE)):
             with jax.named_scope(f"{kind[0]}_attention"):
                 return _attend(q, k, v, pcfg, window, lp.get("sink")), None
 
-        return block(lp, x, rope, attend, cfg, pcfg)[0]
+        return block(lp, x, rope,
+                     FROM_THE_START if kind[0] == MAMBA else attend,
+                     cfg, pcfg)[0]
 
     if pcfg.remat:
         layer = jax.checkpoint(layer)
@@ -489,12 +646,13 @@ def forward(params, tokens, cfg: TransformerConfig,
     Call directly for the oracle, or inside shard_map for SPMD.
     """
     T = tokens.shape[1]
+    _refuse_a_sharded_mamba(cfg, pcfg)
     stacks = layer_stacks(params, cfg)
     if pcfg.pp and len(stacks) > 1:
         raise ValueError("a pipeline over layers of several kinds is not "
                          "expressed: pp needs one stack")
     tables = {a: kind_rope(cfg, a, cfg.max_seq)
-              for a in dict.fromkeys(kind[0] for kind, _ in stacks)}
+              for a in roped_kinds(cfg, stacks)}
     if pcfg.sp:
         positions = lax.axis_index(pcfg.sp) * T + jnp.arange(T)
     else:
@@ -502,9 +660,12 @@ def forward(params, tokens, cfg: TransformerConfig,
 
     x = params["embed"][tokens]                    # [B,T,D]
     for kind, layers in stacks:
-        cos, sin = tables[kind[0]]
-        stack = _stack_fn(cfg, pcfg, functools.partial(
-            apply_rotary, cos=cos, sin=sin, positions=positions), kind)
+        rope = no_rotation
+        if kind[0] in tables:
+            cos, sin = tables[kind[0]]
+            rope = functools.partial(apply_rotary, cos=cos, sin=sin,
+                                     positions=positions)
+        stack = _stack_fn(cfg, pcfg, rope, kind)
         if pcfg.pp:
             x = pipeline_spmd(stack, layers, x, axis=pcfg.pp,
                               num_microbatches=pcfg.num_microbatches)

@@ -64,9 +64,14 @@ name, to be read as deltas:
   whole row of picks of the step dispatched a call EARLIER: it waits
   for what is left of that step, which has run since, and carries the
   transfer. ``read`` builds the returned dict from that host array.
-  The engine's ``prefill`` has no spans of its own: ``serve.prefill``
-  less its hop is the call. Sums beside the phases, ``[calls, sum]``
-  and not seconds: ``serve.engine.ahead`` (1 for a call answered from
+  The engine's ``prefill`` has no span of its own on a trace
+  (``serve.prefill`` less its hop is the call) and keeps one sum
+  without a span, as ``serve.hop`` is: ``serve.engine.prefill``,
+  ``[calls, seconds]`` of the call from the prompt's dispatch to its
+  first token fetched on the host, for which the decoding rows stand
+  still. Sums beside the phases, ``[calls, sum]`` and not seconds:
+  ``serve.engine.prefill_tokens`` (a prefill's prompt length),
+  ``serve.engine.ahead`` (1 for a call answered from
   a step already in flight, 0 for one that had to dispatch and wait)
   and ``serve.engine.rows_wasted`` (rows stepped that no request was
   owed: a finished request's one step more). Of a model with expert
@@ -383,7 +388,8 @@ class _Flight(NamedTuple):
 
 
 class JaxSlotEngine:
-    """Adapts the per-slot KV cache (models/decode.py) to the
+    """Adapts the per-slot cache (models/decode.py: an attention
+    layer's K/V rows, a Mamba layer's recurrent state) to the
     scheduler's engine protocol. Greedy decoding; prompts are int
     token-id sequences. One compiled prefill program per distinct
     prompt length, one compiled step program total.
@@ -407,11 +413,25 @@ class JaxSlotEngine:
       own last answer (it is not sent up again): another value is
       refused before dispatch. A slot in the step in flight that a call
       leaves out has left: its row there is waste (a finished request
-      rides one step more: one K/V position in a dead slot, no time),
-      and it steps again only after a ``prefill``.
+      rides one step more, no time), and it steps again only after a
+      ``prefill``.
     * ``prefill`` forgets its slot's place in the step in flight, so a
       new request is answered only from steps dispatched after it: the
       old row's token never reaches it.
+
+    **What a slot's state may suffer**, for the two kinds of state a
+    layer keeps (models/decode.py). A row left out of a step (``IDLE``:
+    not in ``tokens``, or sitting out at ``max_len``) keeps its state
+    as it was: its K/V rows may take garbage at its frozen position,
+    which the next write there covers before anyone attends it, and its
+    recurrent state, which has no such position to cover later, is
+    written back bit for bit. A finished request's one step more does
+    advance its dead slot, rows and recurrent state alike; that slot is
+    only ever re-entered through ``prefill``, which makes its whole
+    state the new prompt's (rows [0, T0) and the position; the
+    recurrent state computed from zeros), so no request sees its
+    predecessor. ``max_len`` bounds a slot by its attention layers'
+    rows; recurrent state does not grow.
 
     A call makes one device-to-host transfer, and none before its
     dispatch. The slots' positions are mirrored on the host: ``_pos``
@@ -470,14 +490,14 @@ class JaxSlotEngine:
         """Around what consumes the cache. Where a program or a fetch
         raises: the error passes as it is if the cache still lives
         (nothing was taken: a refusal while tracing or compiling;
-        donation takes the buffers at dispatch). Else every slot's K/V
+        donation takes the buffers at dispatch). Else every slot's state
         went with it, and the step in flight too: start over from an
         empty cache and say so, typed."""
         given = self._cache
         try:
             yield
         except Exception as e:  # noqa: BLE001 — typed if the cache went
-            if not given["k"][0].is_deleted():
+            if not given["pos"].is_deleted():
                 raise
             self._start_over()
             raise SlotStateLostError(
@@ -494,6 +514,7 @@ class JaxSlotEngine:
                 f"({self.max_len})")
         if self._flight is not None:    # its old row answers no one
             self._flight.owed.discard(slot)
+        began = time.perf_counter()
         with self._giving_the_cache():
             _, self._cache = self._decode.slot_prefill(
                 self._params, tokens, self._cache, jnp.int32(slot),
@@ -502,7 +523,11 @@ class JaxSlotEngine:
             # queued behind the step in flight; the whole row of picks
             self._last[slot] = int(self._jax.device_get(
                 self._cache["tok"])[slot])
-            return self._last[slot]
+        # sums, no span: a trace holds the step's five phases alone on
+        # this thread, and ``serve.prefill`` around this call
+        phase_add("serve.engine.prefill", time.perf_counter() - began)
+        phase_add("serve.engine.prefill_tokens", tokens.shape[1])
+        return self._last[slot]
 
     def _steer(self, tokens: Dict[int, int], owed) -> List[int]:
         """What the next step feeds each row (models/decode.py): CARRY

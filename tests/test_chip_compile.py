@@ -19,6 +19,14 @@ decode steps hand the host a row of picked tokens and nothing of
 ``[slots, vocab]``; the serving programs of both cells and Ouro's train
 step lower to the text on record.
 
+The third serving cell's programs (``jamba2-3b``: Mamba layers beside
+attention layers, a slot's recurrent state beside its K/V) are held to
+the same at that cell's shapes: every leaf of the cache aliased, the
+recurrent state read and written where it lies with no copy of a run's
+or a layer's state, each prefill holding both kernels (``ssm_scan`` a
+Mamba run, ``flash_fwd`` an attention run), and the scan kernel alone
+accepted by Mosaic at the cell's three prompt lengths.
+
 The topology is described inside a fixture, by the one xdist worker
 that is given this file: libtpu loads in one process at a time, so no
 other test file may do the same and nothing here runs at import.
@@ -31,6 +39,7 @@ import re
 import pytest
 
 KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+SCAN_KERNEL = "ssm_scan"
 # B, T, H, Dh, the dtype, and whether the gradient is compiled too
 SHAPES = {
     # ouro-2.6b-d12.train-2k
@@ -89,7 +98,8 @@ def mosaic_calls(compiled_text):
     names = re.findall(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
         compiled_text)
-    return sorted(kernel_of(n, KERNELS) or n for n in names)
+    return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL,)) or n
+                  for n in names)
 
 
 @pytest.mark.parametrize("cell", sorted(SHAPES))
@@ -604,3 +614,190 @@ def test_ouros_three_programs_lower_to_the_text_on_record(
             lambda q, k, v: jnp.sum(forward(q, k, v).astype(jnp.float32)),
             argnums=(0, 1, 2)))(arg, arg, arg)))
     assert digest("\n".join(jaxprs)) == KERNEL_JAXPRS_BEFORE_KINDS
+
+
+# --------------- Mamba layers beside attention layers, two kinds of state
+
+HYBRID_CELL = "jamba2-3b.rollout-closed"
+LOWERED_HYBRID = {
+    "decode": "4e33907e2350d683",
+    "prefill-128": "9494bbbe99469780",
+    "prefill-256": "d915f689d4473dd3",
+    "prefill-512": "348935eec64c94c4",
+}
+
+
+@pytest.fixture(scope="module")
+def hybrid_cell():
+    """The cell of the model with Mamba and attention layers, as the
+    benchmark's worker builds it."""
+    from benchmarks import loader
+
+    bench = loader.load_benchmark()
+    cell = loader.find_cell(bench, HYBRID_CELL)
+    config = loader.load_config(bench, cell["config"])
+    mix = loader.load_traffic(bench, cell["traffic"])
+    program = loader.family_module(loader.find_family(bench, config),
+                                   "program")
+    return (program.program_config(config, mix["slot_len"]),
+            int(mix["slots"]), int(mix["slot_len"]),
+            sorted(mix["prompt_lengths"]))
+
+
+@pytest.fixture
+def as_on_the_tpu(monkeypatch):
+    """This process's default backend is the CPU; the programs are for
+    the described chip, so take the branches a TPU process takes."""
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    ssm = importlib.import_module("ray_tpu.ops.ssm")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("length", [128, 256, 512])
+def test_the_scan_kernel_compiles_for_v5e_under_its_own_name(
+        length, one_chip, no_compile_cache, as_on_the_tpu):
+    """``ssm_scan`` at the third serving cell's widths (5120 channels, a
+    state of 16, bfloat16 in, float32 dt and state) and its three
+    prompt lengths: Mosaic accepts it at the blocks ``ops.ssm``
+    chooses, and the program names its one custom call ``ssm_scan``,
+    which is what the benchmark's reader looks up in a device trace."""
+    import jax
+    import jax.numpy as jnp
+
+    ssm = importlib.import_module("ray_tpu.ops.ssm")
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    C, N = 5120, 16
+    text = jax.jit(ssm.selective_scan).lower(
+        array((1, length, C), jnp.bfloat16),
+        array((1, length, C), jnp.float32), array((N, C), jnp.float32),
+        array((1, length, N), jnp.bfloat16),
+        array((1, length, N), jnp.bfloat16), array((C,), jnp.float32),
+        array((1, N, C), jnp.float32)).compile().as_text()
+    assert "%ssm_scan.1 = " in text
+    assert mosaic_calls(text) == [SCAN_KERNEL]
+
+
+def test_the_third_serving_cells_shapes_are_the_ones_compiled_here(
+        hybrid_cell):
+    from ray_tpu.models.transformer import layer_runs
+
+    cfg, slots, slot_len, lengths = hybrid_cell
+    assert (slots, slot_len, lengths) == (256, 2048, [128, 256, 512])
+    assert (cfg.n_heads, cfg.head_dim, cfg.kv_heads("full"), cfg.rope,
+            cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv,
+            cfg.d_ff, cfg.vocab, cfg.tie_embeddings) == (
+        20, 128, 1, False, 5120, 16, 160, 4, 8192, 65536, True)
+    assert [(kind[0], n) for kind, n in layer_runs(cfg)] == [
+        ("mamba", 7), ("full", 1), ("mamba", 13), ("full", 1), ("mamba", 6)]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-128",
+                                     "prefill-256", "prefill-512"])
+def test_recurrent_state_beside_kv_is_still_written_in_place(
+        program, hybrid_cell, one_chip, no_compile_cache, as_on_the_tpu):
+    """``slot_decode_step`` and ``slot_prefill`` of the model with Mamba
+    layers, at its cell's shapes, for the described v5e. Every leaf of
+    the cache is aliased to the result (2.92 GB: 2.18 GB of recurrent
+    state in float32, 0.20 GB of convolution tails, 0.54 GB of K and V
+    for the two attention layers); no operation produces an array of a
+    run's or a layer's state, tail, K or V but the in-place writes (a
+    decode step's select-and-write of a Mamba layer's state is one
+    fusion, rooted in the write); the temporaries stay under one
+    layer's state (84 MB: no copy of one has room); arguments and
+    temporaries fit the chip; and each prefill holds both kernels, one
+    call a run of layers."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode, init_params
+
+    cfg, slots, slot_len, _ = hybrid_cell
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len)))
+    shape_of = lambda name: [None if a is None else a.shape  # noqa: E731
+                             for a in cache[name]]
+    assert shape_of("ssm") == [(7, 256, 16, 5120), None,
+                               (13, 256, 16, 5120), None,
+                               (6, 256, 16, 5120)]
+    assert shape_of("conv") == [(7, 3, 256, 5120), None, (13, 3, 256, 5120),
+                                None, (6, 3, 256, 5120)]
+    assert shape_of("k") == shape_of("v") == [
+        None, (1, 256, 2048, 128), None, (1, 256, 2048, 128), None]
+    if program == "decode":
+        compiled = compiled_decode(HYBRID_CELL, cfg, slots, slot_len,
+                                   one_chip)
+    else:
+        length = int(program.split("-")[1])
+        compiled = decode.slot_prefill.lower(
+            params, array((1, length), jnp.int32), cache,
+            array((), jnp.int32), cfg).compile()
+    text = compiled.as_text()
+
+    # every leaf of the cache, and nothing else, aliases the result:
+    # state and tail of the three Mamba runs, K and V of the two
+    # attention runs, pos and tok
+    leaves = re.findall(
+        r"parameter\((\d+)\)[^\n]*op_name=\"cache\[([^\"]*)\]\"", text)
+    assert len(leaves) == 3 + 3 + 2 + 2 + 2
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliased, "nothing is aliased: the cache is not donated"
+    assert sorted(int(n) for n in re.findall(
+        r"\((\d+), \{\}, may-alias\)", aliased.group(1))
+    ) == sorted(int(n) for n, _ in leaves)
+
+    held = [leaf for name in ("ssm", "conv", "k", "v")
+            for leaf in cache[name] if leaf is not None]
+    state_bytes = sum(leaf.dtype.itemsize * math.prod(leaf.shape)
+                      for leaf in held)
+    memory = compiled.memory_analysis()
+    assert 0 <= memory.alias_size_in_bytes - state_bytes <= 8192
+    assert memory.temp_size_in_bytes < 4 * 256 * 16 * 5120
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < HBM_BYTES)
+
+    shapes = {leaf.shape for leaf in held} | {leaf.shape[1:]
+                                              for leaf in held}
+    produced = [(name, op) for name, op in cache_producers(text, shapes)
+                if op != "bitcast"]
+    assert {op for _, op in produced} <= set(IN_PLACE), produced
+    # a write a run and kind of state
+    assert len(produced) == 3 * 2 + 2 * 2, produced
+    if program != "decode":
+        assert mosaic_calls(text) == ["flash_fwd"] * 2 + [SCAN_KERNEL] * 3
+
+
+def test_the_third_cells_decode_step_hands_the_host_a_row_of_picks(
+        hybrid_cell, one_chip, no_compile_cache, as_on_the_tpu):
+    cfg, slots, slot_len, _ = hybrid_cell
+    compiled = compiled_decode(HYBRID_CELL, cfg, slots, slot_len, one_chip)
+    text = compiled.as_text()
+    root = re.search(r"ROOT %[\w.\-]+ = \(([^\n]*?)\) tuple\(",
+                     text[text.index("\nENTRY "):]).group(1)
+    results = re.findall(r"(\w+)\[([\d,]*)\]", root)
+    # the row of picks; beside it the cache's pos and tok
+    assert results.count(("s32", str(slots))) == 3
+    assert not [r for r in results
+                if r[1] == f"{slots},{cfg.vocab}"], results
+    memory = compiled.memory_analysis()
+    assert 4 * slots <= (memory.output_size_in_bytes
+                         - memory.alias_size_in_bytes) <= 4096
+
+
+def test_the_third_cells_serving_programs_lower_to_the_text_on_record(
+        hybrid_cell, one_chip, as_on_the_tpu):
+    assert serving_programs_lowered(hybrid_cell, one_chip) == LOWERED_HYBRID

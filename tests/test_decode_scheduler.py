@@ -642,8 +642,8 @@ ENGINE_COUNTS = ("serve.engine.ahead", "serve.engine.rows_wasted")
 
 def test_engine_phases_cover_the_step():
     """Every step of the real engine records its five phases once (and
-    its two counts beside them), a prefill none of its own, and the
-    step's phases are the step: their sum is 90 to 100 % of its wall
+    its two counts beside them), a prefill its seconds and its prompt's
+    length as two sums, and the step's phases are the step: their sum is 90 to 100 % of its wall
     time (the median step's, so that one stall of a shared box between
     two spans does not decide it)."""
     import statistics
@@ -653,7 +653,11 @@ def test_engine_phases_cover_the_step():
     eng = _tiny_engine(slots=2, max_len=32)
     with recording({}) as table:
         last = {0: eng.prefill(0, [5, 11, 23]), 1: eng.prefill(1, [40, 2])}
-    assert table == {}
+    assert sorted(table) == ["serve.engine.prefill",
+                             "serve.engine.prefill_tokens"]
+    assert table["serve.engine.prefill"][0] == 2
+    assert table["serve.engine.prefill"][1] > 0.0
+    assert table["serve.engine.prefill_tokens"] == [2, 3 + 2]
     with recording({}) as table:
         last = eng.step(last)               # compiles; two dispatches
     assert [table[p][0] for p in ENGINE_STEP_PHASES] == [1] * 5
