@@ -19,7 +19,11 @@ ray_tpu session whose driver never imports jax:
   seeded batch and ``report()``s each loss;
 * kernel leg — inside the trainer's worker: ``flash_attention``
   compiled by Mosaic (``interpret=False``), forward and both backward
-  kernels, against ``ops.attention.attention`` at head_dim 64 and 128.
+  kernels, against ``ops.attention.attention`` at head_dim 64 and 128;
+  and ``decode_attention``'s kernel against its XLA form at the decode
+  shapes of the two serving cells with full-attention layers of their
+  own kind (16 heads of 128 over 8 x 1024; 64 on 4 at 192 / 128 over
+  128 x 3200), slots at unlike positions, the cache poisoned past them.
 
 Every device fact printed comes from inside the worker that holds
 ``TPU``. It exits non-zero with the reason on any failure — at once
@@ -50,6 +54,9 @@ import urllib.request
 # the gradients pass through two such roundings.
 BF16_TOL = {"fwd": 2e-2, "bwd": 4e-2}
 KERNEL_SHAPES = ((2, 1024, 16, 64), (4, 4096, 8, 128))
+# decode steps: slots, query heads, q.k width, value width, K/V heads,
+# rows a slot (ouro-2.6b.decode-closed; mimo-v2-flash-ep16-d7.reason-closed)
+DECODE_SHAPES = ((8, 16, 128, 128, 16, 1024), (128, 64, 192, 128, 4, 3200))
 MOSAIC_CALL = "tpu_custom_call"
 
 
@@ -200,6 +207,59 @@ def kernel_checks(shapes, dtype: str, interpret: bool) -> list:
     return rows
 
 
+def decode_kernel_checks(shapes, dtype: str, interpret: bool) -> list:
+    """``decode_attention`` through its kernel against its XLA form on
+    a clean copy, layer 1 of a run of 2, slots at unlike positions
+    (the first, the last and a spread between) and NaN past each: one
+    row per shape of the normalized error, the Mosaic call count and
+    the block the shape was given."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ray_tpu.ops.attention import (cached_attention, decode_attention,
+                                       decode_rows_fetched)
+
+    rows_out = []
+    for B, H, D, Dv, G, rows in shapes:
+        keys = jax.random.split(jax.random.key(9), 3)
+        row = (lambda w: (H, w)) if G == H else (lambda w: (G * w,))
+        q = jax.random.normal(keys[0], (B, H, D), jnp.dtype(dtype))
+        k = jax.random.normal(keys[1], (2, B, rows) + row(D), q.dtype)
+        v = jax.random.normal(keys[2], (2, B, rows) + row(Dv), q.dtype)
+        pos = (jnp.arange(B) * (rows - 1) // max(B - 1, 1)).astype(jnp.int32)
+        past = (jnp.arange(rows)[None, :] > pos[:, None]).reshape(
+            (1, B, rows) + (1,) * (k.ndim - 3))
+        valid = jnp.arange(rows)[None, None, :] <= pos[:, None, None]
+
+        def kernel(q, k, v, pos):
+            return decode_attention(q, k, v, jnp.int32(1), pos,
+                                    interpret=interpret)
+
+        def xla(q, k, v, pos):
+            return cached_attention(
+                q, lax.dynamic_index_in_dim(k, 1, keepdims=False),
+                lax.dynamic_index_in_dim(v, 1, keepdims=False), valid,
+                D ** -0.5)
+
+        compiled = jax.jit(kernel).lower(q, k, v, pos).compile()
+        poisoned = [jnp.where(past, jnp.nan, t) for t in (k, v)]
+        got = compiled(q, *poisoned, pos).astype(jnp.float32)
+        del poisoned                    # a gigabyte at the second shape
+        clean = [jnp.where(past, 0, t) for t in (k, v)]
+        del k, v
+        want = jax.jit(xla)(q, *clean, pos).astype(jnp.float32)
+        rows_out.append({
+            "shape": [B, H, D, Dv, G, rows], "dtype": dtype,
+            "interpret": interpret,
+            "block": decode_rows_fetched(q, *clean, interpret=interpret),
+            "mosaic_calls": compiled.as_text().count(MOSAIC_CALL),
+            "err": float(jnp.max(jnp.abs(got - want))
+                         / jnp.max(jnp.abs(want))),
+            "finite": bool(jnp.isfinite(got).all())})
+    return rows_out
+
+
 def train_func(config: dict) -> dict:
     """The train leg, inside the Trainer's worker: the kernel checks,
     then ``steps`` optimizer steps on one fixed seeded batch."""
@@ -211,6 +271,9 @@ def train_func(config: dict) -> dict:
     result = {"device": device_report()}
     result["kernels"] = kernel_checks(
         config["kernel_shapes"], config["kernel_dtype"],
+        config["interpret"])
+    result["decode_kernels"] = decode_kernel_checks(
+        config["decode_shapes"], config["kernel_dtype"],
         config["interpret"])
 
     cfg = make_config(config.get("cfg"))
@@ -400,6 +463,11 @@ def check_on_chip(leg: dict, tol: dict) -> None:
         check(row["fwd_err"] <= tol["fwd"] and all(
             row[g + "_err"] <= tol["bwd"] for g in ("dq", "dk", "dv")),
             f"flash attention disagrees with the reference: {row}")
+    for row in leg["decode_kernels"]:
+        check(row["mosaic_calls"] == 1 and row["block"] < row["shape"][-1],
+              f"the decode kernel did not compile with Mosaic: {row}")
+        check(row["finite"] and row["err"] <= tol["fwd"],
+              f"the decode kernel disagrees with its XLA form: {row}")
 
 
 def dump_worker_logs(session_dir: str, tail: int = 3000) -> None:
@@ -452,7 +520,8 @@ def main() -> int:
         print(json.dumps(served), flush=True)
         trained = train_leg({
             "cfg": None, "batch": 16, "seq": 1024, "steps": 5,
-            "kernel_shapes": KERNEL_SHAPES, "kernel_dtype": "bfloat16",
+            "kernel_shapes": KERNEL_SHAPES, "decode_shapes": DECODE_SHAPES,
+            "kernel_dtype": "bfloat16",
             "interpret": False})
         check_on_chip(trained, BF16_TOL)
         print(json.dumps(trained), flush=True)

@@ -50,9 +50,25 @@ scan's carry:
 * ``slot_decode_step`` feeds each row one token: an attention layer
   ropes it at the row's own position, scatters its K/V into the carry
   at ``[layer, rows, pos]`` and attends the layer's K/V, read out of
-  the carry, under a per-row mask; a Mamba layer reads the rows' state
-  out of the carry, advances it by one position and writes it back (no
+  the carry after that write; a Mamba layer reads the rows' state out
+  of the carry, advances it by one position and writes it back (no
   recompute, no dynamic shapes).
+
+**Which runs attend through which form.** A run of full-attention
+layers attends through
+``ops.attention.decode_attention``: the run's whole K and V, as the
+carry holds them after the write, with the layer's index and the rows'
+``pos``. On the TPU that is the kernel ``decode_attend``, which fetches
+a row's K and V block by block up to the block that holds ``pos`` and
+nothing past it (no slice of a layer is made to feed it: the carry is
+its operand); elsewhere, for a shape the kernel has no block for and
+for a layer with a sink, it is ``cached_attention`` over the layer's slice under a per-row mask,
+which reads all ``max_len`` rows. A run of window layers is bounded by
+its ring and keeps ``cached_attention`` (with the layer's sink) on
+every platform. Both forms lean on the one invariant below: **a row
+past a slot's ``pos`` is never attended**, so a reused slot's stale
+tail and an idle row's garbage stay unread; the kernel does not even
+fetch them, and zeroes what its last block holds of them.
 
 **The picked token stays on the device.** ``cache["tok"]``, int32
 [slots], is each row's last pick (:func:`pick`, the one place a token
@@ -133,7 +149,8 @@ from ray_tpu.models.transformer import (EXPERTS, FROM_THE_START, MAMBA,
                                         no_rotation, roped_kinds, scan_run,
                                         unembed)
 from ray_tpu.ops import ssm
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import (cached_attention, decode_attention,
+                                   decode_rows_fetched, flash_attention)
 from ray_tpu.ops.rotary import apply_rotary, rotate
 
 
@@ -236,6 +253,23 @@ def _max_len(cfg: TransformerConfig, runs, states) -> int:
     holds (``cfg.max_seq`` where no layer keeps all its rows)."""
     return next((ck.shape[2] for ((mixer, _), _), (ck, _) in zip(runs, states)
                  if mixer not in (WINDOW, MAMBA)), cfg.max_seq)
+
+
+def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
+    """How many positions of a slot a full-attention layer of
+    ``slot_decode_step`` fetches at a time from this cache: the block
+    of ``ops.attention.decode_attention``'s kernel, all ``max_len``
+    where its XLA form runs, None for a model without such a layer. A
+    slot stepped at position p reads ``(p // n + 1) * n`` of its rows a
+    layer, which is what ``JaxSlotEngine`` counts from its host mirror."""
+    runs = layer_runs(cfg)
+    for ((mixer, _), _), (ck, cv) in zip(runs, _cache_runs(cache, runs)):
+        if mixer not in (WINDOW, MAMBA):
+            q = jax.ShapeDtypeStruct(
+                (ck.shape[1], cfg.n_heads, cfg.head_dim), ck.dtype)
+            return decode_rows_fetched(q, ck, cv,
+                                       sink=mixer in cfg.sink_kinds)
+    return None
 
 
 def _cache_rows(t, like, window: Optional[int]):
@@ -348,8 +382,10 @@ def slot_prefill(params, tokens, cache: Dict, slot,
 def slot_decode_step(params, cache: Dict, token, active,
                      cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
     """One continuous-batching step: each ACTIVE row is fed one token,
-    attends its own prefix (per-row position mask; in a window layer the
-    last ``window`` positions of it) and advances its Mamba layers'
+    attends its own prefix (positions ``[0, pos]``, and of the cache no
+    row past them: ``ops.attention.decode_attention``; in a window
+    layer the last ``window`` positions of it, under a mask) and
+    advances its Mamba layers'
     state by it, advances its own pos and picks its next token into
     ``cache["tok"]``. Inactive rows are free riders — their logits are
     garbage, their pos, tok and Mamba state frozen. ``token`` int32 [B]
@@ -376,17 +412,15 @@ def slot_decode_step(params, cache: Dict, token, active,
     states = _cache_runs(cache, runs)
     max_len = _max_len(cfg, runs, states)
     pos = cache["pos"]  # [B]
-    kinds = list(dict.fromkeys(mixer for (mixer, _), _ in runs
-                               if mixer != MAMBA))
     tables = {a: kind_rope(cfg, a, max_len) for a in roped_kinds(cfg, runs)}
     x = params["embed"][token][:, None, :]  # [B, 1, D]
     sm_scale = cfg.head_dim ** -0.5
     # row r attends positions [0, pos[r]] (pos[r] is written this
-    # step); of a ring, the rows filled so far, all once pos[r] has
-    # passed the window
-    valid = {a: (jnp.arange(cfg.window if a == WINDOW else max_len)
-                 [None, None, :] <= pos[:, None, None])  # [B, 1, rows]
-             for a in kinds}
+    # step): a full-attention run hands ``decode_attention`` the
+    # positions themselves; of a ring, the rows filled so far, all once
+    # pos[r] has passed the window
+    filled = jnp.arange(cfg.window or 0)[None, None, :] \
+        <= pos[:, None, None]                               # [B, 1, rows]
     rows = jnp.arange(B)
 
     def attention_run(x, load, layers, ck, cv, attention):
@@ -410,11 +444,20 @@ def slot_decode_step(params, cache: Dict, token, active,
                     k[:, 0].reshape((B,) + ck.shape[3:]).astype(ck.dtype))
                 nv = cv.at[i, rows, at].set(
                     v[:, 0].reshape((B,) + cv.shape[3:]).astype(cv.dtype))
-                lk = lax.dynamic_index_in_dim(nk, i, keepdims=False)
-                lv = lax.dynamic_index_in_dim(nv, i, keepdims=False)
                 with jax.named_scope(f"{attention}_attention"):
-                    return _attend_cached(q[:, 0], lk, lv, valid[attention],
-                                          sm_scale, lp.get("sink")), (nk, nv)
+                    if window is None:
+                        # the carry itself is the operand: no slice of
+                        # a layer feeds the kernel
+                        o = decode_attention(
+                            q[:, 0], nk, nv, i, pos, sm_scale=sm_scale,
+                            sink=lp.get("sink"))
+                    else:
+                        o = cached_attention(
+                            q[:, 0],
+                            lax.dynamic_index_in_dim(nk, i, keepdims=False),
+                            lax.dynamic_index_in_dim(nv, i, keepdims=False),
+                            filled, sm_scale, lp.get("sink"))
+                return o, (nk, nv)
 
             x, (ck, cv), got = block(lp, x, rope, attend, cfg)
             return x, ck, cv, _tally(load, got)
@@ -469,49 +512,6 @@ def slot_decode_step(params, cache: Dict, token, active,
     if not served:
         return logits, cache
     return (tok if load is None else jnp.concatenate([tok, load])), cache
-
-
-def _attend_cached(q, lk, lv, valid, sm_scale, sink=None):
-    """One new token a row against a layer's cached K/V: q [B, H, Dh],
-    ``valid`` [B, 1, rows] the rows each batch row may attend, ``sink``
-    [H] one more logit a head in the denominator. lk [B, rows, H, Dh]
-    and lv [B, rows, H, Dv] where every query head has its own K/V
-    head. Where G K/V heads serve H / G query heads each the rows are
-    flat, lk [B, rows, G * Dh] and lv [B, rows, G * Dv]: each query is
-    widened to a whole row, zero outside its own K/V head's part, so
-    that both products are plain ones over rows as they lie in memory
-    (G times the multiplications of the heads taken apart, which stay
-    under the time the rows take to read), and the output keeps its own
-    head's part. Accumulation dtypes as ops.attention's: softmax fp32,
-    p cast to the value dtype, p@v accumulated in fp32."""
-    B, H, D = q.shape
-    grouped = lk.ndim == 3
-    if grouped:
-        G = lk.shape[2] // D
-        own = (jnp.arange(H)[:, None] // (H // G)
-               == jnp.arange(G)[None, :])[None, :, :, None]   # [1, H, G, 1]
-        wide = jnp.where(own, q[:, :, None, :], 0).reshape(B, H, G * D)
-        s = jnp.einsum("bhc,bkc->bhk", wide, lk,
-                       preferred_element_type=jnp.float32) * sm_scale
-    else:
-        s = jnp.einsum("bhd,bkhd->bhk", q, lk,
-                       preferred_element_type=jnp.float32) * sm_scale
-    s = jnp.where(valid, s, -jnp.inf)
-    if sink is None:
-        p = jax.nn.softmax(s, axis=-1).astype(lv.dtype)
-    else:
-        column = jnp.broadcast_to(
-            sink.astype(jnp.float32)[None, :, None], (B, H, 1))
-        p = jax.nn.softmax(jnp.concatenate([s, column], axis=-1),
-                           axis=-1)[..., :-1].astype(lv.dtype)
-    if grouped:
-        o = jnp.einsum("bhk,bkc->bhc", p, lv,
-                       preferred_element_type=jnp.float32)
-        o = jnp.sum(jnp.where(own, o.reshape(B, H, G, -1), 0), axis=2)
-    else:
-        o = jnp.einsum("bhk,bkhd->bhd", p, lv,
-                       preferred_element_type=jnp.float32)
-    return o.astype(q.dtype)
 
 
 @functools.partial(jax.jit,

@@ -33,6 +33,12 @@ softmax's denominator and has no value row. The backward kernels know
 none of these: a gradient through such a call recomputes ``attention``
 (the XLA form) and differentiates that.
 
+A decode step has a kernel of its own, ``decode_attention`` (at the
+end of this file): one new token a slot against a run's whole cache,
+each slot's K and V read block by block up to its own position and no
+further; ``cached_attention`` is its XLA form and oracle, and the form
+of a window layer's ring.
+
 Reference-parity note: the reference snapshot has no attention kernels
 at all (SURVEY.md §5.7 — absent); this op underpins the TPU-native
 long-context capability layered on the runtime.
@@ -41,6 +47,7 @@ long-context capability layered on the runtime.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -179,6 +186,26 @@ def _scratch_lanes(block_k):
     return block_k if block_k % _LANES else _LANES
 
 
+def _softmax_step(s, v, m_ref, l_ref, acc_ref):
+    """One k-block of the online softmax: the score tile ``s`` [bq, bk]
+    (float32, masked) and its values ``v`` [bk, Dv] folded into the
+    running max ``m_ref`` [bq, w], denominator ``l_ref`` [bq, w] and
+    numerator ``acc_ref`` [bq, Dv] (``_flash_kernel`` says how each is
+    kept). p is cast to v's dtype, the product accumulates in float32."""
+    block_k, w = s.shape[1], m_ref.shape[1]
+    m_prev = m_ref[...]                           # [bq, w]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)               # rescale old state
+    p = jnp.exp(s - _lanes(m_new, block_k))       # [bq, bk]
+    l_ref[...] = alpha * l_ref[...] + sum(
+        p[:, c:c + w] for c in range(0, block_k, w))
+    acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[1]) \
+        + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, block_q,
                   block_k, num_k, window=None, has_sink=False):
     """One (b, h, qi, ki) grid step of online-softmax attention.
@@ -211,7 +238,6 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, block_q,
     qi, ki = pl.program_id(2), pl.program_id(3)
     kb = ki if window is None else ki + _first_k_block(
         qi, block_q, block_k, window)       # the k-block of this step
-    w = m_ref.shape[1]
 
     @pl.when(ki == 0)
     def _init():
@@ -227,18 +253,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, block_q,
         if masked:
             s = _causal_mask(s, qi * block_q, kb * block_k, q_axis=0,
                              window=window)
-
-        m_prev = m_ref[...]                           # [bq, w]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)               # rescale old state
-        p = jnp.exp(s - _lanes(m_new, block_k))       # [bq, bk]
-        l_ref[...] = alpha * l_ref[...] + sum(
-            p[:, c:c + w] for c in range(0, block_k, w))
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[1]) \
-            + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _softmax_step(s, v, m_ref, l_ref, acc_ref)
 
     # Causal: blocks strictly above the diagonal contribute nothing;
     # under a window, nor do those wholly before the band.
@@ -614,3 +629,323 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return _flash(q, k, v, causal, sm_scale, blocks, interpret)
     return _flash_forward_only(q, k, v, sink, causal, sm_scale, window,
                                blocks[0], interpret)
+
+
+# ------------------------------------------------ one new token a row
+
+DECODE_KERNEL = "decode_attend"     # the pallas_call's ``name=``
+# the most K and V, in bytes, that one grid step of it fetches
+_DECODE_STEP_BYTES = 2 * 2 ** 20
+
+
+def _widen(q, G):
+    """Queries q [B, H, D] of H heads on G K/V heads, each as a whole
+    flat row [G * D] that is zero outside its own K/V head's part, and
+    ``own`` [1, H, G, 1], which part that is."""
+    B, H, D = q.shape
+    own = (jnp.arange(H)[:, None] // (H // G)
+           == jnp.arange(G)[None, :])[None, :, :, None]
+    return jnp.where(own, q[:, :, None, :], 0).reshape(B, H, G * D), own
+
+
+def _own_part(o, own):
+    """o [B, H, G * Dv], a product with whole flat rows, cut to each
+    head's own K/V head's part [B, H, Dv]."""
+    B, H, G = o.shape[0], own.shape[1], own.shape[2]
+    return jnp.sum(jnp.where(own, o.reshape(B, H, G, -1), 0), axis=2)
+
+
+def cached_attention(q, lk, lv, valid, sm_scale, sink=None):
+    """One new token a row against a layer's cached K/V, in XLA: q
+    [B, H, Dh], ``valid`` [B, 1, rows] the rows each batch row may
+    attend, ``sink`` [H] one more logit a head in the denominator. lk
+    [B, rows, H, Dh] and lv [B, rows, H, Dv] where every query head has
+    its own K/V head. Where G K/V heads serve H / G query heads each
+    the rows are flat, lk [B, rows, G * Dh] and lv [B, rows, G * Dv]:
+    each query is widened to a whole row, zero outside its own K/V
+    head's part, so that both products are plain ones over rows as they
+    lie in memory (G times the multiplications of the heads taken
+    apart, which stay under the time the rows take to read), and the
+    output keeps its own head's part. Accumulation dtypes as
+    ``attention``'s: softmax fp32, p cast to the value dtype, p@v
+    accumulated in fp32.
+
+    Every row of lk and lv is read, whatever ``valid`` says, and a row
+    that is not valid weighs exactly 0: what lies there must be finite
+    (0 times NaN is NaN), which a cache that is only ever written with
+    K and V is. This is the form of a window run's ring (bounded by the
+    window, with its sink) on every platform, of a full-attention run
+    wherever ``decode_attention`` does not take the kernel, and the
+    kernel's oracle."""
+    B, H, D = q.shape
+    grouped = lk.ndim == 3
+    if grouped:
+        wide, own = _widen(q, lk.shape[2] // D)
+        s = jnp.einsum("bhc,bkc->bhk", wide, lk,
+                       preferred_element_type=jnp.float32) * sm_scale
+    else:
+        s = jnp.einsum("bhd,bkhd->bhk", q, lk,
+                       preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(valid, s, -jnp.inf)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1).astype(lv.dtype)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None], (B, H, 1))
+        p = jax.nn.softmax(jnp.concatenate([s, column], axis=-1),
+                           axis=-1)[..., :-1].astype(lv.dtype)
+    if grouped:
+        o = _own_part(jnp.einsum("bhk,bkc->bhc", p, lv,
+                                 preferred_element_type=jnp.float32), own)
+    else:
+        o = jnp.einsum("bhk,bkhd->bhd", p, lv,
+                       preferred_element_type=jnp.float32)
+    return o.astype(q.dtype)
+
+
+def _decode_kernel(layer_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
+                   l_ref, acc_ref, *, sm_scale, block_k, group, stride,
+                   num_k):
+    """One (group of slots g, k-block j) grid step of a decode step's
+    attention over the carried cache. Prefetched: layer_ref [1], the
+    layer of the run (the index maps' business), and pos_ref [slots],
+    the position each slot's new token was written at.
+
+    q_ref [group, H, C]; k_ref [group, n, C] and v_ref [group, n, Cv],
+    block j of each slot's rows as they lie, n = block_k * stride:
+    ``stride`` 1 for flat rows (C the whole row, q widened to it), H
+    where a position is H rows of one head each (column c of the scores
+    is then position c // H of head c % H, and a query head keeps its
+    own head's columns); o_ref [group, H, Cv]. Scratch as the forward
+    kernel's, a slot each: m_ref and l_ref [group, H, w], acc_ref
+    [group, H, Cv]. A slot's block past its position is skipped (and
+    was not fetched: the index map named the last live one again); its
+    last live block masks the columns past the position AND zeroes the
+    value rows there, so that nothing a stale tail holds, NaN included,
+    reaches the output."""
+    import jax.experimental.pallas as pl
+
+    g, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    for r in range(group):
+        pos = pos_ref[g * group + r]
+
+        def _tile(edge, r=r, pos=pos):
+            q, k, v = q_ref[r], k_ref[r], v_ref[r]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [H, n]
+            column = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            keep = None
+            if stride > 1:
+                keep = column % stride == jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0)
+            if edge:
+                # rows of this block at or before the position
+                live = (pos + 1 - j * block_k) * stride
+                keep = column < live if keep is None \
+                    else keep & (column < live)
+                v = jnp.where(jax.lax.broadcasted_iota(
+                    jnp.int32, (v.shape[0], 1), 0) < live, v,
+                    jnp.zeros_like(v))
+            if keep is not None:
+                s = jnp.where(keep, s, _NEG_INF)
+            _softmax_step(s, v, m_ref.at[r], l_ref.at[r], acc_ref.at[r])
+
+        pl.when(j < pos // block_k)(functools.partial(_tile, False))
+        pl.when(j == pos // block_k)(functools.partial(_tile, True))
+
+    @pl.when(j == num_k - 1)
+    def _finish():
+        for r in range(group):
+            l = jnp.sum(l_ref[r], axis=-1, keepdims=True)
+            o_ref[r] = (acc_ref[r] / l).astype(o_ref.dtype)
+
+
+def _decode_forward(q, k, v, layer, pos, sm_scale, block_k, group, stride,
+                    interpret):
+    """q [B, H, C]; k [L, B, N, C] and v [L, B, N, Cv], the run's whole
+    arrays, N = rows * stride; returns [B, H, Cv] at q's dtype."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    B, H, C = q.shape
+    N, Cv = k.shape[2], v.shape[3]
+    n = block_k * stride
+    num_k = N // n
+
+    def kv_index(g, j, layer_ref, pos_ref):
+        # a step past the last live block of the group's slots names
+        # that block again: the pipeline issues no copy for it
+        last = pos_ref[g * group] // block_k
+        for r in range(1, group):
+            last = jnp.maximum(last, pos_ref[g * group + r] // block_k)
+        return (layer_ref[0], g, jnp.minimum(j, last), 0)
+
+    def q_index(g, j, layer_ref, pos_ref):
+        return (g, 0, 0)
+
+    w = _scratch_lanes(n)
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, sm_scale=sm_scale,
+                          block_k=block_k, group=group, stride=stride,
+                          num_k=num_k),
+        out_shape=jax.ShapeDtypeStruct((B, H, Cv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B // group, num_k),   # j innermost: scratch carries
+            in_specs=[pl.BlockSpec((group, H, C), q_index),
+                      pl.BlockSpec((None, group, n, C), kv_index),
+                      pl.BlockSpec((None, group, n, Cv), kv_index)],
+            out_specs=pl.BlockSpec((group, H, Cv), q_index),
+            scratch_shapes=[pltpu.VMEM((group, H, w), jnp.float32),
+                            pltpu.VMEM((group, H, w), jnp.float32),
+                            pltpu.VMEM((group, H, Cv), jnp.float32)]),
+        interpret=interpret,
+        name=DECODE_KERNEL,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos.astype(jnp.int32),
+      q, k, v)
+
+
+def decode_blocks(rows: int, row_bytes: int,
+                  slots: int) -> tuple[int, int] | None:
+    """(positions in a block, slots in a grid step) of ``decode_attend``
+    for a cache of ``slots`` x ``rows`` positions whose K and V together
+    take ``row_bytes`` a position, or None where the kernel has no
+    block for the shape (``rows`` no multiple of 128).
+
+    A block is the largest multiple of 128 that divides ``rows``, is at
+    most a quarter of them (what a slot skips, it skips by whole
+    blocks) and keeps a grid step's K and V under ``_DECODE_STEP_BYTES``
+    (double-buffered, they live in the scoped VMEM beside the scores: 8
+    MB a step no longer fit its 16); where a block is far smaller than
+    that (narrow rows), up to 8 slots share a grid step, which then
+    skips by the longest of them.
+
+    Swept on a TPU v5e in bfloat16, microseconds a layer against the
+    XLA form's, slots at the serving cells' positions (PERF.md section
+    6, PR 37). The kernel's time is its copies plus half a microsecond
+    a grid step, fetching or not, so small blocks lose to the grid and
+    large ones to the rows fetched past a position. 8 slots x 1024 rows
+    of 8 KB (16 x 128 heads, K and V) at positions 128-448: 61, 64, 77
+    at blocks of 128, 256, 512 against 129. 128 x 3200 of 2.5 KB at
+    512-3072: 1,642 and 1,203 at 128 and 640 (1,277 with 2 slots a
+    step) against 1,826. 256 x 2048 of 512 bytes at 128-1536: 507 at
+    (512, 1), 377 at (512, 8), 372 at (1024, 4), 378 with the whole row
+    a step, against 380: rows that narrow gain nothing and, with their
+    slots grouped, lose nothing, so they keep the one kernel."""
+    fit = [b for b in range(_BLOCK, rows + 1, _BLOCK) if rows % b == 0]
+    if not fit:
+        return None
+    room = [b for b in fit if b <= max(_BLOCK, rows // 4)
+            and b * row_bytes <= _DECODE_STEP_BYTES]
+    block_k = max(room, default=fit[0])
+    group = max(g for g in (1, 2, 4, 8) if slots % g == 0 and (
+        g == 1 or g * block_k * row_bytes <= _DECODE_STEP_BYTES))
+    return block_k, group
+
+
+def _decode_plan(q, k, v, block_k, group, interpret, sink=False):
+    """(block_k, group, stride) of the kernel for these operands, or
+    None where the XLA form runs (``decode_attention`` says when)."""
+    rows, H = k.shape[2], q.shape[1]
+    stride = 1 if k.ndim == 4 else H
+    if sink or not (interpret or _on_tpu()):
+        return None
+    if block_k is None:
+        row_bytes = k.dtype.itemsize * (
+            math.prod(k.shape[3:]) + math.prod(v.shape[3:]))
+        blocks = decode_blocks(rows, row_bytes, k.shape[1])
+        if blocks is None and not interpret:
+            return None
+        block_k, group = blocks or (rows, 1)
+    if interpret:   # exercises the kernel at any size: no Mosaic tiling
+        block_k = min(block_k, rows)
+    else:
+        tile = 8 * 4 // k.dtype.itemsize    # rows of a tile in memory
+        if (block_k % _BLOCK or k.shape[-1] % _LANES or v.shape[-1] % _LANES
+                or (stride > 1 and (stride % tile or stride & (stride - 1)))):
+            return None
+    if rows % block_k or k.shape[1] % group:
+        return None
+    return block_k, group, stride
+
+
+def decode_rows_fetched(q, k, v, *, sink: bool = False,
+                        interpret: bool = False) -> int:
+    """How many positions of a slot ``decode_attention`` fetches at a
+    time for these operands (arrays or their shapes' structs; ``sink``
+    whether it is given one): the kernel's block, or all ``rows`` where
+    the XLA form runs. A slot at position p costs ``(p // n + 1) * n``
+    of them a layer."""
+    plan = _decode_plan(q, k, v, None, None, interpret, sink)
+    return k.shape[2] if plan is None else plan[0]
+
+
+def decode_attention(q, k, v, layer, pos, *, sm_scale: float | None = None,
+                     sink=None, block_k: int | None = None,
+                     rows_per_step: int | None = None,
+                     interpret: bool = False):
+    """A decode step's attention for a run of full-attention layers,
+    over the run's cache where it lies: one new token a slot, q
+    [B, H, D], against layer ``layer`` (a traced index) of k
+    [L, B, rows, H, D] and v [L, B, rows, H, Dv], or of the flat k
+    [L, B, rows, G * D] and v [L, B, rows, G * Dv] where G K/V heads
+    serve H / G query heads each; slot b attends positions
+    ``[0, pos[b]]`` (its new token is in the cache already) and nothing
+    past them; ``sink`` [H] is one more logit a head, in the
+    denominator only. Returns [B, H, Dv] at q's dtype. Softmax in
+    float32, p cast to v's dtype, p@v accumulated in float32, as
+    ``attention``.
+
+    Two forms behind the one name, as ``flash_attention`` has. On the
+    TPU (or under ``interpret``) the kernel ``decode_attend``: k and v
+    are its operands whole, ``layer`` and ``pos`` prefetched scalars,
+    and a slot's K and V come in blocks of positions **up to the block
+    that holds ``pos[b]`` and no further**: what lies past a slot's
+    position, in a skipped block or in the tail of its last live one,
+    is neither attended nor able to reach the output (NaN included).
+    Rows are taken as they lie: flat ones with q widened to a whole row
+    (``cached_attention``), [H, D] ones as H rows a position of which a
+    query head keeps its own, so both products are plain matrix
+    products of [H, C] with a block. Blocks come from the shape
+    (``decode_blocks``); explicit ``block_k`` and ``rows_per_step``
+    (both) win.
+
+    The XLA form, ``cached_attention`` over the layer's slice under a
+    mask: off the TPU, with a ``sink``, and on the TPU where the shape
+    has no block (``rows`` no multiple of 128, a row's width no multiple
+    of 128 lanes, [H, D] rows whose H is no power of two of whole
+    tiles). It reads all
+    ``rows`` of every slot. ``decode_rows_fetched`` says which a shape
+    gets."""
+    B, H, D = q.shape
+    sm_scale = sm_scale if sm_scale is not None else D ** -0.5
+    if (block_k is None) != (rows_per_step is None):
+        raise ValueError("give both block_k and rows_per_step, or neither")
+    plan = _decode_plan(q, k, v, block_k, rows_per_step, interpret,
+                        sink is not None)
+    if plan is None:
+        lk = jax.lax.dynamic_index_in_dim(k, layer, keepdims=False)
+        lv = jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+        valid = jnp.arange(k.shape[2])[None, None, :] <= pos[:, None, None]
+        return cached_attention(q, lk, lv, valid, sm_scale, sink)
+    block_k, group, stride = plan
+    own = None
+    if stride == 1:
+        q, own = _widen(q, k.shape[3] // D)
+    else:   # a position's H rows follow one another: a bitcast
+        k = k.reshape(k.shape[:2] + (-1, D))
+        v = v.reshape(v.shape[:2] + (-1, v.shape[-1]))
+    # whole tiles of query rows (a padded head's output is dropped)
+    padded = -H % (8 * 4 // q.dtype.itemsize)
+    q = jnp.pad(q, ((0, 0), (0, padded), (0, 0)))
+    o = _decode_forward(q, k, v, layer, pos, sm_scale, block_k, group,
+                        stride, interpret)[:, :H]
+    return o if own is None else _own_part(o, own)

@@ -74,7 +74,14 @@ name, to be read as deltas:
   ``serve.engine.ahead`` (1 for a call answered from
   a step already in flight, 0 for one that had to dispatch and wait)
   and ``serve.engine.rows_wasted`` (rows stepped that no request was
-  owed: a finished request's one step more). Of a model with expert
+  owed: a finished request's one step more). From the host mirror of
+  the slots' positions alone, once a dispatched step:
+  ``serve.engine.kv_rows_read`` (over the slots it steps, the cache
+  rows a full-attention layer fetches for them: whole blocks up to the
+  one written to, the block ``ops.attention.decode_attention`` takes
+  for the cache's shape; all ``max_len`` where its XLA form runs) and
+  ``serve.engine.kv_rows_held`` (stepped slots x ``max_len``): their
+  ratio is how far the bounded read engages. Of a model with expert
   layers the same fetch brings three counts of the step it fetched
   (wasted rows included), each summed over its expert layers:
   ``serve.engine.experts_hit`` (held experts that got a row),
@@ -444,10 +451,10 @@ class JaxSlotEngine:
     dispatch. ``step``'s phases: ``check`` reads the mirror and builds
     the row that steers the step (host only), ``put`` sends that one
     int32 row, ``wait`` fetches the row of picks of the step before,
-    ``read`` builds the dict from that host array; ``serve.engine.ahead``
-    and ``serve.engine.rows_wasted`` count beside them (module
-    docstring). No ``block_until_ready``: the fetch waits for the
-    device.
+    ``read`` builds the dict from that host array; ``serve.engine.ahead``,
+    ``.rows_wasted``, ``.kv_rows_read`` and ``.kv_rows_held`` count
+    beside them (module docstring). No ``block_until_ready``: the fetch
+    waits for the device.
 
     The cache is one device buffer for the engine's life: both programs
     take it donated and return it written in place, so the step in
@@ -474,6 +481,9 @@ class JaxSlotEngine:
         self.slots = int(slots)
         self.max_len = int(max_len)
         self._start_over()
+        # positions a full-attention layer fetches at a time for a slot
+        # (models/decode.py; None: the model has no such layer)
+        self._kv_block = decode_mod.kv_rows_fetched(cfg, self._cache)
 
     def _start_over(self) -> None:
         self._cache = self._flight = None   # a result half made goes first
@@ -566,6 +576,11 @@ class JaxSlotEngine:
             self._params, self._cache, fed, None, self._cfg)
         rode = {slot for slot, t in enumerate(steer)
                 if t != self._decode.IDLE}
+        if self._kv_block:  # whole blocks, up to the one written to
+            n = self._kv_block
+            phase_add("serve.engine.kv_rows_read",
+                      sum((self._pos[slot] // n + 1) * n for slot in rode))
+            phase_add("serve.engine.kv_rows_held", len(rode) * self.max_len)
         for slot in rode:
             self._pos[slot] += 1
         return _Flight(row, rode, len(rode))

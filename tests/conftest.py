@@ -50,3 +50,25 @@ def ray_start_shared():
     info = ray_tpu.init(num_cpus=2)
     yield info
     ray_tpu.shutdown()
+
+
+@pytest.fixture
+def decode_kernel_interpreted(monkeypatch):
+    """``models.decode`` takes ``ops.attention``'s decode kernel under
+    ``interpret`` for its full-attention runs (off the TPU it would
+    take the XLA form): ``slot_decode_step`` traces anew inside the
+    test, and again after it."""
+    import functools
+    import importlib
+
+    from ray_tpu.models import decode
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    for name in ("decode_attention", "decode_rows_fetched"):
+        monkeypatch.setattr(decode, name, functools.partial(
+            getattr(attention, name), interpret=True))
+    decode.slot_decode_step.clear_cache()
+    decode._decode_loop.clear_cache()
+    yield
+    decode.slot_decode_step.clear_cache()
+    decode._decode_loop.clear_cache()

@@ -10,7 +10,11 @@ in a device trace.
 The serving cell's two programs are compiled the same way, at the
 cell's own shapes, and held to what keeps the slot cache one buffer:
 the cache aliased to the result, no temporary the size of a layer's K,
-and no operation that produces K or V but the in-place writes.
+and no operation that produces K or V but the in-place writes. A
+decode step names one Mosaic call ``decode_attend`` a run of
+full-attention layers (none for a window run), which reads the carried
+cache where it lies; that kernel alone is compiled at each serving
+cell's decode shape, at the blocks ``decode_blocks`` gives it.
 
 The second serving cell's programs (``mimo-v2-flash-ep16-d7``: layers of
 several kinds, a cache allocated by kind, a chip's share of the
@@ -40,6 +44,7 @@ import pytest
 
 KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 SCAN_KERNEL = "ssm_scan"
+DECODE_KERNEL = "decode_attend"
 # B, T, H, Dh, the dtype, and whether the gradient is compiled too
 SHAPES = {
     # ouro-2.6b-d12.train-2k
@@ -98,7 +103,7 @@ def mosaic_calls(compiled_text):
     names = re.findall(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
         compiled_text)
-    return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL,)) or n
+    return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL, DECODE_KERNEL)) or n
                   for n in names)
 
 
@@ -138,6 +143,56 @@ def test_flash_kernels_compile_for_v5e_under_their_own_names(
         arg, arg, arg).compile().as_text()
     # the forward, remat's second forward, and the backward's two
     assert mosaic_calls(both) == sorted(KERNELS + ("flash_fwd",))
+
+
+# the fixture of each serving cell, and the block of positions that
+# ``decode_blocks`` gives its full-attention runs' cache
+DECODE_BLOCKS = {"serving_cell": 256, "kinds_cell": 640, "hybrid_cell": 512}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_BLOCKS))
+def test_the_decode_kernel_compiles_for_v5e_under_its_own_name(
+        cell, request, one_chip, no_compile_cache, monkeypatch):
+    """``decode_attend`` alone at a serving cell's decode shape, the
+    first full-attention run of the cache the cell allocates (rows as
+    it holds them: [H, Dh] a position for Ouro, flat where K/V heads
+    are shared), at the blocks ``decode_blocks`` chooses for it: Mosaic
+    accepts them (a table that overflows the scoped VMEM fails here),
+    the run's K and V reach the call as they lie (no operation produces
+    an array of their shape, no temporary has room for one), and the
+    program names its one custom call ``decode_attend``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode
+    from ray_tpu.models.transformer import layer_runs
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg, slots, slot_len, _ = request.getfixturevalue(cell)
+    cache = jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len))
+    assert decode.kv_rows_fetched(cfg, cache) == DECODE_BLOCKS[cell]
+    run = next(r for r, ((mixer, _), _) in enumerate(layer_runs(cfg))
+               if mixer == "full")
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    k, v = (array(cache[name][run].shape, cfg.dtype) for name in "kv")
+    q = array((slots, cfg.n_heads, cfg.head_dim), cfg.dtype)
+    compiled = jax.jit(attention.decode_attention).lower(
+        q, k, v, array((), jnp.int32), array((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "%decode_attend.1 = " in text
+    assert mosaic_calls(text) == [DECODE_KERNEL]
+    assert not cache_producers(text, {k.shape, v.shape, k.shape[1:],
+                                      v.shape[1:]})
+    # the queries (widened to a flat row of G heads, or padded to whole
+    # tiles) and the output, and nothing of the cache's size
+    G = cfg.kv_heads("full")
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 8 * math.prod(q.shape) * (1 if G == cfg.n_heads else G)
 
 
 # ------------------------------------- the slot cache, written in place
@@ -310,8 +365,11 @@ def test_the_slot_cache_is_one_buffer_written_in_place(
     produced = cache_producers(text, {layer, whole})
     assert len(produced) == 2, produced         # K's write and V's
     assert {op for _, op in produced} <= set(IN_PLACE), produced
-    if program != "decode":
-        assert mosaic_calls(text) == ["flash_fwd"]
+    # the one run's kernel: a prefill's forward, a decode step's
+    # attention over the carried cache (its K and V operands are the
+    # in-place writes' results under another shape's name: bitcasts)
+    assert mosaic_calls(text) == (
+        [DECODE_KERNEL] if program == "decode" else ["flash_fwd"])
 
 
 # --------------------- layers of several kinds, a cache by layer kind
@@ -435,8 +493,10 @@ def test_a_cache_by_layer_kind_is_still_written_in_place(
                 if op not in ("bitcast", "copy-start", "copy-done")]
     assert {op for _, op in produced} <= set(IN_PLACE), produced
     assert len(produced) == 8, produced     # K's and V's write, a run
-    if program != "decode":
-        assert mosaic_calls(text) == ["flash_fwd"] * 4
+    # a kernel a run of layers in a prefill; in a decode step one for
+    # each of the two full-attention runs and none for a window run
+    assert mosaic_calls(text) == (
+        [DECODE_KERNEL] * 2 if program == "decode" else ["flash_fwd"] * 4)
 
 
 @pytest.mark.parametrize("cell", [SERVING_CELL, KINDS_CELL])
@@ -491,17 +551,19 @@ def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
 # the device (``cache["tok"]``, the pick inside both programs, a decode
 # step steered by one int32 row and returning the row of picks): those
 # three are on record anew, and the second serving cell's four beside
-# them. Left out: the Mosaic kernels' serialized bodies, which hold the
+# them; the three cells' ``decode`` once more since a full-attention
+# run attends through ``ops.attention.decode_attention`` (every prefill
+# and ``train`` as they were). Left out: the Mosaic kernels' serialized bodies, which hold the
 # line numbers of ops/attention.py, and the results' labels, which name
 # the cache's place in the result's tree
 LOWERED = {
-    "decode": "2fc4dddef25cce21",
+    "decode": "5db38cff621b059f",
     "prefill-128": "73d2ed7d30dcc38c",
     "prefill-256": "dbd7cc3a5ea8d517",
     "train": "4ee3f3d6f707d1e7",
 }
 LOWERED_KINDS = {
-    "decode": "8eaabf43f891f8f9",
+    "decode": "bf26281680591a94",
     "prefill-512": "8bfb8e890cd61412",
     "prefill-1024": "486ec1e4b8f1528b",
     "prefill-2048": "282e4d2871f6e016",
@@ -620,7 +682,7 @@ def test_ouros_three_programs_lower_to_the_text_on_record(
 
 HYBRID_CELL = "jamba2-3b.rollout-closed"
 LOWERED_HYBRID = {
-    "decode": "4e33907e2350d683",
+    "decode": "5be1f66249e1cb8e",
     "prefill-128": "9494bbbe99469780",
     "prefill-256": "d915f689d4473dd3",
     "prefill-512": "348935eec64c94c4",
@@ -777,8 +839,9 @@ def test_recurrent_state_beside_kv_is_still_written_in_place(
     assert {op for _, op in produced} <= set(IN_PLACE), produced
     # a write a run and kind of state
     assert len(produced) == 3 * 2 + 2 * 2, produced
-    if program != "decode":
-        assert mosaic_calls(text) == ["flash_fwd"] * 2 + [SCAN_KERNEL] * 3
+    assert mosaic_calls(text) == (
+        [DECODE_KERNEL] * 2 if program == "decode"
+        else ["flash_fwd"] * 2 + [SCAN_KERNEL] * 3)
 
 
 def test_the_third_cells_decode_step_hands_the_host_a_row_of_picks(

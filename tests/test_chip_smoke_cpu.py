@@ -52,8 +52,9 @@ def test_smoke_legs_compose_on_cpu(tpu_session, monkeypatch, tmp_path):
 
     trained = chip_smoke.train_leg({
         "cfg": TINY, "batch": 2, "seq": 32, "steps": 5,
-        "kernel_shapes": [(1, 32, 2, 16)], "kernel_dtype": "float32",
-        "interpret": True})
+        "kernel_shapes": [(1, 32, 2, 16)],
+        "decode_shapes": [(3, 4, 16, 16, 4, 256), (3, 8, 24, 16, 2, 256)],
+        "kernel_dtype": "float32", "interpret": True})
     assert trained["device"]["chips"] == "0"
     assert trained["device"]["pid"] != served["device"]["pid"]
     assert len(trained["losses"]) == 5
@@ -62,6 +63,11 @@ def test_smoke_legs_compose_on_cpu(tpu_session, monkeypatch, tmp_path):
     # above 0: the kernels ran (interpreted), not attention() against
     # itself
     assert row["finite"] and 0 < min(errors) and max(errors) < 1e-4
+    # the decode kernel ran (interpreted: blocks of 128 in 256 rows),
+    # each slot up to its own position and not into the NaN past it
+    for row in trained["decode_kernels"]:
+        assert row["block"] == 128 and row["finite"]
+        assert 0 < row["err"] < 1e-5
 
 
 def test_on_chip_checks_reject_a_cpu_run():

@@ -532,7 +532,7 @@ def test_a_raising_step_still_closes_and_counts_its_span():
 # ------------------------------------------------------------- jax oracle
 
 
-def _tiny_engine(slots, max_len):
+def _tiny_engine(slots, max_len, max_seq=64):
     """The real engine over a model of two layers whose head is its
     own, so that a greedy sequence wanders (a tied one repeats its last
     token, and a stale token would pass for the right one)."""
@@ -543,7 +543,7 @@ def _tiny_engine(slots, max_len):
     from ray_tpu.serve.decode_scheduler import JaxSlotEngine
 
     cfg = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
-                            d_ff=128, max_seq=64, dtype=jnp.float32,
+                            d_ff=128, max_seq=max_seq, dtype=jnp.float32,
                             tie_embeddings=False)
     return JaxSlotEngine(init_params(jax.random.key(0), cfg), cfg,
                          slots=slots, max_len=max_len)
@@ -637,12 +637,14 @@ def test_a_seeded_load_through_the_scheduler_equals_forward(seed):
     assert 0 < wasted <= sum(n > 1 for _, n in asks) + 1
 
 
-ENGINE_COUNTS = ("serve.engine.ahead", "serve.engine.rows_wasted")
+ENGINE_COUNTS = ("serve.engine.ahead", "serve.engine.rows_wasted",
+                 "serve.engine.kv_rows_read", "serve.engine.kv_rows_held")
 
 
 def test_engine_phases_cover_the_step():
     """Every step of the real engine records its five phases once (and
-    its two counts beside them), a prefill its seconds and its prompt's
+    its four counts beside them: off the TPU a full-attention layer
+    reads every row a stepped slot holds), a prefill its seconds and its prompt's
     length as two sums, and the step's phases are the step: their sum is 90 to 100 % of its wall
     time (the median step's, so that one stall of a shared box between
     two spans does not decide it)."""
@@ -671,9 +673,39 @@ def test_engine_phases_cover_the_step():
         assert [table[p][0] for p in ENGINE_STEP_PHASES] == [1] * 5
         assert table["serve.engine.ahead"] == [1, 1]
         assert table["serve.engine.rows_wasted"] == [1, 0]
+        assert table["serve.engine.kv_rows_read"] == [1, 2 * 32]
+        assert table["serve.engine.kv_rows_held"] == [1, 2 * 32]
         shares.append(sum(table[p][1] for p in ENGINE_STEP_PHASES) / wall)
     assert max(shares) <= 1.0
     assert statistics.median(shares) >= 0.9
+
+
+def test_the_engine_counts_the_rows_a_bounded_read_fetches(
+        decode_kernel_interpreted):
+    """Through the decode kernel (interpreted; a cache of 256 rows gets
+    blocks of 128) the engine's tokens are still forward()'s, and it
+    counts, from its host mirror alone, the rows a full-attention layer
+    fetches for the slots it steps (whole blocks up to the one written
+    to) beside the rows they hold."""
+    from ray_tpu.util.phases import recording
+
+    eng = _tiny_engine(slots=2, max_len=256, max_seq=256)
+    assert eng._kv_block == 128
+    prompts = {0: [5, 11, 23], 1: list(range(1, 127))}
+    want = {slot: _oracle(eng, p, 5) for slot, p in prompts.items()}
+    last = {slot: eng.prefill(slot, p) for slot, p in prompts.items()}
+    got = {slot: [t] for slot, t in last.items()}
+    with recording({}) as table:
+        for _ in range(4):
+            last = eng.step(last)
+            for slot, t in last.items():
+                got[slot].append(t)
+    assert got == want
+    # five dispatches (the first call makes two): slot 0 writes at 3..7,
+    # one block each; slot 1 at 126..130, of which 128.. reach a second
+    assert table["serve.engine.kv_rows_read"] == [
+        5, 5 * 128 + 2 * 128 + 3 * 256]
+    assert table["serve.engine.kv_rows_held"] == [5, 5 * 2 * 256]
 
 
 # --------------------------- one step in flight, one transfer a call

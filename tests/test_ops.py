@@ -255,15 +255,10 @@ _TINY = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(_TINY))
-def test_train_prefill_and_decode_are_one_body(name):
-    """``transformer.block`` under its three callers: ``slot_prefill``
-    of each of B prompts into its slot, then ``slot_decode_step`` with
-    every row active, against ``forward()`` on the growing prefix (no
-    cache code at all). At every step, the prefill's included, the
-    argmax is forward()'s exactly and the logits are forward()'s to
-    float tolerance (the same products summed in another order: a
-    one-row unembed, a cache read back)."""
+def _one_body(name, max_len):
+    """``slot_prefill`` of each of B prompts into its slot, then
+    ``slot_decode_step`` with every row active, against ``forward()``
+    on the growing prefix; returns the step's jaxpr."""
     from ray_tpu.models import TransformerConfig, forward, init_params
     from ray_tpu.models import decode
 
@@ -273,7 +268,7 @@ def test_train_prefill_and_decode_are_one_body(name):
     prompt = jax.random.randint(jax.random.key(1), (B, T0), 0, cfg.vocab)
     tol = 2e-5 if cfg.dtype == jnp.float32 else 3e-2
 
-    cache = decode.init_slot_cache(cfg, B, T0 + steps)
+    cache = decode.init_slot_cache(cfg, B, max_len or T0 + steps)
     rows = []
     for b in range(B):
         logits, cache = decode.slot_prefill(
@@ -296,6 +291,31 @@ def test_train_prefill_and_decode_are_one_body(name):
         full = forward(params, jnp.asarray(prefix), cfg)[:, -1]
     np.testing.assert_array_equal(np.asarray(cache["pos"]),
                                   [T0 + steps] * B)
+    return str(jax.make_jaxpr(
+        functools.partial(decode.slot_decode_step, cfg=cfg))(
+            params, cache, jnp.asarray(nxt, jnp.int32), active))
+
+
+@pytest.mark.parametrize("name", sorted(_TINY))
+def test_train_prefill_and_decode_are_one_body(name):
+    """``transformer.block`` under its three callers: ``slot_prefill``
+    of each of B prompts into its slot, then ``slot_decode_step`` with
+    every row active, against ``forward()`` on the growing prefix (no
+    cache code at all). At every step, the prefill's included, the
+    argmax is forward()'s exactly and the logits are forward()'s to
+    float tolerance (the same products summed in another order: a
+    one-row unembed, a cache read back). Off the TPU the step's
+    attention is the XLA form."""
+    assert "decode_attend" not in _one_body(name, None)
+
+
+@pytest.mark.parametrize("name", sorted(_TINY))
+def test_the_oracle_holds_through_the_decode_kernel(
+        name, decode_kernel_interpreted):
+    """The same, with the step's attention through the kernel
+    ``decode_attend`` (interpreted): a cache of 256 rows gets blocks of
+    128, of which each slot's first alone is live."""
+    assert "decode_attend" in _one_body(name, 256)
 
 
 @pytest.mark.parametrize("name", sorted(_TINY))
@@ -428,3 +448,176 @@ def test_kv_cached_decode_matches_full_forward():
         np.testing.assert_array_equal(toks16[:, t], nxt,
                                       err_msg=f"bf16 step {t}")
         prefix = np.concatenate([prefix, nxt[:, None]], axis=1)
+
+
+# ------------------------------ a decode step's attention: decode_attend
+
+# H query heads of D on G K/V heads, values Dv wide; a cache of ROWS
+# positions in blocks of BLOCK
+_DECODE_LAYOUTS = {
+    "mha-16x128": (16, 128, 128, 16),
+    "grouped-64on4-192-128": (64, 192, 128, 4),
+    "grouped-20on1-128": (20, 128, 128, 1),
+}
+_ROWS, _BLOCK = 64, 16
+_DECODE_POS = {
+    "first": [0, 0, 0, 0],
+    "short-of-an-edge": [_BLOCK - 1, 2 * _BLOCK - 1, 3 * _BLOCK - 1, 15],
+    "on-an-edge": [_BLOCK, 2 * _BLOCK, 3 * _BLOCK, _BLOCK],
+    "last": [_ROWS - 1] * 4,
+    "mixed": [0, _BLOCK - 1, _BLOCK, _ROWS - 1],
+}
+
+
+def _decode_case(layout, dtype, pos, layers=2, rows=_ROWS):
+    """q, the run's K and V poisoned past every slot's position (NaN,
+    +inf and -inf in turn), the same with zeros there, and ``valid``."""
+    H, D, Dv, G = _DECODE_LAYOUTS[layout]
+    B = len(pos)
+    keys = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(keys[0], (B, H, D), dtype)
+    row = (lambda w: (H, w)) if G == H else (lambda w: (G * w,))
+    k = jax.random.normal(keys[1], (layers, B, rows) + row(D), dtype)
+    v = jax.random.normal(keys[2], (layers, B, rows) + row(Dv), dtype)
+    pos = jnp.asarray(pos, jnp.int32)
+    past = jnp.arange(rows)[None, :] > pos[:, None]          # [B, rows]
+    lift = (1, B, rows) + (1,) * (k.ndim - 3)
+    poison = jnp.asarray([jnp.nan, jnp.inf, -jnp.inf], dtype)[
+        jnp.arange(rows) % 3].reshape((1, 1, rows) + lift[3:])
+    poisoned = [jnp.where(past.reshape(lift), poison, t) for t in (k, v)]
+    clean = [jnp.where(past.reshape(lift), 0, t) for t in (k, v)]
+    return q, poisoned, clean, pos, ~past[:, None, :]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("positions", sorted(_DECODE_POS))
+@pytest.mark.parametrize("layout", sorted(_DECODE_LAYOUTS))
+def test_decode_kernel_matches_the_xla_form_and_reads_nothing_past_pos(
+        layout, positions, dtype):
+    """``decode_attend`` (interpreted) against ``cached_attention`` on
+    the layer's slice: every position ``[0, pos]`` of a slot attended,
+    and nothing of what lies past it (NaN and +-inf there, in the
+    skipped blocks and in the tail of the last live one) in the
+    output, which has q's dtype."""
+    from ray_tpu.ops.attention import cached_attention, decode_attention
+
+    q, (pk, pv), (ck, cv), pos, valid = _decode_case(
+        layout, dtype, _DECODE_POS[positions])
+    layer = 1
+    want = cached_attention(q, ck[layer], cv[layer], valid,
+                            q.shape[-1] ** -0.5)
+    for group in (1, 2):
+        got = decode_attention(q, pk, pv, jnp.int32(layer), pos,
+                               block_k=_BLOCK, rows_per_step=group,
+                               interpret=True)
+        assert got.dtype == q.dtype and got.shape == want.shape
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=1e-5 if dtype == jnp.float32 else 2e-2,
+            err_msg=f"{group} slots a grid step")
+
+
+def test_decode_kernel_accumulates_in_float32_across_blocks():
+    """A uniform softmax over 512 positions of value 1, in bfloat16, 32
+    blocks: the numerator and the denominator are carried in float32,
+    so the output is 1 exactly (summed in bfloat16, 512 terms of 1/512
+    stall far below it)."""
+    from ray_tpu.ops.attention import decode_attention
+
+    B, H, D, rows = 2, 4, 16, 512
+    q = jnp.zeros((B, H, D), jnp.bfloat16)
+    k = jax.random.normal(jax.random.key(0), (1, B, rows, H, D),
+                          jnp.bfloat16)
+    v = jnp.ones((1, B, rows, H, D), jnp.bfloat16)
+    got = decode_attention(q, k, v, jnp.int32(0),
+                           jnp.asarray([rows - 1, 300], jnp.int32),
+                           block_k=16, rows_per_step=1, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32), 1.0)
+
+
+# rows, bytes of K and V a position, slots -> (block, slots a grid step)
+_DECODE_BLOCKS = {
+    "ouro-2.6b.decode-closed": ((1024, 2 * 2 * 16 * 128, 8), (256, 1)),
+    "mimo-v2-flash-ep16-d7.reason-closed": (
+        (3200, 2 * 4 * (192 + 128), 128), (640, 1)),
+    "jamba2-3b.rollout-closed": ((2048, 2 * 2 * 128, 256), (512, 8)),
+    "no multiple of 128": ((1000, 8192, 8), None),
+    "rows of 32 KB": ((2048, 32768, 6), (128, 1)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE_BLOCKS))
+def test_decode_blocks_table(cell):
+    from ray_tpu.ops.attention import decode_blocks
+
+    shape, blocks = _DECODE_BLOCKS[cell]
+    assert decode_blocks(*shape) == blocks
+    if blocks:
+        assert shape[0] % blocks[0] == 0 and shape[2] % blocks[1] == 0
+
+
+def test_decode_attention_takes_the_kernel_by_platform_and_shape(
+        monkeypatch):
+    """Off the TPU the XLA form; on it the kernel where the shape has a
+    block (``decode_rows_fetched`` says which), the XLA form with a
+    sink and for rows that are no multiple of 128 or not whole lanes
+    wide; an explicit
+    block comes with its slots a grid step."""
+    import importlib
+
+    attention_mod = importlib.import_module("ray_tpu.ops.attention")
+    decode_attention = attention_mod.decode_attention
+    fetched = attention_mod.decode_rows_fetched
+
+    def struct(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def kernel_runs(q, k, v, sink=None):
+        pos = jax.ShapeDtypeStruct((q.shape[0],), jnp.int32)
+        layer = jax.ShapeDtypeStruct((), jnp.int32)
+        # (a function of its own each time: a trace is kept by function)
+        return "decode_attend" in str(jax.make_jaxpr(
+            lambda *args: decode_attention(*args[:5], sink=args[5]))(
+                q, k, v, layer, pos, sink))
+
+    cell = struct(8, 16, 128), struct(2, 8, 1024, 16, 128), \
+        struct(2, 8, 1024, 16, 128)
+    assert not kernel_runs(*cell) and fetched(*cell) == 1024
+    monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
+    assert kernel_runs(*cell) and fetched(*cell) == 256
+    sink = jax.ShapeDtypeStruct((16,), jnp.float32)     # the XLA form's
+    assert not kernel_runs(*cell, sink) and fetched(*cell, sink=True) == 1024
+    flat = struct(8, 64, 192), struct(1, 8, 3200, 768), \
+        struct(1, 8, 3200, 512)
+    assert kernel_runs(*flat) and fetched(*flat) == 640
+    for q, k, v in (
+            (struct(8, 16, 128), struct(2, 8, 1000, 16, 128),
+             struct(2, 8, 1000, 16, 128)),          # rows
+            (struct(8, 4, 64), struct(2, 8, 1024, 4, 64),
+             struct(2, 8, 1024, 4, 64)),            # half a lane row
+            (struct(8, 12, 128), struct(2, 8, 1024, 12, 128),
+             struct(2, 8, 1024, 12, 128))):         # 12 rows a position
+        assert not kernel_runs(q, k, v) and fetched(q, k, v) == k.shape[2]
+    with pytest.raises(ValueError, match="both block_k and rows_per_step"):
+        decode_attention(*(jnp.zeros(s.shape, s.dtype) for s in cell),
+                         jnp.int32(0), jnp.zeros(8, jnp.int32), block_k=128)
+
+
+def test_decode_attention_with_a_sink_is_the_xla_form():
+    """A layer with a sink logit attends through ``cached_attention``
+    on every platform (asked for the kernel too): the sink is in the
+    denominator, and the output is that form's to the bit."""
+    from ray_tpu.ops.attention import cached_attention, decode_attention
+
+    q, _, (ck, cv), pos, valid = _decode_case(
+        "grouped-20on1-128", jnp.float32, _DECODE_POS["mixed"])
+    sink = jnp.linspace(-1.0, 3.0, q.shape[1])
+    got = decode_attention(q, ck, cv, jnp.int32(1), pos, sink=sink,
+                           interpret=True)
+    want = cached_attention(q, ck[1], cv[1], valid, q.shape[-1] ** -0.5,
+                            sink)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    bare = decode_attention(q, ck, cv, jnp.int32(1), pos, interpret=True)
+    assert float(jnp.max(jnp.abs(bare - got))) > 1e-3
