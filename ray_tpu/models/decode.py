@@ -126,6 +126,14 @@ Invariants the scheduler relies on:
   ``max_len`` bounds a slot by its attention runs' rows; a model of
   Mamba layers alone is bounded by ``cfg.max_seq``.
 
+**Which part of the block an operation of a decode step computes** is
+in the compiled step's metadata: every stretch of ``slot_decode_step``
+is traced under the scope of its part (``transformer.PARTS``) and of its
+run of layers, and :func:`program_parts` reads the compiled text into
+``{instruction: [run, part]}``, the table a device trace is joined with
+(``JaxSlotEngine.parts()``, serve/decode_scheduler.py). A prefill has
+the block's own scopes and no table yet.
+
 Oracle: greedy decoding must match the per-step argmax of the FULL
 forward() on the growing prefix, which shares no cache code —
 tests/test_ops.py, tests/test_decode_scheduler.py and
@@ -137,15 +145,17 @@ to step) to the training forward's semantics.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Tuple
+import re
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (EXPERTS, FROM_THE_START, MAMBA,
-                                        WINDOW, TransformerConfig, block,
-                                        kind_rope, layer_runs, layer_stacks,
+from ray_tpu.models.transformer import (EXPERTS, FROM_THE_START,
+                                        LAYER_WEIGHTS, MAMBA, PARTS, WINDOW,
+                                        TransformerConfig, block, kind_rope,
+                                        layer_runs, layer_stacks,
                                         no_rotation, roped_kinds, scan_run,
                                         unembed)
 from ray_tpu.ops import ssm
@@ -288,7 +298,9 @@ def _tally(load, got):
     got a row, rows routed to them, the fullest one's rows."""
     if got is None:
         return load
-    return load + jnp.stack([jnp.sum(got > 0), jnp.sum(got), jnp.max(got)])
+    with jax.named_scope("experts"):
+        return load + jnp.stack([jnp.sum(got > 0), jnp.sum(got),
+                                 jnp.max(got)])
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -405,23 +417,29 @@ def slot_decode_step(params, cache: Dict, token, active,
     to it, while the next step already runs on ``cache["tok"]``."""
     B = token.shape[0]
     served = active is None
-    if served:
-        active = token != IDLE
-    token = jnp.where(token >= 0, token, cache["tok"])
     runs = layer_runs(cfg)
     states = _cache_runs(cache, runs)
     max_len = _max_len(cfg, runs, states)
     pos = cache["pos"]  # [B]
-    tables = {a: kind_rope(cfg, a, max_len) for a in roped_kinds(cfg, runs)}
-    x = params["embed"][token][:, None, :]  # [B, 1, D]
+    with jax.named_scope("embed"):
+        if served:
+            active = token != IDLE
+        token = jnp.where(token >= 0, token, cache["tok"])
+    with jax.named_scope("qkv"):
+        tables = {a: kind_rope(cfg, a, max_len)
+                  for a in roped_kinds(cfg, runs)}
+    with jax.named_scope("embed"):
+        x = params["embed"][token][:, None, :]  # [B, 1, D]
     sm_scale = cfg.head_dim ** -0.5
     # row r attends positions [0, pos[r]] (pos[r] is written this
     # step): a full-attention run hands ``decode_attention`` the
     # positions themselves; of a ring, the rows filled so far, all once
     # pos[r] has passed the window
-    filled = jnp.arange(cfg.window or 0)[None, None, :] \
-        <= pos[:, None, None]                               # [B, 1, rows]
-    rows = jnp.arange(B)
+    with jax.named_scope("window_attention"):
+        filled = jnp.arange(cfg.window or 0)[None, None, :] \
+            <= pos[:, None, None]                           # [B, 1, rows]
+    with jax.named_scope("full_attention"):
+        rows = jnp.arange(B)    # of the cache's in-place write, either kind
 
     def attention_run(x, load, layers, ck, cv, attention):
         window = cfg.window if attention == WINDOW else None
@@ -438,13 +456,13 @@ def slot_decode_step(params, cache: Dict, token, active,
             x, ck, cv, load = carry  # ck/cv: the whole [L, B, rows, G, Dh]
 
             def attend(q, k, v):
-                # write, then attend: the layer's K/V are read out of the
-                # carry after the rows' new token is in it
-                nk = ck.at[i, rows, at].set(
-                    k[:, 0].reshape((B,) + ck.shape[3:]).astype(ck.dtype))
-                nv = cv.at[i, rows, at].set(
-                    v[:, 0].reshape((B,) + cv.shape[3:]).astype(cv.dtype))
                 with jax.named_scope(f"{attention}_attention"):
+                    # write, then attend: the layer's K/V are read out of
+                    # the carry after the rows' new token is in it
+                    nk = ck.at[i, rows, at].set(
+                        k[:, 0].reshape((B,) + ck.shape[3:]).astype(ck.dtype))
+                    nv = cv.at[i, rows, at].set(
+                        v[:, 0].reshape((B,) + cv.shape[3:]).astype(cv.dtype))
                     if window is None:
                         # the carry itself is the operand: no slice of
                         # a layer feeds the kernel
@@ -467,9 +485,11 @@ def slot_decode_step(params, cache: Dict, token, active,
     def mamba_run(x, load, layers, cs, cc):
         def body(carry, lp, i):
             x, cs, cc, load = carry     # [L, B, N, C] and [L, K-1, B, C]
-            state = lax.dynamic_index_in_dim(cs, i, keepdims=False)
-            tail = lax.dynamic_index_in_dim(cc, i, keepdims=False).swapaxes(
-                0, 1)                   # [B, K-1, C]
+            with jax.named_scope("ssm_step"):
+                state = lax.dynamic_index_in_dim(cs, i, keepdims=False)
+            with jax.named_scope("mamba_mixer"):
+                tail = lax.dynamic_index_in_dim(
+                    cc, i, keepdims=False).swapaxes(0, 1)   # [B, K-1, C]
 
             def conv(u, w, b):
                 return ssm.causal_conv(u, w, b, tail)
@@ -483,35 +503,40 @@ def slot_decode_step(params, cache: Dict, token, active,
             x, (new_tail, new_state), got = block(
                 lp, x, None, ssm.Recurrence(conv, step), cfg)
             # an inactive row's summary stays bit for bit what it was
-            cs = lax.dynamic_update_slice(cs, jnp.where(
-                active[:, None, None], new_state, state)[None],
-                (i, 0, 0, 0))
-            cc = lax.dynamic_update_slice(cc, jnp.where(
-                active[:, None, None], new_tail.astype(cc.dtype),
-                tail).swapaxes(0, 1)[None], (i, 0, 0, 0))
+            with jax.named_scope("ssm_step"):
+                cs = lax.dynamic_update_slice(cs, jnp.where(
+                    active[:, None, None], new_state, state)[None],
+                    (i, 0, 0, 0))
+            with jax.named_scope("mamba_mixer"):
+                cc = lax.dynamic_update_slice(cc, jnp.where(
+                    active[:, None, None], new_tail.astype(cc.dtype),
+                    tail).swapaxes(0, 1)[None], (i, 0, 0, 0))
             return x, cs, cc, _tally(load, got)
 
         return scan_run(body, (x, cs, cc, load), layers)
 
     load = jnp.zeros_like(cache["load"]) if "load" in cache else None
     new = []
-    for ((mixer, _), layers), (first, second) in zip(
-            layer_stacks(params, cfg), states):
-        if mixer == MAMBA:
-            x, first, second, load = mamba_run(x, load, layers, first, second)
-        else:
-            x, first, second, load = attention_run(x, load, layers, first,
-                                                   second, mixer)
+    for r, (((mixer, _), layers), (first, second)) in enumerate(zip(
+            layer_stacks(params, cfg), states)):
+        with jax.named_scope(f"run{r}"):
+            if mixer == MAMBA:
+                x, first, second, load = mamba_run(x, load, layers, first,
+                                                   second)
+            else:
+                x, first, second, load = attention_run(
+                    x, load, layers, first, second, mixer)
         new.append((first, second))
     logits = unembed(params, x[:, 0], eps=cfg.norm_eps)
-    tok = jnp.where(active, pick(logits), cache["tok"])
-    cache = _with_states(cache, runs, new,
-                         pos=jnp.where(active, pos + 1, pos), tok=tok)
-    if load is not None:
-        cache["load"] = load
-    if not served:
-        return logits, cache
-    return (tok if load is None else jnp.concatenate([tok, load])), cache
+    with jax.named_scope("head"):
+        tok = jnp.where(active, pick(logits), cache["tok"])
+        cache = _with_states(cache, runs, new,
+                             pos=jnp.where(active, pos + 1, pos), tok=tok)
+        if load is not None:
+            cache["load"] = load
+        if not served:
+            return logits, cache
+        return (tok if load is None else jnp.concatenate([tok, load])), cache
 
 
 @functools.partial(jax.jit,
@@ -572,3 +597,119 @@ def generate(params, prompt, cfg: TransformerConfig, *, steps: int,
                                     jnp.float32),
                         cfg=cfg, steps=steps,
                         sample=temperature > 0.0)
+
+
+# ------------------------------------ a compiled program, part by part
+
+# opcodes that hold other computations' instructions and are no work
+# of their own, and (in the order below) the attributes that name them
+_CONTAINERS = {"while": ("body", "condition"), "call": ("to_apply",),
+               "conditional": ("true_computation", "false_computation",
+                               "branch_computations")}
+# ... and opcodes that name memory or order and run nothing
+_NO_EVENT = frozenset((
+    "parameter", "tuple", "get-tuple-element", "constant", "bitcast",
+    "after-all", "opt-barrier", "partition-id", "replica-id"))
+_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_RUN = re.compile(r"^run\d+$")
+
+
+def _scope_of(op_name: str) -> Tuple[Optional[str], Optional[str]]:
+    """(the ``run<i>`` on an ``op_name`` path, the innermost name of
+    ``PARTS`` on it), None where there is none."""
+    run = part = None
+    for name in op_name.split("/"):
+        if _RUN.match(name):
+            run = name
+        elif name in PARTS:
+            part = name
+    return run, part
+
+
+def decode_parts(cfg: TransformerConfig) -> List[str]:
+    """The scopes that the compiled ``slot_decode_step`` of ``cfg``
+    names somewhere: the parts its layers' kinds imply and every run."""
+    runs = layer_runs(cfg)
+    want = {"embed", "head"} | {f"run{r}" for r in range(len(runs))}
+    for (mixer, ffn), _ in runs:
+        want |= ({"mamba_mixer", "ssm_step"} if mixer == MAMBA
+                 else {"qkv", f"{mixer}_attention", "attn_out"})
+        want |= {"router", "experts"} if ffn == EXPERTS else {"mlp"}
+    return sorted(want)
+
+
+def program_parts(compiled_text: str, expect: Iterable[str] = ()
+                  ) -> Optional[Dict[str, List[Optional[str]]]]:
+    """``{instruction: [run, part]}`` of a compiled program's text
+    (``jitted.lower(...).compile().as_text()``): for every instruction
+    of the entry computation, and of the loop bodies, branches and
+    called computations it reaches, that can be a device event of its
+    own (fusions, custom calls, copies, dots, scatters ...; not
+    ``while``, ``conditional`` or ``call``, which hold the others, not
+    parameters, tuples and their like, and nothing inside a fused
+    computation), the ``run<i>`` on its ``op_name`` path and the
+    innermost name of ``transformer.PARTS`` there. The profiler names a
+    device event by its instruction, so this is the join between a
+    trace and the scopes of ``transformer.block``.
+
+    **A fusion is counted where its own metadata puts it, which is its
+    root's**: one that spans two parts (a norm fused into the product
+    before it) reads whole under the part of what it ends in. Where an
+    instruction's own path names no run or no part, it has those of the
+    loop, branch or call that holds it, as that container's path names
+    them. Inside a run and inside no part it is that run's
+    ``LAYER_WEIGHTS``: the scan's slice of each stacked weight and what
+    XLA hangs on it, which only a run that kept its loop has. Outside
+    every run and part both are None: what the compiler made in the
+    entry computation and gave no metadata (a parameter's copy into
+    faster memory, the relaid weight of a single layer's run, which XLA
+    unrolls) is in no part, and a reader counts it as unscoped.
+
+    None, never a partial table, where one of ``expect`` (scope names,
+    ``decode_parts(cfg)``) is nowhere in the text's metadata: the
+    executable came from another tree's compile cache entry (jax's
+    cache key leaves ``op_name`` out) and its scopes are not this
+    tree's."""
+    named = {name for op_name in set(_OP_NAME.findall(compiled_text))
+             for name in op_name.split("/")}
+    if not set(expect) <= named:
+        return None
+    # computation -> [(instruction, opcode, the rest of its line)]
+    computations, entry, current = {}, None, None
+    for line in compiled_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = computations.setdefault(head.group(2), [])
+            entry = head.group(2) if head.group(1) else entry
+        elif current is not None and (m := _INSTRUCTION.match(line)):
+            opcode = _OPCODE.search(" " + m.group(2))
+            if opcode:
+                current.append((m.group(1), opcode.group(1), m.group(2)))
+
+    def held(rest, key):
+        names = re.search(key + r"=\{?([^}\s]+(?:, [^}\s]+)*)", rest)
+        return [c.strip("%,") for c in names.group(1).split(", ")] \
+            if names else []
+
+    table, seen = {}, set()
+
+    def walk(computation, run, part):
+        if computation in seen:
+            return
+        seen.add(computation)
+        for name, opcode, rest in computations.get(computation, ()):
+            found = _OP_NAME.search(rest)
+            own_run, own_part = _scope_of(found.group(1) if found else "")
+            r, p = own_run or run, own_part or part
+            if opcode in _CONTAINERS:
+                for key in _CONTAINERS[opcode]:
+                    for inner in held(rest, key):
+                        walk(inner, r, p)
+            elif opcode not in _NO_EVENT:
+                table[name] = [r, p or (LAYER_WEIGHTS if r else None)]
+
+    walk(entry, None, None)
+    return table

@@ -78,6 +78,39 @@ FULL, WINDOW, MAMBA = "full", "window", "mamba"
 DENSE, EXPERTS = "dense", "experts"
 LayerKind = Tuple[str, str]
 
+# The parts of a step, by name: ``block`` and ``unembed`` trace each
+# stretch of what they compute inside a ``jax.named_scope`` of one of
+# them, the callers' ``attend`` closures inside the attention's, so
+# that a compiled program's ``op_name`` metadata says which part an
+# instruction computes. ``slot_decode_step`` scopes what it adds around
+# the block as well, because its table is read
+# (``models.decode.program_parts``); ``forward`` and ``slot_prefill``
+# carry the block's and the head's scopes and no more until something
+# reads theirs. Metadata alone: XLA fuses as before and a step pays
+# nothing. Where scopes nest, the innermost names the operation: a
+# decode step's recurrence reads as ``ssm_step``, the mixer around it
+# as ``mamba_mixer``.
+PARTS = (
+    "embed",                # the token's select and the embedding lookup
+    "qkv",                  # attention norm, wq / wk / wv, rope, value scale
+    "full_attention",       # scores, softmax, p.V (or the kernel's call)
+    "window_attention",     # ... and the cache's in-place write beside them
+    "attn_out",             # wo and the residual add
+    "mamba_mixer",          # norm, projections, convolution, gate, residual
+    "ssm_step",             # a decode step's recurrence, its state read and
+                            # written where it lies
+    "ssm_scan",             # the recurrence over a whole sequence
+    "router",               # feed-forward norm and the router
+    "experts",              # the held experts' products and the residual
+    "mlp",                  # the dense feed-forward, its norm and residual
+    "head",                 # final norm, unembed and the pick
+)
+# Around each run's scan in the decode step (``layer_runs``' order) lies
+# a scope ``run<i>``. What a run's loop body holds outside every part,
+# the scan's slice of each stacked weight and the copy XLA hangs on it
+# for a product's layout, is read as this part of that run.
+LAYER_WEIGHTS = "layer_weights"
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -465,33 +498,40 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
     router is an expert layer: its feed-forward is the part the experts
     held here give (parallel/experts.py). Returns (x, state, load):
     ``load`` the rows each held expert got, int32 [experts_held], None
-    for a dense layer."""
+    for a dense layer. Each stretch of it is traced under the scope of
+    its part (``PARTS``); ``attend`` brings its own, the attention's
+    kind."""
     B, T, D = x.shape
 
-    h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
     if "w_in" in lp:
         with jax.named_scope("mamba_mixer"):
+            h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
             o, state = mamba_mixer(lp, h, attend, cfg)
+            x = x + o.astype(x.dtype)
     else:
-        if pcfg.tp:
-            h = tp_copy(h, pcfg.tp)
-        q = (h @ lp["wq"]).reshape(B, T, -1, cfg.head_dim)  # H_local heads
-        k = (h @ lp["wk"]).reshape(B, T, -1, cfg.head_dim)
-        v = (h @ lp["wv"]).reshape(B, T, -1, cfg.v_dim)
-        if cfg.value_scale != 1.0:
-            v = v * cfg.value_scale
-        o, state = attend(rope(q), rope(k), v)
-        o = o.reshape(B, T, -1) @ lp["wo"]             # row-parallel
-        if pcfg.tp:
-            o = tp_allreduce(o, pcfg.tp)
-    x = x + o.astype(x.dtype)
+        with jax.named_scope("qkv"):
+            h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
+            if pcfg.tp:
+                h = tp_copy(h, pcfg.tp)
+            q = (h @ lp["wq"]).reshape(B, T, -1, cfg.head_dim)  # H_local
+            k = (h @ lp["wk"]).reshape(B, T, -1, cfg.head_dim)
+            v = (h @ lp["wv"]).reshape(B, T, -1, cfg.v_dim)
+            if cfg.value_scale != 1.0:
+                v = v * cfg.value_scale
+            q, k = rope(q), rope(k)
+        o, state = attend(q, k, v)          # the caller's scope, by kind
+        with jax.named_scope("attn_out"):
+            o = o.reshape(B, T, -1) @ lp["wo"]         # row-parallel
+            if pcfg.tp:
+                o = tp_allreduce(o, pcfg.tp)
+            x = x + o.astype(x.dtype)
 
-    h = rmsnorm(x, lp["mlp_norm"], eps=cfg.norm_eps)
-    if pcfg.tp:
-        h = tp_copy(h, pcfg.tp)
     load = None
     if "router" in lp:
         with jax.named_scope("router"):
+            h = rmsnorm(x, lp["mlp_norm"], eps=cfg.norm_eps)
+            if pcfg.tp:
+                h = tp_copy(h, pcfg.tp)
             chosen, weights = route(h.reshape(B * T, D), lp["router"],
                                     lp["router_bias"],
                                     cfg.experts_per_token)
@@ -502,14 +542,22 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
                 held=cfg.experts_held, base=lp.get("expert_base", 0),
                 tile=expert_tile(B * T, cfg.experts_per_token,
                                  cfg.n_experts))
-        d = d.reshape(B, T, D)
+            d = d.reshape(B, T, D)
+            if pcfg.tp:
+                d = tp_allreduce(d, pcfg.tp)
+            x = x + d.astype(x.dtype)
     else:
-        g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
-        u = (h @ lp["w_up"]).astype(jnp.float32)
-        d = (g * u).astype(x.dtype) @ lp["w_down"]     # row-parallel
-    if pcfg.tp:
-        d = tp_allreduce(d, pcfg.tp)
-    return x + d.astype(x.dtype), state, load
+        with jax.named_scope("mlp"):
+            h = rmsnorm(x, lp["mlp_norm"], eps=cfg.norm_eps)
+            if pcfg.tp:
+                h = tp_copy(h, pcfg.tp)
+            g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
+            u = (h @ lp["w_up"]).astype(jnp.float32)
+            d = (g * u).astype(x.dtype) @ lp["w_down"]     # row-parallel
+            if pcfg.tp:
+                d = tp_allreduce(d, pcfg.tp)
+            x = x + d.astype(x.dtype)
+    return x, state, load
 
 
 def mamba_mixer(lp, h, recur: ssm.Recurrence, cfg: TransformerConfig):
@@ -577,11 +625,12 @@ def unembed(params, x, *, last=False, eps: float = 1e-6):
     x's dtype and the logits are float32: a stable softmax-xent, and
     one rounding for every caller, so that a greedy decode agrees with
     the full forward's argmax."""
-    x = rmsnorm(x, params["final_norm"], eps=eps)
-    if last:
-        x = x[:, -1]
-    head = params["head"] if "head" in params else params["embed"].T
-    return (x @ head.astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"], eps=eps)
+        if last:
+            x = x[:, -1]
+        head = params["head"] if "head" in params else params["embed"].T
+        return (x @ head.astype(x.dtype)).astype(jnp.float32)
 
 
 def kind_rope(cfg: TransformerConfig, attention: str, max_seq: int):

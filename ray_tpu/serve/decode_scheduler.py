@@ -87,6 +87,35 @@ name, to be read as deltas:
   ``serve.engine.experts_hit`` (held experts that got a row),
   ``serve.engine.expert_rows`` (rows routed to held experts) and
   ``serve.engine.expert_rows_max`` (the fullest expert's rows).
+
+Where the DEVICE's time of a decode step goes is recorded in the
+compiled step itself, as names: every operation of it is traced under
+the ``jax.named_scope`` of its part (``models.transformer.PARTS``:
+``embed``, ``qkv``, ``full_attention`` / ``window_attention``,
+``attn_out``, ``mamba_mixer`` with ``ssm_step`` inside it, ``router``,
+``experts``, ``mlp``, ``head``) and every run of alike layers under
+``run<i>``; what a run's loop holds outside every part (the scan's
+slice of each stacked weight and the copies XLA hangs on it) reads as
+that run's ``layer_weights``, and what the compiler made outside every
+run and gave no name as in no part. The scopes are metadata of the
+compiled program (``op_name``): XLA fuses as without them and a step
+pays nothing. ``stats()["parts"]`` is ``{"slot_decode_step":
+{instruction: [run, part]}}`` where the engine offers ``parts()``
+(:class:`JaxSlotEngine`: made once, by its first step on its own
+thread while the device runs that step, from the very executable that
+runs, with no compile; None until then, and where the executable's
+scopes are another tree's), and the key is absent for an engine that
+compiles nothing. A prefill carries the block's scopes too, and nothing
+reads them yet. A profiler names a device event by its
+HLO instruction, so an operator joins the two:
+
+    table = scheduler.stats()["parts"]["slot_decode_step"]
+    # events of a jax.profiler trace's "XLA Ops" line on the device
+    # plane, inside a run of the module jit_slot_decode_step(...):
+    name = event.name.split(" = ")[0].split("(")[0].strip().lstrip("%")
+    run, part = table.get(name, (None, None))    # a `while` is not listed
+
+(``benchmarks/inside_parts.py`` is that join over a traced slice.)
 """
 
 from __future__ import annotations
@@ -109,6 +138,9 @@ logger = logging.getLogger(__name__)
 # ``cache["load"]`` (models/decode.py): sums, kept beside the phases
 EXPERT_COUNTS = ("serve.engine.experts_hit", "serve.engine.expert_rows",
                  "serve.engine.expert_rows_max")
+# the decode step's program, as a device trace names it (the jitted
+# function's name: models/decode.py)
+DECODE_PROGRAM = "slot_decode_step"
 
 
 @dataclass
@@ -208,7 +240,19 @@ class DecodeScheduler:
         return int(self._engine.slots) + self._max_queue_depth
 
     def stats(self) -> dict:
-        return {
+        """The loop's counters and phase sums and, where the engine
+        offers ``parts()``, ``"parts": {program: {instruction: [run,
+        part]}}`` of the decode step it runs (module docstring): the
+        engine's own table, handed on, nothing computed here.
+
+        TEMPORARY: an engine without ``parts`` is asked for the one it
+        wraps as ``inner``. That is the attribute of the benchmark's
+        ``benchmarks/worker.py::TimedEngine``, which stands between
+        this loop and its ``JaxSlotEngine`` and which no PR but a
+        ``benchmark`` PR may edit. The PR that enters the parts'
+        metrics gives ``TimedEngine`` a ``parts`` of its own and takes
+        this walk out (ROADMAP M15)."""
+        out = {
             "queue_depth": len(self._queue),
             "active_slots": len(self._active),
             "free_slots": len(self._free),
@@ -226,6 +270,12 @@ class DecodeScheduler:
             "request_s": self.request_s,
             "phases": phase_totals(table=self._phases),
         }
+        engine = self._engine
+        while engine is not None and not hasattr(engine, "parts"):
+            engine = getattr(engine, "inner", None)     # temporary: above
+        if engine is not None:
+            out["parts"] = {DECODE_PROGRAM: engine.parts()}
+        return out
 
     async def aclose(self) -> None:
         """Stop the loop; fail queued and in-flight requests typed."""
@@ -480,10 +530,41 @@ class JaxSlotEngine:
         self._cfg = cfg
         self.slots = int(slots)
         self.max_len = int(max_len)
+        self._parts = None      # the compiled step's table: parts()
         self._start_over()
         # positions a full-attention layer fetches at a time for a slot
         # (models/decode.py; None: the model has no such layer)
         self._kv_block = decode_mod.kv_rows_fetched(cfg, self._cache)
+
+    def parts(self) -> Optional[Dict[str, list]]:
+        """``{instruction: [run, part]}`` of the compiled decode step
+        that this engine runs (``models.decode.program_parts``): which
+        run of layers and which part of ``transformer.block`` each of
+        its device operations computes. ``step`` makes it, on its own
+        thread; here it is only handed out, so any thread may ask, as
+        often as it likes. None before the first step, and None where
+        the executable's scopes are not this tree's (loaded from a
+        compile cache entry another tree wrote)."""
+        return self._parts
+
+    def _read_parts(self, fed) -> Optional[Dict[str, list]]:
+        """The table of the step just dispatched with the row ``fed``,
+        read while the device runs it: lowering the step again for the
+        arguments it just took is answered from jax's in-memory caches,
+        so this compiles nothing and loads nothing; what it costs is
+        the executable's text (0.02 to 0.5 s by the program's size,
+        once). None where there is none to be had; the first step after
+        the next ``_start_over`` then asks again."""
+        decode = self._decode
+        try:
+            text = decode.slot_decode_step.lower(
+                self._params, self._cache, fed, None,
+                self._cfg).compile().as_text()
+            return decode.program_parts(text, decode.decode_parts(self._cfg))
+        except Exception as e:  # noqa: BLE001 — a step must not fail for
+            # its table: a step that is no jitted program has no text
+            logger.warning("no table of the decode step's parts: %r", e)
+            return None
 
     def _start_over(self) -> None:
         self._cache = self._flight = None   # a result half made goes first
@@ -590,6 +671,7 @@ class JaxSlotEngine:
             return {}
         IDLE = self._decode.IDLE
         with phase("serve.engine.check"):
+            first = self._flight is None    # of this cache's life
             before = self._flight or _Flight(None, set(), 0)
             ahead = any(slot in before.owed for slot in tokens)
             steers = [self._steer(tokens, before.owed)]
@@ -618,6 +700,8 @@ class JaxSlotEngine:
                         self._last[slot] = None
                     before = flights[0]
                 self._flight = flights[-1]
+            if first and self._parts is None:
+                self._parts = self._read_parts(fed[-1])
             with phase("serve.engine.wait"):
                 # the call's one transfer: waits for the step before,
                 # then brings its whole int32 row

@@ -90,6 +90,9 @@ class Replica:
         if sched is not None:
             try:
                 out["decode"] = sched.stats()
+                # the compiled step's table of some thousand entries
+                # (DecodeScheduler.stats) does not ride every poll
+                out["decode"].pop("parts", None)
             except Exception:  # noqa: BLE001 — stats must never fail
                 pass           # the autoscaler poll
         return out
