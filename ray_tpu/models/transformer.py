@@ -482,6 +482,29 @@ def _attend(q, k, v, pcfg: ParallelConfig, window=None, sink=None):
     raise ValueError(f"unknown attn impl {impl!r}")
 
 
+def _heads(h, w, width: int):
+    """h [B, T, D] @ w [D, H * width], as heads: [B, T, H, width]. XLA
+    folds the reshape into the product, which then wants the weight as
+    [H, width, D], the transpose of what is stored: a copy of the
+    matrix a layer, and before it a slice of its own out of the layers'
+    stack into fast memory, since the copy stands between the scan's
+    slice and the product (65 of a decode layer's 243 us on a v5e at 8
+    rows of 2048: PERF.md section 6, PR 39). Where the rows are many,
+    that copy is small beside the product and the folded form is the
+    better one (kept flat, the rows' own results are copied instead:
+    the train step's). Where they are fewer than the weight's own rows
+    (a decode step's token a row, one prompt's prefill) the weight is
+    the larger operand: the flat result is kept from the reshape
+    (``lax.optimization_barrier``: the identity, no arithmetic), and
+    the product reads its layer's matrix where it lies in the stack,
+    as ``wo``'s and the feed-forward's do."""
+    B, T, D = h.shape
+    flat = h @ w
+    if B * T < D:
+        flat = lax.optimization_barrier(flat)
+    return flat.reshape(B, T, -1, width)
+
+
 def block(lp, x, rope, attend, cfg: TransformerConfig,
           pcfg: ParallelConfig = ParallelConfig()):
     """The transformer block, on local shards. x: [B_l, T_l, D]
@@ -513,9 +536,9 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
             h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
             if pcfg.tp:
                 h = tp_copy(h, pcfg.tp)
-            q = (h @ lp["wq"]).reshape(B, T, -1, cfg.head_dim)  # H_local
-            k = (h @ lp["wk"]).reshape(B, T, -1, cfg.head_dim)
-            v = (h @ lp["wv"]).reshape(B, T, -1, cfg.v_dim)
+            q = _heads(h, lp["wq"], cfg.head_dim)       # H_local of them
+            k = _heads(h, lp["wk"], cfg.head_dim)
+            v = _heads(h, lp["wv"], cfg.v_dim)
             if cfg.value_scale != 1.0:
                 v = v * cfg.value_scale
             q, k = rope(q), rope(k)

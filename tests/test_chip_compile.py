@@ -31,6 +31,11 @@ or a layer's state, each prefill holding both kernels (``ssm_scan`` a
 Mamba run, ``flash_fwd`` an attention run), and the scan kernel alone
 accepted by Mosaic at the cell's three prompt lengths.
 
+Every serving cell's decode step is also held to reading each layer's
+``wq``, ``wk`` and ``wv`` where they lie in the stack: no slice of a
+layer's matrix into fast memory as an operation of its own and no
+relaid copy of one, in a run that kept its loop and in one XLA unrolled.
+
 The topology is described inside a fixture, by the one xdist worker
 that is given this file: libtpu loads in one process at a time, so no
 other test file may do the same and nothing here runs at import.
@@ -220,11 +225,10 @@ def computations(compiled_text):
     return out
 
 
-def cache_producers(compiled_text, shapes):
-    """The instructions outside fused computations whose result has one
-    of ``shapes``, as (name, what it does): parameters and tuple
-    elements name memory and are left out; a fusion does what the root
-    of the computation it calls does."""
+def outside_fusions(compiled_text):
+    """(computation, name, shape, what it does, rest) of the instructions
+    outside fused computations: a fusion does what the root of the
+    computation it calls does, a custom call what its target says."""
     comps = computations(compiled_text)
 
     def called(rest):
@@ -234,17 +238,26 @@ def cache_producers(compiled_text, shapes):
                for c, ins in comps.items() if any(i[0] for i in ins)}
     fused = {called(rest) for ins in comps.values()
              for *_, opcode, rest in ins if opcode == "fusion"}
-    found = []
     for comp, ins in comps.items():
         if comp in fused:
             continue
         for _, name, shape, opcode, rest in ins:
-            if shape not in shapes or opcode in ("parameter",
-                                                 "get-tuple-element"):
-                continue
-            found.append((name, root_of[called(rest)]
-                          if opcode == "fusion" else opcode))
-    return found
+            if opcode == "fusion":
+                opcode = root_of[called(rest)]
+            elif opcode == "custom-call":
+                opcode = re.search(r'custom_call_target="(\w+)"',
+                                   rest).group(1)
+            yield comp, name, shape, opcode, rest
+
+
+def cache_producers(compiled_text, shapes):
+    """The instructions outside fused computations whose result has one
+    of ``shapes``, as (name, what it does): parameters and tuple
+    elements name memory and are left out."""
+    return [(name, does)
+            for _, name, shape, does, _ in outside_fusions(compiled_text)
+            if shape in shapes
+            and does not in ("parameter", "get-tuple-element")]
 
 
 # the two cells' decode steps as compiled for the described chip, kept
@@ -489,8 +502,11 @@ def test_a_cache_by_layer_kind_is_still_written_in_place(
     # a bitcast names memory; a copy-start/copy-done pair is the
     # compiler's prefetch of one window layer's ring (50 MB) into the
     # chip's fast memory, which is that layer's read and no second one
+    # (or, in the decode step, four slice-start/slice-done quarters of
+    # the ring, which a ``ConcatBitcast`` names as one array)
     produced = [(name, op) for name, op in cache_producers(text, shapes)
-                if op not in ("bitcast", "copy-start", "copy-done")]
+                if op not in ("bitcast", "copy-start", "copy-done",
+                              "ConcatBitcast")]
     assert {op for _, op in produced} <= set(IN_PLACE), produced
     assert len(produced) == 8, produced     # K's and V's write, a run
     # a kernel a run of layers in a prefill; in a decode step one for
@@ -553,20 +569,24 @@ def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
 # three are on record anew, and the second serving cell's four beside
 # them; the three cells' ``decode`` once more since a full-attention
 # run attends through ``ops.attention.decode_attention`` (every prefill
-# and ``train`` as they were). Left out: the Mosaic kernels' serialized bodies, which hold the
+# and ``train`` as they were); and every serving program of the three
+# cells, the prefills too, since ``block`` keeps the flat q, k and v
+# products from their reshape to heads where the rows are fewer than the
+# weight's (``train``, whose rows are not, and the kernels' jaxprs as
+# they were). Left out: the Mosaic kernels' serialized bodies, which hold the
 # line numbers of ops/attention.py, and the results' labels, which name
 # the cache's place in the result's tree
 LOWERED = {
-    "decode": "5db38cff621b059f",
-    "prefill-128": "73d2ed7d30dcc38c",
-    "prefill-256": "dbd7cc3a5ea8d517",
+    "decode": "a3e7e77eb383f004",
+    "prefill-128": "70d028c10c8b6cff",
+    "prefill-256": "a25f52704aaeb6c6",
     "train": "4ee3f3d6f707d1e7",
 }
 LOWERED_KINDS = {
-    "decode": "bf26281680591a94",
-    "prefill-512": "8bfb8e890cd61412",
-    "prefill-1024": "486ec1e4b8f1528b",
-    "prefill-2048": "282e4d2871f6e016",
+    "decode": "b4b1440d73aae73d",
+    "prefill-512": "47fd3173921c4b1c",
+    "prefill-1024": "b6764f67406acf72",
+    "prefill-2048": "159daa3d1d966ee3",
 }
 KERNEL_JAXPRS_BEFORE_KINDS = "345359b76e414d9d"
 
@@ -682,10 +702,10 @@ def test_ouros_three_programs_lower_to_the_text_on_record(
 
 HYBRID_CELL = "jamba2-3b.rollout-closed"
 LOWERED_HYBRID = {
-    "decode": "5be1f66249e1cb8e",
-    "prefill-128": "9494bbbe99469780",
-    "prefill-256": "d915f689d4473dd3",
-    "prefill-512": "348935eec64c94c4",
+    "decode": "78fef1c0a186c3c6",
+    "prefill-128": "5dfd27e60294ead6",
+    "prefill-256": "d9740ea2bc50b407",
+    "prefill-512": "b3b436c3575270ac",
 }
 
 
@@ -864,3 +884,65 @@ def test_the_third_cells_decode_step_hands_the_host_a_row_of_picks(
 def test_the_third_cells_serving_programs_lower_to_the_text_on_record(
         hybrid_cell, one_chip, as_on_the_tpu):
     assert serving_programs_lowered(hybrid_cell, one_chip) == LOWERED_HYBRID
+
+
+# --------------- q, k and v read out of the stacked weights where they lie
+
+CELL_FIXTURES = {SERVING_CELL: "serving_cell", KINDS_CELL: "kinds_cell",
+                 HYBRID_CELL: "hybrid_cell"}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_FIXTURES))
+def test_a_decode_steps_q_k_v_products_read_the_stack_where_it_lies(
+        cell, request, one_chip, no_compile_cache, as_on_the_tpu):
+    """The three serving cells' decode steps as compiled for the
+    described v5e (``compiled_decode``: no compile where a test above
+    has run). Outside fused computations no ``copy`` and no fusion
+    rooted in a ``dynamic-slice`` yields an array of a layer's ``wq``,
+    ``wk`` or ``wv`` (as many elements, ``d_model`` among its
+    dimensions, whatever its layout or memory space): no layer's matrix
+    is sliced into fast memory as an operation of its own, none is
+    relaid, in a run that kept its loop and in a run XLA unrolled. The
+    compiler's own prefetches (``copy-start`` / ``copy-done``) are not
+    counted, nor a copy of the rows' flat result ([slots, 1, H * Dh]).
+    And in a run that kept its loop each of the three products under
+    the scope ``qkv`` takes the stacked leaf itself among its operands,
+    as ``wo``'s and the feed-forward's do. With the reshape to heads
+    folded into the product XLA asks for the weight transposed, and
+    this fails in all three cells: six such instructions in cell 1,
+    ten in cell 4, two in cell 5."""
+    import jax
+
+    from ray_tpu.models import init_params
+    from ray_tpu.models.transformer import layer_stacks
+
+    cfg, slots, slot_len, _ = request.getfixturevalue(CELL_FIXTURES[cell])
+    text = compiled_decode(cell, cfg, slots, slot_len, one_chip).as_text()
+    stacks = [{name: stack[name].shape for name in ("wq", "wk", "wv")}
+              for _, stack in layer_stacks(jax.eval_shape(
+                  lambda: init_params(jax.random.key(0), cfg)), cfg)
+              if "wq" in stack]
+    sizes = {math.prod(shape[1:]) for run in stacks for shape in run.values()}
+    found = list(outside_fusions(text))
+
+    # a layer's matrix sliced out or relaid
+    made = [(name, shape, does) for _, name, shape, does, _ in found
+            if does in ("copy", "dynamic-slice") and cfg.d_model in shape
+            and math.prod(shape) in sizes]
+    assert not made, made
+
+    # the products of the runs that kept their loop: in a loop's body,
+    # not in the entry computation (an unrolled run's carry their
+    # ``while/body`` path there too)
+    entry = re.search(r"^ENTRY %([\w.\-]+) ", text, re.M).group(1)
+    shape_of = {(comp, name): shape for comp, name, shape, _, _ in found}
+    products = [
+        (name, [shape_of.get((comp, operand)) for operand in re.findall(
+            r"%([\w.\-]+)", rest.split(")")[0])])
+        for comp, name, _, does, rest in found
+        if comp != entry and does not in ("parameter", "get-tuple-element")
+        and re.search(r'op_name="[^"]*/qkv/dot_general"', rest)]
+    looped = [run for run in stacks if run["wq"][0] > 1]
+    assert len(products) == 3 * len(looped), products
+    leaves = {shape for run in looped for shape in run.values()}
+    assert all(leaves & set(operands) for _, operands in products), products

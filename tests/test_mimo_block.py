@@ -380,3 +380,88 @@ def test_a_model_of_one_kind_keeps_its_one_stack_of_layers():
     np.testing.assert_allclose(
         forward(dict(stacked, layers=(params["layers"],)), tokens, kinds),
         forward(params, tokens, cfg), atol=1e-6)
+
+
+# ------------------------- a decode step's q, k and v, and the forward's
+
+# one small model for each family's heads: every head with K and V of
+# its own; 64 query heads on 8, the value narrower than q and k, rope on
+# part of a head, v scaled; 20 on 1 with no rotation at all
+QKV_HEADS = {
+    "a-head-its-own-kv": dict(n_heads=4),
+    "64-on-8-value-narrower": dict(
+        n_heads=64, n_kv_heads=8, qk_head_dim=24, v_head_dim=16,
+        rotary_dim=8, value_scale=0.707),
+    "20-on-1-no-rotation": dict(n_heads=20, n_kv_heads=1, qk_head_dim=16,
+                                rope=False),
+}
+
+
+@pytest.mark.parametrize("heads", sorted(QKV_HEADS))
+def test_a_decode_steps_q_k_v_are_the_forwards_bit_for_bit(heads):
+    """``block`` keeps the three flat products from their reshape to
+    heads where the rows are fewer than the weight's own (a decode
+    step's one token a row: models/transformer.py says why). What it
+    then hands the attention, at the decode step's shape and rope, is
+    bit for bit what the form the training forward keeps computes, the
+    reshape written on the product: the same three bfloat16 products,
+    the same rope, the same roundings."""
+    from ray_tpu.models.transformer import block, kind_rope, no_rotation
+    from ray_tpu.ops.norms import rmsnorm
+
+    cfg = TransformerConfig(vocab=97, d_model=64, n_layers=1, d_ff=64,
+                            max_seq=32, dtype=jnp.bfloat16,
+                            **QKV_HEADS[heads])
+    params = init_params(jax.random.key(3), cfg)
+    lp = jax.tree.map(lambda a: a[0] * 6 if a.ndim >= 3 else a[0],
+                      params["layers"])
+    slots = 4
+
+    def rope_at(pos):       # as ``slot_decode_step`` rotates: a row at
+        if not cfg.rope:    # its own position
+            return no_rotation
+        cos, sin = kind_rope(cfg, F, cfg.max_seq)
+        return lambda t: rotate(t, cos[pos][:, None, None, :],
+                                sin[pos][:, None, None, :])
+
+    def handed(q, k, v):    # what the block hands the attention
+        return jnp.zeros(q.shape[:3] + v.shape[3:], q.dtype), (q, k, v)
+
+    @jax.jit
+    def of_the_block(x, pos):
+        return block(lp, x, rope_at(pos), handed, cfg)[1]
+
+    @jax.jit
+    def written_on_the_product(x, pos):
+        B, T, _ = x.shape
+        h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
+        q = (h @ lp["wq"]).reshape(B, T, -1, cfg.head_dim)
+        k = (h @ lp["wk"]).reshape(B, T, -1, cfg.head_dim)
+        v = (h @ lp["wv"]).reshape(B, T, -1, cfg.v_dim)
+        if cfg.value_scale != 1.0:
+            v = v * cfg.value_scale
+        rope = rope_at(pos)
+        return rope(q), rope(k), v
+
+    key = jax.random.key(7)
+    for step in range(3):
+        key, a, b = jax.random.split(key, 3)
+        tokens = jax.random.randint(a, (slots,), 0, cfg.vocab)
+        pos = jax.random.randint(b, (slots,), 0, cfg.max_seq)
+        x = params["embed"][tokens][:, None, :] * 20     # [slots, 1, D]
+        for got, want, name in zip(of_the_block(x, pos),
+                                   written_on_the_product(x, pos), "qkv"):
+            assert got.dtype == want.dtype == jnp.bfloat16
+            assert got.shape == want.shape
+            assert float(jnp.std(want.astype(jnp.float32))) > 0.1
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(jnp.float32)),
+                np.asarray(want.astype(jnp.float32)),
+                err_msg=f"{name} at step {step}")
+    # the decode step's shape takes the flat form, the training shape
+    # (rows no fewer than the weight's) keeps the reshape on the product
+    kept_flat = "optimization_barrier"
+    assert kept_flat in str(jax.make_jaxpr(of_the_block)(x, pos))
+    many = jnp.zeros((2, cfg.max_seq, cfg.d_model), cfg.dtype)
+    assert kept_flat not in str(jax.make_jaxpr(
+        lambda x: block(lp, x, no_rotation, handed, cfg)[1])(many))
