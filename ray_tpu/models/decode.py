@@ -1,7 +1,7 @@
 """Cached autoregressive decoding for the flagship transformer: a
-slot's state between tokens, of the two kinds its layers keep.
+slot's state between tokens, of the kinds its layers keep.
 
-**Two kinds of state, one cache.** The cache is a dict of tuples, each
+**Rows or a summary, one cache.** The cache is a dict of tuples, each
 with one entry for each run of alike layers (``transformer.layer_runs``;
 a model whose layers are all alike has one run), and a run's entry is
 an array in the tuples of its own kind of state and ``None`` in the
@@ -26,7 +26,14 @@ others:
   the slots beside the channels, so that the two dimensions the chip
   tiles are whole multiples of a tile and not K - 1 = 3 rows). It has no rows and no position: it does not grow, a
   token's step replaces it whole, and what it was before cannot be read
-  back. A model without Mamba layers has neither tuple.
+  back. A model without Mamba layers has neither tuple;
+* a retention run keeps a summary too, a matrix a K/V head:
+  ``cache["ret"][r]`` ``[L, slots, G, Dv, D]`` float32, the head's
+  state S after the slot's last token (D = ``ops.retention.feature_dim``
+  of the head's width: 8,320 at 128), and ``cache["ret_z"][r]``
+  ``[L, slots, G, D]``, its normaliser. **A model of such layers alone
+  has no K/V at all**: no ``k``, no ``v``, no ring, and nothing in its
+  cache is sized by ``max_len``.
 
 A model with expert layers carries ``cache["load"]``, int32 [3]: the
 held experts that got a row, the rows routed to held experts and the
@@ -52,7 +59,10 @@ scan's carry:
   at ``[layer, rows, pos]`` and attends the layer's K/V, read out of
   the carry after that write; a Mamba layer reads the rows' state out
   of the carry, advances it by one position and writes it back (no
-  recompute, no dynamic shapes).
+  recompute, no dynamic shapes); a retention layer hands the run's
+  whole S and z to ``ops.retention.retention_step``, which on the TPU
+  is one kernel that reads each live row's state once and writes it
+  once where it lies, and touches no other row.
 
 **Which runs attend through which form.** A run of full-attention
 layers attends through
@@ -104,14 +114,17 @@ Invariants the scheduler relies on:
   rewrites rows [0, T0) (of a ring, the rows its last ``window``
   positions fall on) and resets the slot's pos; the stale tail beyond
   T0 is always overwritten (step s writes position pos BEFORE attending
-  it) and never attended. Of a summary: it replaces the slot's state
-  and tail whole, computed from zeros.
+  it) and never attended. Of a summary (a Mamba layer's state and
+  tail, a retention layer's S and z): it replaces the slot's whole,
+  computed from zeros.
 * ``slot_decode_step`` advances ``pos``, replaces ``tok`` and advances
-  a Mamba layer's state and tail only where ``active``: **an inactive
-  row's summary is bit for bit what it was** (the step computes a new
-  one for every row and writes back the old one there: the select
-  costs no traffic, the layer's state is read and written whole either
-  way). Rows are not so guarded, and need not be: a step writes every
+  a Mamba or retention layer's summary only where ``active``: **an
+  inactive row's summary is bit for bit what it was** (a Mamba layer's
+  step computes a new one for every row and writes back the old one
+  there: the select costs no traffic, the layer's state is read and
+  written whole either way; the retention kernel neither reads nor
+  writes such a row, and its XLA form selects as the Mamba step does).
+  Rows are not so guarded, and need not be: a step writes every
   row's K/V unconditionally (a masked write would cost a gather per
   layer), so an inactive row's cache may take garbage at its frozen
   pos, which no one reads before the next write there. A summary has
@@ -124,7 +137,8 @@ Invariants the scheduler relies on:
   last position; the callers refuse before that (``generate``'s
   ``T0 + steps > max_len``, ``JaxSlotEngine.step``'s host mirror).
   ``max_len`` bounds a slot by its attention runs' rows; a model of
-  Mamba layers alone is bounded by ``cfg.max_seq``.
+  Mamba or retention layers alone keeps nothing that grows, and its
+  bound is ``cfg.max_seq``, the rope's table.
 
 **Which part of the block an operation of a decode step computes** is
 in the compiled step's metadata: every stretch of ``slot_decode_step``
@@ -153,12 +167,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.transformer import (EXPERTS, FROM_THE_START,
-                                        LAYER_WEIGHTS, MAMBA, PARTS, WINDOW,
+                                        LAYER_WEIGHTS, MAMBA, PARTS,
+                                        RETENTION, SUMMARIES, WINDOW,
                                         TransformerConfig, block, kind_rope,
                                         layer_runs, layer_stacks,
-                                        no_rotation, roped_kinds, scan_run,
-                                        unembed)
-from ray_tpu.ops import ssm
+                                        no_rotation, retain_from_the_start,
+                                        roped_kinds, scan_run, unembed)
+from ray_tpu.ops import retention, ssm
 from ray_tpu.ops.attention import (cached_attention, decode_attention,
                                    decode_rows_fetched, flash_attention)
 from ray_tpu.ops.rotary import apply_rotary, rotate
@@ -191,13 +206,16 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
     [4, 192] or [8, 192] do not (the compiler's own layouts for those
     cost two copies of K a step). ``ssm`` and ``conv``, in a model with
     Mamba layers: a Mamba run's state [n, slots, N, C] float32 and its
-    convolution's tail [n, K - 1, slots, C]."""
+    convolution's tail [n, K - 1, slots, C]. ``ret`` and ``ret_z``, in
+    a model with retention layers: a retention run's state
+    [n, slots, G, Dv, D] and normaliser [n, slots, G, D], float32. A
+    model with no attention layer has no ``k`` and no ``v``."""
     runs = layer_runs(cfg)
 
     def rows(width):
         made = []
         for (mixer, _), n in runs:
-            if mixer == MAMBA:
+            if mixer in SUMMARIES:
                 made.append(None)
                 continue
             G = cfg.kv_heads(mixer)
@@ -207,34 +225,40 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
                 + row, cfg.dtype))
         return tuple(made)
 
-    def summaries(shape, dtype):
+    def summaries(of, shape, dtype):
         return tuple(jnp.zeros((n,) + shape, dtype)
-                     if mixer == MAMBA else None for (mixer, _), n in runs)
+                     if mixer == of else None for (mixer, _), n in runs)
 
-    cache = {"k": rows(cfg.head_dim), "v": rows(cfg.v_dim),
-             "pos": jnp.zeros((slots,), jnp.int32),
+    cache = {"pos": jnp.zeros((slots,), jnp.int32),
              "tok": jnp.zeros((slots,), jnp.int32)}
+    if any(mixer not in SUMMARIES for (mixer, _), _ in runs):
+        cache.update(k=rows(cfg.head_dim), v=rows(cfg.v_dim))
     if cfg.has_mamba:
-        cache["ssm"] = summaries((slots, cfg.ssm_state, cfg.ssm_inner),
+        cache["ssm"] = summaries(
+            MAMBA, (slots, cfg.ssm_state, cfg.ssm_inner), jnp.float32)
+        cache["conv"] = summaries(
+            MAMBA, (cfg.ssm_conv - 1, slots, cfg.ssm_inner), cfg.dtype)
+    if cfg.has_retention:
+        G, D = cfg.kv_heads(RETENTION), retention.feature_dim(cfg.head_dim)
+        cache["ret"] = summaries(RETENTION, (slots, G, cfg.v_dim, D),
                                  jnp.float32)
-        cache["conv"] = summaries((cfg.ssm_conv - 1, slots, cfg.ssm_inner),
-                                  cfg.dtype)
+        cache["ret_z"] = summaries(RETENTION, (slots, G, D), jnp.float32)
     if any(ffn == EXPERTS for (_, ffn), _ in runs):
         cache["load"] = jnp.zeros((3,), jnp.int32)
     return cache
 
 
 # which two tuples of the cache hold a run's state, by its mixer
-ROWS, SUMMARY = ("k", "v"), ("ssm", "conv")
+ROWS, SUMMARY, RETAINED = ("k", "v"), ("ssm", "conv"), ("ret", "ret_z")
 
 
 def _state_names(mixer: str):
-    return SUMMARY if mixer == MAMBA else ROWS
+    return {MAMBA: SUMMARY, RETENTION: RETAINED}.get(mixer, ROWS)
 
 
 def _cache_runs(cache: Dict, runs):
     """Each run's pair of state arrays: (K, V) of an attention run,
-    (state, tail) of a Mamba run."""
+    (state, tail) of a Mamba run, (S, z) of a retention run."""
     names = {name for (mixer, _), _ in runs for name in _state_names(mixer)}
     for name in sorted(names):
         if not (isinstance(cache.get(name), tuple)
@@ -249,7 +273,7 @@ def _cache_runs(cache: Dict, runs):
 
 def _with_states(cache: Dict, runs, states, **more) -> Dict:
     """``cache`` with each run's pair of state arrays replaced."""
-    new = {name: [None] * len(runs) for name in ROWS + SUMMARY
+    new = {name: [None] * len(runs) for name in ROWS + SUMMARY + RETAINED
            if name in cache}
     for r, (((mixer, _), _), pair) in enumerate(zip(runs, states)):
         for name, array in zip(_state_names(mixer), pair):
@@ -262,7 +286,7 @@ def _max_len(cfg: TransformerConfig, runs, states) -> int:
     """Rows of a full-attention run's cache: the longest sequence a slot
     holds (``cfg.max_seq`` where no layer keeps all its rows)."""
     return next((ck.shape[2] for ((mixer, _), _), (ck, _) in zip(runs, states)
-                 if mixer not in (WINDOW, MAMBA)), cfg.max_seq)
+                 if mixer != WINDOW and mixer not in SUMMARIES), cfg.max_seq)
 
 
 def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
@@ -274,12 +298,19 @@ def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
     layer, which is what ``JaxSlotEngine`` counts from its host mirror."""
     runs = layer_runs(cfg)
     for ((mixer, _), _), (ck, cv) in zip(runs, _cache_runs(cache, runs)):
-        if mixer not in (WINDOW, MAMBA):
+        if mixer != WINDOW and mixer not in SUMMARIES:
             q = jax.ShapeDtypeStruct(
                 (ck.shape[1], cfg.n_heads, cfg.head_dim), ck.dtype)
             return decode_rows_fetched(q, ck, cv,
                                        sink=mixer in cfg.sink_kinds)
     return None
+
+
+def keeps_summaries(cfg: TransformerConfig) -> bool:
+    """Whether a layer of ``cfg`` keeps a summary a slot (a Mamba
+    layer's state, a retention layer's): every row a decode step steps
+    then has that state read and written whole, whatever its length."""
+    return cfg.has_mamba or cfg.has_retention
 
 
 def _cache_rows(t, like, window: Optional[int]):
@@ -375,11 +406,26 @@ def slot_prefill(params, tokens, cache: Dict, slot,
         x, cs, tails = scan_run(body, (x, cs, tails), layers)
         return x, cs, lax.dynamic_update_slice(cc, tails, (0, 0, slot, 0))
 
+    def retention_run(x, layers, cs, cz):
+        def body(carry, lp, i):
+            x, cs, cz = carry   # the whole [L, slots, G, Dv, D], [.., G, D]
+            # the training forward's retention, from nothing before it;
+            # the slot's state is the prompt's and nothing else's
+            x, (S, z), _ = block(lp, x, ropes.get(RETENTION, no_rotation),
+                                 retain_from_the_start, cfg)
+            cs = lax.dynamic_update_slice(cs, S[None], (i, slot, 0, 0, 0))
+            cz = lax.dynamic_update_slice(cz, z[None], (i, slot, 0, 0))
+            return x, cs, cz
+
+        return scan_run(body, (x, cs, cz), layers)
+
     new = []
     for ((mixer, _), layers), (first, second) in zip(
             layer_stacks(params, cfg), states):
         if mixer == MAMBA:
             x, first, second = mamba_run(x, layers, first, second)
+        elif mixer == RETENTION:
+            x, first, second = retention_run(x, layers, first, second)
         else:
             x, first, second = attention_run(x, layers, first, second, mixer)
         new.append((first, second))
@@ -441,16 +487,20 @@ def slot_decode_step(params, cache: Dict, token, active,
     with jax.named_scope("full_attention"):
         rows = jnp.arange(B)    # of the cache's in-place write, either kind
 
+    def row_rope(mixer):
+        if mixer not in tables:
+            return no_rotation
+        cos, sin = tables[mixer]
+
+        def rope(t):  # every row at its own position
+            return rotate(t, cos[pos][:, None, None, :],
+                          sin[pos][:, None, None, :])
+        return rope
+
     def attention_run(x, load, layers, ck, cv, attention):
         window = cfg.window if attention == WINDOW else None
         at = pos if window is None else pos % window
-        rope = no_rotation
-        if attention in tables:
-            cos, sin = tables[attention]
-
-            def rope(t):  # every row at its own position
-                return rotate(t, cos[pos][:, None, None, :],
-                              sin[pos][:, None, None, :])
+        rope = row_rope(attention)
 
         def body(carry, lp, i):
             x, ck, cv, load = carry  # ck/cv: the whole [L, B, rows, G, Dh]
@@ -515,6 +565,27 @@ def slot_decode_step(params, cache: Dict, token, active,
 
         return scan_run(body, (x, cs, cc, load), layers)
 
+    def retention_run(x, load, layers, cs, cz):
+        rope = row_rope(RETENTION)
+
+        def body(carry, lp, i):
+            x, cs, cz, load = carry     # [L, B, G, Dv, D] and [L, B, G, D]
+
+            def retain(q, k, v, g):
+                with jax.named_scope("retention_step"):
+                    # the carry itself is the operand: a live row's
+                    # state is read and written where it lies, an
+                    # inactive row's not at all
+                    o, ns, nz = retention.retention_step(
+                        q[:, 0], k[:, 0], v[:, 0], g[:, 0], cs, cz, i,
+                        active)
+                return o[:, None], (ns, nz)
+
+            x, (cs, cz), got = block(lp, x, rope, retain, cfg)
+            return x, cs, cz, _tally(load, got)
+
+        return scan_run(body, (x, cs, cz, load), layers)
+
     load = jnp.zeros_like(cache["load"]) if "load" in cache else None
     new = []
     for r, (((mixer, _), layers), (first, second)) in enumerate(zip(
@@ -523,6 +594,9 @@ def slot_decode_step(params, cache: Dict, token, active,
             if mixer == MAMBA:
                 x, first, second, load = mamba_run(x, load, layers, first,
                                                    second)
+            elif mixer == RETENTION:
+                x, first, second, load = retention_run(
+                    x, load, layers, first, second)
             else:
                 x, first, second, load = attention_run(
                     x, load, layers, first, second, mixer)
@@ -636,6 +710,8 @@ def decode_parts(cfg: TransformerConfig) -> List[str]:
     want = {"embed", "head"} | {f"run{r}" for r in range(len(runs))}
     for (mixer, ffn), _ in runs:
         want |= ({"mamba_mixer", "ssm_step"} if mixer == MAMBA
+                 else {"qkv", "retention_step", "attn_out"}
+                 if mixer == RETENTION
                  else {"qkv", f"{mixer}_attention", "attn_out"})
         want |= {"router", "experts"} if ffn == EXPERTS else {"mlp"}
     return sorted(want)

@@ -4,9 +4,12 @@ share of sparse experts), whose layers may be of several kinds in one
 model. The mixer is attention (all heads alike, or fewer K/V heads than
 query heads; rope on the whole head, on its first dimensions or not at
 all; full or windowed, each with its own K/V heads and rope base, with
-or without a sink logit) or a Mamba layer: a gated selective
+or without a sink logit), a Mamba layer: a gated selective
 state-space recurrence behind a short causal convolution
-(ops/ssm.py), which carries the order of the sequence itself.
+(ops/ssm.py), which carries the order of the sequence itself, or a
+power-retention layer: attention's projections, heads and rope with
+``(q.k)^2`` in the place of ``exp(q.k)`` and a learned decay, whose
+whole past is a matrix a K/V head (ops/retention.py).
 
 **One block.** ``block`` is the only definition of the layer: norms,
 projections, feed-forward and residuals. What differs between training,
@@ -14,7 +17,8 @@ prefill and decode is handed to it: ``rope`` (how q and k are rotated)
 and ``attend`` (what the queries attend, or how a Mamba layer's
 convolution and scan run, and the state that comes back); what differs
 between layers is in the layer's own weights (a router makes it an
-expert layer, ``w_in`` a Mamba layer) and in the closures its caller
+expert layer, ``w_in`` a Mamba layer, ``w_g`` a retention layer) and
+in the closures its caller
 builds for its kind. ``forward`` here and ``slot_prefill`` /
 ``slot_decode_step`` in models/decode.py each scan it over the stacked
 layers; ``unembed`` is their shared final norm and head. A change to
@@ -60,6 +64,7 @@ from jax import lax
 
 from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.retention import retention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
 from ray_tpu.parallel.collectives import (axis_size, shard_map,
@@ -73,8 +78,13 @@ from jax.sharding import PartitionSpec as P
 
 
 # A layer's kind: (mixer, feed-forward). The mixer is one of the two
-# kinds of attention or a Mamba layer.
-FULL, WINDOW, MAMBA = "full", "window", "mamba"
+# kinds of attention, a Mamba layer or a power-retention layer (of
+# degree 2: a pair scores (q.k)^2, the feature map of ops/retention.py;
+# no other degree is expressed, so the kind has no field for one).
+FULL, WINDOW, MAMBA, RETENTION = "full", "window", "mamba", "retention"
+# the mixers that keep no row a position but a summary that does not
+# grow: nothing of theirs is sharded over tp, sp or pp
+SUMMARIES = (MAMBA, RETENTION)
 DENSE, EXPERTS = "dense", "experts"
 LayerKind = Tuple[str, str]
 
@@ -92,7 +102,8 @@ LayerKind = Tuple[str, str]
 # as ``mamba_mixer``.
 PARTS = (
     "embed",                # the token's select and the embedding lookup
-    "qkv",                  # attention norm, wq / wk / wv, rope, value scale
+    "qkv",                  # attention norm, wq / wk / wv, the q and k norms,
+                            # rope, value scale, a retention layer's gate
     "full_attention",       # scores, softmax, p.V (or the kernel's call)
     "window_attention",     # ... and the cache's in-place write beside them
     "attn_out",             # wo and the residual add
@@ -100,6 +111,9 @@ PARTS = (
     "ssm_step",             # a decode step's recurrence, its state read and
                             # written where it lies
     "ssm_scan",             # the recurrence over a whole sequence
+    "retention_step",       # a decode step's retention: the state read,
+                            # advanced and written where it lies
+    "retention_chunk",      # retention over a whole sequence, chunk by chunk
     "router",               # feed-forward norm and the router
     "experts",              # the held experts' products and the residual
     "mlp",                  # the dense feed-forward, its norm and residual
@@ -159,6 +173,9 @@ class TransformerConfig:
     ssm_state: int = 0
     ssm_dt_rank: int = 0
     ssm_conv: int = 0
+    # an RMSNorm over each head of q and of k, before rope (weights
+    # q_norm, k_norm [head_dim], shared by the heads)
+    qk_norm: bool = False
 
     def __post_init__(self):
         def refuse(key, why):
@@ -170,8 +187,8 @@ class TransformerConfig:
                 refuse("layer_kinds", f"{len(kinds)} kinds for "
                        f"{self.n_layers} layers")
             for mixer, ffn in kinds:
-                if mixer not in (FULL, WINDOW, MAMBA) or ffn not in (
-                        DENSE, EXPERTS):
+                if mixer not in (FULL, WINDOW, MAMBA, RETENTION) or ffn \
+                        not in (DENSE, EXPERTS):
                     refuse("layer_kinds", f"unknown kind "
                            f"{(mixer, ffn)!r}")
         if any(a == WINDOW for a, _ in kinds or ()) and not self.window:
@@ -202,6 +219,9 @@ class TransformerConfig:
                 and self.ssm_dt_rank > 0 and self.ssm_conv > 1):
             refuse("ssm_inner", "Mamba layers need ssm_inner, ssm_state, "
                    "ssm_dt_rank and a convolution of at least 2 positions")
+        if self.has_retention and self.head_dim % 2:
+            refuse("qk_head_dim", f"retention layers need heads of even "
+                   f"width, not {self.head_dim}")
 
     @property
     def head_dim(self) -> int:
@@ -220,6 +240,10 @@ class TransformerConfig:
     @property
     def has_mamba(self) -> bool:
         return any(mixer == MAMBA for mixer, _ in self.layer_kinds or ())
+
+    @property
+    def has_retention(self) -> bool:
+        return any(mixer == RETENTION for mixer, _ in self.layer_kinds or ())
 
     def kv_heads(self, attention: str = FULL) -> int:
         heads = self.n_kv_heads or self.n_heads
@@ -294,6 +318,19 @@ def mamba_dt_bias(key, shape, low: float = 1e-3, high: float = 1e-1):
     return dt + jnp.log(-jnp.expm1(-dt))       # softplus's inverse
 
 
+def retention_gate_bias(key, shape, low: float = 64.0, high: float = 8192.0):
+    """A start for the bias of a retention layer's gate: such that the
+    per-step decay sigmoid(bias) has a half-life log-uniform between
+    ``low`` and ``high`` positions, so that under fresh weights (whose
+    projection adds next to nothing) a head's state neither dies in a
+    few tokens (bias 0 halves it every step) nor stands still.
+    float32."""
+    half = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                   * (jnp.log(high) - jnp.log(low)) + jnp.log(low))
+    decay = jnp.exp2(-1.0 / half)
+    return jnp.log(decay) - jnp.log1p(-decay)       # sigmoid's inverse
+
+
 def _init_mamba(keys, cfg: TransformerConfig, n: int):
     """The mixer of ``n`` stacked Mamba layers. Matrices normal(0.02)
     at the model's dtype; what feeds the recurrence is float32 and
@@ -350,6 +387,11 @@ def _init_run(keys, cfg: TransformerConfig, kind: LayerKind, n: int):
             "wo": w(keys[3], (n, H * Dv, D)),
             "mlp_norm": jnp.ones((n, D), dt),
         }
+    if attention != MAMBA and cfg.qk_norm:
+        run.update(q_norm=jnp.ones((n, Dh), dt), k_norm=jnp.ones((n, Dh), dt))
+    if attention == RETENTION:
+        run.update(w_g=w(keys[7], (n, D, G)),
+                   b_g=retention_gate_bias(keys[8], (n, G)))
     if attention in cfg.sink_kinds:
         run["sink"] = init(keys[7], (n, H), jnp.float32)
     if ffn == EXPERTS:
@@ -400,11 +442,12 @@ def param_specs(pcfg: ParallelConfig,
     of one kind without ``cfg``, else of ``cfg``'s. Heads and the
     feed-forward's width (an expert's own, inside each expert) go over
     ``tp``; a stack's layers over ``pp``, which only a model of one
-    kind can have. A model with Mamba layers is refused ``tp``, ``sp``
-    and ``pp``: no sharding of that mixer is expressed."""
+    kind can have. A model with Mamba or retention layers is refused
+    ``tp``, ``sp`` and ``pp``: no sharding of those mixers is
+    expressed."""
     pp, tp = pcfg.pp, pcfg.tp
     if cfg is not None:
-        _refuse_a_sharded_mamba(cfg, pcfg)
+        _refuse_a_sharded_summary(cfg, pcfg)
 
     def run_specs(kind):
         attention, ffn = kind
@@ -423,6 +466,10 @@ def param_specs(pcfg: ParallelConfig,
                 "wo": P(pp, tp, None),
                 "mlp_norm": P(pp, None),
             }
+        if cfg is not None and cfg.qk_norm and attention != MAMBA:
+            specs.update(q_norm=P(pp, None), k_norm=P(pp, None))
+        if attention == RETENTION:      # refused tp and pp: replicated
+            specs.update(w_g=P(None, None, None), b_g=P(None, None))
         if cfg is not None and attention in cfg.sink_kinds:
             specs["sink"] = P(pp, tp)
         if ffn == EXPERTS:
@@ -449,15 +496,20 @@ def param_specs(pcfg: ParallelConfig,
     return specs
 
 
-def _refuse_a_sharded_mamba(cfg: TransformerConfig,
-                            pcfg: ParallelConfig) -> None:
+def _refuse_a_sharded_summary(cfg: TransformerConfig,
+                              pcfg: ParallelConfig) -> None:
     sharded = [axis for axis in ("tp", "sp", "pp") if getattr(pcfg, axis)]
-    if cfg.has_mamba and sharded:
-        raise ValueError(
-            f"a model with Mamba layers runs on one device or under dp "
-            f"alone: no sharding of that mixer over {', '.join(sharded)} "
-            f"is expressed (its channels for tp, its scan for sp, its "
-            f"runs of unlike layers for pp)")
+    for has, layers, what in (
+            (cfg.has_mamba, "Mamba", "its channels for tp, its scan for sp, "
+             "its runs of unlike layers for pp"),
+            (cfg.has_retention, "retention", "its K/V heads and their "
+             "states for tp, its chunks' carried state for sp, a stack "
+             "with a state a layer for pp")):
+        if has and sharded:
+            raise ValueError(
+                f"a model with {layers} layers runs on one device or under "
+                f"dp alone: no sharding of that mixer over "
+                f"{', '.join(sharded)} is expressed ({what})")
 
 
 def _attend(q, k, v, pcfg: ParallelConfig, window=None, sink=None):
@@ -514,6 +566,14 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
     width) and gives the attention output, which is flattened here to
     [B, T, H_local * Dv], and whatever the caller keeps of the layer
     (the K/V a cache holds; None in training). A layer that has
+    ``w_g`` is a retention layer: the same projections, heads and rope,
+    and beside them the gate, g [B, T, G] float32, the log of each K/V
+    head's decay at each position (log sigmoid of a projection of the
+    normed input and a bias, <= 0); its caller's ``attend(q, k, v, g)``
+    runs the retention from whatever state it starts from and the
+    state that comes back is (S, z) (ops/retention.py). Where
+    ``cfg.qk_norm``, q and k of either pass through an RMSNorm over
+    each head before rope. A layer that has
     ``w_in`` is a Mamba layer: ``rope`` is not called and ``attend`` is
     an ``ops.ssm.Recurrence``, the convolution and the scan as its
     caller runs them (:func:`mamba_mixer`); the state that comes back
@@ -541,8 +601,16 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
             v = _heads(h, lp["wv"], cfg.v_dim)
             if cfg.value_scale != 1.0:
                 v = v * cfg.value_scale
+            if cfg.qk_norm:
+                q = rmsnorm(q, lp["q_norm"], eps=cfg.norm_eps)
+                k = rmsnorm(k, lp["k_norm"], eps=cfg.norm_eps)
             q, k = rope(q), rope(k)
-        o, state = attend(q, k, v)          # the caller's scope, by kind
+            gate = ()
+            if "w_g" in lp:
+                gate = (jax.nn.log_sigmoid(jnp.matmul(
+                    h, lp["w_g"], preferred_element_type=jnp.float32)
+                    + lp["b_g"]),)
+        o, state = attend(q, k, v, *gate)   # the caller's scope, by kind
         with jax.named_scope("attn_out"):
             o = o.reshape(B, T, -1) @ lp["wo"]         # row-parallel
             if pcfg.tp:
@@ -672,6 +740,14 @@ def roped_kinds(cfg: TransformerConfig, runs) -> list:
                               if mixer != MAMBA))
 
 
+def retain_from_the_start(q, k, v, g):
+    """A retention layer's ``attend`` over a whole sequence from nothing
+    before it: training's, which drops the state that comes back, and a
+    prefill's, which keeps it."""
+    with jax.named_scope("retention_chunk"):
+        return retention(q, k, v, g)
+
+
 def no_rotation(t):
     """``rope`` of a model whose attention has no positional term."""
     return t
@@ -698,9 +774,8 @@ def _stack_fn(cfg, pcfg, rope, kind: LayerKind = (FULL, DENSE)):
             with jax.named_scope(f"{kind[0]}_attention"):
                 return _attend(q, k, v, pcfg, window, lp.get("sink")), None
 
-        return block(lp, x, rope,
-                     FROM_THE_START if kind[0] == MAMBA else attend,
-                     cfg, pcfg)[0]
+        how = {MAMBA: FROM_THE_START, RETENTION: retain_from_the_start}
+        return block(lp, x, rope, how.get(kind[0], attend), cfg, pcfg)[0]
 
     if pcfg.remat:
         layer = jax.checkpoint(layer)
@@ -718,7 +793,7 @@ def forward(params, tokens, cfg: TransformerConfig,
     Call directly for the oracle, or inside shard_map for SPMD.
     """
     T = tokens.shape[1]
-    _refuse_a_sharded_mamba(cfg, pcfg)
+    _refuse_a_sharded_summary(cfg, pcfg)
     stacks = layer_stacks(params, cfg)
     if pcfg.pp and len(stacks) > 1:
         raise ValueError("a pipeline over layers of several kinds is not "

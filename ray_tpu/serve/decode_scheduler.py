@@ -81,7 +81,11 @@ name, to be read as deltas:
   one written to, the block ``ops.attention.decode_attention`` takes
   for the cache's shape; all ``max_len`` where its XLA form runs) and
   ``serve.engine.kv_rows_held`` (stepped slots x ``max_len``): their
-  ratio is how far the bounded read engages. Of a model with expert
+  ratio is how far the bounded read engages. Of a model whose layers
+  keep a summary a slot (Mamba, retention), likewise once a dispatched
+  step: ``serve.engine.state_rows`` (the rows in it, each of which has
+  its whole state read and written, owed an answer or not). Of a
+  model with expert
   layers the same fetch brings three counts of the step it fetched
   (wasted rows included), each summed over its expert layers:
   ``serve.engine.experts_hit`` (held experts that got a row),
@@ -158,8 +162,12 @@ class DecodeScheduler:
     """One in-flight decode batch; admission at step boundaries.
 
     ``submit`` is awaited per request and resolves with the generated
-    token list. The background loop starts lazily on the first submit
-    and parks (zero cycles) whenever queue and batch are both empty.
+    token list. The background loop starts lazily on a submit and ends
+    whenever queue and batch are both empty; the next submit starts it
+    again. An idle scheduler therefore holds no task: one that nobody
+    refers to any more is collected, and its engine's device cache
+    with it (a parked task would keep both alive for the process's
+    life: 4.4 GB of state beside whatever the owner runs next).
     """
 
     def __init__(self, engine, *, max_queue_depth: Optional[int] = None,
@@ -177,7 +185,6 @@ class DecodeScheduler:
         self._active: Dict[int, _Request] = {}
         self._max_queue_depth = int(max_queue_depth)
         self._retry_after_s = float(retry_after_s)
-        self._wakeup = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
         self._closed = False
         # counters surfaced by stats() (and the replica's stats() ->
@@ -223,7 +230,6 @@ class DecodeScheduler:
                        asyncio.get_running_loop().create_future(),
                        time.perf_counter())
         self._queue.append(req)
-        self._wakeup.set()
         if self._loop_task is None or self._loop_task.done():
             self._loop_task = rpc.spawn_logged(self._run(),
                                                "serve-decode-loop")
@@ -408,9 +414,8 @@ class DecodeScheduler:
         while not self._closed:
             await self._admit()
             if not self._active:
-                self._wakeup.clear()
                 if not self._queue:
-                    await self._wakeup.wait()
+                    return  # idle: ``submit`` starts the loop again
                 continue
             tokens = {slot: req.tokens[-1]
                       for slot, req in self._active.items()}
@@ -446,8 +451,9 @@ class _Flight(NamedTuple):
 
 class JaxSlotEngine:
     """Adapts the per-slot cache (models/decode.py: an attention
-    layer's K/V rows, a Mamba layer's recurrent state) to the
-    scheduler's engine protocol. Greedy decoding; prompts are int
+    layer's K/V rows, a Mamba layer's recurrent state, a retention
+    layer's matrix state) to the scheduler's engine protocol. Greedy
+    decoding; prompts are int
     token-id sequences. One compiled prefill program per distinct
     prompt length, one compiled step program total.
 
@@ -488,7 +494,14 @@ class JaxSlotEngine:
     state the new prompt's (rows [0, T0) and the position; the
     recurrent state computed from zeros), so no request sees its
     predecessor. ``max_len`` bounds a slot by its attention layers'
-    rows; recurrent state does not grow.
+    rows; recurrent state does not grow, and for a model of Mamba or
+    retention layers alone ``max_len`` sizes no buffer but the rope's
+    table: the capacity check stays, since a position past the table
+    has no rotation. Such a model's step moves each stepped row's whole
+    state whoever is owed its token, so the rows a step moved are
+    counted beside the answers it owed: ``serve.engine.state_rows``
+    (``[steps dispatched, rows in them]``, from the host's mirror, no
+    device read) against the ``rows`` a caller keeps of its own calls.
 
     A call makes one device-to-host transfer, and none before its
     dispatch. The slots' positions are mirrored on the host: ``_pos``
@@ -502,8 +515,9 @@ class JaxSlotEngine:
     the row that steers the step (host only), ``put`` sends that one
     int32 row, ``wait`` fetches the row of picks of the step before,
     ``read`` builds the dict from that host array; ``serve.engine.ahead``,
-    ``.rows_wasted``, ``.kv_rows_read`` and ``.kv_rows_held`` count
-    beside them (module docstring). No ``block_until_ready``: the fetch
+    ``.rows_wasted``, ``.kv_rows_read``, ``.kv_rows_held`` and
+    ``.state_rows`` count beside them (module docstring). No
+    ``block_until_ready``: the fetch
     waits for the device.
 
     The cache is one device buffer for the engine's life: both programs
@@ -535,6 +549,8 @@ class JaxSlotEngine:
         # positions a full-attention layer fetches at a time for a slot
         # (models/decode.py; None: the model has no such layer)
         self._kv_block = decode_mod.kv_rows_fetched(cfg, self._cache)
+        # whether a stepped row's summary state is read and written whole
+        self._summary = decode_mod.keeps_summaries(cfg)
 
     def parts(self) -> Optional[Dict[str, list]]:
         """``{instruction: [run, part]}`` of the compiled decode step
@@ -662,6 +678,8 @@ class JaxSlotEngine:
             phase_add("serve.engine.kv_rows_read",
                       sum((self._pos[slot] // n + 1) * n for slot in rode))
             phase_add("serve.engine.kv_rows_held", len(rode) * self.max_len)
+        if self._summary:
+            phase_add("serve.engine.state_rows", len(rode))
         for slot in rode:
             self._pos[slot] += 1
         return _Flight(row, rode, len(rode))

@@ -31,6 +31,16 @@ or a layer's state, each prefill holding both kernels (``ssm_scan`` a
 Mamba run, ``flash_fwd`` an attention run), and the scan kernel alone
 accepted by Mosaic at the cell's three prompt lengths.
 
+The fourth serving cell's programs (``brumby-14b-d8``: every mixer a
+power-retention layer, a matrix state a K/V head and no K/V at all)
+are held to the same at that cell's shapes: the cache has no key and no
+value leaf, its state leaves are aliased to the result, no operation
+yields an array of the run's or a layer's state but the in-place writes
+and the step's own kernel, the decode step's temporaries stay in the
+tens of MB, a decode step names one Mosaic call ``retention_step`` and
+a prefill one ``retention_chunk``, and both kernels alone are accepted
+by Mosaic at the cell's widths.
+
 Every serving cell's decode step is also held to reading each layer's
 ``wq``, ``wk`` and ``wv`` where they lie in the stack: no slice of a
 layer's matrix into fast memory as an operation of its own and no
@@ -50,6 +60,7 @@ import pytest
 KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 SCAN_KERNEL = "ssm_scan"
 DECODE_KERNEL = "decode_attend"
+RETENTION_KERNELS = ("retention_chunk", "retention_step")
 # B, T, H, Dh, the dtype, and whether the gradient is compiled too
 SHAPES = {
     # ouro-2.6b-d12.train-2k
@@ -108,8 +119,8 @@ def mosaic_calls(compiled_text):
     names = re.findall(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
         compiled_text)
-    return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL, DECODE_KERNEL)) or n
-                  for n in names)
+    return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL, DECODE_KERNEL)
+                            + RETENTION_KERNELS) or n for n in names)
 
 
 @pytest.mark.parametrize("cell", sorted(SHAPES))
@@ -730,10 +741,9 @@ def hybrid_cell():
 def as_on_the_tpu(monkeypatch):
     """This process's default backend is the CPU; the programs are for
     the described chip, so take the branches a TPU process takes."""
-    attention = importlib.import_module("ray_tpu.ops.attention")
-    ssm = importlib.import_module("ray_tpu.ops.ssm")
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    for ops in ("attention", "ssm", "retention"):
+        monkeypatch.setattr(importlib.import_module("ray_tpu.ops." + ops),
+                            "_on_tpu", lambda: True)
 
 
 @pytest.mark.parametrize("length", [128, 256, 512])
@@ -886,16 +896,222 @@ def test_the_third_cells_serving_programs_lower_to_the_text_on_record(
     assert serving_programs_lowered(hybrid_cell, one_chip) == LOWERED_HYBRID
 
 
+# --------------- retention layers alone: a matrix state and no K/V at all
+
+RETENTION_CELL = "brumby-14b-d8.longdoc-closed"
+LOWERED_RETENTION = {
+    "decode": "04d22836d1841314",
+    "prefill-2048": "506ce466008ff5d8",
+    "prefill-4096": "de88dcd2d68124f8",
+    "prefill-8192": "8ac4d211427ad764",
+}
+
+
+@pytest.fixture(scope="module")
+def retention_cell():
+    """The cell of the model whose every mixer is a retention layer, as
+    the benchmark's worker builds it."""
+    from benchmarks import loader
+
+    bench = loader.load_benchmark()
+    cell = loader.find_cell(bench, RETENTION_CELL)
+    config = loader.load_config(bench, cell["config"])
+    mix = loader.load_traffic(bench, cell["traffic"])
+    program = loader.family_module(loader.find_family(bench, config),
+                                   "program")
+    return (program.program_config(config, mix["slot_len"]),
+            int(mix["slots"]), int(mix["slot_len"]),
+            sorted(mix["prompt_lengths"]))
+
+
+def test_the_fourth_serving_cells_shapes_are_the_ones_compiled_here(
+        retention_cell):
+    from ray_tpu.models.transformer import layer_runs
+
+    cfg, slots, slot_len, lengths = retention_cell
+    assert (slots, slot_len, lengths) == (16, 9216, [2048, 4096, 8192])
+    assert (cfg.n_heads, cfg.head_dim, cfg.kv_heads("retention"),
+            cfg.qk_norm, cfg.rope, cfg.d_model,
+            cfg.d_ff, cfg.vocab, cfg.tie_embeddings, cfg.max_seq) == (
+        40, 128, 8, True, True, 5120, 17408, 151936, False, 9216)
+    assert [(kind[0], n) for kind, n in layer_runs(cfg)] == [
+        ("retention", 8)]
+    # a prefill on either side of the rule that keeps the flat q, k and
+    # v products from their reshape to heads (rows fewer than d_model)
+    assert lengths[0] < lengths[1] < cfg.d_model < lengths[2]
+
+
+@pytest.mark.parametrize("kernel", ["chunk-2048", "chunk-8192", "step"])
+def test_the_retention_kernels_compile_for_v5e_under_their_own_names(
+        kernel, one_chip, no_compile_cache, as_on_the_tpu):
+    """``retention_chunk`` and ``retention_step`` at the fourth serving
+    cell's widths (40 query heads on 8 K/V heads of 128, bfloat16 in, a
+    float32 state [128, 8320] a head): Mosaic accepts them at the
+    blocks ``ops.retention`` chooses, and the program names its one
+    custom call after the kernel, which is what the benchmark's readers
+    look up in a device trace. The step's state arrays, the run's whole
+    (4.4 GB at 16 slots), alias its results: the kernel's own
+    temporaries are some hundreds of KB."""
+    import jax
+    import jax.numpy as jnp
+
+    ret = importlib.import_module("ray_tpu.ops.retention")
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    D = ret.feature_dim(128)
+    assert D == 8320
+    if kernel == "step":
+        slots, layers = 16, 8
+        compiled = jax.jit(ret.retention_step, donate_argnums=(4, 5)).lower(
+            array((slots, 40, 128), jnp.bfloat16),
+            array((slots, 8, 128), jnp.bfloat16),
+            array((slots, 8, 128), jnp.bfloat16),
+            array((slots, 8), jnp.float32),
+            array((layers, slots, 8, 128, D), jnp.float32),
+            array((layers, slots, 8, D), jnp.float32),
+            array((), jnp.int32), array((slots,), jnp.bool_)).compile()
+        memory = compiled.memory_analysis()
+        state = 4 * layers * slots * 8 * (128 + 1) * D
+        assert 0 <= memory.alias_size_in_bytes - state <= 8192
+        assert memory.temp_size_in_bytes < 2 ** 20
+        name = "retention_step"
+    else:
+        length = int(kernel.split("-")[1])
+        compiled = jax.jit(ret.retention).lower(
+            array((1, length, 40, 128), jnp.bfloat16),
+            array((1, length, 8, 128), jnp.bfloat16),
+            array((1, length, 8, 128), jnp.bfloat16),
+            array((1, length, 8), jnp.float32)).compile()
+        name = "retention_chunk"
+    text = compiled.as_text()
+    assert f"%{name}.1 = " in text
+    assert mosaic_calls(text) == [name]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-2048",
+                                     "prefill-8192"])
+def test_a_matrix_state_and_no_kv_is_written_in_place(
+        program, retention_cell, one_chip, no_compile_cache, as_on_the_tpu):
+    """``slot_decode_step`` and ``slot_prefill`` of the model of
+    retention layers alone, at its cell's shapes, for the described
+    v5e. The cache has no key and no value leaf; its four leaves (the
+    run's state, 4.36 GB, its normaliser, pos and tok) are aliased to
+    the result; no operation produces an array of the run's or of a
+    layer's state or normaliser but the in-place writes and, in a
+    decode step, the step's own kernel, whose results they are; a
+    decode step's temporaries stay in the tens of MB (a copy of one
+    layer's state of the 16 slots is 545 MB, of the run's 4.4 GB);
+    arguments and temporaries fit the chip; a decode step holds one
+    ``retention_step`` call for the run, a prefill one
+    ``retention_chunk``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode, init_params
+
+    cfg, slots, slot_len, _ = retention_cell
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len)))
+    assert set(cache) == {"pos", "tok", "ret", "ret_z"}
+    assert [a.shape for a in cache["ret"]] == [(8, 16, 8, 128, 8320)]
+    assert [a.shape for a in cache["ret_z"]] == [(8, 16, 8, 8320)]
+    if program == "decode":
+        compiled = compiled_decode(RETENTION_CELL, cfg, slots, slot_len,
+                                   one_chip)
+    else:
+        length = int(program.split("-")[1])
+        compiled = decode.slot_prefill.lower(
+            params, array((1, length), jnp.int32), cache,
+            array((), jnp.int32), cfg).compile()
+    text = compiled.as_text()
+
+    leaves = re.findall(
+        r"parameter\((\d+)\)[^\n]*op_name=\"cache\[([^\"]*)\]\"", text)
+    assert len(leaves) == 4
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliased, "nothing is aliased: the cache is not donated"
+    assert sorted(int(n) for n in re.findall(
+        r"\((\d+), \{\}, may-alias\)", aliased.group(1))
+    ) == sorted(int(n) for n, _ in leaves)
+
+    held = list(cache["ret"] + cache["ret_z"])
+    state_bytes = sum(4 * math.prod(leaf.shape) for leaf in held)
+    memory = compiled.memory_analysis()
+    assert 0 <= memory.alias_size_in_bytes - state_bytes <= 8192
+    one_layer = state_bytes // 8
+    assert memory.temp_size_in_bytes < (
+        one_layer // 8 if program == "decode" else 4 * one_layer)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < HBM_BYTES)
+
+    shapes = {leaf.shape for leaf in held} | {leaf.shape[1:]
+                                              for leaf in held}
+    # a bitcast names memory; a copy-start/copy-done pair (with the
+    # slices a ``ConcatBitcast`` names as one array) is the compiler's
+    # own move of the run's normaliser (34 MB, a hundredth of the
+    # state) into the chip's fast memory for the length of the step
+    produced = [(name, op) for name, op in cache_producers(text, shapes)
+                if op not in ("bitcast", "copy-start", "copy-done",
+                              "ConcatBitcast")]
+    allowed = set(IN_PLACE) | (
+        {"tpu_custom_call"} if program == "decode" else set())
+    assert {op for _, op in produced} <= allowed, produced
+    # a prefill's write of the state and of its normaliser; a decode
+    # step's are the kernel's own results, one tuple, which no shape
+    # above names
+    assert len(produced) == (0 if program == "decode" else 2), produced
+    assert mosaic_calls(text) == [
+        "retention_step" if program == "decode" else "retention_chunk"]
+
+
+def test_the_fourth_cells_decode_step_hands_the_host_a_row_of_picks(
+        retention_cell, one_chip, no_compile_cache, as_on_the_tpu):
+    cfg, slots, slot_len, _ = retention_cell
+    compiled = compiled_decode(RETENTION_CELL, cfg, slots, slot_len,
+                               one_chip)
+    text = compiled.as_text()
+    root = re.search(r"ROOT %[\w.\-]+ = \(([^\n]*?)\) tuple\(",
+                     text[text.index("\nENTRY "):]).group(1)
+    results = re.findall(r"(\w+)\[([\d,]*)\]", root)
+    # the row of picks; beside it the cache's pos and tok
+    assert results.count(("s32", str(slots))) == 3
+    assert not [r for r in results
+                if r[1] == f"{slots},{cfg.vocab}"], results
+    memory = compiled.memory_analysis()
+    assert 4 * slots <= (memory.output_size_in_bytes
+                         - memory.alias_size_in_bytes) <= 4096
+
+
+def test_the_fourth_cells_serving_programs_lower_to_the_text_on_record(
+        retention_cell, one_chip, as_on_the_tpu):
+    assert serving_programs_lowered(retention_cell, one_chip) == \
+        LOWERED_RETENTION
+
+
 # --------------- q, k and v read out of the stacked weights where they lie
 
 CELL_FIXTURES = {SERVING_CELL: "serving_cell", KINDS_CELL: "kinds_cell",
-                 HYBRID_CELL: "hybrid_cell"}
+                 HYBRID_CELL: "hybrid_cell",
+                 RETENTION_CELL: "retention_cell"}
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_FIXTURES))
 def test_a_decode_steps_q_k_v_products_read_the_stack_where_it_lies(
         cell, request, one_chip, no_compile_cache, as_on_the_tpu):
-    """The three serving cells' decode steps as compiled for the
+    """The four serving cells' decode steps as compiled for the
     described v5e (``compiled_decode``: no compile where a test above
     has run). Outside fused computations no ``copy`` and no fusion
     rooted in a ``dynamic-slice`` yields an array of a layer's ``wq``,
@@ -906,7 +1122,8 @@ def test_a_decode_steps_q_k_v_products_read_the_stack_where_it_lies(
     compiler's own prefetches (``copy-start`` / ``copy-done``) are not
     counted, nor a copy of the rows' flat result ([slots, 1, H * Dh]).
     And in a run that kept its loop each of the three products under
-    the scope ``qkv`` takes the stacked leaf itself among its operands,
+    the scope ``qkv`` (four with a retention layer's gate) takes the
+    stacked leaf itself among its operands,
     as ``wo``'s and the feed-forward's do. With the reshape to heads
     folded into the product XLA asks for the weight transposed, and
     this fails in all three cells: six such instructions in cell 1,
@@ -918,7 +1135,9 @@ def test_a_decode_steps_q_k_v_products_read_the_stack_where_it_lies(
 
     cfg, slots, slot_len, _ = request.getfixturevalue(CELL_FIXTURES[cell])
     text = compiled_decode(cell, cfg, slots, slot_len, one_chip).as_text()
-    stacks = [{name: stack[name].shape for name in ("wq", "wk", "wv")}
+    # (a retention layer's gate is a fourth product of the part)
+    stacks = [{name: stack[name].shape
+               for name in ("wq", "wk", "wv", "w_g") if name in stack}
               for _, stack in layer_stacks(jax.eval_shape(
                   lambda: init_params(jax.random.key(0), cfg)), cfg)
               if "wq" in stack]
@@ -943,6 +1162,6 @@ def test_a_decode_steps_q_k_v_products_read_the_stack_where_it_lies(
         if comp != entry and does not in ("parameter", "get-tuple-element")
         and re.search(r'op_name="[^"]*/qkv/dot_general"', rest)]
     looped = [run for run in stacks if run["wq"][0] > 1]
-    assert len(products) == 3 * len(looped), products
+    assert len(products) == sum(len(run) for run in looped), products
     leaves = {shape for run in looped for shape in run.values()}
     assert all(leaves & set(operands) for _, operands in products), products

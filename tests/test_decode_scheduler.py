@@ -121,6 +121,73 @@ def test_single_request_generates_max_tokens():
     asyncio.run(run())
 
 
+def test_an_idle_scheduler_holds_no_task_and_is_collected_with_its_engine():
+    """The loop ends when queue and batch are empty and the next submit
+    starts it again; between bursts nothing but its owner refers to the
+    scheduler, so dropping it frees the engine (on the chip: the slot
+    cache, gigabytes that whatever runs next may need)."""
+    import gc
+    import weakref
+
+    async def run():
+        engine = FreeRunEngine(slots=2)
+        sched = DecodeScheduler(engine)
+        assert await sched.submit([1], max_tokens=3) == [101, 102, 103]
+        await asyncio.sleep(0)          # the loop sees nothing to do
+        assert sched._loop_task.done()
+        # a second burst starts it again
+        assert await asyncio.gather(
+            sched.submit([5], max_tokens=2), sched.submit([7], max_tokens=4)
+        ) == [[105, 106], [107, 108, 109, 110]]
+        await asyncio.sleep(0)
+        gone = weakref.ref(engine)
+        del engine, sched
+        gc.collect()
+        assert gone() is None
+
+    asyncio.run(run())
+
+
+def test_the_phase_sums_go_on_across_the_loops_restarts():
+    """Each burst's loop task records into the scheduler's one table:
+    ``stats()`` after a second burst holds both bursts' spans and
+    counters, not the last task's alone."""
+    async def run():
+        sched = DecodeScheduler(FreeRunEngine(slots=2))
+        await sched.submit([1], max_tokens=3)
+        await asyncio.sleep(0)
+        first = sched.stats()
+        assert sched._loop_task.done()
+        await sched.submit([2], max_tokens=4)
+        await asyncio.sleep(0)
+        second = sched.stats()
+        for name in ("serve.admit", "serve.step", "serve.emit"):
+            (n1, s1), (n2, s2) = first["phases"][name], second["phases"][name]
+            assert 0 < n1 < n2 and 0.0 < s1 < s2, name
+        assert (first["steps"], second["steps"]) == (2, 5)
+        assert (first["completed"], second["completed"]) == (1, 2)
+
+    asyncio.run(run())
+
+
+def test_closing_a_scheduler_whose_loop_has_ended_changes_nothing():
+    async def run():
+        engine = FreeRunEngine(slots=2)
+        sched = DecodeScheduler(engine)
+        assert await sched.submit([1], max_tokens=2) == [101, 102]
+        await asyncio.sleep(0)
+        assert sched._loop_task.done()
+        before = sched.stats()
+        await sched.aclose()            # no task to cancel, nobody to fail
+        assert sched.stats() == before
+        assert sched._loop_task is None and len(engine.step_slots) == 1
+        with pytest.raises(ServeOverloadedError):
+            await sched.submit([3], max_tokens=1)
+        await sched.aclose()            # and once more
+
+    asyncio.run(run())
+
+
 def test_occupancy_never_exceeds_slots():
     async def run():
         eng = FreeRunEngine(slots=3)
