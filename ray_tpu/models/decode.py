@@ -535,8 +535,6 @@ def slot_decode_step(params, cache: Dict, token, active,
     def mamba_run(x, load, layers, cs, cc):
         def body(carry, lp, i):
             x, cs, cc, load = carry     # [L, B, N, C] and [L, K-1, B, C]
-            with jax.named_scope("ssm_step"):
-                state = lax.dynamic_index_in_dim(cs, i, keepdims=False)
             with jax.named_scope("mamba_mixer"):
                 tail = lax.dynamic_index_in_dim(
                     cc, i, keepdims=False).swapaxes(0, 1)   # [B, K-1, C]
@@ -546,17 +544,17 @@ def slot_decode_step(params, cache: Dict, token, active,
 
             def step(u, dt, A, b, c, D):
                 with jax.named_scope("ssm_step"):
-                    y, new = ssm.selective_step(
-                        u[:, 0], dt[:, 0], A, b[:, 0], c[:, 0], D, state)
-                return y[:, None], new
+                    # the carry itself is the operand: a row's state is
+                    # read once, advanced and written where it lies, an
+                    # inactive row's bit for bit what it was
+                    y, ns = ssm.carried_step(
+                        u[:, 0], dt[:, 0], A, b[:, 0], c[:, 0], D, cs, i,
+                        active)
+                return y[:, None], ns
 
-            x, (new_tail, new_state), got = block(
+            x, (new_tail, cs), got = block(
                 lp, x, None, ssm.Recurrence(conv, step), cfg)
-            # an inactive row's summary stays bit for bit what it was
-            with jax.named_scope("ssm_step"):
-                cs = lax.dynamic_update_slice(cs, jnp.where(
-                    active[:, None, None], new_state, state)[None],
-                    (i, 0, 0, 0))
+            # ... and so its convolution's tail
             with jax.named_scope("mamba_mixer"):
                 cc = lax.dynamic_update_slice(cc, jnp.where(
                     active[:, None, None], new_tail.astype(cc.dtype),

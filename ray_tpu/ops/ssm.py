@@ -34,6 +34,24 @@ channel *and* per state index), so the kernel is bound by the vector
 unit, not by memory: what it saves over XLA is a loop iteration's
 overhead a position (a ``lax.scan`` over T) or several passes over
 ``[T, N, C]`` float32 in memory (an associative scan over T).
+
+A decode step is the other way round: one position a row, and the
+state of every row, ``[rows, N, C]`` float32 a layer, to be read,
+advanced and written. ``carried_step`` takes a run's whole carried
+state ``[L, rows, N, C]`` and the layer's index, as
+``retention.retention_step`` does, and has two forms too:
+
+* XLA: the layer's slice through ``selective_step``, the one statement
+  of a step's mathematics, selected by ``active`` and written back with
+  a ``dynamic_update_slice``. XLA makes the sum over N a fusion of its
+  own, which reads the state a second time.
+* a Pallas kernel named ``ssm_step``: the state array is its operand
+  where it lies (aliased to its result), the layer's index and the
+  rows' liveness prefetched scalars; the grid walks blocks of whole
+  rows, and **each state value is read once, advanced,
+  multiplied into y and written back where it lay**; a row left out
+  goes back as it came. Bound by memory: the state's bytes, once each
+  way.
 """
 
 from __future__ import annotations
@@ -47,10 +65,21 @@ from jax import lax
 
 from ray_tpu.ops.attention import _on_tpu
 
-KERNEL = "ssm_scan"         # the pallas_call's ``name=``
+KERNEL = "ssm_scan"         # the pallas_calls' ``name=``
+STEP_KERNEL = "ssm_step"
 _CHUNK = 64                 # positions in a chunk of the XLA form
 _BLOCK_T = 128              # positions in a grid step of the kernel
 _BLOCK_C = 512              # channels in a grid step of the kernel
+# the step kernel: rows of the state in a grid step (a tile of two-byte
+# rows, two of four-byte ones), each with all its channels, so that a
+# block is one stretch of memory: [16, 16, 5120] float32 is 5.2 MB, in
+# and out and each twice in flight, which with the rows' small operands
+# has to fit the kernel's share of fast memory; channels in a pass
+# inside it, whose values stay in registers
+_STEP_ROWS = 16
+_STEP_SUB_C = 256
+_LANES = 128
+_VMEM_LIMIT = 32 * 2 ** 20
 
 
 class Recurrence(NamedTuple):
@@ -264,3 +293,123 @@ def selective_scan(u, dt, A, B, C, D, state=None, *,
     return _scan_forward_only(u, dt, A, B, C, D, state, block_t, block_c,
                               interpret)
 
+
+# ------------------------------------------------------- the step kernel
+
+def _step_kernel(layer_ref, live_ref, u_ref, dt_ref, a_ref, b_ref, c_ref,
+                 d_ref, s_in, y_ref, s_out, y_rows, *, sub_c: int):
+    """One grid step of a decode step's state pass: a block of rows,
+    whole. Prefetched: layer_ref [1] (the index map's alone), live_ref
+    [rows of the state] int32. Blocks: u, dt, y [rows, C]; a [N, C]; d
+    [1, C]; b, c [rows, N, lanes], a row's B and C down the sublanes and
+    the same on every lane; s_in, s_out [rows, N, C]. ``y_rows``
+    [rows, sub_c] float32 gathers the rows' outputs, so that y is
+    stored whole tiles at a time."""
+    import jax.experimental.pallas as pl
+
+    rows, _, Cn = s_in.shape
+    first = pl.program_id(0) * rows
+    wide = sub_c // b_ref.shape[2]
+
+    def channels(j, _):
+        at = pl.ds(pl.multiple_of(j * sub_c, sub_c), sub_c)
+        A = a_ref[:, at]
+        u = u_ref[:, at].astype(jnp.float32)
+        dt = dt_ref[:, at]
+        fed = dt * u
+        for r in range(rows):
+            old = s_in[r, :, at]                                # [N, sub_c]
+            b = jnp.tile(b_ref[r].astype(jnp.float32), (1, wide))
+            c = jnp.tile(c_ref[r].astype(jnp.float32), (1, wide))
+            new = jnp.exp(dt[r:r + 1] * A) * old + fed[r:r + 1] * b
+            y_rows[r:r + 1, :] = jnp.sum(new * c, axis=0, keepdims=True)
+            # a row left out goes back bit for bit as it came
+            s_out[r, :, at] = jnp.where(live_ref[first + r] != 0, new, old)
+        y_ref[:, at] = (y_rows[...] + d_ref[:, at] * u).astype(y_ref.dtype)
+
+    lax.fori_loop(0, Cn // sub_c, channels, None)
+
+
+def _step_pallas(u, dt, A, B, C, D, states, layer, active, block_r, sub_c,
+                 interpret):
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    R, Cn = u.shape
+    N = A.shape[0]
+    lanes = min(_LANES, sub_c)
+
+    def down_the_sublanes(t):   # [R, N] -> [R, N, lanes], lane-dense
+        return jnp.broadcast_to(t[:, :, None], (R, N, lanes))
+
+    by_row = pl.BlockSpec((block_r, Cn), lambda i, *_: (i, 0))
+    per_row = pl.BlockSpec((block_r, N, lanes), lambda i, *_: (i, 0, 0))
+    s_spec = pl.BlockSpec((None, block_r, N, Cn),
+                          lambda i, layer_ref, live_ref: (layer_ref[0], i,
+                                                          0, 0))
+    y, states = pl.pallas_call(
+        functools.partial(_step_kernel, sub_c=sub_c),
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R // block_r,),
+            in_specs=[by_row, by_row,
+                      pl.BlockSpec((N, Cn), lambda i, *_: (0, 0)),
+                      per_row, per_row,
+                      pl.BlockSpec((1, Cn), lambda i, *_: (0, 0)),
+                      s_spec],
+            out_specs=[by_row, s_spec],
+            scratch_shapes=[pltpu.VMEM((block_r, sub_c), jnp.float32)]),
+        # the state array is written where it lies (operand 8, the two
+        # prefetched scalars counted)
+        input_output_aliases={8: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=STEP_KERNEL,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
+      u, dt.astype(jnp.float32), A.astype(jnp.float32),
+      down_the_sublanes(B), down_the_sublanes(C),
+      D.astype(jnp.float32).reshape(1, Cn), states)
+    return y, states
+
+
+def step_blocks(rows: int, N: int, Cn: int, interpret: bool = False):
+    """(rows of the state in a grid step of the step kernel, channels
+    in a pass inside it), None where this shape runs the XLA form: off
+    the TPU, a state that is no whole number of sublane tiles, channels
+    that are no whole passes, rows that are no whole blocks, a block of
+    rows of which fast memory does not hold four and the small operands
+    beside them (reckoned as a fifth)."""
+    if interpret:   # exercises the kernel at any size: no Mosaic tiling
+        block_r, sub_c = min(_STEP_ROWS, rows), min(_STEP_SUB_C, Cn)
+        return None if rows % block_r or Cn % sub_c else (block_r, sub_c)
+    if (not _on_tpu() or N % 8 or Cn % _STEP_SUB_C or rows % _STEP_ROWS
+            or 5 * 4 * _STEP_ROWS * N * Cn > _VMEM_LIMIT):
+        return None
+    return _STEP_ROWS, _STEP_SUB_C
+
+
+def carried_step(u, dt, A, B, C, D, states, layer, active, *,
+                 interpret: bool = False):
+    """A decode step's recurrence for one layer of a run, over the run's
+    carried state where it lies: one position a row, u and dt [R, C], B
+    and C [R, N], against layer ``layer`` (a traced index) of ``states``
+    [L, R, N, C] float32; ``active`` bool [R]. Returns (y [R, C] at u's
+    dtype, ``states``): an active row's state advanced by its token, **a
+    row left out kept bit for bit** (its y is garbage).
+
+    On the TPU (or under ``interpret``) the kernel ``ssm_step`` (module
+    docstring): the state array is its operand whole and its result in
+    place; elsewhere ``selective_step`` over the layer's slice, selected
+    and written back whole."""
+    blocks = step_blocks(u.shape[0], A.shape[0], u.shape[1], interpret)
+    if blocks is not None:
+        return _step_pallas(u, dt, A, B, C, D, states, layer, active,
+                            *blocks, interpret)
+    old = lax.dynamic_index_in_dim(states, layer, keepdims=False)
+    y, new = selective_step(u, dt, A, B, C, D, old)
+    return y, lax.dynamic_update_slice(states, jnp.where(
+        active[:, None, None], new, old)[None], (layer, 0, 0, 0))

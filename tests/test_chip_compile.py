@@ -28,8 +28,10 @@ attention layers, a slot's recurrent state beside its K/V) are held to
 the same at that cell's shapes: every leaf of the cache aliased, the
 recurrent state read and written where it lies with no copy of a run's
 or a layer's state, each prefill holding both kernels (``ssm_scan`` a
-Mamba run, ``flash_fwd`` an attention run), and the scan kernel alone
-accepted by Mosaic at the cell's three prompt lengths.
+Mamba run, ``flash_fwd`` an attention run), a decode step one
+``ssm_step`` call a Mamba run, whose operand and result the run's
+whole state is, and both kernels alone accepted by Mosaic: the scan at
+the cell's three prompt lengths, the step at its 256 rows.
 
 The fourth serving cell's programs (``brumby-14b-d8``: every mixer a
 power-retention layer, a matrix state a K/V head and no K/V at all)
@@ -59,6 +61,7 @@ import pytest
 
 KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 SCAN_KERNEL = "ssm_scan"
+STEP_KERNEL = "ssm_step"
 DECODE_KERNEL = "decode_attend"
 RETENTION_KERNELS = ("retention_chunk", "retention_step")
 # B, T, H, Dh, the dtype, and whether the gradient is compiled too
@@ -119,7 +122,8 @@ def mosaic_calls(compiled_text):
     names = re.findall(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
         compiled_text)
-    return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL, DECODE_KERNEL)
+    return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL, STEP_KERNEL,
+                                          DECODE_KERNEL)
                             + RETENTION_KERNELS) or n for n in names)
 
 
@@ -713,7 +717,7 @@ def test_ouros_three_programs_lower_to_the_text_on_record(
 
 HYBRID_CELL = "jamba2-3b.rollout-closed"
 LOWERED_HYBRID = {
-    "decode": "78fef1c0a186c3c6",
+    "decode": "806b345381d91ce6",
     "prefill-128": "5dfd27e60294ead6",
     "prefill-256": "d9740ea2bc50b407",
     "prefill-512": "b3b436c3575270ac",
@@ -773,6 +777,40 @@ def test_the_scan_kernel_compiles_for_v5e_under_its_own_name(
     assert mosaic_calls(text) == [SCAN_KERNEL]
 
 
+def test_the_step_kernel_compiles_for_v5e_under_its_own_name(
+        one_chip, no_compile_cache, as_on_the_tpu):
+    """``ssm_step`` at the third serving cell's decode shape (256 rows,
+    a state of 16 a channel, 5120 channels, bfloat16 in, float32 dt and
+    state) over its longest run's state (13 layers, 1.09 GB): Mosaic
+    accepts it at the blocks ``ops.ssm`` chooses, the program names its
+    one custom call ``ssm_step``, and the state array aliases the
+    result: what the program holds beside it is the rows' B and C laid
+    down the sublanes, a few MB."""
+    import jax
+    import jax.numpy as jnp
+
+    ssm = importlib.import_module("ray_tpu.ops.ssm")
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, layers, C, N = 256, 13, 5120, 16
+    assert ssm.step_blocks(rows, N, C) is not None
+    compiled = jax.jit(ssm.carried_step, donate_argnums=(6,)).lower(
+        array((rows, C), jnp.bfloat16), array((rows, C), jnp.float32),
+        array((N, C), jnp.float32), array((rows, N), jnp.bfloat16),
+        array((rows, N), jnp.bfloat16), array((C,), jnp.float32),
+        array((layers, rows, N, C), jnp.float32), array((), jnp.int32),
+        array((rows,), jnp.bool_)).compile()
+    memory = compiled.memory_analysis()
+    state = 4 * layers * rows * N * C
+    assert 0 <= memory.alias_size_in_bytes - state <= 8192
+    assert memory.temp_size_in_bytes < 8 * 2 ** 20
+    text = compiled.as_text()
+    assert "%ssm_step.1 = " in text
+    assert mosaic_calls(text) == [STEP_KERNEL]
+
+
 def test_the_third_serving_cells_shapes_are_the_ones_compiled_here(
         hybrid_cell):
     from ray_tpu.models.transformer import layer_runs
@@ -796,12 +834,13 @@ def test_recurrent_state_beside_kv_is_still_written_in_place(
     the cache is aliased to the result (2.92 GB: 2.18 GB of recurrent
     state in float32, 0.20 GB of convolution tails, 0.54 GB of K and V
     for the two attention layers); no operation produces an array of a
-    run's or a layer's state, tail, K or V but the in-place writes (a
-    decode step's select-and-write of a Mamba layer's state is one
-    fusion, rooted in the write); the temporaries stay under one
-    layer's state (84 MB: no copy of one has room); arguments and
-    temporaries fit the chip; and each prefill holds both kernels, one
-    call a run of layers."""
+    run's or a layer's state, tail, K or V but the in-place writes and,
+    in a decode step, the step's own kernel, whose operand and result a
+    run's whole state is; the temporaries stay under one layer's state
+    (84 MB: no copy of one has room); arguments and temporaries fit the
+    chip; each prefill holds both kernels, one call a run of layers,
+    and a decode step one ``decode_attend`` an attention run and one
+    ``ssm_step`` a Mamba run."""
     import jax
     import jax.numpy as jnp
 
@@ -866,11 +905,16 @@ def test_recurrent_state_beside_kv_is_still_written_in_place(
                                               for leaf in held}
     produced = [(name, op) for name, op in cache_producers(text, shapes)
                 if op != "bitcast"]
-    assert {op for _, op in produced} <= set(IN_PLACE), produced
-    # a write a run and kind of state
-    assert len(produced) == 3 * 2 + 2 * 2, produced
+    allowed = set(IN_PLACE) | (
+        {"tpu_custom_call"} if program == "decode" else set())
+    assert {op for _, op in produced} <= allowed, produced
+    # a write a run and kind of state; a decode step's Mamba states are
+    # the kernel's own results, one tuple with y, which no shape above
+    # names
+    assert len(produced) == (3 + 2 * 2 if program == "decode"
+                             else 3 * 2 + 2 * 2), produced
     assert mosaic_calls(text) == (
-        [DECODE_KERNEL] * 2 if program == "decode"
+        [DECODE_KERNEL] * 2 + [STEP_KERNEL] * 3 if program == "decode"
         else ["flash_fwd"] * 2 + [SCAN_KERNEL] * 3)
 
 

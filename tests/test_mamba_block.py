@@ -14,6 +14,7 @@ convolution of 4), a tied head.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -205,11 +206,17 @@ def test_a_reused_slot_never_sees_its_predecessor(model):
                                       np.asarray(want[1]))
 
 
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["the layer's slice", "the step's kernel"])
 @pytest.mark.parametrize("served", [False, True],
                          ids=["active mask", "IDLE in the served row"])
 def test_a_row_left_out_of_a_step_keeps_its_recurrent_state_bit_for_bit(
-        model, served):
+        model, served, kernel, monkeypatch, fresh_programs):
     _, sz, cfg, params = model
+    if kernel:  # the decode step's ``ssm_step``, interpreted on the CPU
+        monkeypatch.setattr(ssm, "carried_step", functools.partial(
+            ssm.carried_step, interpret=True))
+        fresh_programs()
     prompts = jax.random.randint(jax.random.key(6), (3, 8), 0, sz.vocab)
     cache = decode.init_slot_cache(cfg, 3, 64)
     for row in range(3):

@@ -2,8 +2,11 @@
 forms against each other (one position at a time, the chunked XLA form,
 the Pallas kernel in interpret mode), the state that comes back
 included; a sequence cut in two and carried over the cut; the causal
-convolution's tail across such an edge; and the kernel's gradient,
-which is the chunked form's.
+convolution's tail across such an edge; the kernel's gradient, which
+is the chunked form's; and a decode step's two forms over a run's
+carried state (the layer's slice through ``selective_step``, the
+``ssm_step`` kernel in interpret mode) against ``selective_step``, a
+row left out kept bit for bit and the other layers untouched.
 
 Tolerances: float32 throughout, the same sums in another order. The
 chunked form multiplies decays together before it applies them and the
@@ -154,6 +157,114 @@ def test_which_form_runs_is_read_from_the_platform_and_the_shape(
     assert not has_kernel(*short)               # not a multiple of 128
     narrow = inputs(256, C=384)
     assert not has_kernel(*narrow)              # nor of the channel tile
+
+
+# ------------------------------------------ a decode step, state carried
+
+def step_inputs(rows, C, N=8, layers=3, dtype=jnp.float32, seed=0):
+    """One position a row, and a run's carried state [layers, rows, N,
+    C]."""
+    u, dt, A, B, Cm, D, _ = inputs(1, C=C, N=N, rows=rows, dtype=dtype,
+                                   seed=seed)
+    states = jax.random.normal(jax.random.key(seed + 100),
+                               (layers, rows, N, C))
+    return u[:, 0], dt[:, 0], A, B[:, 0], Cm[:, 0], D, states
+
+
+STEP_FORMS = {
+    # rows, channels, then whether the kernel runs (interpreted)
+    "the layer's slice, off the TPU": (6, 192, False),
+    "kernel, one block of rows": (4, 128, True),
+    "kernel, blocks of 16 rows, two passes a block": (32, 512, True),
+    "kernel, channels short of a lane tile": (16, 64, True),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("form", sorted(STEP_FORMS))
+def test_every_form_of_the_step_is_the_recurrence(form, dtype):
+    rows, C, kernel = STEP_FORMS[form]
+    u, dt, A, B, Cm, D, states = step_inputs(rows, C, dtype=dtype)
+    assert (ssm.step_blocks(rows, A.shape[0], C, interpret=kernel)
+            is not None) == kernel
+    layer = 1
+    want_y, want_s = ssm.selective_step(u, dt, A, B, Cm, D, states[layer])
+    y, new = jax.jit(lambda *a: ssm.carried_step(*a, interpret=kernel))(
+        u, dt, A, B, Cm, D, states, jnp.int32(layer), jnp.ones(rows, bool))
+    assert y.dtype == dtype and new.dtype == jnp.float32
+    assert new.shape == states.shape
+    assert gap(new[layer], want_s) < TOLERANCE
+    if dtype == jnp.float32:
+        assert gap(y, want_y) < TOLERANCE
+    else:   # rounded once: neighbouring values at most
+        assert gap(y, want_y) <= 2 ** -8 * float(jnp.max(jnp.abs(
+            want_y.astype(jnp.float32))))
+    assert float(jnp.max(jnp.abs(want_y.astype(jnp.float32)))) > 1.0
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["the layer's slice", "kernel"])
+def test_a_step_advances_live_rows_of_one_layer_and_nothing_else(
+        kernel, layer):
+    """Over a run's state of three layers and a traced layer index:
+    live rows of that layer advance; rows left out keep their state bit
+    for bit, whether a whole block of rows is left out or one among
+    live ones; the other layers are what they were."""
+    rows = 48
+    u, dt, A, B, Cm, D, states = step_inputs(rows, 256, seed=2)
+    # the first block of 16 rows mixed, the second all left out, the
+    # third all live
+    active = jnp.asarray([r % 3 != 1 for r in range(16)]
+                         + [False] * 16 + [True] * 16)
+    _, want = ssm.selective_step(u, dt, A, B, Cm, D, states[layer])
+    run = jax.jit(lambda *a: ssm.carried_step(*a, interpret=kernel))
+    _, new = run(u, dt, A, B, Cm, D, states, jnp.int32(layer), active)
+    live = np.asarray(active)
+    assert gap(new[layer][live], want[live]) < TOLERANCE
+    assert not np.array_equal(np.asarray(new[layer][live]),
+                              np.asarray(states[layer][live]))
+    np.testing.assert_array_equal(np.asarray(new[layer][~live]),
+                                  np.asarray(states[layer][~live]))
+    for other in set(range(3)) - {layer}:
+        np.testing.assert_array_equal(np.asarray(new[other]),
+                                      np.asarray(states[other]))
+    # no row live at all: the state is what it was
+    _, still = run(u, dt, A, B, Cm, D, states, jnp.int32(layer),
+                   jnp.zeros(rows, bool))
+    np.testing.assert_array_equal(np.asarray(still), np.asarray(states))
+
+
+@pytest.mark.parametrize("shape,on_tpu,kernel", [
+    ((256, 16, 5120), True, True),      # the serving cell's own
+    ((256, 16, 5120), False, False),    # the CPU: XLA
+    ((32, 8, 512), True, True),
+    ((250, 16, 5120), True, False),     # rows that are no whole blocks
+    ((256, 12, 5120), True, False),     # a state of no whole sublane tiles
+    ((256, 16, 5000), True, False),     # channels that are no whole passes
+    ((256, 16, 16384), True, False),    # a block of rows too large
+], ids=["cell 5", "off the TPU", "small", "ragged rows", "ragged state",
+        "ragged channels", "wide"])
+def test_which_form_of_the_step_runs_is_read_from_the_platform_and_the_shape(
+        monkeypatch, shape, on_tpu, kernel):
+    rows, N, C = shape
+    monkeypatch.setattr(ssm, "_on_tpu", lambda: on_tpu)
+
+    def array(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    # (a function of its own: a trace is remembered by its function)
+    jaxpr = str(jax.make_jaxpr(lambda *a: ssm.carried_step(*a))(
+        array(rows, C, dtype=jnp.bfloat16), array(rows, C), array(N, C),
+        array(rows, N, dtype=jnp.bfloat16),
+        array(rows, N, dtype=jnp.bfloat16), array(C),
+        array(2, rows, N, C), array(dtype=jnp.int32),
+        array(rows, dtype=jnp.bool_)))
+    assert ("pallas_call" in jaxpr) == kernel
+    assert (ssm.STEP_KERNEL in jaxpr) == kernel
+    # the XLA form is the layer's slice, written back in place
+    assert ("dynamic_update_slice" in jaxpr) == (not kernel)
 
 
 # ------------------------------------------------------- the convolution
