@@ -5,7 +5,8 @@ slot's state between tokens, of the kinds its layers keep.
 with one entry for each run of alike layers (``transformer.layer_runs``;
 a model whose layers are all alike has one run), and a run's entry is
 an array in the tuples of its own kind of state and ``None`` in the
-others:
+others (in a run of periods of several layers the entry is itself a
+tuple, one such array or ``None`` for each layer of the period):
 
 * an attention run keeps *rows*: ``cache["k"][r]`` ``[L, slots, rows,
   H, Dh]`` and ``cache["v"][r]`` ``[.., Dv]`` (``[L, slots, rows,
@@ -35,6 +36,20 @@ others:
   has no K/V at all**: no ``k``, no ``v``, no ring, and nothing in its
   cache is sized by ``max_len``.
 
+**Layers that keep nothing.** A gated memory unit and a cross layer
+have no entry anywhere: the first gates the *memory*, the scan output
+(before its gate) of the last Mamba layer before it at the same
+position, which both programs carry from layer to layer and from run to
+run beside x and which no one keeps between tokens; the second
+projects queries alone and attends **the K and V of the last
+full-attention layer before it, in that layer's own cache**: one cache
+that grows, written by one layer and read by every cross layer behind
+it. ``slot_decode_step`` hands ``decode_attention`` the full layer's
+run's arrays as they lie after that run's in-place write of the step's
+token (the carry of another run's scan, closed over: no slice, no
+copy), with that layer's index; ``slot_prefill`` hands a cross layer
+the prompt's K and V as the full layer made them.
+
 A model with expert layers carries ``cache["load"]``, int32 [3]: the
 held experts that got a row, the rows routed to held experts and the
 fullest expert's rows in the last decode step, each summed over the
@@ -53,7 +68,11 @@ scan's carry:
   forward's rope, attention and scan (the flash and ``ssm_scan``
   kernels on TPU, XLA off it) and writes into the carry at ``(layer,
   slot)`` the roped K/V of the prompt's positions, or the state and the
-  convolution's tail after its last one;
+  convolution's tail after its last one. Where the model ends in
+  layers that mix nothing over the sequence (gated memory units, cross
+  layers) it runs in two stages: the whole prompt up to the last layer
+  that does mix, whose K and V alone are made at every position, and
+  the last position alone from there on (``prefill_stages``);
 * ``slot_decode_step`` feeds each row one token: an attention layer
   ropes it at the row's own position, scatters its K/V into the carry
   at ``[layer, rows, pos]`` and attends the layer's K/V, read out of
@@ -166,13 +185,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (EXPERTS, FROM_THE_START,
-                                        LAYER_WEIGHTS, MAMBA, PARTS,
-                                        RETENTION, SUMMARIES, WINDOW,
+from ray_tpu.models.transformer import (BORROWERS, CROSS, EXPERTS,
+                                        FROM_THE_START, FULL, GMU,
+                                        LAYER_WEIGHTS, MAMBA,
+                                        PARTS, RETENTION, SUMMARIES, WINDOW,
                                         TransformerConfig, block, kind_rope,
+                                        last_position,
                                         layer_runs, layer_stacks,
-                                        no_rotation, retain_from_the_start,
-                                        roped_kinds, scan_run, unembed)
+                                        no_rotation, nothing_lent,
+                                        one_period, period_of,
+                                        retain_from_the_start, roped_kinds,
+                                        run_layers, scan_run, unembed)
 from ray_tpu.ops import retention, ssm
 from ray_tpu.ops.attention import (cached_attention, decode_attention,
                                    decode_rows_fetched, flash_attention)
@@ -193,12 +216,24 @@ def pick(logits, key=None, temperature=None):
     return jax.random.categorical(key, logits / temperature).astype(jnp.int32)
 
 
+def _by_layer(runs, make) -> tuple:
+    """One entry a run of ``make(mixer, layers in the run)``, and for a
+    run of periods of several layers a tuple of them, one for each
+    layer of the period: the form of each of the cache's tuples."""
+    return tuple(
+        make(kind[0], n) if isinstance(kind[0], str)
+        else tuple(make(mixer, n) for mixer, _ in kind)
+        for kind, n in runs)
+
+
 def init_slot_cache(cfg: TransformerConfig, slots: int,
                     max_len: int) -> Dict:
     """The cache of ``slots`` sequences, each with a decode offset of
     its own: tuples with one entry a run of alike layers (one in all
     where the layers are all alike), ``None`` where the run keeps no
-    state of that kind. ``k`` and ``v``: an attention run's rows, a
+    state of that kind; in a run of periods of several layers the entry
+    is a tuple, one for each layer of the period. ``k`` and ``v``: an
+    attention run's rows, a
     window run at ``cfg.window`` of them. Where every query head has a
     K/V head of its own a row is [H, Dh]; where G K/V heads serve more
     query heads each it is flat, [G * Dh], the heads side by side: rows
@@ -209,29 +244,29 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
     convolution's tail [n, K - 1, slots, C]. ``ret`` and ``ret_z``, in
     a model with retention layers: a retention run's state
     [n, slots, G, Dv, D] and normaliser [n, slots, G, D], float32. A
-    model with no attention layer has no ``k`` and no ``v``."""
+    model with no attention layer has no ``k`` and no ``v``; a gated
+    memory unit and a cross layer keep nothing anywhere."""
     runs = layer_runs(cfg)
 
     def rows(width):
-        made = []
-        for (mixer, _), n in runs:
-            if mixer in SUMMARIES:
-                made.append(None)
-                continue
+        def made(mixer, n):
+            if mixer in SUMMARIES + BORROWERS:
+                return None
             G = cfg.kv_heads(mixer)
             row = (G, width) if G == cfg.n_heads else (G * width,)
-            made.append(jnp.zeros(
+            return jnp.zeros(
                 (n, slots, cfg.window if mixer == WINDOW else max_len)
-                + row, cfg.dtype))
-        return tuple(made)
+                + row, cfg.dtype)
+        return _by_layer(runs, made)
 
     def summaries(of, shape, dtype):
-        return tuple(jnp.zeros((n,) + shape, dtype)
-                     if mixer == of else None for (mixer, _), n in runs)
+        return _by_layer(runs, lambda mixer, n: jnp.zeros(
+            (n,) + shape, dtype) if mixer == of else None)
 
+    mixers = {mixer for _, _, mixer, _ in run_layers(runs)}
     cache = {"pos": jnp.zeros((slots,), jnp.int32),
              "tok": jnp.zeros((slots,), jnp.int32)}
-    if any(mixer not in SUMMARIES for (mixer, _), _ in runs):
+    if mixers & {FULL, WINDOW}:
         cache.update(k=rows(cfg.head_dim), v=rows(cfg.v_dim))
     if cfg.has_mamba:
         cache["ssm"] = summaries(
@@ -243,23 +278,29 @@ def init_slot_cache(cfg: TransformerConfig, slots: int,
         cache["ret"] = summaries(RETENTION, (slots, G, cfg.v_dim, D),
                                  jnp.float32)
         cache["ret_z"] = summaries(RETENTION, (slots, G, D), jnp.float32)
-    if any(ffn == EXPERTS for (_, ffn), _ in runs):
+    if any(ffn == EXPERTS for _, _, _, ffn in run_layers(runs)):
         cache["load"] = jnp.zeros((3,), jnp.int32)
     return cache
 
 
-# which two tuples of the cache hold a run's state, by its mixer
+# which two tuples of the cache hold a layer's state, by its mixer
 ROWS, SUMMARY, RETAINED = ("k", "v"), ("ssm", "conv"), ("ret", "ret_z")
 
 
 def _state_names(mixer: str):
+    if mixer in BORROWERS:
+        return ()
     return {MAMBA: SUMMARY, RETENTION: RETAINED}.get(mixer, ROWS)
 
 
 def _cache_runs(cache: Dict, runs):
-    """Each run's pair of state arrays: (K, V) of an attention run,
-    (state, tail) of a Mamba run, (S, z) of a retention run."""
-    names = {name for (mixer, _), _ in runs for name in _state_names(mixer)}
+    """Each run's state: a tuple with one pair of arrays for each layer
+    of the run's period (one pair, then, for a run of alike layers):
+    (K, V) of an attention layer, (state, tail) of a Mamba layer,
+    (S, z) of a retention layer, (None, None) of a layer that keeps
+    nothing."""
+    names = {name for _, _, mixer, _ in run_layers(runs)
+             for name in _state_names(mixer)}
     for name in sorted(names):
         if not (isinstance(cache.get(name), tuple)
                 and len(cache[name]) == len(runs)):
@@ -267,26 +308,45 @@ def _cache_runs(cache: Dict, runs):
                 f"the cache holds a tuple cache[{name!r}], one array for "
                 f"each of the model's {len(runs)} runs of alike layers "
                 f"(None where a run keeps no such state: init_slot_cache)")
-    return [tuple(cache[name][r] for name in _state_names(mixer))
-            for r, ((mixer, _), _) in enumerate(runs)]
+
+    def pair(mixer, held):
+        names = _state_names(mixer)
+        return tuple(held(name) for name in names) if names else (None, None)
+
+    return [(pair(kind[0], lambda name: cache[name][r]),)
+            if isinstance(kind[0], str)
+            else tuple(pair(mixer, lambda name: cache[name][r][j])
+                       for j, (mixer, _) in enumerate(kind))
+            for r, (kind, _) in enumerate(runs)]
 
 
 def _with_states(cache: Dict, runs, states, **more) -> Dict:
-    """``cache`` with each run's pair of state arrays replaced."""
+    """``cache`` with each run's state replaced (the form of
+    ``_cache_runs``)."""
     new = {name: [None] * len(runs) for name in ROWS + SUMMARY + RETAINED
            if name in cache}
-    for r, (((mixer, _), _), pair) in enumerate(zip(runs, states)):
-        for name, array in zip(_state_names(mixer), pair):
-            new[name][r] = array
+    for r, ((kind, _), state) in enumerate(zip(runs, states)):
+        layers = [dict(zip(_state_names(mixer), pair))
+                  for (mixer, _), pair in zip(period_of(kind), state)]
+        for name in new:
+            held = tuple(layer.get(name) for layer in layers)
+            new[name][r] = held[0] if isinstance(kind[0], str) else held
     return dict(cache, **{name: tuple(held) for name, held in new.items()},
                 **more)
+
+
+def _layer_states(runs, states):
+    """[(mixer, (first, second)), ...] of every layer of one period of
+    every run, in the layers' order."""
+    return [(mixer, pair) for (kind, _), state in zip(runs, states)
+            for (mixer, _), pair in zip(period_of(kind), state)]
 
 
 def _max_len(cfg: TransformerConfig, runs, states) -> int:
     """Rows of a full-attention run's cache: the longest sequence a slot
     holds (``cfg.max_seq`` where no layer keeps all its rows)."""
-    return next((ck.shape[2] for ((mixer, _), _), (ck, _) in zip(runs, states)
-                 if mixer != WINDOW and mixer not in SUMMARIES), cfg.max_seq)
+    return next((ck.shape[2] for mixer, (ck, _) in _layer_states(runs, states)
+                 if mixer == FULL), cfg.max_seq)
 
 
 def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
@@ -297,13 +357,23 @@ def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
     slot stepped at position p reads ``(p // n + 1) * n`` of its rows a
     layer, which is what ``JaxSlotEngine`` counts from its host mirror."""
     runs = layer_runs(cfg)
-    for ((mixer, _), _), (ck, cv) in zip(runs, _cache_runs(cache, runs)):
-        if mixer != WINDOW and mixer not in SUMMARIES:
+    for mixer, (ck, cv) in _layer_states(runs, _cache_runs(cache, runs)):
+        if mixer == FULL:
             q = jax.ShapeDtypeStruct(
                 (ck.shape[1], cfg.n_heads, cfg.head_dim), ck.dtype)
             return decode_rows_fetched(q, ck, cv,
                                        sink=mixer in cfg.sink_kinds)
     return None
+
+
+def kv_readers(cfg: TransformerConfig) -> int:
+    """How many layers of a decode step read a full-attention layer's
+    rows, for each layer that holds such rows: 1, and with cross
+    layers, which read the rows of the full layer before them, as many
+    more as that layer lends to (8 where seven cross layers share one
+    cache)."""
+    mixers = [mixer for mixer, _ in cfg.layer_kinds or ()]
+    return 1 + mixers.count(CROSS) // max(1, mixers.count(FULL))
 
 
 def keeps_summaries(cfg: TransformerConfig) -> bool:
@@ -334,6 +404,76 @@ def _tally(load, got):
                                  jnp.max(got)])
 
 
+def prefill_stages(cfg: TransformerConfig) -> Tuple[int, Optional[int]]:
+    """Where a prefill stops running the whole prompt: (the first layer
+    that runs at the last position alone, the layer handed ``last`` or
+    None). Behind the last layer that mixes over the sequence (every
+    layer from there on a gated memory unit or a cross layer) nothing
+    needs any position's activations but the last's, whose logits the
+    prefill is for. That last mixing layer itself, if it is an
+    attention layer, makes its K and V at every position (the cache's,
+    and what the cross layers attend) and everything else of itself at
+    the last (``block``'s ``last``). A model with no such layers runs
+    every layer at every position: (n_layers, None)."""
+    mixers = [mixer for mixer, _ in cfg.layer_kinds or ()]
+    tail = len(mixers)
+    while tail and mixers[tail - 1] in BORROWERS:
+        tail -= 1
+    if tail == len(mixers) or tail == 0:
+        return cfg.n_layers, None
+    return tail, tail - 1 if mixers[tail - 1] in (FULL, WINDOW) else None
+
+
+def _changes_stage(cfg: TransformerConfig, depth: int, n: int,
+                   period: int) -> bool:
+    """Whether the run of ``n`` periods of ``period`` layers whose
+    first layer is layer ``depth`` is the one inside which a prefill
+    goes on at the last position alone: it holds the change and is one
+    period, which needs no scan."""
+    tail, split = prefill_stages(cfg)
+    last = depth + n * period - 1
+    return n == 1 and tail < cfg.n_layers and (
+        depth <= tail <= last or split == last)
+
+
+def prefill_cross_rows(cfg: TransformerConfig, T0: int) -> Optional[int]:
+    """How many positions of a prompt of ``T0`` the first layer behind
+    the last mixing one runs over in ``slot_prefill``: 1 where the
+    stage changes before it, ``T0`` where it lies in a run of several
+    periods that holds the change, None for a model with no such
+    layer."""
+    tail, depth = prefill_stages(cfg)[0], 0
+    if tail == cfg.n_layers:
+        return None
+    for kind, n in layer_runs(cfg):
+        period = len(period_of(kind))
+        if depth <= tail < depth + n * period:
+            return 1 if depth == tail or _changes_stage(
+                cfg, depth, n, period) else T0
+        depth += n * period
+    return None
+
+
+def _either_stage(whole, cos, sin, T0: int):
+    """``rope`` of a prefill in two stages: ``whole`` for the prompt's
+    ``T0`` positions, and for the second stage's queries, which are the
+    last position's alone, that position's rotation."""
+    def rope(t):
+        if t.shape[1] == T0:
+            return whole(t)
+        return apply_rotary(t, cos=cos, sin=sin, positions=jnp.arange(
+            T0 - t.shape[1], T0))
+    return rope
+
+
+def _at_the_last(x, lent):
+    """x [1, T, D] and the memory beside it (``lent``'s first) cut to
+    the last position; the lent K and V stay whole."""
+    return last_position(x), tuple(
+        t if t is None or at else last_position(t)
+        for at, t in enumerate(lent))
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache",))
 def slot_prefill(params, tokens, cache: Dict, slot,
@@ -346,89 +486,163 @@ def slot_prefill(params, tokens, cache: Dict, slot,
     ``cache["tok"][slot]``; the cache given is consumed and the one
     returned is its memory, updated in place. Compiles once per
     distinct T0 — serving callers should bucket or pad prompt lengths
-    if retrace cost matters."""
+    if retrace cost matters.
+
+    **Two stages, where the model ends in layers that mix nothing over
+    the sequence** (``prefill_stages``): the whole prompt through the
+    layers up to the last one that does, of which an attention layer
+    makes its K and V at every position and the rest of itself at the
+    last; then the last position alone, x [1, 1, D] and the memory
+    beside it, through the gated memory units and cross layers behind
+    it, each cross layer's one query attending the K and V just made.
+    The stage changes inside a run of one period or between runs (a
+    scan's carry keeps its shape): a run of several periods that holds
+    the change is run whole at every position and cut behind it."""
     _, T0 = tokens.shape
     runs = layer_runs(cfg)
     states = _cache_runs(cache, runs)
     max_len = _max_len(cfg, runs, states)
+    sm_scale = cfg.head_dim ** -0.5
     ropes = {}
     for attention in roped_kinds(cfg, runs):
         cos, sin = kind_rope(cfg, attention, max_len)
         ropes[attention] = functools.partial(
             apply_rotary, cos=cos, sin=sin, positions=jnp.arange(T0))
+        if cfg.lends:
+            ropes[attention] = _either_stage(ropes[attention], cos, sin, T0)
     x = params["embed"][tokens]
 
-    def attention_run(x, layers, ck, cv, attention):
+    # a layer of each kind: (x, lent, lp, i, first, second, last) ->
+    # (x, lent, first, second); ``lent`` (the memory, the last full
+    # layer's K, its V), ``first`` and ``second`` the layer's run's
+    # whole state arrays, written at (i, slot)
+    def attention_layer(attention):
         window = cfg.window if attention == WINDOW else None
 
-        def body(carry, lp, i):
-            x, ck, cv = carry  # ck/cv: the whole [L, slots, rows, G, Dh]
-
+        def layer(x, lent, lp, i, ck, cv, last=False):
+            # ck/cv: the whole [L, slots, rows, G, Dh]
             def attend(q, k, v):
                 # the training forward's local attention, so the last
                 # token's logits are forward()'s; the roped k and v are
                 # what a later step attends
                 with jax.named_scope(f"{attention}_attention"):
                     return flash_attention(
-                        q, k, v, causal=True, window=window,
-                        sink=lp.get("sink")), (k, v)
+                        q, k, v, causal=True, sm_scale=sm_scale,
+                        window=window, sink=lp.get("sink")), (k, v)
 
             x, (k, v), _ = block(lp, x, ropes.get(attention, no_rotation),
-                                 attend, cfg)
+                                 attend, cfg, last=last)
             ck = lax.dynamic_update_slice(
                 ck, _cache_rows(k, ck, window)[None],
                 (i, slot) + (0,) * (ck.ndim - 2))
             cv = lax.dynamic_update_slice(
                 cv, _cache_rows(v, cv, window)[None],
                 (i, slot) + (0,) * (cv.ndim - 2))
-            return x, ck, cv
+            if attention == FULL and cfg.has_cross:
+                lent = (lent[0], k, v)
+            return x, lent, ck, cv
 
-        return scan_run(body, (x, ck, cv), layers)
+        return layer
 
-    def mamba_run(x, layers, cs, cc):
-        def body(carry, lp, i):
-            x, cs, tails = carry    # cs: the whole [L, slots, N, C]
-            # the training forward's convolution and scan
-            x, (tail, state), _ = block(lp, x, None, FROM_THE_START, cfg)
-            cs = lax.dynamic_update_slice(
-                cs, state[None].astype(cs.dtype), (i, slot, 0, 0))
-            tails = lax.dynamic_update_slice(
-                tails, tail.swapaxes(0, 1)[None].astype(tails.dtype),
-                (i, 0, 0, 0))
-            return x, cs, tails
+    def mamba_layer(x, lent, lp, i, cs, tails, last=False):
+        # cs: the whole [L, slots, N, C]
+        # the training forward's convolution and scan
+        x, (tail, state, *memory), _ = block(lp, x, None, FROM_THE_START,
+                                             cfg)
+        cs = lax.dynamic_update_slice(
+            cs, state[None].astype(cs.dtype), (i, slot, 0, 0))
+        tails = lax.dynamic_update_slice(
+            tails, tail.swapaxes(0, 1)[None].astype(tails.dtype),
+            (i, 0, 0, 0))
+        return x, (*memory, *lent[len(memory):]), cs, tails
 
-        # the layers' tails are gathered [L, K - 1, 1, C] and written
-        # into the slot once, behind the scan: carried through it, the
-        # tails' array (rows of the model's dtype, written at a slot
-        # that is no multiple of a tile) is copied whole on the way in
-        # and on the way out
-        tails = jnp.zeros(cc.shape[:2] + (1,) + cc.shape[3:], cc.dtype)
-        x, cs, tails = scan_run(body, (x, cs, tails), layers)
-        return x, cs, lax.dynamic_update_slice(cc, tails, (0, 0, slot, 0))
+    def retention_layer(x, lent, lp, i, cs, cz, last=False):
+        # the whole [L, slots, G, Dv, D], [.., G, D]
+        # the training forward's retention, from nothing before it;
+        # the slot's state is the prompt's and nothing else's
+        x, (S, z), _ = block(lp, x, ropes.get(RETENTION, no_rotation),
+                             retain_from_the_start, cfg)
+        cs = lax.dynamic_update_slice(cs, S[None], (i, slot, 0, 0, 0))
+        cz = lax.dynamic_update_slice(cz, z[None], (i, slot, 0, 0))
+        return x, lent, cs, cz
 
-    def retention_run(x, layers, cs, cz):
-        def body(carry, lp, i):
-            x, cs, cz = carry   # the whole [L, slots, G, Dv, D], [.., G, D]
-            # the training forward's retention, from nothing before it;
-            # the slot's state is the prompt's and nothing else's
-            x, (S, z), _ = block(lp, x, ropes.get(RETENTION, no_rotation),
-                                 retain_from_the_start, cfg)
-            cs = lax.dynamic_update_slice(cs, S[None], (i, slot, 0, 0, 0))
-            cz = lax.dynamic_update_slice(cz, z[None], (i, slot, 0, 0))
-            return x, cs, cz
+    def gmu_layer(x, lent, lp, i, first, second, last=False):
+        return block(lp, x, None, lent[0], cfg)[0], lent, first, second
 
-        return scan_run(body, (x, cs, cz), layers)
+    def cross_layer(x, lent, lp, i, first, second, last=False):
+        def attend(q, k, v):
+            # every position its own prefix of the full layer's K/V, or
+            # the last position, one query, all of it
+            with jax.named_scope("cross_attention"):
+                return flash_attention(q, lent[1], lent[2], causal=True,
+                                       sm_scale=sm_scale), None
 
-    new = []
-    for ((mixer, _), layers), (first, second) in zip(
-            layer_stacks(params, cfg), states):
-        if mixer == MAMBA:
-            x, first, second = mamba_run(x, layers, first, second)
-        elif mixer == RETENTION:
-            x, first, second = retention_run(x, layers, first, second)
+        x, _, _ = block(lp, x, ropes.get(CROSS, no_rotation), attend, cfg)
+        return x, lent, first, second
+
+    def layer_of(mixer):
+        return {MAMBA: mamba_layer, RETENTION: retention_layer,
+                GMU: gmu_layer, CROSS: cross_layer}.get(
+                    mixer) or attention_layer(mixer)
+
+    tail, split = prefill_stages(cfg)
+
+    def run(x, lent, kind, layers, state, depth, n):
+        """One run: its layers over x, its state written. ``depth`` the
+        index of its first layer in the model. The carry holds the
+        state as two tuples, each layer of the period's first array and
+        its second."""
+        period = period_of(kind)
+        layer = [layer_of(mixer) for mixer, _ in period]
+        firsts, held = zip(*state)
+        # a Mamba layer's tails are gathered [L, K - 1, 1, C] and
+        # written into the slot once, behind the scan: carried through
+        # it, the tails' array (rows of the model's dtype, written at a
+        # slot that is no multiple of a tile) is copied whole on the
+        # way in and on the way out
+        seconds = tuple(
+            jnp.zeros(cc.shape[:2] + (1,) + cc.shape[3:], cc.dtype)
+            if mixer == MAMBA else cc
+            for (mixer, _), cc in zip(period, held))
+
+        def body(carry, lps, i, staged=False):
+            x, firsts, seconds, *lent = carry
+            firsts, seconds, lent = list(firsts), list(seconds), tuple(lent)
+            for j, lp in enumerate(lps if len(period) > 1 else (lps,)):
+                at = depth + i * len(period) + j
+                if cfg.differential:
+                    lp = dict(lp, depth=at)
+                if staged and at == tail and x.shape[1] > 1:
+                    x, lent = _at_the_last(x, lent)
+                x, lent, firsts[j], seconds[j] = layer[j](
+                    x, lent, lp, i, firsts[j], seconds[j],
+                    last=staged and at == split)
+                if staged and at == split:
+                    x, lent = _at_the_last(x, lent)
+            return (x, tuple(firsts), tuple(seconds), *lent)
+
+        if depth >= tail and x.shape[1] > 1:
+            x, lent = _at_the_last(x, lent)
+        carry = (x, firsts, seconds, *lent)
+        if _changes_stage(cfg, depth, n, len(period)):
+            # one period, no scan: the stage may change inside it
+            x, firsts, seconds, *lent = body(
+                carry, one_period(layers), 0, staged=True)
         else:
-            x, first, second = attention_run(x, layers, first, second, mixer)
-        new.append((first, second))
+            x, firsts, seconds, *lent = scan_run(body, carry, layers)
+        seconds = tuple(
+            lax.dynamic_update_slice(cc, tails, (0, 0, slot, 0))
+            if mixer == MAMBA else tails
+            for (mixer, _), cc, tails in zip(period, held, seconds))
+        return x, tuple(lent), tuple(zip(firsts, seconds))
+
+    new, depth = [], 0
+    lent = nothing_lent(cfg, x) if cfg.lends else (None, None, None)
+    for ((kind, layers), (_, n)), state in zip(
+            zip(layer_stacks(params, cfg), runs), states):
+        x, lent, state = run(x, lent, kind, layers, state, depth, n)
+        new.append(state)
+        depth += n * len(period_of(kind))
     logits = unembed(params, x, last=True, eps=cfg.norm_eps)
     return logits, _with_states(
         cache, runs, new, pos=cache["pos"].at[slot].set(T0),
@@ -497,14 +711,18 @@ def slot_decode_step(params, cache: Dict, token, active,
                           sin[pos][:, None, None, :])
         return rope
 
-    def attention_run(x, load, layers, ck, cv, attention):
+    # a layer of each kind: (x, load, memory, shared, lp, i, first,
+    # second) -> (x, load, memory, shared, first, second); ``memory``
+    # the rows' memory [B, 1, C] (None in a model without gated memory
+    # units), ``shared`` (K, V, layer) of the last full-attention layer
+    # as they lie in its run's carry, behind that run's write
+    def attention_layer(attention):
         window = cfg.window if attention == WINDOW else None
         at = pos if window is None else pos % window
         rope = row_rope(attention)
 
-        def body(carry, lp, i):
-            x, ck, cv, load = carry  # ck/cv: the whole [L, B, rows, G, Dh]
-
+        def layer(x, load, memory, shared, lp, i, ck, cv):
+            # ck/cv: the whole [L, B, rows, G, Dh]
             def attend(q, k, v):
                 with jax.named_scope(f"{attention}_attention"):
                     # write, then attend: the layer's K/V are read out of
@@ -528,77 +746,111 @@ def slot_decode_step(params, cache: Dict, token, active,
                 return o, (nk, nv)
 
             x, (ck, cv), got = block(lp, x, rope, attend, cfg)
-            return x, ck, cv, _tally(load, got)
+            if attention == FULL and cfg.has_cross:
+                shared = (ck, cv, i)
+            return x, _tally(load, got), memory, shared, ck, cv
 
-        return scan_run(body, (x, ck, cv, load), layers)
+        return layer
 
-    def mamba_run(x, load, layers, cs, cc):
-        def body(carry, lp, i):
-            x, cs, cc, load = carry     # [L, B, N, C] and [L, K-1, B, C]
-            with jax.named_scope("mamba_mixer"):
-                tail = lax.dynamic_index_in_dim(
-                    cc, i, keepdims=False).swapaxes(0, 1)   # [B, K-1, C]
+    def mamba_layer(x, load, memory, shared, lp, i, cs, cc):
+        # [L, B, N, C] and [L, K-1, B, C]
+        with jax.named_scope("mamba_mixer"):
+            tail = lax.dynamic_index_in_dim(
+                cc, i, keepdims=False).swapaxes(0, 1)   # [B, K-1, C]
 
-            def conv(u, w, b):
-                return ssm.causal_conv(u, w, b, tail)
+        def conv(u, w, b):
+            return ssm.causal_conv(u, w, b, tail)
 
-            def step(u, dt, A, b, c, D):
-                with jax.named_scope("ssm_step"):
-                    # the carry itself is the operand: a row's state is
-                    # read once, advanced and written where it lies, an
-                    # inactive row's bit for bit what it was
-                    y, ns = ssm.carried_step(
-                        u[:, 0], dt[:, 0], A, b[:, 0], c[:, 0], D, cs, i,
-                        active)
-                return y[:, None], ns
+        def step(u, dt, A, b, c, D):
+            with jax.named_scope("ssm_step"):
+                # the carry itself is the operand: a row's state is
+                # read once, advanced and written where it lies, an
+                # inactive row's bit for bit what it was
+                y, ns = ssm.carried_step(
+                    u[:, 0], dt[:, 0], A, b[:, 0], c[:, 0], D, cs, i,
+                    active)
+            return y[:, None], ns
 
-            x, (new_tail, cs), got = block(
-                lp, x, None, ssm.Recurrence(conv, step), cfg)
-            # ... and so its convolution's tail
-            with jax.named_scope("mamba_mixer"):
-                cc = lax.dynamic_update_slice(cc, jnp.where(
-                    active[:, None, None], new_tail.astype(cc.dtype),
-                    tail).swapaxes(0, 1)[None], (i, 0, 0, 0))
-            return x, cs, cc, _tally(load, got)
+        x, (new_tail, cs, *made), got = block(
+            lp, x, None, ssm.Recurrence(conv, step), cfg)
+        # ... and so its convolution's tail
+        with jax.named_scope("mamba_mixer"):
+            cc = lax.dynamic_update_slice(cc, jnp.where(
+                active[:, None, None], new_tail.astype(cc.dtype),
+                tail).swapaxes(0, 1)[None], (i, 0, 0, 0))
+        return (x, _tally(load, got), made[0] if made else memory, shared,
+                cs, cc)
 
-        return scan_run(body, (x, cs, cc, load), layers)
+    def retention_layer(x, load, memory, shared, lp, i, cs, cz):
+        # [L, B, G, Dv, D] and [L, B, G, D]
+        def retain(q, k, v, g):
+            with jax.named_scope("retention_step"):
+                # the carry itself is the operand: a live row's
+                # state is read and written where it lies, an
+                # inactive row's not at all
+                o, ns, nz = retention.retention_step(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], cs, cz, i,
+                    active)
+            return o[:, None], (ns, nz)
 
-    def retention_run(x, load, layers, cs, cz):
-        rope = row_rope(RETENTION)
+        x, (cs, cz), got = block(lp, x, row_rope(RETENTION), retain, cfg)
+        return x, _tally(load, got), memory, shared, cs, cz
 
-        def body(carry, lp, i):
-            x, cs, cz, load = carry     # [L, B, G, Dv, D] and [L, B, G, D]
+    def gmu_layer(x, load, memory, shared, lp, i, first, second):
+        x, _, got = block(lp, x, None, memory, cfg)
+        return x, _tally(load, got), memory, shared, first, second
 
-            def retain(q, k, v, g):
-                with jax.named_scope("retention_step"):
-                    # the carry itself is the operand: a live row's
-                    # state is read and written where it lies, an
-                    # inactive row's not at all
-                    o, ns, nz = retention.retention_step(
-                        q[:, 0], k[:, 0], v[:, 0], g[:, 0], cs, cz, i,
-                        active)
-                return o[:, None], (ns, nz)
+    def cross_layer(x, load, memory, shared, lp, i, first, second):
+        def attend(q, k, v):
+            with jax.named_scope("cross_attention"):
+                # the full layer's carry is the operand, as it lies
+                # behind that layer's write of this step's token
+                sk, sv, layer = shared
+                return decode_attention(q[:, 0], sk, sv, layer, pos,
+                                        sm_scale=sm_scale), None
 
-            x, (cs, cz), got = block(lp, x, rope, retain, cfg)
-            return x, cs, cz, _tally(load, got)
+        x, _, got = block(lp, x, row_rope(CROSS), attend, cfg)
+        return x, _tally(load, got), memory, shared, first, second
 
-        return scan_run(body, (x, cs, cz, load), layers)
+    def layer_of(mixer):
+        return {MAMBA: mamba_layer, RETENTION: retention_layer,
+                GMU: gmu_layer, CROSS: cross_layer}.get(
+                    mixer) or attention_layer(mixer)
+
+    def run(x, load, memory, shared, kind, layers, state, depth):
+        period = period_of(kind)
+        layer = [layer_of(mixer) for mixer, _ in period]
+
+        def body(carry, lps, i):
+            x, firsts, seconds, load, memory = carry
+            firsts, seconds, within = list(firsts), list(seconds), shared
+            for j, lp in enumerate(lps if len(period) > 1 else (lps,)):
+                if cfg.differential:
+                    lp = dict(lp, depth=depth + i * len(period) + j)
+                x, load, memory, within, firsts[j], seconds[j] = layer[j](
+                    x, load, memory, within, lp, i, firsts[j], seconds[j])
+            return x, tuple(firsts), tuple(seconds), load, memory
+
+        x, firsts, seconds, load, memory = scan_run(
+            body, (x, *zip(*state), load, memory), layers)
+        full = [j for j, (mixer, _) in enumerate(period) if mixer == FULL]
+        if full and cfg.has_cross:
+            # the run's last full layer, as the run leaves it
+            shared = (firsts[full[-1]], seconds[full[-1]],
+                      firsts[full[-1]].shape[0] - 1)
+        return x, load, memory, shared, tuple(zip(firsts, seconds))
 
     load = jnp.zeros_like(cache["load"]) if "load" in cache else None
-    new = []
-    for r, (((mixer, _), layers), (first, second)) in enumerate(zip(
-            layer_stacks(params, cfg), states)):
+    with jax.named_scope("gmu"):
+        memory = nothing_lent(cfg, x)[0] if cfg.has_gmu else None
+    new, depth, shared = [], 0, None
+    for r, (((kind, layers), (_, n)), state) in enumerate(zip(
+            zip(layer_stacks(params, cfg), runs), states)):
         with jax.named_scope(f"run{r}"):
-            if mixer == MAMBA:
-                x, first, second, load = mamba_run(x, load, layers, first,
-                                                   second)
-            elif mixer == RETENTION:
-                x, first, second, load = retention_run(
-                    x, load, layers, first, second)
-            else:
-                x, first, second, load = attention_run(
-                    x, load, layers, first, second, mixer)
-        new.append((first, second))
+            x, load, memory, shared, state = run(
+                x, load, memory, shared, kind, layers, state, depth)
+        new.append(state)
+        depth += n * len(period_of(kind))
     logits = unembed(params, x[:, 0], eps=cfg.norm_eps)
     with jax.named_scope("head"):
         tok = jnp.where(active, pick(logits), cache["tok"])
@@ -706,8 +958,9 @@ def decode_parts(cfg: TransformerConfig) -> List[str]:
     names somewhere: the parts its layers' kinds imply and every run."""
     runs = layer_runs(cfg)
     want = {"embed", "head"} | {f"run{r}" for r in range(len(runs))}
-    for (mixer, ffn), _ in runs:
+    for _, _, mixer, ffn in run_layers(runs):
         want |= ({"mamba_mixer", "ssm_step"} if mixer == MAMBA
+                 else {"gmu"} if mixer == GMU
                  else {"qkv", "retention_step", "attn_out"}
                  if mixer == RETENTION
                  else {"qkv", f"{mixer}_attention", "attn_out"})

@@ -9,7 +9,15 @@ state-space recurrence behind a short causal convolution
 (ops/ssm.py), which carries the order of the sequence itself, or a
 power-retention layer: attention's projections, heads and rope with
 ``(q.k)^2`` in the place of ``exp(q.k)`` and a learned decay, whose
-whole past is a matrix a K/V head (ops/retention.py).
+whole past is a matrix a K/V head (ops/retention.py). Two mixers keep
+nothing of their own and read what an earlier layer made inside the
+same pass: a gated memory unit, which gates the scan's output (before
+its own gate) of the last Mamba layer before it, the *memory*, and a
+cross-attention layer, which projects queries alone and attends the
+K/V of the last full-attention layer before it. Attention of any kind
+may be *differential*: query heads in pairs, two softmaxes over one
+doubled value head, the second taken from the first times a learned
+scalar, a norm over each pair's output.
 
 **One block.** ``block`` is the only definition of the layer: norms,
 projections, feed-forward and residuals. What differs between training,
@@ -17,7 +25,10 @@ prefill and decode is handed to it: ``rope`` (how q and k are rotated)
 and ``attend`` (what the queries attend, or how a Mamba layer's
 convolution and scan run, and the state that comes back); what differs
 between layers is in the layer's own weights (a router makes it an
-expert layer, ``w_in`` a Mamba layer, ``w_g`` a retention layer) and
+expert layer, ``w_in`` a Mamba layer, ``w_g`` a retention layer,
+``w_mem`` a gated memory unit, ``wq`` without ``wk`` a cross layer,
+``lambda_q1`` differential attention, ``attn_norm_b`` LayerNorm where
+the others have RMSNorm, ``bq`` biased projections) and
 in the closures its caller
 builds for its kind. ``forward`` here and ``slot_prefill`` /
 ``slot_decode_step`` in models/decode.py each scan it over the stacked
@@ -27,12 +38,25 @@ the architecture is a change to ``block``, ``init_params`` and
 
 **Layers of several kinds.** Layers of unlike shape cannot share one
 scan. ``TransformerConfig.layer_kinds`` gives each layer's kind; the
-published order is cut into runs of alike layers (``layer_runs``), each
-run's weights are stacked on a leading dimension of their own
-(``params["layers"]`` is then a tuple of such stacks, one a run, where
-a model of one kind keeps the one stack), and every caller scans run
-after run. Unrolling instead would compile one body a layer where this
-compiles one a run.
+published order is cut into runs of alike *periods* (``layer_runs``):
+of alike layers where the kinds change rarely (a period of one layer,
+which is every model of one kind), of alike groups of layers where they
+alternate ((Mamba, window) x 8 is one run of eight periods of two, not
+sixteen runs of one). Each run's weights are stacked on a leading
+dimension of their own (``params["layers"]`` is then a tuple of such
+stacks, one a run, where a model of one kind keeps the one stack; a run
+of periods of several layers holds a tuple of stacks, one for each
+layer of the period), and every caller scans run after run, the
+period's layers one after the other inside the scan's body. Unrolling
+instead would compile one body a layer where this compiles one for
+each layer of a run's period.
+
+**What travels beside x.** A model with gated memory units or cross
+layers carries two more values from layer to layer inside a pass: the
+memory (``[B, T, ssm_inner]``, replaced by every Mamba layer) and the
+K/V of the last full-attention layer. ``forward`` carries them through
+its runs beside x; models/decode.py's two programs do the same with the
+memory, and hand a cross layer the full layer's cache itself.
 
 ``forward`` runs in two modes sharing every line of math:
 
@@ -65,7 +89,7 @@ from jax import lax
 from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.retention import retention
-from ray_tpu.ops.norms import rmsnorm
+from ray_tpu.ops.norms import layernorm, rmsnorm
 from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
 from ray_tpu.parallel.collectives import (axis_size, shard_map,
                                            tp_allreduce, tp_copy)
@@ -78,13 +102,21 @@ from jax.sharding import PartitionSpec as P
 
 
 # A layer's kind: (mixer, feed-forward). The mixer is one of the two
-# kinds of attention, a Mamba layer or a power-retention layer (of
+# kinds of attention, a Mamba layer, a power-retention layer (of
 # degree 2: a pair scores (q.k)^2, the feature map of ops/retention.py;
-# no other degree is expressed, so the kind has no field for one).
+# no other degree is expressed, so the kind has no field for one), a
+# gated memory unit or a cross-attention layer.
 FULL, WINDOW, MAMBA, RETENTION = "full", "window", "mamba", "retention"
+GMU, CROSS = "gmu", "cross"
+MIXERS = (FULL, WINDOW, MAMBA, RETENTION, GMU, CROSS)
 # the mixers that keep no row a position but a summary that does not
 # grow: nothing of theirs is sharded over tp, sp or pp
 SUMMARIES = (MAMBA, RETENTION)
+# the mixers that keep nothing between tokens and mix nothing over the
+# sequence themselves: a gated memory unit reads the memory of the last
+# Mamba layer before it (that layer's scan output, before its gate), a
+# cross layer the K/V of the last full-attention layer before it
+BORROWERS = (GMU, CROSS)
 DENSE, EXPERTS = "dense", "experts"
 LayerKind = Tuple[str, str]
 
@@ -106,8 +138,11 @@ PARTS = (
                             # rope, value scale, a retention layer's gate
     "full_attention",       # scores, softmax, p.V (or the kernel's call)
     "window_attention",     # ... and the cache's in-place write beside them
+    "cross_attention",      # ... of a cross layer, which writes nothing
     "attn_out",             # wo and the residual add
     "mamba_mixer",          # norm, projections, convolution, gate, residual
+    "gmu",                  # a gated memory unit: norm, both projections,
+                            # the gate on the memory, residual
     "ssm_step",             # a decode step's recurrence, its state read and
                             # written where it lies
     "ssm_scan",             # the recurrence over a whole sequence
@@ -176,6 +211,21 @@ class TransformerConfig:
     # an RMSNorm over each head of q and of k, before rope (weights
     # q_norm, k_norm [head_dim], shared by the heads)
     qk_norm: bool = False
+    # differential attention, in every attention and cross layer: query
+    # heads 2p and 2p + 1 are a pair on K/V pair p // (pairs a K/V
+    # pair): each softmaxes over its own key head of that pair, both
+    # weigh the pair's two value heads side by side, and the pair's
+    # output is RMSNorm(first - lambda * second) * (1 - lambda_init),
+    # lambda learned (four vectors a layer) around lambda_init(depth)
+    differential: bool = False
+    # biases on wq, wk, wv and wo
+    attn_bias: bool = False
+    # every norm of the block and the final one a LayerNorm with scale
+    # and bias, where False has RMSNorm
+    layer_norm: bool = False
+    # a Mamba layer's dt, B and C each through an RMSNorm of its own
+    # between the two projections (Jamba's); False: Mamba-1 as published
+    ssm_inner_norms: bool = True
 
     def __post_init__(self):
         def refuse(key, why):
@@ -186,11 +236,19 @@ class TransformerConfig:
             if len(kinds) != self.n_layers:
                 refuse("layer_kinds", f"{len(kinds)} kinds for "
                        f"{self.n_layers} layers")
-            for mixer, ffn in kinds:
-                if mixer not in (FULL, WINDOW, MAMBA, RETENTION) or ffn \
-                        not in (DENSE, EXPERTS):
+            for at, (mixer, ffn) in enumerate(kinds):
+                if mixer not in MIXERS or ffn not in (DENSE, EXPERTS):
                     refuse("layer_kinds", f"unknown kind "
                            f"{(mixer, ffn)!r}")
+                before = [m for m, _ in kinds[:at]]
+                if mixer == CROSS and FULL not in before:
+                    refuse("layer_kinds", f"layer {at} is a cross layer "
+                           f"with no full-attention layer before it "
+                           f"whose K/V it could attend")
+                if mixer == GMU and MAMBA not in before:
+                    refuse("layer_kinds", f"layer {at} is a gated memory "
+                           f"unit with no Mamba layer before it whose "
+                           f"memory it could gate")
         if any(a == WINDOW for a, _ in kinds or ()) and not self.window:
             refuse("window", "window layers need a window")
         for key in ("n_kv_heads", "window_kv_heads"):
@@ -222,6 +280,25 @@ class TransformerConfig:
         if self.has_retention and self.head_dim % 2:
             refuse("qk_head_dim", f"retention layers need heads of even "
                    f"width, not {self.head_dim}")
+        if self.differential:
+            for key in ("n_heads", "n_kv_heads", "window_kv_heads"):
+                heads = getattr(self, key)
+                if heads is not None and heads % 2:
+                    refuse(key, f"differential attention pairs its heads: "
+                           f"{heads} is odd")
+            if self.has_retention or self.sink_kinds:
+                refuse("differential", "no differential form of a "
+                       "retention layer or of a sink logit is expressed")
+        if self.has_cross:
+            for kind, n in layer_runs(self):
+                mixers = [mixer for mixer, _ in period_of(kind)]
+                if n > 1 and FULL in mixers and CROSS in mixers[
+                        :len(mixers) - mixers[::-1].index(FULL)]:
+                    refuse("layer_kinds", f"a cross layer before the "
+                           f"full-attention layer of its own period "
+                           f"({mixers} x {n}) would attend the period "
+                           f"before's K/V, which no serving program "
+                           f"carries from one period to the next")
 
     @property
     def head_dim(self) -> int:
@@ -245,6 +322,20 @@ class TransformerConfig:
     def has_retention(self) -> bool:
         return any(mixer == RETENTION for mixer, _ in self.layer_kinds or ())
 
+    @property
+    def has_gmu(self) -> bool:
+        return any(mixer == GMU for mixer, _ in self.layer_kinds or ())
+
+    @property
+    def has_cross(self) -> bool:
+        return any(mixer == CROSS for mixer, _ in self.layer_kinds or ())
+
+    @property
+    def lends(self) -> bool:
+        """Whether a layer reads what an earlier one made inside the
+        same pass: the memory, or a full-attention layer's K/V."""
+        return self.has_gmu or self.has_cross
+
     def kv_heads(self, attention: str = FULL) -> int:
         heads = self.n_kv_heads or self.n_heads
         if attention == WINDOW:
@@ -257,17 +348,54 @@ class TransformerConfig:
         return self.rope_theta
 
 
-def layer_runs(cfg: TransformerConfig) -> Tuple[Tuple[LayerKind, int], ...]:
-    """The layers in their order as runs of alike ones: ((kind, how
-    many), ...). Each run is one stack of weights and one scan."""
-    kinds = cfg.layer_kinds or ((FULL, DENSE),) * cfg.n_layers
-    runs = []
-    for kind in kinds:
-        if runs and runs[-1][0] == tuple(kind):
-            runs[-1][1] += 1
-        else:
-            runs.append([tuple(kind), 1])
-    return tuple((kind, n) for kind, n in runs)
+def layer_runs(cfg: TransformerConfig) -> tuple:
+    """The layers in their order as runs of alike periods: ((kind, how
+    many), ...). Each run is one stack of weights and one scan. The
+    layers are cut into periods of p layers each and neighbouring
+    periods that are alike make a run; **p is the one that leaves the
+    fewest layer bodies to compile**, p times the number of runs (the
+    smaller p where two tie; p divides the layers). Where the kinds
+    change rarely that is p = 1 and a run is a run of alike layers,
+    ``kind`` the layers' ``(mixer, feed-forward)``: every model of one
+    kind, a dense layer before five window layers and a full one, a
+    Mamba model with an attention layer every fourteenth. Where they
+    alternate it is the alternation's length and ``kind`` is the
+    period, a tuple of its layers' kinds: (Mamba, window) x 8, (Mamba,
+    full) x 1, (gated memory, cross) x 7 are three runs at p = 2 (six
+    bodies) where p = 1 gives thirty-two. ``period_of`` reads either
+    form."""
+    kinds = tuple(tuple(kind) for kind in (
+        cfg.layer_kinds or ((FULL, DENSE),) * cfg.n_layers))
+    best = None
+    for p in range(1, len(kinds) + 1):
+        if best is not None and p >= p_best * len(best):
+            break
+        if len(kinds) % p:
+            continue
+        runs = []
+        for at in range(0, len(kinds), p):
+            if runs and runs[-1][0] == kinds[at:at + p]:
+                runs[-1][1] += 1
+            else:
+                runs.append([kinds[at:at + p], 1])
+        if best is None or p * len(runs) < p_best * len(best):
+            best, p_best = runs, p
+    return tuple((period[0] if len(period) == 1 else period, n)
+                 for period, n in best)
+
+
+def period_of(kind) -> Tuple[LayerKind, ...]:
+    """The kinds of the layers of one period of a run, from the run's
+    ``kind`` as ``layer_runs`` gives it: a layer's own kind is a period
+    of one."""
+    return (kind,) if isinstance(kind[0], str) else tuple(kind)
+
+
+def run_layers(runs) -> list:
+    """[(run, layer of its period, mixer, feed-forward), ...] of every
+    layer of one period of every run, in the layers' order."""
+    return [(r, j, mixer, ffn) for r, (kind, _) in enumerate(runs)
+            for j, (mixer, ffn) in enumerate(period_of(kind))]
 
 
 def layer_stacks(params, cfg: TransformerConfig):
@@ -275,7 +403,8 @@ def layer_stacks(params, cfg: TransformerConfig):
     the one place that reads ``params["layers"]``. A model of one kind
     keeps it as the one stack, the form its checkpoints, its callers
     and the benchmark's ``ouro`` family hold; one with ``layer_kinds``
-    holds a tuple of stacks, one a run."""
+    holds a tuple of stacks, one a run (a run of periods of several
+    layers: a tuple of stacks, one for each layer of the period)."""
     runs, layers = layer_runs(cfg), params["layers"]
     if cfg.layer_kinds is None:
         layers = (layers,)
@@ -344,14 +473,14 @@ def _init_mamba(keys, cfg: TransformerConfig, n: int):
     def w(kk, shape):
         return init(kk, shape, jnp.float32).astype(dt)
 
+    inner = {"dt_norm": jnp.ones((n, R), dt), "b_norm": jnp.ones((n, N), dt),
+             "c_norm": jnp.ones((n, N), dt)} if cfg.ssm_inner_norms else {}
     return {
         "w_in": w(keys[0], (n, D, 2 * C)),
         "conv_w": w(keys[7], (n, K, C)),
         "conv_b": w(keys[8], (n, C)),
         "w_x": w(keys[1], (n, C, R + 2 * N)),
-        "dt_norm": jnp.ones((n, R), dt),
-        "b_norm": jnp.ones((n, N), dt),
-        "c_norm": jnp.ones((n, N), dt),
+        **inner,
         "w_dt": w(keys[2], (n, R, C)),
         "dt_bias": mamba_dt_bias(keys[9], (n, C)),
         "a_log": jnp.broadcast_to(
@@ -378,6 +507,11 @@ def _init_run(keys, cfg: TransformerConfig, kind: LayerKind, n: int):
         run = dict(_init_mamba(keys, cfg, n),
                    attn_norm=jnp.ones((n, D), dt),
                    mlp_norm=jnp.ones((n, D), dt))
+    elif attention == GMU:
+        run = {"attn_norm": jnp.ones((n, D), dt),
+               "w_mem": w(keys[0], (n, D, cfg.ssm_inner)),
+               "w_out": w(keys[3], (n, cfg.ssm_inner, D)),
+               "mlp_norm": jnp.ones((n, D), dt)}
     else:
         run = {
             "attn_norm": jnp.ones((n, D), dt),
@@ -387,7 +521,24 @@ def _init_run(keys, cfg: TransformerConfig, kind: LayerKind, n: int):
             "wo": w(keys[3], (n, H * Dv, D)),
             "mlp_norm": jnp.ones((n, D), dt),
         }
-    if attention != MAMBA and cfg.qk_norm:
+        if attention == CROSS:      # the queries alone are its own
+            del run["wk"], run["wv"]
+        if cfg.attn_bias:
+            run.update({"b" + name[1:]: jnp.zeros(run[name].shape[::2], dt)
+                        for name in ("wq", "wk", "wv", "wo") if name in run})
+        if cfg.differential:
+            # lambda's four vectors at normal(0.1), so that lambda is
+            # near lambda_init and the second softmax matters
+            lam = jax.random.split(jax.random.fold_in(keys[0], 1), 4)
+            run.update({
+                name: 0.1 * jax.random.normal(kk, (n, Dh), jnp.float32)
+                for name, kk in zip(("lambda_q1", "lambda_k1", "lambda_q2",
+                                     "lambda_k2"), lam)},
+                sub_norm=jnp.ones((n, 2 * Dv), dt))
+    if cfg.layer_norm:
+        run.update(attn_norm_b=jnp.zeros((n, D), dt),
+                   mlp_norm_b=jnp.zeros((n, D), dt))
+    if attention not in (MAMBA, GMU) and cfg.qk_norm:
         run.update(q_norm=jnp.ones((n, Dh), dt), k_norm=jnp.ones((n, Dh), dt))
     if attention == RETENTION:
         run.update(w_g=w(keys[7], (n, D, G)),
@@ -411,17 +562,23 @@ def _init_run(keys, cfg: TransformerConfig, kind: LayerKind, n: int):
 def init_params(key, cfg: TransformerConfig):
     """Pytree of params; layer weights stacked on a leading L dim, or,
     with ``layer_kinds``, a tuple of such stacks, one for each run of
-    alike layers (``layer_runs``)."""
+    alike periods (``layer_runs``; for a period of several layers a
+    tuple of stacks, one for each of them)."""
     k = jax.random.split(key, 8)
     runs = layer_runs(cfg)
     if cfg.layer_kinds is None:
         (kind, n), = runs
         layers = _init_run(list(k[1:]) + [None] * 3, cfg, kind, n)
     else:
-        layers = tuple(
-            _init_run(jax.random.split(jax.random.fold_in(key, 1 + r), 10),
-                      cfg, kind, n)
-            for r, (kind, n) in enumerate(runs))
+        def run(r, kind, n):
+            at = jax.random.fold_in(key, 1 + r)
+            if isinstance(kind[0], str):
+                return _init_run(jax.random.split(at, 10), cfg, kind, n)
+            return tuple(
+                _init_run(jax.random.split(jax.random.fold_in(at, j), 10),
+                          cfg, one, n) for j, one in enumerate(kind))
+
+        layers = tuple(run(r, kind, n) for r, (kind, n) in enumerate(runs))
     init = jax.nn.initializers.normal(0.02)
     params = {
         "embed": init(k[0], (cfg.vocab, cfg.d_model),
@@ -429,6 +586,8 @@ def init_params(key, cfg: TransformerConfig):
         "layers": layers,
         "final_norm": jnp.ones((cfg.d_model,), cfg.dtype),
     }
+    if cfg.layer_norm:
+        params["final_norm_b"] = jnp.zeros((cfg.d_model,), cfg.dtype)
     if not cfg.tie_embeddings:
         params["head"] = init(jax.random.fold_in(key, 0),
                               (cfg.d_model, cfg.vocab),
@@ -442,21 +601,29 @@ def param_specs(pcfg: ParallelConfig,
     of one kind without ``cfg``, else of ``cfg``'s. Heads and the
     feed-forward's width (an expert's own, inside each expert) go over
     ``tp``; a stack's layers over ``pp``, which only a model of one
-    kind can have. A model with Mamba or retention layers is refused
-    ``tp``, ``sp`` and ``pp``: no sharding of those mixers is
-    expressed."""
+    kind can have. A model with Mamba or retention layers, or one whose
+    layers read an earlier layer's memory or K/V, is refused ``tp``,
+    ``sp`` and ``pp``: no sharding of those mixers is expressed."""
     pp, tp = pcfg.pp, pcfg.tp
     if cfg is not None:
         _refuse_a_sharded_summary(cfg, pcfg)
 
     def run_specs(kind):
         attention, ffn = kind
+        def whole(*leaves):     # refused tp and pp: replicated
+            return {name: P(*(None,) * rank) for name, rank in leaves}
+
         if attention == MAMBA:
-            specs = {name: P(*(None,) * rank) for name, rank in (
+            specs = whole(
                 ("attn_norm", 2), ("mlp_norm", 2), ("w_in", 3),
-                ("conv_w", 3), ("conv_b", 2), ("w_x", 3), ("dt_norm", 2),
-                ("b_norm", 2), ("c_norm", 2), ("w_dt", 3), ("dt_bias", 2),
-                ("a_log", 3), ("d_skip", 2), ("w_out", 3))}
+                ("conv_w", 3), ("conv_b", 2), ("w_x", 3), ("w_dt", 3),
+                ("dt_bias", 2), ("a_log", 3), ("d_skip", 2), ("w_out", 3))
+            if cfg.ssm_inner_norms:
+                specs.update(whole(("dt_norm", 2), ("b_norm", 2),
+                                   ("c_norm", 2)))
+        elif attention == GMU:
+            specs = whole(("attn_norm", 2), ("mlp_norm", 2), ("w_mem", 3),
+                          ("w_out", 3))
         else:
             specs = {
                 "attn_norm": P(pp, None),
@@ -466,7 +633,20 @@ def param_specs(pcfg: ParallelConfig,
                 "wo": P(pp, tp, None),
                 "mlp_norm": P(pp, None),
             }
-        if cfg is not None and cfg.qk_norm and attention != MAMBA:
+        if attention == CROSS:
+            del specs["wk"], specs["wv"]
+        attends = attention not in (MAMBA, GMU)
+        if cfg is not None and attends and cfg.attn_bias:
+            specs.update({"b" + name[1:]: P(pp, tp if name != "wo" else None)
+                          for name in ("wq", "wk", "wv", "wo")
+                          if name in specs})
+        if cfg is not None and attends and cfg.differential:
+            specs.update(whole(("lambda_q1", 2), ("lambda_k1", 2),
+                               ("lambda_q2", 2), ("lambda_k2", 2),
+                               ("sub_norm", 2)))
+        if cfg is not None and cfg.layer_norm:
+            specs.update(attn_norm_b=P(pp, None), mlp_norm_b=P(pp, None))
+        if cfg is not None and cfg.qk_norm and attends:
             specs.update(q_norm=P(pp, None), k_norm=P(pp, None))
         if attention == RETENTION:      # refused tp and pp: replicated
             specs.update(w_g=P(None, None, None), b_g=P(None, None))
@@ -489,10 +669,14 @@ def param_specs(pcfg: ParallelConfig,
         if pp:
             raise ValueError("a pipeline over layers of several kinds is "
                              "not expressed: pp needs one stack")
-        specs["layers"] = tuple(run_specs(kind)
-                                for kind, _ in layer_runs(cfg))
+        specs["layers"] = tuple(
+            run_specs(kind) if isinstance(kind[0], str)
+            else tuple(run_specs(one) for one in kind)
+            for kind, _ in layer_runs(cfg))
     if cfg is not None and not cfg.tie_embeddings:
         specs["head"] = P(None, None)
+    if cfg is not None and cfg.layer_norm:
+        specs["final_norm_b"] = P(None)
     return specs
 
 
@@ -504,7 +688,15 @@ def _refuse_a_sharded_summary(cfg: TransformerConfig,
              "its runs of unlike layers for pp"),
             (cfg.has_retention, "retention", "its K/V heads and their "
              "states for tp, its chunks' carried state for sp, a stack "
-             "with a state a layer for pp")):
+             "with a state a layer for pp"),
+            (cfg.lends, "gated-memory or cross", "the memory's channels and "
+             "the lent K/V's heads for tp, positions that an earlier layer "
+             "made on another device for sp, a value handed from one stage's "
+             "layer to another stage's for pp"),
+            (cfg.differential, "differential-attention", "head pairs that "
+             "one device must hold whole for tp, the pair's two softmaxes "
+             "over one ring for sp; pp has no stack of layers that take "
+             "their own depth")):
         if has and sharded:
             raise ValueError(
                 f"a model with {layers} layers runs on one device or under "
@@ -512,7 +704,8 @@ def _refuse_a_sharded_summary(cfg: TransformerConfig,
                 f"{', '.join(sharded)} is expressed ({what})")
 
 
-def _attend(q, k, v, pcfg: ParallelConfig, window=None, sink=None):
+def _attend(q, k, v, pcfg: ParallelConfig, window=None, sink=None,
+            sm_scale=None):
     impl = pcfg.attn
     if impl == "auto":
         impl = "ring" if pcfg.sp else "local"
@@ -521,8 +714,8 @@ def _attend(q, k, v, pcfg: ParallelConfig, window=None, sink=None):
         # is the TPU and T is a multiple of 128 (the kernel chooses its
         # blocks from T and multiplies at q's dtype); the XLA reference
         # otherwise (ops.attention.flash_attention).
-        return flash_attention(q, k, v, causal=True, window=window,
-                               sink=sink)
+        return flash_attention(q, k, v, causal=True, sm_scale=sm_scale,
+                               window=window, sink=sink)
     if window is not None or sink is not None or k.shape[2] != q.shape[2] \
             or v.shape[3] != q.shape[3]:
         raise ValueError("sequence-parallel attention takes heads all "
@@ -534,8 +727,18 @@ def _attend(q, k, v, pcfg: ParallelConfig, window=None, sink=None):
     raise ValueError(f"unknown attn impl {impl!r}")
 
 
-def _heads(h, w, width: int):
-    """h [B, T, D] @ w [D, H * width], as heads: [B, T, H, width]. XLA
+def _norm(x, lp, name: str, eps: float):
+    """The norm ``name`` of a layer (or of the model, ``lp`` the
+    parameters): a LayerNorm with scale and bias where ``lp`` holds
+    ``<name>_b``, else an RMSNorm."""
+    if name + "_b" in lp:
+        return layernorm(x, lp[name], lp[name + "_b"], eps=eps)
+    return rmsnorm(x, lp[name], eps=eps)
+
+
+def _heads(h, w, width: int, bias=None):
+    """h [B, T, D] @ w [D, H * width] (+ bias), as heads: [B, T, H,
+    width]. XLA
     folds the reshape into the product, which then wants the weight as
     [H, width, D], the transpose of what is stored: a copy of the
     matrix a layer, and before it a slice of its own out of the layers'
@@ -552,13 +755,66 @@ def _heads(h, w, width: int):
     as ``wo``'s and the feed-forward's do."""
     B, T, D = h.shape
     flat = h @ w
+    if bias is not None:
+        flat = flat + bias
     if B * T < D:
         flat = lax.optimization_barrier(flat)
     return flat.reshape(B, T, -1, width)
 
 
+def _paired(q, k, v):
+    """Differential attention's heads as one call of plain grouped
+    attention serves them. q [B, T, H, Dh]: query heads 2p and 2p + 1
+    are a pair; k and v [B, T, G, Dh] (None in a cross layer): K/V
+    heads 2g and 2g + 1 are a pair, which serves the H / G query pairs
+    p with p // (H / G) == g. The first query of a pair scores against
+    the first key of its K/V pair, the second against the second, and
+    both weigh the two value heads side by side. So: a K/V pair is one
+    head of twice the width (a reshape: the heads lie side by side in a
+    row already), and a query is widened to that width with zeros in
+    the half that is the other key's, so that its product with the
+    doubled key is its product with its own. Grouped attention over
+    [B, T, H, 2 Dh] on [B, T, G / 2, 2 Dh] (query head h on K/V head
+    h // (2 H / G), at the scale of Dh) then gives every query's
+    softmax over its own key times the doubled value. Twice the
+    multiplications in q.k for no copy of K or V and no second call."""
+    B, T, H, Dh = q.shape
+    own = (jnp.arange(H) % 2)[:, None, None] == jnp.arange(2)[None, :, None]
+    q = jnp.where(own, q[..., None, :], 0).reshape(B, T, H, 2 * Dh)
+
+    def pair(t):
+        return None if t is None else t.reshape(
+            t.shape[:2] + (t.shape[2] // 2, 2 * t.shape[3]))
+    return q, pair(k), pair(v)
+
+
+def last_position(t):
+    """t [B, T, ..] at its last position alone: [B, 1, ..]."""
+    return t[:, -1:]
+
+
+def lambda_init(depth):
+    """Differential attention's lambda_init at a layer's depth (its
+    index in the model)."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, jnp.float32))
+
+
+def _differ(o, lp, eps: float):
+    """o [.., H, 2 Dv], each query's softmax times its K/V pair's
+    doubled value, to the pairs' outputs [.., H / 2, 2 Dv]:
+    RMSNorm(first - lambda second) (1 - lambda_init), in float32, at
+    o's dtype."""
+    (H, W), dtype = o.shape[-2:], o.dtype
+    start = lambda_init(lp["depth"])
+    lam = (jnp.exp(jnp.sum(lp["lambda_q1"] * lp["lambda_k1"]))
+           - jnp.exp(jnp.sum(lp["lambda_q2"] * lp["lambda_k2"])) + start)
+    o = o.astype(jnp.float32).reshape(o.shape[:-2] + (H // 2, 2, W))
+    return (rmsnorm(o[..., 0, :] - lam * o[..., 1, :], lp["sub_norm"],
+                    eps=eps) * (1.0 - start)).astype(dtype)
+
+
 def block(lp, x, rope, attend, cfg: TransformerConfig,
-          pcfg: ParallelConfig = ParallelConfig()):
+          pcfg: ParallelConfig = ParallelConfig(), last: bool = False):
     """The transformer block, on local shards. x: [B_l, T_l, D]
     (tp-replicated); ``lp`` one layer's weights. ``rope(t)`` rotates q
     and k; ``attend(q, k, v) -> (o, state)`` takes them as [B, T,
@@ -577,9 +833,28 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
     ``w_in`` is a Mamba layer: ``rope`` is not called and ``attend`` is
     an ``ops.ssm.Recurrence``, the convolution and the scan as its
     caller runs them (:func:`mamba_mixer`); the state that comes back
-    is (the convolution's tail, the scan's state). A layer that has a
+    is (the convolution's tail, the scan's state) and, in a model with
+    gated memory units, behind them the *memory*: the scan's output
+    before the gate, [B, T, ssm_inner], which its caller carries to
+    the layers that read it. A layer that has ``w_mem`` is such a
+    unit: ``attend`` is the memory itself (of the same rows as x), the
+    mixer is ``w_out(silu(w_mem h) * memory)`` and no state comes
+    back. A layer that has ``wq`` and no ``wk`` is a cross layer: it
+    projects queries alone, ``attend(q, None, None)`` attends the K/V
+    its caller has from the last full-attention layer. A layer that
+    has ``lambda_q1`` has differential attention (:func:`_paired`):
+    ``attend`` is handed the widened queries and the doubled K/V heads
+    (which are what a cache keeps, flat rows either way) and must
+    score at ``cfg.head_dim ** -0.5``, the scale of the head before it
+    was widened; ``lp["depth"]`` is the layer's index in the model.
+    Biases and a LayerNorm's are applied where ``lp`` holds them. A
+    layer that has a
     router is an expert layer: its feed-forward is the part the experts
-    held here give (parallel/experts.py). Returns (x, state, load):
+    held here give (parallel/experts.py). With ``last`` (an attention
+    or cross layer of a prefill, behind which nothing mixes over the
+    sequence any more) the layer's K and V are made at every position
+    and everything else of it at the last one alone: what comes back
+    is x [B, 1, D]. Returns (x, state, load):
     ``load`` the rows each held expert got, int32 [experts_held], None
     for a dense layer. Each stretch of it is traced under the scope of
     its part (``PARTS``); ``attend`` brings its own, the attention's
@@ -588,23 +863,40 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
 
     if "w_in" in lp:
         with jax.named_scope("mamba_mixer"):
-            h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
+            h = _norm(x, lp, "attn_norm", cfg.norm_eps)
             o, state = mamba_mixer(lp, h, attend, cfg)
             x = x + o.astype(x.dtype)
+    elif "w_mem" in lp:
+        with jax.named_scope("gmu"):
+            h = _norm(x, lp, "attn_norm", cfg.norm_eps)
+            gate = jax.nn.silu((h @ lp["w_mem"]).astype(jnp.float32))
+            o = (gate * attend.astype(jnp.float32)).astype(x.dtype) \
+                @ lp["w_out"]
+            x, state = x + o.astype(x.dtype), None
     else:
         with jax.named_scope("qkv"):
-            h = rmsnorm(x, lp["attn_norm"], eps=cfg.norm_eps)
+            h = _norm(x, lp, "attn_norm", cfg.norm_eps)
             if pcfg.tp:
                 h = tp_copy(h, pcfg.tp)
-            q = _heads(h, lp["wq"], cfg.head_dim)       # H_local of them
-            k = _heads(h, lp["wk"], cfg.head_dim)
-            v = _heads(h, lp["wv"], cfg.v_dim)
-            if cfg.value_scale != 1.0:
-                v = v * cfg.value_scale
+            if last:
+                x, T = last_position(x), 1
+            q = _heads(last_position(h) if last else h, lp["wq"],
+                       cfg.head_dim, lp.get("bq"))      # H_local of them
+            k = v = None
+            if "wk" in lp:
+                k = _heads(h, lp["wk"], cfg.head_dim, lp.get("bk"))
+                v = _heads(h, lp["wv"], cfg.v_dim, lp.get("bv"))
+                if cfg.value_scale != 1.0:
+                    v = v * cfg.value_scale
             if cfg.qk_norm:
                 q = rmsnorm(q, lp["q_norm"], eps=cfg.norm_eps)
-                k = rmsnorm(k, lp["k_norm"], eps=cfg.norm_eps)
-            q, k = rope(q), rope(k)
+                if k is not None:
+                    k = rmsnorm(k, lp["k_norm"], eps=cfg.norm_eps)
+            q = rope(q)
+            if k is not None:
+                k = rope(k)
+            if "lambda_q1" in lp:
+                q, k, v = _paired(q, k, v)
             gate = ()
             if "w_g" in lp:
                 gate = (jax.nn.log_sigmoid(jnp.matmul(
@@ -612,7 +904,11 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
                     + lp["b_g"]),)
         o, state = attend(q, k, v, *gate)   # the caller's scope, by kind
         with jax.named_scope("attn_out"):
+            if "lambda_q1" in lp:
+                o = _differ(o, lp, cfg.norm_eps)
             o = o.reshape(B, T, -1) @ lp["wo"]         # row-parallel
+            if "bo" in lp:
+                o = o + lp["bo"]
             if pcfg.tp:
                 o = tp_allreduce(o, pcfg.tp)
             x = x + o.astype(x.dtype)
@@ -620,7 +916,7 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
     load = None
     if "router" in lp:
         with jax.named_scope("router"):
-            h = rmsnorm(x, lp["mlp_norm"], eps=cfg.norm_eps)
+            h = _norm(x, lp, "mlp_norm", cfg.norm_eps)
             if pcfg.tp:
                 h = tp_copy(h, pcfg.tp)
             chosen, weights = route(h.reshape(B * T, D), lp["router"],
@@ -639,7 +935,7 @@ def block(lp, x, rope, attend, cfg: TransformerConfig,
             x = x + d.astype(x.dtype)
     else:
         with jax.named_scope("mlp"):
-            h = rmsnorm(x, lp["mlp_norm"], eps=cfg.norm_eps)
+            h = _norm(x, lp, "mlp_norm", cfg.norm_eps)
             if pcfg.tp:
                 h = tp_copy(h, pcfg.tp)
             g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
@@ -655,18 +951,22 @@ def mamba_mixer(lp, h, recur: ssm.Recurrence, cfg: TransformerConfig):
     """A Mamba layer's mixer over h [B, T, D], the normed input: the
     projection to the channels u and their gate z; the causal
     convolution and its silu (``recur.conv``); dt, B and C projected
-    from the result, each through its own RMSNorm, dt widened to the
+    from the result, each through its own RMSNorm where the layer has
+    them (``dt_norm``), dt widened to the
     channels and through a softplus; the selective scan
     (``recur.scan``); the gate; the projection back. The matrix
     products run at h's dtype; dt, A and the state are float32. Returns
     (the mixer's output [B, T, D], (the convolution's tail [B, K - 1,
-    C], the scan's state [B, N, C]))."""
+    C], the scan's state [B, N, C])), and behind the two, in a model
+    with gated memory units, the scan's output y [B, T, C] before the
+    gate: the memory."""
     C, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
     uz = h @ lp["w_in"]
     z = uz[..., C:]
     u, tail = recur.conv(uz[..., :C], lp["conv_w"], lp["conv_b"])
     low = u @ lp["w_x"]
-    dt, b, c = (rmsnorm(low[..., a:z_], lp[name], eps=cfg.norm_eps)
+    dt, b, c = (low[..., a:z_] if name not in lp
+                else rmsnorm(low[..., a:z_], lp[name], eps=cfg.norm_eps)
                 for name, a, z_ in (("dt_norm", 0, R), ("b_norm", R, R + N),
                                     ("c_norm", R + N, R + 2 * N)))
     dt = jax.nn.softplus(
@@ -674,7 +974,8 @@ def mamba_mixer(lp, h, recur: ssm.Recurrence, cfg: TransformerConfig):
         + lp["dt_bias"])
     y, state = recur.scan(u, dt, -jnp.exp(lp["a_log"]), b, c, lp["d_skip"])
     gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    return gated.astype(h.dtype) @ lp["w_out"], (tail, state)
+    memory = (y,) if cfg.has_gmu else ()
+    return gated.astype(h.dtype) @ lp["w_out"], (tail, state) + memory
 
 
 EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
@@ -682,7 +983,9 @@ EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
 
 def scan_run(body, carry, layers, index: bool = True):
     """``lax.scan`` of ``body(carry, lp, i) -> carry`` over one run's
-    stacked layers: ``lp`` layer i's weights. (``index`` False scans the
+    stacked layers: ``lp`` layer i's weights, or, where the run is of
+    periods of several layers (``layers`` a tuple of stacks), the
+    tuple of period i's layers' weights. (``index`` False scans the
     layers alone and gives ``i`` None.) An expert run's matrices are
     not sliced a layer at a time: the slice would be a copy of every
     held expert (0.8 GB a layer at 16 experts of 4096 x 2048), made so
@@ -690,23 +993,52 @@ def scan_run(body, carry, layers, index: bool = True):
     whole, the layers' experts flattened to one leading dimension, and
     ``expert_base``, where layer i's begin (``block`` hands both to
     ``expert_ffn``)."""
-    n = layers["attn_norm"].shape[0]
-    if "router" not in layers:
+    stacks = layers if isinstance(layers, tuple) else (layers,)
+    n = stacks[0]["attn_norm"].shape[0]
+    if not any("router" in stack for stack in stacks):
         if not index:
             return lax.scan(lambda c, lp: (body(c, lp, None), None), carry,
                             layers)[0]
         return lax.scan(lambda c, xs: (body(c, *xs), None), carry,
                         (layers, jnp.arange(n)))[0]
-    sliced = {k: v for k, v in layers.items() if k not in EXPERT_MATRICES}
-    held = layers["w_gate"].shape[1]
-    whole = {k: layers[k].reshape((n * held,) + layers[k].shape[2:])
-             for k in EXPERT_MATRICES}
+
+    def split(stack):
+        """(what the scan slices, what the body adds to a slice)."""
+        if "router" not in stack:
+            return stack, lambda i: {}
+        held = stack["w_gate"].shape[1]
+        whole = {k: stack[k].reshape((n * held,) + stack[k].shape[2:])
+                 for k in EXPERT_MATRICES}
+        return ({k: v for k, v in stack.items() if k not in EXPERT_MATRICES},
+                lambda i: dict(whole, expert_base=i * held))
+
+    sliced, rest = zip(*(split(stack) for stack in stacks))
+
+    period = isinstance(layers, tuple)
 
     def step(c, xs):
-        lp, i = xs
-        return body(c, dict(lp, **whole, expert_base=i * held), i), None
+        lps, i = xs
+        lps = tuple(dict(lp, **more(i))
+                    for lp, more in zip(lps if period else (lps,), rest))
+        return body(c, lps if period else lps[0], i), None
 
-    return lax.scan(step, carry, (sliced, jnp.arange(n)))[0]
+    return lax.scan(step, carry, (sliced if period else sliced[0],
+                                  jnp.arange(n)))[0]
+
+
+def one_period(layers):
+    """The weights of a run of one period as ``scan_run``'s body gets
+    them at i = 0, with no scan: such a run has nothing to scan, and a
+    body that is no loop's may change the shape of what it carries (a
+    prefill that goes on at the last position alone)."""
+    def first(stack):
+        lp = {k: v[0] for k, v in stack.items()}
+        if "router" in stack:   # experts whole, as the scan's body has them
+            lp.update({k: stack[k].reshape((-1,) + stack[k].shape[2:])
+                       for k in EXPERT_MATRICES}, expert_base=0)
+        return lp
+    return tuple(first(stack) for stack in layers) \
+        if isinstance(layers, tuple) else first(layers)
 
 
 def unembed(params, x, *, last=False, eps: float = 1e-6):
@@ -717,7 +1049,7 @@ def unembed(params, x, *, last=False, eps: float = 1e-6):
     one rounding for every caller, so that a greedy decode agrees with
     the full forward's argmax."""
     with jax.named_scope("head"):
-        x = rmsnorm(x, params["final_norm"], eps=eps)
+        x = _norm(x, params, "final_norm", eps)
         if last:
             x = x[:, -1]
         head = params["head"] if "head" in params else params["embed"].T
@@ -736,8 +1068,9 @@ def roped_kinds(cfg: TransformerConfig, runs) -> list:
     attention kinds, unless the model has no rotation at all."""
     if not cfg.rope:
         return []
-    return list(dict.fromkeys(mixer for (mixer, _), *_ in runs
-                              if mixer != MAMBA))
+    return list(dict.fromkeys(
+        mixer for kind, *_ in runs for mixer, _ in period_of(kind)
+        if mixer not in (MAMBA, GMU)))
 
 
 def retain_from_the_start(q, k, v, g):
@@ -764,31 +1097,76 @@ def _scan_from_the_start(*args):
 FROM_THE_START = ssm.Recurrence(ssm.causal_conv, _scan_from_the_start)
 
 
-def _stack_fn(cfg, pcfg, rope, kind: LayerKind = (FULL, DENSE)):
-    """Scan one run of (locally held) alike layers over one
-    activation."""
-    window = cfg.window if kind[0] == WINDOW else None
+def _stack_fn(cfg, pcfg, ropes, kind=(FULL, DENSE), first: int = 0):
+    """Scan one run of (locally held) alike layers, or alike periods
+    of layers, over one activation. ``ropes``: each roped mixer's
+    ``rope``; ``first``: the depth of the run's first layer. Where the
+    model lends (``cfg.lends``) the activation travels with what is
+    lent: (x, the memory, the last full-attention layer's K, its V)."""
+    period = period_of(kind)
 
-    def layer(lp, x):
+    def layer(mixer, lp, carry):
+        x, memory, *lent = carry if cfg.lends else (carry, None)
+        window = cfg.window if mixer == WINDOW else None
+
         def attend(q, k, v):
-            with jax.named_scope(f"{kind[0]}_attention"):
-                return _attend(q, k, v, pcfg, window, lp.get("sink")), None
+            with jax.named_scope(f"{mixer}_attention"):
+                if mixer == CROSS:
+                    k, v = lent
+                return (_attend(q, k, v, pcfg, window, lp.get("sink"),
+                                cfg.head_dim ** -0.5),
+                        (k, v) if cfg.lends else None)
 
-        how = {MAMBA: FROM_THE_START, RETENTION: retain_from_the_start}
-        return block(lp, x, rope, how.get(kind[0], attend), cfg, pcfg)[0]
+        how = {MAMBA: FROM_THE_START, RETENTION: retain_from_the_start,
+               GMU: memory}
+        x, state, _ = block(lp, x, ropes.get(mixer, no_rotation),
+                            how.get(mixer, attend), cfg, pcfg)
+        if not cfg.lends:
+            return x
+        if mixer == MAMBA and cfg.has_gmu:
+            memory = state[2]
+        if mixer == FULL and cfg.has_cross:
+            lent = state
+        return (x, memory, *lent)
 
+    layer = [functools.partial(layer, mixer) for mixer, _ in period]
     if pcfg.remat:
-        layer = jax.checkpoint(layer)
+        layer = [jax.checkpoint(one) for one in layer]
 
-    def run(layers, x):
-        return scan_run(lambda h, lp, _: layer(lp, h), x, layers,
-                        index=False)
+    def body(carry, lps, i):
+        if len(period) == 1:
+            lps = (lps,)
+        for j, (one, lp) in enumerate(zip(layer, lps)):
+            if cfg.differential:
+                lp = dict(lp, depth=first + i * len(period) + j)
+            carry = one(lp, carry)
+        return carry
+
+    def run(layers, carry):
+        return scan_run(body, carry, layers, index=cfg.differential)
     return run
+
+
+def nothing_lent(cfg: TransformerConfig, x) -> tuple:
+    """What travels beside x [B, T, D] in a model that lends, before
+    any layer has made it: (the memory, the lent K, the lent V), zeros
+    of their shapes where the model has a layer that reads them, None
+    where it has none."""
+    B, T, _ = x.shape
+    width = 2 if cfg.differential else 1
+    memory = jnp.zeros((B, T, cfg.ssm_inner), x.dtype) if cfg.has_gmu \
+        else None
+    if not cfg.has_cross:
+        return memory, None, None
+    G = cfg.kv_heads(FULL) // width
+    return (memory, jnp.zeros((B, T, G, width * cfg.head_dim), x.dtype),
+            jnp.zeros((B, T, G, width * cfg.v_dim), x.dtype))
 
 
 def forward(params, tokens, cfg: TransformerConfig,
             pcfg: ParallelConfig = ParallelConfig()):
     """tokens: [B_local, T_local] int32 → logits [B_l, T_l, V] (fp32).
+    Every layer at every position.
 
     Call directly for the oracle, or inside shard_map for SPMD.
     """
@@ -804,20 +1182,24 @@ def forward(params, tokens, cfg: TransformerConfig,
         positions = lax.axis_index(pcfg.sp) * T + jnp.arange(T)
     else:
         positions = jnp.arange(T)
+    ropes = {a: functools.partial(apply_rotary, cos=cos, sin=sin,
+                                  positions=positions)
+             for a, (cos, sin) in tables.items()}
 
     x = params["embed"][tokens]                    # [B,T,D]
-    for kind, layers in stacks:
-        rope = no_rotation
-        if kind[0] in tables:
-            cos, sin = tables[kind[0]]
-            rope = functools.partial(apply_rotary, cos=cos, sin=sin,
-                                     positions=positions)
-        stack = _stack_fn(cfg, pcfg, rope, kind)
+    if cfg.lends:
+        x = (x, *nothing_lent(cfg, x))
+    first = 0
+    for (kind, layers), (_, n) in zip(stacks, layer_runs(cfg)):
+        stack = _stack_fn(cfg, pcfg, ropes, kind, first)
         if pcfg.pp:
             x = pipeline_spmd(stack, layers, x, axis=pcfg.pp,
                               num_microbatches=pcfg.num_microbatches)
         else:
             x = stack(layers, x)
+        first += len(period_of(kind)) * n
+    if cfg.lends:
+        x = x[0]
     return unembed(params, x, eps=cfg.norm_eps)
 
 

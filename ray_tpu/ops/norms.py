@@ -13,3 +13,14 @@ def rmsnorm(x, weight, *, eps: float = 1e-6):
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     y = x32 * jnp.reciprocal(jnp.sqrt(var + eps))
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def layernorm(x, weight, bias, *, eps: float = 1e-5):
+    """LayerNorm with scale and bias over the last dimension, in fp32,
+    cast back to the input dtype."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    y = centred * jnp.reciprocal(jnp.sqrt(var + eps))
+    return (y * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)

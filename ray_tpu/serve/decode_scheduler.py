@@ -126,6 +126,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import functools
 import logging
 import time
 from collections import deque
@@ -515,8 +516,12 @@ class JaxSlotEngine:
     the row that steers the step (host only), ``put`` sends that one
     int32 row, ``wait`` fetches the row of picks of the step before,
     ``read`` builds the dict from that host array; ``serve.engine.ahead``,
-    ``.rows_wasted``, ``.kv_rows_read``, ``.kv_rows_held`` and
-    ``.state_rows`` count beside them (module docstring). No
+    ``.rows_wasted``, ``.kv_rows_read``, ``.kv_rows_held`` (each times
+    the layers that read a held layer's rows, where cross layers share
+    one cache), ``.state_rows`` and, of a model whose prefill goes on
+    at the last position alone behind its last mixing layer,
+    ``.prefill_cross_rows`` (``[prefills, positions the layers behind
+    it ran over]``) count beside them (module docstring). No
     ``block_until_ready``: the fetch
     waits for the device.
 
@@ -549,6 +554,13 @@ class JaxSlotEngine:
         # positions a full-attention layer fetches at a time for a slot
         # (models/decode.py; None: the model has no such layer)
         self._kv_block = decode_mod.kv_rows_fetched(cfg, self._cache)
+        # layers that read such rows a step, for each layer that holds
+        # them: 1, and more where cross layers share a layer's cache
+        self._kv_readers = decode_mod.kv_readers(cfg)
+        # positions of a prompt that a prefill's second stage runs over
+        # (None: the model's prefill has one stage)
+        self._cross_rows = functools.partial(decode_mod.prefill_cross_rows,
+                                             cfg)
         # whether a stepped row's summary state is read and written whole
         self._summary = decode_mod.keeps_summaries(cfg)
 
@@ -634,6 +646,9 @@ class JaxSlotEngine:
         # this thread, and ``serve.prefill`` around this call
         phase_add("serve.engine.prefill", time.perf_counter() - began)
         phase_add("serve.engine.prefill_tokens", tokens.shape[1])
+        behind = self._cross_rows(tokens.shape[1])
+        if behind is not None:  # a prefill in two stages: its second's rows
+            phase_add("serve.engine.prefill_cross_rows", behind)
         return self._last[slot]
 
     def _steer(self, tokens: Dict[int, int], owed) -> List[int]:
@@ -674,10 +689,11 @@ class JaxSlotEngine:
         rode = {slot for slot, t in enumerate(steer)
                 if t != self._decode.IDLE}
         if self._kv_block:  # whole blocks, up to the one written to
-            n = self._kv_block
-            phase_add("serve.engine.kv_rows_read",
-                      sum((self._pos[slot] // n + 1) * n for slot in rode))
-            phase_add("serve.engine.kv_rows_held", len(rode) * self.max_len)
+            n, readers = self._kv_block, self._kv_readers
+            phase_add("serve.engine.kv_rows_read", readers * sum(
+                (self._pos[slot] // n + 1) * n for slot in rode))
+            phase_add("serve.engine.kv_rows_held",
+                      readers * len(rode) * self.max_len)
         if self._summary:
             phase_add("serve.engine.state_rows", len(rode))
         for slot in rode:

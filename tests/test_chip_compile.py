@@ -43,6 +43,17 @@ tens of MB, a decode step names one Mosaic call ``retention_step`` and
 a prefill one ``retention_chunk``, and both kernels alone are accepted
 by Mosaic at the cell's widths.
 
+The fifth serving cell's programs (``phi-4-mini-flash``: runs of
+periods of two layers, one full-attention layer whose cache seven cross
+layers read, gated memory units, a prefill in two stages) are held to
+the same at that cell's shapes: every leaf of the cache aliased, the
+one growing cache produced by nothing but its own layer's in-place
+write (the cross run takes the full run's arrays as they lie: no copy,
+no slice of a layer), a decode step two ``decode_attend`` calls (the
+full layer's, and one in the cross run's loop) and two ``ssm_step``, a
+prefill one ``flash_fwd`` and two ``ssm_scan`` (the full layer's one
+query, the prompt's last, needs no kernel).
+
 Every serving cell's decode step is also held to reading each layer's
 ``wq``, ``wk`` and ``wv`` where they lie in the stack: no slice of a
 layer's matrix into fast memory as an operation of its own and no
@@ -1145,17 +1156,190 @@ def test_the_fourth_cells_serving_programs_lower_to_the_text_on_record(
         LOWERED_RETENTION
 
 
+
+# ------- one cache that eight layers read, runs of periods of two layers
+
+SHARED_CELL = "phi-4-mini-flash.session-closed"
+LOWERED_SHARED = {
+    "decode": "79c54a82e38ff733",
+    "prefill-1024": "5a92f917fea65eda",
+    "prefill-2048": "0de56d1419d9fc90",
+    "prefill-4096": "8d660b5aeb509d8d",
+}
+
+
+@pytest.fixture(scope="module")
+def shared_cell():
+    """The cell of the model whose cross layers read one layer's cache,
+    as the benchmark's worker builds it."""
+    from benchmarks import loader
+
+    bench = loader.load_benchmark()
+    cell = loader.find_cell(bench, SHARED_CELL)
+    config = loader.load_config(bench, cell["config"])
+    mix = loader.load_traffic(bench, cell["traffic"])
+    program = loader.family_module(loader.find_family(bench, config),
+                                   "program")
+    return (program.program_config(config, mix["slot_len"]),
+            int(mix["slots"]), int(mix["slot_len"]),
+            sorted(mix["prompt_lengths"]))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-1024",
+                                     "prefill-2048", "prefill-4096"])
+def test_a_cache_that_eight_layers_read_is_still_written_in_place(
+        program, shared_cell, one_chip, no_compile_cache, as_on_the_tpu):
+    """``slot_decode_step`` and ``slot_prefill`` of the model with one
+    growing cache, at its cell's shapes, for the described v5e. Every
+    leaf of the cache is aliased to the result (a slot is 55.7 MB: the
+    full layer's 6,144 rows, eight rings of 512, nine states and
+    tails); no operation produces an array of a run's or a layer's K,
+    V, state or tail but the in-place writes and, in a decode step, the
+    ``ssm_step`` kernel: **the seven cross layers read the full
+    layer's K and V where its run left them**, no copy and no slice of
+    it; the temporaries stay far under that layer's K (a decode step's
+    in the MB, a prefill's under 0.4 GB of activations); arguments and
+    temporaries fit the chip; a decode step holds two ``decode_attend``
+    calls (the full layer's own and the one in the cross run's loop)
+    and one ``ssm_step`` a run with Mamba layers, a prefill one
+    ``flash_fwd`` (the window run's; the full layer attends from the
+    prompt's last position alone) and one ``ssm_scan`` a Mamba run."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode, init_params
+    from ray_tpu.models.transformer import layer_runs
+
+    cfg, slots, slot_len, _ = shared_cell
+    assert [(tuple(m for m, _ in kind), n) for kind, n in layer_runs(cfg)
+            ] == [(("mamba", "window"), 8), (("mamba", "full"), 1),
+                  (("gmu", "cross"), 7)]
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    cache = described(jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len)))
+    shape_of = lambda name: [[None if a is None else a.shape  # noqa: E731
+                              for a in run] for run in cache[name]]
+    assert shape_of("ssm") == [[(8, slots, 16, 5120), None],
+                               [(1, slots, 16, 5120), None], [None, None]]
+    assert shape_of("conv") == [[(8, 3, slots, 5120), None],
+                                [(1, 3, slots, 5120), None], [None, None]]
+    assert shape_of("k") == shape_of("v") == [
+        [None, (8, slots, 512, 1280)], [None, (1, slots, slot_len, 1280)],
+        [None, None]]
+    if program == "decode":
+        compiled = compiled_decode(SHARED_CELL, cfg, slots, slot_len,
+                                   one_chip)
+    else:
+        length = int(program.split("-")[1])
+        compiled = decode.slot_prefill.lower(
+            params, array((1, length), jnp.int32), cache,
+            array((), jnp.int32), cfg).compile()
+    text = compiled.as_text()
+
+    # every leaf of the cache, and nothing else, aliases the result:
+    # state and tail of the two runs with Mamba layers, K and V of the
+    # window run and of the full layer, pos and tok
+    leaves = re.findall(
+        r"parameter\((\d+)\)[^\n]*op_name=\"cache\[([^\"]*)\]\"", text)
+    assert len(leaves) == 2 * 2 + 2 * 2 + 2
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_comp", text)
+    assert aliased, "nothing is aliased: the cache is not donated"
+    assert sorted(int(n) for n in re.findall(
+        r"\((\d+), \{\}, may-alias\)", aliased.group(1))
+    ) == sorted(int(n) for n, _ in leaves)
+
+    held = [leaf for name in ("ssm", "conv", "k", "v")
+            for leaf in jax.tree.leaves(cache[name])]
+    state_bytes = sum(leaf.dtype.itemsize * math.prod(leaf.shape)
+                      for leaf in held)
+    assert round(state_bytes / slots / 1e6, 1) == 55.7
+    memory = compiled.memory_analysis()
+    assert 0 <= memory.alias_size_in_bytes - state_bytes <= 8192
+    # the full layer's K of all slots is 1.0 GB: no copy of it has room
+    assert memory.temp_size_in_bytes < (
+        16 * 2 ** 20 if program == "decode" else 400 * 2 ** 20)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < HBM_BYTES)
+
+    shapes = {leaf.shape for leaf in held} | {leaf.shape[1:]
+                                              for leaf in held}
+    # (the compiler's own prefetch into fast memory of the 2 MB that are
+    # the single Mamba layer's tails, ``copy-start`` / ``copy-done``, is
+    # no copy in memory)
+    produced = [(name, op) for name, op in cache_producers(text, shapes)
+                if op not in ("bitcast", "copy-start", "copy-done")]
+    allowed = set(IN_PLACE) | (
+        {"tpu_custom_call"} if program == "decode" else set())
+    assert {op for _, op in produced} <= allowed, produced
+    # a write a run and kind of state that the run keeps: K and V of
+    # the window run and of the full layer, the tails of the two Mamba
+    # runs (their states are the step kernel's own results in a decode
+    # step, one tuple with y, which no shape above names; a prefill
+    # writes them; of the single Mamba layer's tails, 2 MB, a decode
+    # step replaces the whole array, which is no write into one). The
+    # cross run writes nothing
+    assert len(produced) == (2 * 2 + 1 if program == "decode"
+                             else 2 * 2 + 2 * 2), produced
+    assert mosaic_calls(text) == (
+        [DECODE_KERNEL] * 2 + [STEP_KERNEL] * 2 if program == "decode"
+        else ["flash_fwd"] + [SCAN_KERNEL] * 2)
+
+
+def test_the_fifth_cells_decode_step_hands_the_host_a_row_of_picks(
+        shared_cell, one_chip, no_compile_cache, as_on_the_tpu):
+    cfg, slots, slot_len, _ = shared_cell
+    compiled = compiled_decode(SHARED_CELL, cfg, slots, slot_len, one_chip)
+    text = compiled.as_text()
+    root = re.search(r"ROOT %[\w.\-]+ = \(([^\n]*?)\) tuple\(",
+                     text[text.index("\nENTRY "):]).group(1)
+    results = re.findall(r"(\w+)\[([\d,]*)\]", root)
+    # the row of picks; beside it the cache's pos and tok
+    assert results.count(("s32", str(slots))) == 3
+    assert not [r for r in results
+                if r[1] == f"{slots},{cfg.vocab}"], results
+    # every part the layers' kinds imply names an instruction, the two
+    # new ones among them, and a cross layer's kernel call is its part's
+    from ray_tpu.models import decode
+
+    table = decode.program_parts(text, decode.decode_parts(cfg))
+    assert table is not None
+    parts = {(run, part) for run, part in table.values()}
+    assert {("run2", "gmu"), ("run2", "cross_attention"),
+            ("run1", "full_attention"), ("run0", "window_attention"),
+            ("run0", "ssm_step"), ("run1", "ssm_step")} <= parts
+    attends = sorted(tuple(where) for name, where in table.items()
+                     if name.startswith(DECODE_KERNEL))
+    assert attends == [("run1", "full_attention"),
+                       ("run2", "cross_attention")]
+
+
+def test_the_fifth_cells_serving_programs_lower_to_the_text_on_record(
+        shared_cell, one_chip, as_on_the_tpu):
+    assert serving_programs_lowered(shared_cell, one_chip) == LOWERED_SHARED
+
+
 # --------------- q, k and v read out of the stacked weights where they lie
 
 CELL_FIXTURES = {SERVING_CELL: "serving_cell", KINDS_CELL: "kinds_cell",
                  HYBRID_CELL: "hybrid_cell",
-                 RETENTION_CELL: "retention_cell"}
+                 RETENTION_CELL: "retention_cell",
+                 SHARED_CELL: "shared_cell"}
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_FIXTURES))
 def test_a_decode_steps_q_k_v_products_read_the_stack_where_it_lies(
         cell, request, one_chip, no_compile_cache, as_on_the_tpu):
-    """The four serving cells' decode steps as compiled for the
+    """The five serving cells' decode steps as compiled for the
     described v5e (``compiled_decode``: no compile where a test above
     has run). Outside fused computations no ``copy`` and no fusion
     rooted in a ``dynamic-slice`` yields an array of a layer's ``wq``,
@@ -1180,10 +1364,13 @@ def test_a_decode_steps_q_k_v_products_read_the_stack_where_it_lies(
     cfg, slots, slot_len, _ = request.getfixturevalue(CELL_FIXTURES[cell])
     text = compiled_decode(cell, cfg, slots, slot_len, one_chip).as_text()
     # (a retention layer's gate is a fourth product of the part)
+    # (... and a cross layer's only product is q's; a run of periods of
+    # several layers holds a stack for each layer of the period)
     stacks = [{name: stack[name].shape
                for name in ("wq", "wk", "wv", "w_g") if name in stack}
-              for _, stack in layer_stacks(jax.eval_shape(
+              for _, run in layer_stacks(jax.eval_shape(
                   lambda: init_params(jax.random.key(0), cfg)), cfg)
+              for stack in (run if isinstance(run, tuple) else (run,))
               if "wq" in stack]
     sizes = {math.prod(shape[1:]) for run in stacks for shape in run.values()}
     found = list(outside_fusions(text))
