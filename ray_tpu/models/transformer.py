@@ -73,7 +73,9 @@ memory, and hand a cross layer the full layer's cache itself.
 Design notes for TPU: params live in bf16 MXU-aligned blocks, layers
 are stacked on a leading dim and scanned (one compiled layer body),
 fp32 accumulation everywhere that matters, optional per-layer
-``jax.checkpoint`` to trade FLOPs for HBM.
+``jax.checkpoint`` (``ParallelConfig.remat``) that keeps a layer's
+matrix products and what its flash backward kernels read for the
+backward and computes the elementwise rest again (``KEPT``).
 """
 
 from __future__ import annotations
@@ -87,7 +89,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops import ssm
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import SAVED as FLASH_SAVED, flash_attention
 from ray_tpu.ops.retention import retention
 from ray_tpu.ops.norms import layernorm, rmsnorm
 from ray_tpu.ops.rotary import apply_rotary, rope_frequencies
@@ -416,7 +418,8 @@ def layer_stacks(params, cfg: TransformerConfig):
 
 # The dense decoder the repo serves and trains at full width on one
 # v5e chip: 168M parameters, head_dim 64, bf16. B16 x T1024 with
-# remat fits the chip's HBM; without remat it does not.
+# remat keeps 28,672 bytes a token and layer (``KEPT``: 3.8 GB over
+# its 8 layers) beside 1 GB of state; without remat it does not fit.
 DENSE_168M = TransformerConfig(
     vocab=32768, d_model=1024, n_heads=16, n_layers=8, d_ff=4096,
     max_seq=1024, dtype=jnp.bfloat16)
@@ -430,7 +433,16 @@ class ParallelConfig:
     sp: Optional[str] = None
     tp: Optional[str] = None
     attn: str = "auto"          # auto | local | ring | ulysses
-    remat: bool = False         # jax.checkpoint around each block
+    # jax.checkpoint around each block under the one rule ``KEPT``: a
+    # layer's matrix products and what its flash backward kernels read
+    # are held from the forward to the backward, the elementwise rest
+    # is computed again. In bytes a token and layer, for a dense layer
+    # of heads all alike: itemsize x (6 d_model + 2 d_ff) + 4 n_heads
+    # (the layer's input, q, k, v, out, wo's result; gate and up; lse),
+    # where keeping nothing held itemsize x d_model: 47,168 against
+    # 4,096 at Ouro's widths. A step that fitted only because
+    # everything was computed again needs a smaller batch.
+    remat: bool = False
     num_microbatches: Optional[int] = None
 
     def data_axes(self):
@@ -1097,6 +1109,20 @@ def _scan_from_the_start(*args):
 FROM_THE_START = ssm.Recurrence(ssm.causal_conv, _scan_from_the_start)
 
 
+# What ``remat=True`` keeps of a layer from the forward to the backward:
+# the results of its matrix products that the backward reads (``wo``'s,
+# gate, up; a Mamba or retention layer's likewise) and what the flash
+# backward kernels read (``ops.attention.SAVED``: q behind its rope, k,
+# v and the forward's out, heads first, and lse; these stand in for the
+# q, k and v products, which nothing then reads). The rest (norms, silu,
+# the gate's product with up, any other kernel's result; rope and the
+# q, k and v products themselves where the attention is not the flash
+# kernel's) the backward computes again from these.
+KEPT = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED))
+
+
 def _stack_fn(cfg, pcfg, ropes, kind=(FULL, DENSE), first: int = 0):
     """Scan one run of (locally held) alike layers, or alike periods
     of layers, over one activation. ``ropes``: each roped mixer's
@@ -1131,7 +1157,13 @@ def _stack_fn(cfg, pcfg, ropes, kind=(FULL, DENSE), first: int = 0):
 
     layer = [functools.partial(layer, mixer) for mixer, _ in period]
     if pcfg.remat:
-        layer = [jax.checkpoint(one) for one in layer]
+        # (a layer runs inside ``scan_run``'s loop and nowhere else, so
+        # XLA cannot merge what the backward computes again with the
+        # forward's; the barrier that would forbid it makes every kept
+        # value a copy out of the loop's stack before the backward reads
+        # it)
+        layer = [jax.checkpoint(one, policy=KEPT, prevent_cse=False)
+                 for one in layer]
 
     def body(carry, lps, i):
         if len(period) == 1:
@@ -1221,7 +1253,11 @@ def make_train_step(cfg: TransformerConfig, pcfg: ParallelConfig,
                     mesh=None, optimizer=None):
     """Build a jitted ``step(params, opt_state, batch) → (params,
     opt_state, loss)``. With a mesh, wraps the per-rank step in
-    shard_map over all four axes with real param/batch shardings."""
+    shard_map over all four axes with real param/batch shardings.
+    **The step consumes its state**: ``params`` and ``opt_state`` are
+    donated, with and without a mesh, so the device holds them once
+    (the results take their place) and the arrays passed in are deleted
+    when the call returns; go on with the ones it returns."""
     import optax
 
     optimizer = optimizer or optax.adamw(3e-4)
@@ -1262,7 +1298,7 @@ def make_train_step(cfg: TransformerConfig, pcfg: ParallelConfig,
         return params, opt_state, loss
 
     if mesh is None:
-        return jax.jit(local_step), optimizer
+        return jax.jit(local_step, donate_argnums=(0, 1)), optimizer
 
     pspecs = param_specs(pcfg, cfg)
     opt_specs = _opt_state_specs(optimizer, cfg, pspecs)
@@ -1273,7 +1309,7 @@ def make_train_step(cfg: TransformerConfig, pcfg: ParallelConfig,
         in_specs=(pspecs, opt_specs, batch_spec),
         out_specs=(pspecs, opt_specs, P()),
         check_vma=False)
-    return jax.jit(step), optimizer
+    return jax.jit(step, donate_argnums=(0, 1)), optimizer
 
 
 def init_train_state(key, cfg: TransformerConfig, pcfg: ParallelConfig,

@@ -12,6 +12,10 @@ accumulating dK/dV (q-blocks innermost), one accumulating dQ
 (k-blocks innermost) — so the backward never materialises [Tq, Tk]
 either. ``delta = rowsum(dO * O)`` is precomputed by XLA (one fused
 elementwise reduce). Shapes everywhere: [batch, seq, heads, head_dim].
+What the forward hands the backward are the backward kernels' operands
+as they read them (q, k, v and out heads first, lse a row a head), each
+under a name (``SAVED``) that a ``jax.checkpoint`` around the caller
+can keep by.
 
 What one grid step is given is read from the input: the products take
 their operands at the dtype they come in (probabilities and score
@@ -51,6 +55,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
 _LANES = 128  # f32 VMEM lane width; the m/l scratch rows are as wide
@@ -61,6 +66,11 @@ KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 # Largest (block_q, block_k) of each: flash_blocks.
 _BLOCK_CAPS = {"flash_fwd": (1024, 1024), "flash_bwd_dkv": (512, 512),
                "flash_bwd_dq": (1024, 1024)}
+# The backward kernels' residuals, by the names ``_flash_fwd`` puts on
+# them: what a ``jax.checkpoint`` around the caller keeps
+# (``save_only_these_names``) so that its backward runs the two
+# backward kernels and nothing of the forward again.
+SAVED = ("flash_q", "flash_k", "flash_v", "flash_out", "flash_lse")
 
 
 def attention(q, k, v, *, causal: bool = True,
@@ -278,17 +288,27 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, block_q,
             + jnp.log(l)
 
 
-def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                   interpret, window=None, sink=None):
+def _heads_first(t):
+    """[B,T,H,D] ↔ [B,H,T,D]: the kernels want the MXU dims (T, D)
+    trailing."""
+    return t.transpose(0, 2, 1, 3)
+
+
+def _flash_forward(q, k, v, *args, **kwargs):
+    out, lse = _flash_forward_heads_first(
+        _heads_first(q), _heads_first(k), _heads_first(v), *args, **kwargs)
+    return _heads_first(out), lse
+
+
+def _flash_forward_heads_first(qt, kt, vt, causal, sm_scale, block_q,
+                               block_k, interpret, window=None, sink=None):
+    """The forward kernel on [B,H,T,D] operands: (out [B,H,T,Dv], lse
+    [B,H,T,1] float32)."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    B, T, H, D = q.shape
-    G, Dv = k.shape[2], v.shape[3]
-    # [B,T,H,D] → [B,H,T,D] so the MXU dims (T, D) are trailing.
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    B, H, T, D = qt.shape
+    G, Dv = kt.shape[1], vt.shape[3]
     num_k = T // block_k
     if window is not None:
         # the most k-blocks any q-block's band touches
@@ -314,9 +334,9 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
                                      lambda b, h, i, j: (h, 0, 0)))
         operands.append(jnp.broadcast_to(
             sink.astype(jnp.float32)[:, None, None], (H, 1, _LANES)))
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        out_shape=(jax.ShapeDtypeStruct((B, H, T, Dv), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((B, H, T, Dv), qt.dtype),
                    jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32)),
         grid=grid,
         in_specs=in_specs,
@@ -332,7 +352,6 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
         interpret=interpret,
         name="flash_fwd",
     )(*operands)
-    return out.transpose(0, 2, 1, 3), lse
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
@@ -478,20 +497,20 @@ def _flash_dq(qt, kt, vt, dot, lse, delta, causal, sm_scale, block_q,
     )(qt, kt, vt, dot, lse, delta)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
+def _flash_backward(qt, kt, vt, out, lse, g, causal, sm_scale, blocks,
                     interpret):
-    B, T, H, D = q.shape
-    qt, kt, vt, dot = (x.transpose(0, 2, 1, 3) for x in (q, k, v, g))
+    """(dq, dk, dv) [B,T,H,D] from the residuals as ``_flash_fwd``
+    keeps them, heads first, and g [B,T,H,D]."""
+    dot = _heads_first(g)
     # delta_i = rowsum(dO_i * O_i): one fused XLA reduce, [B, H, T].
-    delta = jnp.einsum("bqhd,bqhd->bhq", g.astype(jnp.float32),
+    delta = jnp.einsum("bhqd,bhqd->bhq", dot.astype(jnp.float32),
                        out.astype(jnp.float32))
-    dk, dv = _flash_dkv(qt, kt, vt, dot, lse.reshape(B, H, 1, T),
+    dk, dv = _flash_dkv(qt, kt, vt, dot, lse[:, :, None, :],
                         delta[:, :, None, :], causal, sm_scale,
                         *blocks[1], interpret)
-    dq = _flash_dq(qt, kt, vt, dot, lse, delta[..., None], causal,
+    dq = _flash_dq(qt, kt, vt, dot, lse[..., None], delta[..., None], causal,
                    sm_scale, *blocks[2], interpret)
-    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
-            dv.transpose(0, 2, 1, 3))
+    return _heads_first(dq), _heads_first(dk), _heads_first(dv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -502,15 +521,25 @@ def _flash(q, k, v, causal, sm_scale, blocks, interpret):
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, blocks, interpret):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, *blocks[0],
-                              interpret)
-    return out, (q, k, v, out, lse)
+    """The residuals are the backward kernels' operands as they read
+    them, each under its name in ``SAVED``: q (behind its rope), k and
+    v heads first, the forward's out likewise, and lse as [B, H, T]
+    (it leaves the kernel a column [B, H, T, 1], which HBM pads to 128
+    lanes a value). A backward that is handed them runs no rope, no
+    transpose of q, k or v and no forward kernel again. (The names are
+    on the values the forward goes on with: a name on a copy beside
+    them keeps the copy and computes the value again.)"""
+    qt, kt, vt = (checkpoint_name(_heads_first(t), name)
+                  for t, name in zip((q, k, v), SAVED))
+    out, lse = _flash_forward_heads_first(qt, kt, vt, causal, sm_scale,
+                                          *blocks[0], interpret)
+    out, lse = (checkpoint_name(t, name)
+                for t, name in zip((out, lse[..., 0]), SAVED[3:]))
+    return _heads_first(out), (qt, kt, vt, out, lse)
 
 
 def _flash_bwd(causal, sm_scale, blocks, interpret, res, g):
-    q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                           blocks, interpret)
+    return _flash_backward(*res, g, causal, sm_scale, blocks, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -145,6 +145,8 @@ def test_flash_kernels_compile_for_v5e_under_their_own_names(
     import jax.numpy as jnp
     from jax import lax
 
+    from ray_tpu.models import transformer
+
     # (ray_tpu.ops exports a function under the module's name)
     attention = importlib.import_module("ray_tpu.ops.attention")
 
@@ -156,11 +158,13 @@ def test_flash_kernels_compile_for_v5e_under_their_own_names(
 
     def loss(q, k, v):
         # as models/transformer.py runs its layers under remat: a scan
-        # over checkpointed bodies, so that the kernels get the names
-        # the training program gives them (under a bare jax.grad the
-        # compiler wraps them: jvp_flash_fwd_)
+        # over bodies checkpointed under the module's own rule, so that
+        # the kernels get the names the training program gives them
+        # (under a bare jax.grad the compiler wraps them:
+        # jvp_flash_fwd_)
         layer = jax.checkpoint(
-            lambda h: attention.flash_attention(h, k, v, causal=True))
+            lambda h: attention.flash_attention(h, k, v, causal=True),
+            policy=transformer.KEPT)
         out, _ = lax.scan(lambda h, _: (layer(h), None), q, None, length=2)
         return jnp.sum(out.astype(jnp.float32))
 
@@ -172,8 +176,9 @@ def test_flash_kernels_compile_for_v5e_under_their_own_names(
         return
     both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         arg, arg, arg).compile().as_text()
-    # the forward, remat's second forward, and the backward's two
-    assert mosaic_calls(both) == sorted(KERNELS + ("flash_fwd",))
+    # the forward and the backward's two: the rule keeps what the
+    # backward kernels read, so the backward runs no second forward
+    assert mosaic_calls(both) == sorted(KERNELS)
 
 
 # the fixture of each serving cell, and the block of positions that
@@ -588,7 +593,7 @@ def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
 
 
 # digests of the lowered programs, made by this file's own normaliser.
-# ``train`` is the parent's of before layers had kinds (commit 0b4d871),
+# ``train`` was the parent's of before layers had kinds (commit 0b4d871),
 # and so were cell 1's serving programs until a step kept its picks on
 # the device (``cache["tok"]``, the pick inside both programs, a decode
 # step steered by one int32 row and returning the row of picks): those
@@ -599,14 +604,18 @@ def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
 # cells, the prefills too, since ``block`` keeps the flat q, k and v
 # products from their reshape to heads where the rows are fewer than the
 # weight's (``train``, whose rows are not, and the kernels' jaxprs as
-# they were). Left out: the Mosaic kernels' serialized bodies, which hold the
-# line numbers of ops/attention.py, and the results' labels, which name
-# the cache's place in the result's tree
+# they were); and ``train`` alone since ``remat=True`` keeps the layers'
+# products and what the flash backward kernels read and the step's
+# state is donated (every serving program as it was). Left out: the
+# Mosaic kernels' serialized bodies, which hold the line numbers of
+# ops/attention.py, and the results' labels, which name the cache's
+# place in the result's tree
+TRAIN_CELL = "ouro-2.6b-d12.train-2k"
 LOWERED = {
     "decode": "a3e7e77eb383f004",
     "prefill-128": "70d028c10c8b6cff",
     "prefill-256": "a25f52704aaeb6c6",
-    "train": "4ee3f3d6f707d1e7",
+    "train": "7bc8d8cd2dc6b5c3",
 }
 LOWERED_KINDS = {
     "decode": "b4b1440d73aae73d",
@@ -614,7 +623,12 @@ LOWERED_KINDS = {
     "prefill-1024": "b6764f67406acf72",
     "prefill-2048": "159daa3d1d966ee3",
 }
-KERNEL_JAXPRS_BEFORE_KINDS = "345359b76e414d9d"
+# the forward kernel's as they were before layers had kinds (commit
+# 0b4d871); the gradient's since its forward names what the backward
+# kernels read (``ops.attention.SAVED``) and hands it over as they read
+# it: q, k, v and out heads first, ``lse`` as [B, H, T]
+KERNEL_JAXPRS = {"forward": "8cc7f54ad19da989",
+                 "gradient": "412db7ef88980e58"}
 
 
 def without_kernel_bodies(lowered_text):
@@ -659,6 +673,39 @@ def serving_programs_lowered(cell, one_chip):
             for name, low in lowered.items()}
 
 
+def train_step_lowered(one_chip):
+    """(the train cell's step lowered for the described chip, as the
+    benchmark's worker builds it; the bytes of its params and
+    opt_state)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import loader
+    from ray_tpu.models import init_params
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    bench = loader.load_benchmark()
+    cell = loader.find_cell(bench, TRAIN_CELL)
+    config = loader.load_config(bench, cell["config"])
+    mix = loader.load_traffic(bench, cell["traffic"])
+    program = loader.family_module(loader.find_family(bench, config),
+                                   "program")
+    cfg = program.program_config(config, mix["seq"])
+    step, optimizer = program.make_train_step(cfg, mix)
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    state = (params, described(jax.eval_shape(optimizer.init, params)))
+    batch = jax.ShapeDtypeStruct((mix["batch"], mix["seq"]), jnp.int32,
+                                 sharding=one_chip)
+    return (step.lower(*state, {"tokens": batch, "targets": batch}),
+            sum(math.prod(a.shape) * a.dtype.itemsize
+                for a in jax.tree.leaves(state)))
+
+
 def test_the_second_cells_serving_programs_lower_to_the_text_on_record(
         kinds_cell, one_chip, monkeypatch):
     attention = importlib.import_module("ray_tpu.ops.attention")
@@ -670,58 +717,67 @@ def test_ouros_three_programs_lower_to_the_text_on_record(
         serving_cell, one_chip, monkeypatch):
     """``slot_decode_step`` as served, ``slot_prefill`` (128, 256) and
     the train step of the two Ouro cells lower to the StableHLO on
-    record, operation for operation (the train step to the parent's of
-    before layers had kinds: a model of one kind goes through the code
-    that runs several and comes out as it went in), and the three flash
-    kernels trace to that parent's jaxprs. A change that means to alter
-    these programs records its own digests here."""
+    record, operation for operation, and the three flash kernels trace
+    to the jaxprs on record. A change that means to alter these
+    programs records its own digests here."""
     import jax
     import jax.numpy as jnp
-
-    from benchmarks import loader
-    from ray_tpu.models import decode, init_params
 
     attention = importlib.import_module("ray_tpu.ops.attention")
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
 
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    def array(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     digests = serving_programs_lowered(serving_cell, one_chip)
-
-    bench = loader.load_benchmark()
-    cell = loader.find_cell(bench, "ouro-2.6b-d12.train-2k")
-    config = loader.load_config(bench, cell["config"])
-    mix = loader.load_traffic(bench, cell["traffic"])
-    program = loader.family_module(loader.find_family(bench, config),
-                                   "program")
-    train_cfg = program.program_config(config, mix["seq"])
-    step, optimizer = program.make_train_step(train_cfg, mix)
-    params = described(jax.eval_shape(
-        lambda: init_params(jax.random.key(0), train_cfg)))
-    batch = array((mix["batch"], mix["seq"]), jnp.int32)
-    digests["train"] = digest(without_kernel_bodies(step.lower(
-        params, described(jax.eval_shape(optimizer.init, params)),
-        {"tokens": batch, "targets": batch}).as_text()))
+    digests["train"] = digest(without_kernel_bodies(
+        train_step_lowered(one_chip)[0].as_text()))
     assert digests == LOWERED
 
-    jaxprs = []
+    jaxprs = {"forward": [], "gradient": []}
     for shape in ((4, 2048, 16, 128), (1, 128, 16, 128), (1, 256, 16, 128)):
         arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
 
         def forward(q, k, v):
             return attention.flash_attention(q, k, v, causal=True)
 
-        jaxprs.append(str(jax.make_jaxpr(forward)(arg, arg, arg)))
-        jaxprs.append(str(jax.make_jaxpr(jax.grad(
+        jaxprs["forward"].append(str(jax.make_jaxpr(forward)(arg, arg, arg)))
+        jaxprs["gradient"].append(str(jax.make_jaxpr(jax.grad(
             lambda q, k, v: jnp.sum(forward(q, k, v).astype(jnp.float32)),
             argnums=(0, 1, 2)))(arg, arg, arg)))
-    assert digest("\n".join(jaxprs)) == KERNEL_JAXPRS_BEFORE_KINDS
+    assert {name: digest("\n".join(texts))
+            for name, texts in jaxprs.items()} == KERNEL_JAXPRS
+
+
+# what a program gets of the chip's 16 GiB (``bytes_limit`` of a v5e's
+# ``memory_stats()``), and what the compiler's count of the train step
+# must leave of it for what it cannot see (the runtime's own buffers,
+# the batch made on the device beside the step, fragmentation): 1.5
+# GiB, a tenth of the chip
+PROGRAM_BYTES = 16_909_336_064
+TRAIN_STEP_SPARE = 3 * 2 ** 29
+
+
+def test_the_train_step_keeps_what_its_backward_reads_and_fits(
+        one_chip, no_compile_cache, monkeypatch):
+    """The train cell's step, compiled whole for the described chip:
+    one ``flash_fwd`` a layer's body and the backward's two (the rule
+    of ``remat=True`` keeps what the backward kernels read, so the
+    backward runs no second forward); ``params`` and ``opt_state``
+    aliased to the results, every byte of them (the step consumes its
+    state); and arguments + results - aliased + temporaries, which with
+    the layers' products kept is most of the chip, under the 15.75 GiB
+    a program gets by ``TRAIN_STEP_SPARE``."""
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+
+    lowered, state_bytes = train_step_lowered(one_chip)
+    compiled = lowered.compile()
+    assert mosaic_calls(compiled.as_text()) == sorted(KERNELS)
+    memory = compiled.memory_analysis()
+    assert 4.2e9 < state_bytes < 4.4e9
+    # (the device pads a leaf to whole tiles: some 0.1 MB over the tree)
+    assert 0 <= memory.alias_size_in_bytes - state_bytes < 2 ** 20
+    held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert held < PROGRAM_BYTES - TRAIN_STEP_SPARE, held
 
 
 # --------------- Mamba layers beside attention layers, two kinds of state

@@ -171,9 +171,11 @@ def test_spmd_train_step_matches_oracle(mcfg):
 
     pspecs = param_specs(pcfg)
     sh = lambda s: NamedSharding(mesh, s)  # noqa: E731
+    # each step consumes its state, and device_put hands a replicated
+    # leaf's own buffer to the device the two share: the mesh gets a copy
     params_d = jax.device_put(
-        params, jax.tree.map(sh, pspecs,
-                             is_leaf=lambda x: isinstance(x, P)))
+        jax.tree.map(jnp.copy, params),
+        jax.tree.map(sh, pspecs, is_leaf=lambda x: isinstance(x, P)))
     opt_d = jax.device_put(
         opt_state, jax.tree.map(
             sh, _opt_state_specs(opt, cfg, pspecs),
