@@ -21,9 +21,10 @@ ray_tpu session whose driver never imports jax:
   compiled by Mosaic (``interpret=False``), forward and both backward
   kernels, against ``ops.attention.attention`` at head_dim 64 and 128;
   and ``decode_attention``'s kernel against its XLA form at the decode
-  shapes of the two serving cells with full-attention layers of their
-  own kind (16 heads of 128 over 8 x 1024; 64 on 4 at 192 / 128 over
-  128 x 3200), slots at unlike positions, the cache poisoned past them.
+  shapes of the four cells whose decode step attends (16 heads of 128
+  over 8 x 1024; 64 on 4 at 192 / 128 over 128 x 3200; 20 on 1 at 128
+  over 256 x 2048; 40 on 10 at 128 over 64 x 6144), slots at unlike
+  positions, the cache poisoned past them, with the chunk each took.
 
 Every device fact printed comes from inside the worker that holds
 ``TPU``. It exits non-zero with the reason on any failure — at once
@@ -56,7 +57,9 @@ BF16_TOL = {"fwd": 2e-2, "bwd": 4e-2}
 KERNEL_SHAPES = ((2, 1024, 16, 64), (4, 4096, 8, 128))
 # decode steps: slots, query heads, q.k width, value width, K/V heads,
 # rows a slot (ouro-2.6b.decode-closed; mimo-v2-flash-ep16-d7.reason-closed)
-DECODE_SHAPES = ((8, 16, 128, 128, 16, 1024), (128, 64, 192, 128, 4, 3200))
+# B, H, D, Dv, G, rows of the four cells whose decode step attends
+DECODE_SHAPES = ((8, 16, 128, 128, 16, 1024), (128, 64, 192, 128, 4, 3200),
+                 (256, 20, 128, 128, 1, 2048), (64, 40, 128, 128, 10, 6144))
 MOSAIC_CALL = "tpu_custom_call"
 
 
@@ -212,7 +215,7 @@ def decode_kernel_checks(shapes, dtype: str, interpret: bool) -> list:
     a clean copy, layer 1 of a run of 2, slots at unlike positions
     (the first, the last and a spread between) and NaN past each: one
     row per shape of the normalized error, the Mosaic call count and
-    the block the shape was given."""
+    the chunk of positions the shape was given."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -245,14 +248,14 @@ def decode_kernel_checks(shapes, dtype: str, interpret: bool) -> list:
         compiled = jax.jit(kernel).lower(q, k, v, pos).compile()
         poisoned = [jnp.where(past, jnp.nan, t) for t in (k, v)]
         got = compiled(q, *poisoned, pos).astype(jnp.float32)
-        del poisoned                    # a gigabyte at the second shape
+        del poisoned                    # 4 GB at the last shape
         clean = [jnp.where(past, 0, t) for t in (k, v)]
         del k, v
         want = jax.jit(xla)(q, *clean, pos).astype(jnp.float32)
         rows_out.append({
             "shape": [B, H, D, Dv, G, rows], "dtype": dtype,
             "interpret": interpret,
-            "block": decode_rows_fetched(q, *clean, interpret=interpret),
+            "chunk": decode_rows_fetched(q, *clean, interpret=interpret),
             "mosaic_calls": compiled.as_text().count(MOSAIC_CALL),
             "err": float(jnp.max(jnp.abs(got - want))
                          / jnp.max(jnp.abs(want))),
@@ -464,7 +467,7 @@ def check_on_chip(leg: dict, tol: dict) -> None:
             row[g + "_err"] <= tol["bwd"] for g in ("dq", "dk", "dv")),
             f"flash attention disagrees with the reference: {row}")
     for row in leg["decode_kernels"]:
-        check(row["mosaic_calls"] == 1 and row["block"] < row["shape"][-1],
+        check(row["mosaic_calls"] == 1 and row["chunk"] < row["shape"][-1],
               f"the decode kernel did not compile with Mosaic: {row}")
         check(row["finite"] and row["err"] <= tol["fwd"],
               f"the decode kernel disagrees with its XLA form: {row}")

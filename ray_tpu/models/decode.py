@@ -87,17 +87,17 @@ scan's carry:
 layers attends through
 ``ops.attention.decode_attention``: the run's whole K and V, as the
 carry holds them after the write, with the layer's index and the rows'
-``pos``. On the TPU that is the kernel ``decode_attend``, which fetches
-a row's K and V block by block up to the block that holds ``pos`` and
+``pos``. On the TPU that is the kernel ``decode_attend``, which copies
+a row's K and V chunk by chunk up to the chunk that holds ``pos`` and
 nothing past it (no slice of a layer is made to feed it: the carry is
-its operand); elsewhere, for a shape the kernel has no block for and
+its operand); elsewhere, for a shape the kernel has no chunk for and
 for a layer with a sink, it is ``cached_attention`` over the layer's slice under a per-row mask,
 which reads all ``max_len`` rows. A run of window layers is bounded by
 its ring and keeps ``cached_attention`` (with the layer's sink) on
 every platform. Both forms lean on the one invariant below: **a row
 past a slot's ``pos`` is never attended**, so a reused slot's stale
 tail and an idle row's garbage stay unread; the kernel does not even
-fetch them, and zeroes what its last block holds of them.
+copy them, and zeroes what its last chunk holds of them.
 
 **The picked token stays on the device.** ``cache["tok"]``, int32
 [slots], is each row's last pick (:func:`pick`, the one place a token
@@ -351,8 +351,8 @@ def _max_len(cfg: TransformerConfig, runs, states) -> int:
 
 def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
     """How many positions of a slot a full-attention layer of
-    ``slot_decode_step`` fetches at a time from this cache: the block
-    of ``ops.attention.decode_attention``'s kernel, all ``max_len``
+    ``slot_decode_step`` fetches at a time from this cache: the chunk
+    ``ops.attention.decode_attention``'s kernel copies, all ``max_len``
     where its XLA form runs, None for a model without such a layer. A
     slot stepped at position p reads ``(p // n + 1) * n`` of its rows a
     layer, which is what ``JaxSlotEngine`` counts from its host mirror."""

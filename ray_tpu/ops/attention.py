@@ -39,7 +39,7 @@ none of these: a gradient through such a call recomputes ``attention``
 
 A decode step has a kernel of its own, ``decode_attention`` (at the
 end of this file): one new token a slot against a run's whole cache,
-each slot's K and V read block by block up to its own position and no
+each slot's K and V copied chunk by chunk up to its own position and no
 further; ``cached_attention`` is its XLA form and oracle, and the form
 of a window layer's ring.
 
@@ -663,8 +663,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
 # ------------------------------------------------ one new token a row
 
 DECODE_KERNEL = "decode_attend"     # the pallas_call's ``name=``
-# the most K and V, in bytes, that one grid step of it fetches
-_DECODE_STEP_BYTES = 2 * 2 ** 20
+# the least K and V, in bytes, of a row's chunk; chunks in VMEM at a time
+_DECODE_CHUNK_BYTES = 2 ** 18
+_DECODE_DEPTH = 3
 
 
 def _widen(q, G):
@@ -732,72 +733,113 @@ def cached_attention(q, lk, lv, valid, sm_scale, sink=None):
     return o.astype(q.dtype)
 
 
-def _decode_kernel(layer_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-                   l_ref, acc_ref, *, sm_scale, block_k, group, stride,
-                   num_k):
-    """One (group of slots g, k-block j) grid step of a decode step's
-    attention over the carried cache. Prefetched: layer_ref [1], the
-    layer of the run (the index maps' business), and pos_ref [slots],
-    the position each slot's new token was written at.
+def _decode_kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                   v_buf, sems, count_ref, m_ref, l_ref, acc_ref, *, sm_scale,
+                   chunk, stride):
+    """Grid step b of a decode step's attention over the carried cache:
+    row b up to its own position. Prefetched: layer_ref [1], the layer
+    of the run, and pos_ref [slots], the position each slot's new token
+    was written at.
 
-    q_ref [group, H, C]; k_ref [group, n, C] and v_ref [group, n, Cv],
-    block j of each slot's rows as they lie, n = block_k * stride:
-    ``stride`` 1 for flat rows (C the whole row, q widened to it), H
-    where a position is H rows of one head each (column c of the scores
-    is then position c // H of head c % H, and a query head keeps its
-    own head's columns); o_ref [group, H, Cv]. Scratch as the forward
-    kernel's, a slot each: m_ref and l_ref [group, H, w], acc_ref
-    [group, H, Cv]. A slot's block past its position is skipped (and
-    was not fetched: the index map named the last live one again); its
-    last live block masks the columns past the position AND zeroes the
-    value rows there, so that nothing a stale tail holds, NaN included,
-    reaches the output."""
+    q_ref [H, C] and o_ref [H, Cv] are the row's blocks of the grid;
+    k_hbm [L, slots, N, C] and v_hbm [L, slots, N, Cv] are the run's
+    whole arrays where they lie, and the kernel copies a row's chunks of
+    n = chunk * stride of its rows itself: ``stride`` 1 for flat rows
+    (C the whole row, q widened to it), H where a position is H rows of
+    one head each (column c of the scores is then position c // H of
+    head c % H, and a query head keeps its own head's columns).
+
+    The kernel takes the chunks in one order, row after row, each row's
+    from its first to ``pos // chunk`` and none past it, and keeps
+    ``depth`` of them in VMEM (k_buf [depth, n, C], v_buf [depth, n,
+    Cv], a DMA semaphore each; ``_DECODE_DEPTH``): before a chunk is
+    multiplied, the copy of the one ``depth - 1`` places after it in
+    that order is started, the next row's first chunks under a row's
+    last ones, so that the copies run back to back from the first row
+    to the last, across the grid's steps. ``count_ref`` counts the
+    chunks taken, from one grid step to the next; chunk c of the order
+    lies in buffer c % depth. Online softmax across the row's chunks in
+    m_ref, l_ref [H, w] and acc_ref [H, Cv] as the forward kernel keeps
+    them; only the last chunk masks the columns past the position, and
+    zeroes the value rows there, so that nothing a stale tail holds, NaN
+    included, reaches the output."""
     import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
 
-    g, j = pl.program_id(0), pl.program_id(1)
+    b, slots = pl.program_id(0), pl.num_programs(0)
+    n, depth = chunk * stride, k_buf.shape[0]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def copies(row, i, slot):
+        at = pl.ds(pl.multiple_of(i * n, n), n)
+        return [pltpu.make_async_copy(hbm.at[layer_ref[0], row, at],
+                                      buf.at[slot], sems.at[kv, slot])
+                for kv, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf)))]
 
-    for r in range(group):
-        pos = pos_ref[g * group + r]
+    def after(row, i):
+        """The chunk after chunk i of ``row`` in the order (row
+        ``slots`` once the last row's are done)."""
+        more = i < pos_ref[jnp.minimum(row, slots - 1)] // chunk
+        return jnp.where(more, row, row + 1), jnp.where(more, i + 1, 0)
 
-        def _tile(edge, r=r, pos=pos):
-            q, k, v = q_ref[r], k_ref[r], v_ref[r]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # [H, n]
-            column = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            keep = None
-            if stride > 1:
-                keep = column % stride == jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 0)
-            if edge:
-                # rows of this block at or before the position
-                live = (pos + 1 - j * block_k) * stride
-                keep = column < live if keep is None \
-                    else keep & (column < live)
-                v = jnp.where(jax.lax.broadcasted_iota(
-                    jnp.int32, (v.shape[0], 1), 0) < live, v,
-                    jnp.zeros_like(v))
-            if keep is not None:
-                s = jnp.where(keep, s, _NEG_INF)
-            _softmax_step(s, v, m_ref.at[r], l_ref.at[r], acc_ref.at[r])
+    def issue(row, i, slot):
+        @pl.when(row < slots)
+        def _():
+            for copy in copies(row, i, slot):
+                copy.start()
 
-        pl.when(j < pos // block_k)(functools.partial(_tile, False))
-        pl.when(j == pos // block_k)(functools.partial(_tile, True))
+    @pl.when(b == 0)
+    def _first():
+        count_ref[0] = 0
+        row, i = 0, 0
+        for c in range(depth - 1):
+            issue(row, i, c)
+            row, i = after(row, i)
 
-    @pl.when(j == num_k - 1)
-    def _finish():
-        for r in range(group):
-            l = jnp.sum(l_ref[r], axis=-1, keepdims=True)
-            o_ref[r] = (acc_ref[r] / l).astype(o_ref.dtype)
+    pos = pos_ref[b]
+
+    def attend(i, c, edge):
+        """The row's chunk i, chunk c of the order, folded into its
+        softmax, the copy of the chunk ``depth - 1`` after it started
+        first."""
+        row, ahead = b, i
+        for _ in range(depth - 1):
+            row, ahead = after(row, ahead)
+        issue(row, ahead, (c + depth - 1) % depth)
+        for copy in copies(b, i, c % depth):
+            copy.wait()
+        q, k, v = q_ref[...], k_buf[c % depth], v_buf[c % depth]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [H, n]
+        column = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = None
+        if stride > 1:
+            keep = column % stride == jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+        if edge:
+            # rows of this chunk at or before the position
+            live = (pos + 1 - i * chunk) * stride
+            keep = column < live if keep is None else keep & (column < live)
+            v = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (n, 1), 0) < live, v, jnp.zeros_like(v))
+        if keep is not None:
+            s = jnp.where(keep, s, _NEG_INF)
+        _softmax_step(s, v, m_ref, l_ref, acc_ref)
+        return c + 1
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    last = pos // chunk
+    c = jax.lax.fori_loop(0, last, lambda i, c: attend(i, c, False),
+                          count_ref[0])
+    count_ref[0] = attend(last, c, True)
+    l = jnp.sum(l_ref[...], axis=-1, keepdims=True)
+    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _decode_forward(q, k, v, layer, pos, sm_scale, block_k, group, stride,
+def _decode_forward(q, k, v, layer, pos, sm_scale, chunk, stride,
                     interpret):
     """q [B, H, C]; k [L, B, N, C] and v [L, B, N, Cv], the run's whole
     arrays, N = rows * stride; returns [B, H, Cv] at q's dtype."""
@@ -805,121 +847,119 @@ def _decode_forward(q, k, v, layer, pos, sm_scale, block_k, group, stride,
     import jax.experimental.pallas.tpu as pltpu
 
     B, H, C = q.shape
-    N, Cv = k.shape[2], v.shape[3]
-    n = block_k * stride
-    num_k = N // n
+    Cv = v.shape[3]
+    n = chunk * stride
 
-    def kv_index(g, j, layer_ref, pos_ref):
-        # a step past the last live block of the group's slots names
-        # that block again: the pipeline issues no copy for it
-        last = pos_ref[g * group] // block_k
-        for r in range(1, group):
-            last = jnp.maximum(last, pos_ref[g * group + r] // block_k)
-        return (layer_ref[0], g, jnp.minimum(j, last), 0)
-
-    def q_index(g, j, layer_ref, pos_ref):
-        return (g, 0, 0)
+    def row(b, layer_ref, pos_ref):
+        return (b, 0, 0)
 
     w = _scratch_lanes(n)
     return pl.pallas_call(
-        functools.partial(_decode_kernel, sm_scale=sm_scale,
-                          block_k=block_k, group=group, stride=stride,
-                          num_k=num_k),
+        functools.partial(_decode_kernel, sm_scale=sm_scale, chunk=chunk,
+                          stride=stride),
         out_shape=jax.ShapeDtypeStruct((B, H, Cv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B // group, num_k),   # j innermost: scratch carries
-            in_specs=[pl.BlockSpec((group, H, C), q_index),
-                      pl.BlockSpec((None, group, n, C), kv_index),
-                      pl.BlockSpec((None, group, n, Cv), kv_index)],
-            out_specs=pl.BlockSpec((group, H, Cv), q_index),
-            scratch_shapes=[pltpu.VMEM((group, H, w), jnp.float32),
-                            pltpu.VMEM((group, H, w), jnp.float32),
-                            pltpu.VMEM((group, H, Cv), jnp.float32)]),
+            grid=(B,),
+            in_specs=[pl.BlockSpec((None, H, C), row),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, H, Cv), row),
+            scratch_shapes=[pltpu.VMEM((_DECODE_DEPTH, n, C), k.dtype),
+                            pltpu.VMEM((_DECODE_DEPTH, n, Cv), v.dtype),
+                            pltpu.SemaphoreType.DMA((2, _DECODE_DEPTH)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((H, w), jnp.float32),
+                            pltpu.VMEM((H, w), jnp.float32),
+                            pltpu.VMEM((H, Cv), jnp.float32)]),
+        # the rows follow one another: a row starts the next one's
+        # copies; three chunks of a row of 32 KB a position and their
+        # scores outgrow the 16 MiB of VMEM a kernel is given by default
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 2 ** 20),
         interpret=interpret,
         name=DECODE_KERNEL,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos.astype(jnp.int32),
       q, k, v)
 
 
-def decode_blocks(rows: int, row_bytes: int,
-                  slots: int) -> tuple[int, int] | None:
-    """(positions in a block, slots in a grid step) of ``decode_attend``
-    for a cache of ``slots`` x ``rows`` positions whose K and V together
-    take ``row_bytes`` a position, or None where the kernel has no
-    block for the shape (``rows`` no multiple of 128).
+def decode_chunks(rows: int, row_bytes: int) -> int | None:
+    """Positions in a chunk of ``decode_attend`` for a cache of ``rows``
+    positions whose K and V together take ``row_bytes`` a position, or
+    None where the kernel has no chunk for the shape (``rows`` no
+    multiple of 128).
 
-    A block is the largest multiple of 128 that divides ``rows``, is at
-    most a quarter of them (what a slot skips, it skips by whole
-    blocks) and keeps a grid step's K and V under ``_DECODE_STEP_BYTES``
-    (double-buffered, they live in the scoped VMEM beside the scores: 8
-    MB a step no longer fit its 16); where a block is far smaller than
-    that (narrow rows), up to 8 slots share a grid step, which then
-    skips by the longest of them.
+    A chunk is the smallest multiple of 128 that divides ``rows`` and
+    whose K and V take at least ``_DECODE_CHUNK_BYTES`` (what a row
+    copies past its position is under one chunk), but at most a quarter
+    of ``rows`` where that is 128 or more. ``_DECODE_DEPTH`` chunks are
+    in VMEM at a time, two of them in flight while one is multiplied.
 
-    Swept on a TPU v5e in bfloat16, microseconds a layer against the
-    XLA form's, slots at the serving cells' positions (PERF.md section
-    6, PR 37). The kernel's time is its copies plus half a microsecond
-    a grid step, fetching or not, so small blocks lose to the grid and
-    large ones to the rows fetched past a position. 8 slots x 1024 rows
-    of 8 KB (16 x 128 heads, K and V) at positions 128-448: 61, 64, 77
-    at blocks of 128, 256, 512 against 129. 128 x 3200 of 2.5 KB at
-    512-3072: 1,642 and 1,203 at 128 and 640 (1,277 with 2 slots a
-    step) against 1,826. 256 x 2048 of 512 bytes at 128-1536: 507 at
-    (512, 1), 377 at (512, 8), 372 at (1024, 4), 378 with the whole row
-    a step, against 380: rows that narrow gain nothing and, with their
-    slots grouped, lose nothing, so they keep the one kernel."""
-    fit = [b for b in range(_BLOCK, rows + 1, _BLOCK) if rows % b == 0]
+    Swept on a TPU v5e in bfloat16, microseconds a call, each the
+    median of a scan of many, rows at the serving cells' positions, as
+    (chunk, depth) against the parent's kernel (PR 37's grid of blocks;
+    PERF.md section 6, PR 45). 8 x 1024 of 8 KB (16 x 128 heads) at a
+    mean of 251: 31.9 (128, 2), 31.8 (128, 3), 32.1 (128, 4), 37.9
+    (256, 3), the copies alone 31.5, against 49.7. 64 x 6144 of 5 KB
+    at 2,886: 1,434, 1,344, 1,345 at 128 and depths 2, 3, 4, 1,372 and
+    1,370 at 256, 1,394 at 384 (3), the copies alone 1,343, against
+    1,558. 128 x 3200 of 2.5 KB at 1,730: 1,293, 999 and 1,012 at 128,
+    1,007 and 1,016 at 640 (2, 3), the copies alone 909, against 1,188.
+    256 x 2048 of 512 bytes at 806: 351 and 271 at 512 (2, 3), 329 and
+    293 at 1024, 412 at 256 (3), against 397. So a chunk of a quarter
+    MB or more keeps the copy engine near its rate with two copies
+    queued (one is not enough below a MB), a smaller one loses to the
+    copies' own cost and a larger one to the rows copied past each
+    position; with rows of 5 KB and more the kernel is its copies
+    alone. Up to 8 rows a grid step moved no shape by more than half a
+    percent: the copies run on across the grid's steps."""
+    fit = [c for c in range(_BLOCK, rows + 1, _BLOCK) if rows % c == 0]
     if not fit:
         return None
-    room = [b for b in fit if b <= max(_BLOCK, rows // 4)
-            and b * row_bytes <= _DECODE_STEP_BYTES]
-    block_k = max(room, default=fit[0])
-    group = max(g for g in (1, 2, 4, 8) if slots % g == 0 and (
-        g == 1 or g * block_k * row_bytes <= _DECODE_STEP_BYTES))
-    return block_k, group
+    room = [c for c in fit if c <= max(_BLOCK, rows // 4)]
+    return next((c for c in room if c * row_bytes >= _DECODE_CHUNK_BYTES),
+                room[-1])
 
 
-def _decode_plan(q, k, v, block_k, group, interpret, sink=False):
-    """(block_k, group, stride) of the kernel for these operands, or
-    None where the XLA form runs (``decode_attention`` says when)."""
+def _decode_plan(q, k, v, chunk, interpret, sink=False):
+    """(chunk, stride) of the kernel for these operands, or None where
+    the XLA form runs (``decode_attention`` says when)."""
     rows, H = k.shape[2], q.shape[1]
     stride = 1 if k.ndim == 4 else H
     if sink or not (interpret or _on_tpu()):
         return None
-    if block_k is None:
+    if chunk is None:
         row_bytes = k.dtype.itemsize * (
             math.prod(k.shape[3:]) + math.prod(v.shape[3:]))
-        blocks = decode_blocks(rows, row_bytes, k.shape[1])
-        if blocks is None and not interpret:
+        chunk = decode_chunks(rows, row_bytes)
+        if chunk is None and not interpret:
             return None
-        block_k, group = blocks or (rows, 1)
     if interpret:   # exercises the kernel at any size: no Mosaic tiling
-        block_k = min(block_k, rows)
+        chunk = min(chunk or rows, rows)
     else:
         tile = 8 * 4 // k.dtype.itemsize    # rows of a tile in memory
-        if (block_k % _BLOCK or k.shape[-1] % _LANES or v.shape[-1] % _LANES
+        if (chunk % _BLOCK or k.shape[-1] % _LANES or v.shape[-1] % _LANES
                 or (stride > 1 and (stride % tile or stride & (stride - 1)))):
             return None
-    if rows % block_k or k.shape[1] % group:
+    if rows % chunk:
         return None
-    return block_k, group, stride
+    return chunk, stride
 
 
 def decode_rows_fetched(q, k, v, *, sink: bool = False,
                         interpret: bool = False) -> int:
-    """How many positions of a slot ``decode_attention`` fetches at a
+    """How many positions of a slot ``decode_attention`` copies at a
     time for these operands (arrays or their shapes' structs; ``sink``
-    whether it is given one): the kernel's block, or all ``rows`` where
+    whether it is given one): the kernel's chunk, or all ``rows`` where
     the XLA form runs. A slot at position p costs ``(p // n + 1) * n``
-    of them a layer."""
-    plan = _decode_plan(q, k, v, None, None, interpret, sink)
+    of them a layer, which is what the kernel copies for it."""
+    plan = _decode_plan(q, k, v, None, interpret, sink)
     return k.shape[2] if plan is None else plan[0]
 
 
 def decode_attention(q, k, v, layer, pos, *, sm_scale: float | None = None,
-                     sink=None, block_k: int | None = None,
-                     rows_per_step: int | None = None,
+                     sink=None, chunk: int | None = None,
                      interpret: bool = False):
     """A decode step's attention for a run of full-attention layers,
     over the run's cache where it lies: one new token a slot, q
@@ -935,37 +975,33 @@ def decode_attention(q, k, v, layer, pos, *, sm_scale: float | None = None,
 
     Two forms behind the one name, as ``flash_attention`` has. On the
     TPU (or under ``interpret``) the kernel ``decode_attend``: k and v
-    are its operands whole, ``layer`` and ``pos`` prefetched scalars,
-    and a slot's K and V come in blocks of positions **up to the block
-    that holds ``pos[b]`` and no further**: what lies past a slot's
-    position, in a skipped block or in the tail of its last live one,
-    is neither attended nor able to reach the output (NaN included).
-    Rows are taken as they lie: flat ones with q widened to a whole row
-    (``cached_attention``), [H, D] ones as H rows a position of which a
-    query head keeps its own, so both products are plain matrix
-    products of [H, C] with a block. Blocks come from the shape
-    (``decode_blocks``); explicit ``block_k`` and ``rows_per_step``
-    (both) win.
+    are its operands whole, where they lie, ``layer`` and ``pos``
+    prefetched scalars, and the kernel copies a slot's K and V itself,
+    in chunks of positions **up to the chunk that holds ``pos[b]`` and
+    no further**: what lies past a slot's position, in the chunks not
+    copied or in the tail of its last one, is neither attended nor able
+    to reach the output (NaN included). Rows are taken as they lie:
+    flat ones with q widened to a whole row (``cached_attention``),
+    [H, D] ones as H rows a position of which a query head keeps its
+    own, so both products are plain matrix products of [H, C] with a
+    chunk. Chunks come from the shape (``decode_chunks``); an explicit
+    ``chunk`` wins.
 
     The XLA form, ``cached_attention`` over the layer's slice under a
     mask: off the TPU, with a ``sink``, and on the TPU where the shape
-    has no block (``rows`` no multiple of 128, a row's width no multiple
+    has no chunk (``rows`` no multiple of 128, a row's width no multiple
     of 128 lanes, [H, D] rows whose H is no power of two of whole
-    tiles). It reads all
-    ``rows`` of every slot. ``decode_rows_fetched`` says which a shape
-    gets."""
+    tiles). It reads all ``rows`` of every slot.
+    ``decode_rows_fetched`` says which a shape gets."""
     B, H, D = q.shape
     sm_scale = sm_scale if sm_scale is not None else D ** -0.5
-    if (block_k is None) != (rows_per_step is None):
-        raise ValueError("give both block_k and rows_per_step, or neither")
-    plan = _decode_plan(q, k, v, block_k, rows_per_step, interpret,
-                        sink is not None)
+    plan = _decode_plan(q, k, v, chunk, interpret, sink is not None)
     if plan is None:
         lk = jax.lax.dynamic_index_in_dim(k, layer, keepdims=False)
         lv = jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
         valid = jnp.arange(k.shape[2])[None, None, :] <= pos[:, None, None]
         return cached_attention(q, lk, lv, valid, sm_scale, sink)
-    block_k, group, stride = plan
+    chunk, stride = plan
     own = None
     if stride == 1:
         q, own = _widen(q, k.shape[3] // D)
@@ -975,6 +1011,6 @@ def decode_attention(q, k, v, layer, pos, *, sm_scale: float | None = None,
     # whole tiles of query rows (a padded head's output is dropped)
     padded = -H % (8 * 4 // q.dtype.itemsize)
     q = jnp.pad(q, ((0, 0), (0, padded), (0, 0)))
-    o = _decode_forward(q, k, v, layer, pos, sm_scale, block_k, group,
-                        stride, interpret)[:, :H]
+    o = _decode_forward(q, k, v, layer, pos, sm_scale, chunk, stride,
+                        interpret)[:, :H]
     return o if own is None else _own_part(o, own)

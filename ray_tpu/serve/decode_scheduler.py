@@ -77,9 +77,10 @@ name, to be read as deltas:
   owed: a finished request's one step more). From the host mirror of
   the slots' positions alone, once a dispatched step:
   ``serve.engine.kv_rows_read`` (over the slots it steps, the cache
-  rows a full-attention layer fetches for them: whole blocks up to the
-  one written to, the block ``ops.attention.decode_attention`` takes
-  for the cache's shape; all ``max_len`` where its XLA form runs) and
+  rows a full-attention layer fetches for them: whole chunks up to the
+  one written to, the chunk ``ops.attention.decode_attention``'s
+  kernel copies for the cache's shape; all ``max_len`` where its XLA
+  form runs) and
   ``serve.engine.kv_rows_held`` (stepped slots x ``max_len``): their
   ratio is how far the bounded read engages. Of a model whose layers
   keep a summary a slot (Mamba, retention), likewise once a dispatched
@@ -553,7 +554,7 @@ class JaxSlotEngine:
         self._start_over()
         # positions a full-attention layer fetches at a time for a slot
         # (models/decode.py; None: the model has no such layer)
-        self._kv_block = decode_mod.kv_rows_fetched(cfg, self._cache)
+        self._kv_chunk = decode_mod.kv_rows_fetched(cfg, self._cache)
         # layers that read such rows a step, for each layer that holds
         # them: 1, and more where cross layers share a layer's cache
         self._kv_readers = decode_mod.kv_readers(cfg)
@@ -688,8 +689,8 @@ class JaxSlotEngine:
             self._params, self._cache, fed, None, self._cfg)
         rode = {slot for slot, t in enumerate(steer)
                 if t != self._decode.IDLE}
-        if self._kv_block:  # whole blocks, up to the one written to
-            n, readers = self._kv_block, self._kv_readers
+        if self._kv_chunk:  # whole chunks, up to the one written to
+            n, readers = self._kv_chunk, self._kv_readers
             phase_add("serve.engine.kv_rows_read", readers * sum(
                 (self._pos[slot] // n + 1) * n for slot in rode))
             phase_add("serve.engine.kv_rows_held",

@@ -12,9 +12,10 @@ cell's own shapes, and held to what keeps the slot cache one buffer:
 the cache aliased to the result, no temporary the size of a layer's K,
 and no operation that produces K or V but the in-place writes. A
 decode step names one Mosaic call ``decode_attend`` a run of
-full-attention layers (none for a window run), which reads the carried
-cache where it lies; that kernel alone is compiled at each serving
-cell's decode shape, at the blocks ``decode_blocks`` gives it.
+full-attention layers (none for a window run), which copies each row
+of the carried cache itself, where it lies, up to the row's position;
+that kernel alone is compiled at each attending cell's decode shape,
+at the chunk ``decode_chunks`` gives it.
 
 The second serving cell's programs (``mimo-v2-flash-ep16-d7``: layers of
 several kinds, a cache allocated by kind, a chip's share of the
@@ -181,21 +182,23 @@ def test_flash_kernels_compile_for_v5e_under_their_own_names(
     assert mosaic_calls(both) == sorted(KERNELS)
 
 
-# the fixture of each serving cell, and the block of positions that
-# ``decode_blocks`` gives its full-attention runs' cache
-DECODE_BLOCKS = {"serving_cell": 256, "kinds_cell": 640, "hybrid_cell": 512}
+# the fixture of each serving cell whose decode step attends, and the
+# chunk of positions that ``decode_chunks`` gives its full-attention
+# runs' cache
+DECODE_CHUNKS = {"serving_cell": 128, "kinds_cell": 128, "hybrid_cell": 512,
+                 "shared_cell": 128}
 
 
-@pytest.mark.parametrize("cell", sorted(DECODE_BLOCKS))
+@pytest.mark.parametrize("cell", sorted(DECODE_CHUNKS))
 def test_the_decode_kernel_compiles_for_v5e_under_its_own_name(
         cell, request, one_chip, no_compile_cache, monkeypatch):
-    """``decode_attend`` alone at a serving cell's decode shape, the
+    """``decode_attend`` alone at an attending cell's decode shape, the
     first full-attention run of the cache the cell allocates (rows as
     it holds them: [H, Dh] a position for Ouro, flat where K/V heads
-    are shared), at the blocks ``decode_blocks`` chooses for it: Mosaic
-    accepts them (a table that overflows the scoped VMEM fails here),
-    the run's K and V reach the call as they lie (no operation produces
-    an array of their shape, no temporary has room for one), and the
+    are shared), at the chunk ``decode_chunks`` chooses for it: Mosaic
+    accepts it (buffers that overflow the scoped VMEM fail here), the
+    run's K and V reach the call as they lie (no operation produces an
+    array of their shape, no temporary has room for one), and the
     program names its one custom call ``decode_attend``."""
     import jax
     import jax.numpy as jnp
@@ -208,15 +211,19 @@ def test_the_decode_kernel_compiles_for_v5e_under_its_own_name(
     cfg, slots, slot_len, _ = request.getfixturevalue(cell)
     cache = jax.eval_shape(
         lambda: decode.init_slot_cache(cfg, slots, slot_len))
-    assert decode.kv_rows_fetched(cfg, cache) == DECODE_BLOCKS[cell]
-    run = next(r for r, ((mixer, _), _) in enumerate(layer_runs(cfg))
-               if mixer == "full")
+    assert decode.kv_rows_fetched(cfg, cache) == DECODE_CHUNKS[cell]
+    runs = layer_runs(cfg)
+    full = next(pair for mixer, pair in decode._layer_states(
+        runs, decode._cache_runs(cache, runs)) if mixer == "full")
 
     def array(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    k, v = (array(cache[name][run].shape, cfg.dtype) for name in "kv")
-    q = array((slots, cfg.n_heads, cfg.head_dim), cfg.dtype)
+    k, v = (array(t.shape, cfg.dtype) for t in full)
+    # (differential attention's heads go in pairs, each query twice as
+    # wide: ``transformer._paired``)
+    q = array((slots, cfg.n_heads, cfg.head_dim * (
+        2 if cfg.differential else 1)), cfg.dtype)
     compiled = jax.jit(attention.decode_attention).lower(
         q, k, v, array((), jnp.int32), array((slots,), jnp.int32)).compile()
     text = compiled.as_text()
@@ -606,19 +613,22 @@ def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
 # weight's (``train``, whose rows are not, and the kernels' jaxprs as
 # they were); and ``train`` alone since ``remat=True`` keeps the layers'
 # products and what the flash backward kernels read and the step's
-# state is donated (every serving program as it was). Left out: the
-# Mosaic kernels' serialized bodies, which hold the line numbers of
-# ops/attention.py, and the results' labels, which name the cache's
-# place in the result's tree
+# state is donated (every serving program as it was); and the four
+# attending cells' ``decode`` since ``decode_attend`` copies each row's
+# chunks of K and V itself, from the run's arrays in ``pl.ANY`` (every
+# prefill, ``train`` and the fourth serving cell's programs as they
+# were). Left out: the Mosaic kernels' serialized bodies, which hold the
+# line numbers of ops/attention.py, and the results' labels, which name
+# the cache's place in the result's tree
 TRAIN_CELL = "ouro-2.6b-d12.train-2k"
 LOWERED = {
-    "decode": "a3e7e77eb383f004",
+    "decode": "2af5bcf489aafbec",
     "prefill-128": "70d028c10c8b6cff",
     "prefill-256": "a25f52704aaeb6c6",
     "train": "7bc8d8cd2dc6b5c3",
 }
 LOWERED_KINDS = {
-    "decode": "b4b1440d73aae73d",
+    "decode": "d8958c81b432859c",
     "prefill-512": "47fd3173921c4b1c",
     "prefill-1024": "b6764f67406acf72",
     "prefill-2048": "159daa3d1d966ee3",
@@ -784,7 +794,7 @@ def test_the_train_step_keeps_what_its_backward_reads_and_fits(
 
 HYBRID_CELL = "jamba2-3b.rollout-closed"
 LOWERED_HYBRID = {
-    "decode": "806b345381d91ce6",
+    "decode": "13ca2a6ead239fab",
     "prefill-128": "5dfd27e60294ead6",
     "prefill-256": "d9740ea2bc50b407",
     "prefill-512": "b3b436c3575270ac",
@@ -1217,7 +1227,7 @@ def test_the_fourth_cells_serving_programs_lower_to_the_text_on_record(
 
 SHARED_CELL = "phi-4-mini-flash.session-closed"
 LOWERED_SHARED = {
-    "decode": "79c54a82e38ff733",
+    "decode": "2f58eb2a7ab9b3d6",
     "prefill-1024": "5a92f917fea65eda",
     "prefill-2048": "0de56d1419d9fc90",
     "prefill-4096": "8d660b5aeb509d8d",

@@ -63,10 +63,10 @@ def test_smoke_legs_compose_on_cpu(tpu_session, monkeypatch, tmp_path):
     # above 0: the kernels ran (interpreted), not attention() against
     # itself
     assert row["finite"] and 0 < min(errors) and max(errors) < 1e-4
-    # the decode kernel ran (interpreted: blocks of 128 in 256 rows),
+    # the decode kernel ran (interpreted: chunks of 128 in 256 rows),
     # each slot up to its own position and not into the NaN past it
     for row in trained["decode_kernels"]:
-        assert row["block"] == 128 and row["finite"]
+        assert row["chunk"] == 128 and row["finite"]
         assert 0 < row["err"] < 1e-5
 
 

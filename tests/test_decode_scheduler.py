@@ -750,14 +750,14 @@ def test_engine_phases_cover_the_step():
 def test_the_engine_counts_the_rows_a_bounded_read_fetches(
         decode_kernel_interpreted):
     """Through the decode kernel (interpreted; a cache of 256 rows gets
-    blocks of 128) the engine's tokens are still forward()'s, and it
+    chunks of 128) the engine's tokens are still forward()'s, and it
     counts, from its host mirror alone, the rows a full-attention layer
-    fetches for the slots it steps (whole blocks up to the one written
+    copies for the slots it steps (whole chunks up to the one written
     to) beside the rows they hold."""
     from ray_tpu.util.phases import recording
 
     eng = _tiny_engine(slots=2, max_len=256, max_seq=256)
-    assert eng._kv_block == 128
+    assert eng._kv_chunk == 128
     prompts = {0: [5, 11, 23], 1: list(range(1, 127))}
     want = {slot: _oracle(eng, p, 5) for slot, p in prompts.items()}
     last = {slot: eng.prefill(slot, p) for slot, p in prompts.items()}

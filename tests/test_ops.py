@@ -453,19 +453,20 @@ def test_kv_cached_decode_matches_full_forward():
 # ------------------------------ a decode step's attention: decode_attend
 
 # H query heads of D on G K/V heads, values Dv wide; a cache of ROWS
-# positions in blocks of BLOCK
+# positions in chunks of CHUNK
 _DECODE_LAYOUTS = {
     "mha-16x128": (16, 128, 128, 16),
     "grouped-64on4-192-128": (64, 192, 128, 4),
     "grouped-20on1-128": (20, 128, 128, 1),
 }
-_ROWS, _BLOCK = 64, 16
+_ROWS, _CHUNK = 64, 16
 _DECODE_POS = {
     "first": [0, 0, 0, 0],
-    "short-of-an-edge": [_BLOCK - 1, 2 * _BLOCK - 1, 3 * _BLOCK - 1, 15],
-    "on-an-edge": [_BLOCK, 2 * _BLOCK, 3 * _BLOCK, _BLOCK],
+    "short-of-an-edge": [_CHUNK - 1, 2 * _CHUNK - 1, 3 * _CHUNK - 1, 15],
+    "on-an-edge": [_CHUNK, 2 * _CHUNK, 3 * _CHUNK, _CHUNK],
+    "past-an-edge": [_CHUNK + 1, 2 * _CHUNK + 1, 3 * _CHUNK + 1, 1],
     "last": [_ROWS - 1] * 4,
-    "mixed": [0, _BLOCK - 1, _BLOCK, _ROWS - 1],
+    "mixed": [0, _CHUNK - 1, _CHUNK, _ROWS - 1],
 }
 
 
@@ -498,8 +499,9 @@ def test_decode_kernel_matches_the_xla_form_and_reads_nothing_past_pos(
     """``decode_attend`` (interpreted) against ``cached_attention`` on
     the layer's slice: every position ``[0, pos]`` of a slot attended,
     and nothing of what lies past it (NaN and +-inf there, in the
-    skipped blocks and in the tail of the last live one) in the
-    output, which has q's dtype."""
+    chunks not copied and in the tail of the last one) in the output,
+    which has q's dtype; each row's copies started under the row before
+    it."""
     from ray_tpu.ops.attention import cached_attention, decode_attention
 
     q, (pk, pv), (ck, cv), pos, valid = _decode_case(
@@ -507,20 +509,17 @@ def test_decode_kernel_matches_the_xla_form_and_reads_nothing_past_pos(
     layer = 1
     want = cached_attention(q, ck[layer], cv[layer], valid,
                             q.shape[-1] ** -0.5)
-    for group in (1, 2):
-        got = decode_attention(q, pk, pv, jnp.int32(layer), pos,
-                               block_k=_BLOCK, rows_per_step=group,
-                               interpret=True)
-        assert got.dtype == q.dtype and got.shape == want.shape
-        np.testing.assert_allclose(
-            np.asarray(got, np.float32), np.asarray(want, np.float32),
-            atol=1e-5 if dtype == jnp.float32 else 2e-2,
-            err_msg=f"{group} slots a grid step")
+    got = decode_attention(q, pk, pv, jnp.int32(layer), pos, chunk=_CHUNK,
+                           interpret=True)
+    assert got.dtype == q.dtype and got.shape == want.shape
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=1e-5 if dtype == jnp.float32 else 2e-2)
 
 
 def test_decode_kernel_accumulates_in_float32_across_blocks():
     """A uniform softmax over 512 positions of value 1, in bfloat16, 32
-    blocks: the numerator and the denominator are carried in float32,
+    chunks: the numerator and the denominator are carried in float32,
     so the output is 1 exactly (summed in bfloat16, 512 terms of 1/512
     stall far below it)."""
     from ray_tpu.ops.attention import decode_attention
@@ -532,39 +531,116 @@ def test_decode_kernel_accumulates_in_float32_across_blocks():
     v = jnp.ones((1, B, rows, H, D), jnp.bfloat16)
     got = decode_attention(q, k, v, jnp.int32(0),
                            jnp.asarray([rows - 1, 300], jnp.int32),
-                           block_k=16, rows_per_step=1, interpret=True)
+                           chunk=16, interpret=True)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_array_equal(np.asarray(got, np.float32), 1.0)
 
 
-# rows, bytes of K and V a position, slots -> (block, slots a grid step)
-_DECODE_BLOCKS = {
-    "ouro-2.6b.decode-closed": ((1024, 2 * 2 * 16 * 128, 8), (256, 1)),
+# rows, bytes of K and V a position -> positions in a chunk
+_DECODE_CHUNKS = {
+    "ouro-2.6b.decode-closed": ((1024, 2 * 2 * 16 * 128), 128),
     "mimo-v2-flash-ep16-d7.reason-closed": (
-        (3200, 2 * 4 * (192 + 128), 128), (640, 1)),
-    "jamba2-3b.rollout-closed": ((2048, 2 * 2 * 128, 256), (512, 8)),
-    "no multiple of 128": ((1000, 8192, 8), None),
-    "rows of 32 KB": ((2048, 32768, 6), (128, 1)),
+        (3200, 2 * 4 * (192 + 128)), 128),
+    "jamba2-3b.rollout-closed": ((2048, 2 * 2 * 128), 512),
+    "phi-4-mini-flash.session-closed": ((6144, 2 * 2 * 1280), 128),
+    "no multiple of 128": ((1000, 8192), None),
+    "rows of 32 KB": ((2048, 32768), 128),
 }
 
 
-@pytest.mark.parametrize("cell", sorted(_DECODE_BLOCKS))
-def test_decode_blocks_table(cell):
-    from ray_tpu.ops.attention import decode_blocks
+@pytest.mark.parametrize("cell", sorted(_DECODE_CHUNKS))
+def test_decode_chunks_table(cell):
+    from ray_tpu.ops.attention import decode_chunks
 
-    shape, blocks = _DECODE_BLOCKS[cell]
-    assert decode_blocks(*shape) == blocks
-    if blocks:
-        assert shape[0] % blocks[0] == 0 and shape[2] % blocks[1] == 0
+    shape, chunk = _DECODE_CHUNKS[cell]
+    assert decode_chunks(*shape) == chunk
+    if chunk:
+        assert shape[0] % chunk == 0
+
+
+# q, k and v as each cell's decode step hands them to the kernel (a run
+# of one layer), and a few slots' positions
+_DECODE_CELLS = {
+    "ouro-2.6b.decode-closed": ((8, 16, 128), (8, 1024, 16, 128),
+                                (8, 1024, 16, 128)),
+    "mimo-v2-flash-ep16-d7.reason-closed": (
+        (128, 64, 192), (128, 3200, 768), (128, 3200, 512)),
+    "jamba2-3b.rollout-closed": ((256, 20, 128), (256, 2048, 128),
+                                 (256, 2048, 128)),
+    "phi-4-mini-flash.session-closed": ((64, 40, 128), (64, 6144, 1280),
+                                        (64, 6144, 1280)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+def test_decode_rows_fetched_is_what_the_kernel_copies(cell, monkeypatch):
+    """At a cell's decode shape the kernel copies a row's K and V in
+    chunks of ``decode_rows_fetched`` positions, the first chunk to the
+    one that holds the row's position, each once: ``(p // n + 1) * n``
+    positions a row, which is what the engine counts. Counted where the
+    copies are started, in the interpreted kernel at the cell's rows
+    and widths (six slots, at 0, n - 1, n, n + 1 and the last row, and
+    0 again), the plan taken as on the TPU."""
+    import importlib
+
+    import jax.experimental.pallas.tpu as pltpu
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    qs, ks, vs = _DECODE_CELLS[cell]
+
+    def struct(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    full = struct(qs), struct((1,) + ks), struct((1,) + vs)
+    n = attention.decode_rows_fetched(*full)
+    stride = attention._decode_plan(*full, None, False)[1]
+    monkeypatch.undo()
+
+    rows = ks[1]
+    pos = [0, n - 1, n, n + 1, rows - 1, 0]
+    started = []
+    real = pltpu.make_async_copy
+
+    class Counted:
+        """A copy whose start is counted: the row and the first of the
+        positions it copies (its source is a chunk of one row)."""
+
+        def __init__(self, src, dst, sem):
+            self.copy, self.at = real(src, dst, sem), src.transforms[-1]
+            self.size = dst.shape[0] // stride
+
+        def start(self):
+            _, row, first = self.at.indices[:3]
+            jax.debug.callback(
+                lambda r, f, size=self.size: started.append(
+                    (int(r), int(f) // stride, size)), row, first.start)
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    monkeypatch.setattr(pltpu, "make_async_copy", Counted)
+    B = len(pos)
+    k = jnp.zeros((1, B) + ks[1:], jnp.bfloat16)
+    v = jnp.zeros((1, B) + vs[1:], jnp.bfloat16)
+    q = jnp.zeros((B,) + qs[1:], jnp.bfloat16)
+    attention.decode_attention(q, k, v, jnp.int32(0), jnp.asarray(
+        pos, jnp.int32), chunk=n, interpret=True).block_until_ready()
+    # K and V: each chunk of a row twice
+    want = sorted((r, first, n) for r, p in enumerate(pos)
+                  for first in range(0, (p // n + 1) * n, n)) * 2
+    assert sorted(started) == sorted(want)
+    assert sum(size for _, _, size in started) == 2 * sum(
+        (p // n + 1) * n for p in pos)
 
 
 def test_decode_attention_takes_the_kernel_by_platform_and_shape(
         monkeypatch):
     """Off the TPU the XLA form; on it the kernel where the shape has a
-    block (``decode_rows_fetched`` says which), the XLA form with a
+    chunk (``decode_rows_fetched`` says which), the XLA form with a
     sink and for rows that are no multiple of 128 or not whole lanes
-    wide; an explicit
-    block comes with its slots a grid step."""
+    wide; an explicit chunk wins."""
     import importlib
 
     attention_mod = importlib.import_module("ray_tpu.ops.attention")
@@ -586,12 +662,12 @@ def test_decode_attention_takes_the_kernel_by_platform_and_shape(
         struct(2, 8, 1024, 16, 128)
     assert not kernel_runs(*cell) and fetched(*cell) == 1024
     monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
-    assert kernel_runs(*cell) and fetched(*cell) == 256
+    assert kernel_runs(*cell) and fetched(*cell) == 128
     sink = jax.ShapeDtypeStruct((16,), jnp.float32)     # the XLA form's
     assert not kernel_runs(*cell, sink) and fetched(*cell, sink=True) == 1024
     flat = struct(8, 64, 192), struct(1, 8, 3200, 768), \
         struct(1, 8, 3200, 512)
-    assert kernel_runs(*flat) and fetched(*flat) == 640
+    assert kernel_runs(*flat) and fetched(*flat) == 128
     for q, k, v in (
             (struct(8, 16, 128), struct(2, 8, 1000, 16, 128),
              struct(2, 8, 1000, 16, 128)),          # rows
@@ -600,9 +676,6 @@ def test_decode_attention_takes_the_kernel_by_platform_and_shape(
             (struct(8, 12, 128), struct(2, 8, 1024, 12, 128),
              struct(2, 8, 1024, 12, 128))):         # 12 rows a position
         assert not kernel_runs(q, k, v) and fetched(q, k, v) == k.shape[2]
-    with pytest.raises(ValueError, match="both block_k and rows_per_step"):
-        decode_attention(*(jnp.zeros(s.shape, s.dtype) for s in cell),
-                         jnp.int32(0), jnp.zeros(8, jnp.int32), block_k=128)
 
 
 def test_decode_attention_with_a_sink_is_the_xla_form():
