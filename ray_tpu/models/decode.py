@@ -1,59 +1,18 @@
 """Cached autoregressive decoding for the flagship transformer: a
 slot's state between tokens, of the kinds its layers keep.
 
-**Rows or a summary, one cache.** The cache is a dict of tuples, each
-with one entry for each run of alike layers (``transformer.layer_runs``;
-a model whose layers are all alike has one run), and a run's entry is
-an array in the tuples of its own kind of state and ``None`` in the
-others (in a run of periods of several layers the entry is itself a
-tuple, one such array or ``None`` for each layer of the period):
-
-* an attention run keeps *rows*: ``cache["k"][r]`` ``[L, slots, rows,
-  H, Dh]`` and ``cache["v"][r]`` ``[.., Dv]`` (``[L, slots, rows,
-  G * Dh]``, the K/V heads side by side in a row, where G of them serve
-  more query heads each: ``init_slot_cache``), one row a position, read
-  up to the slot's decode offset ``cache["pos"]`` ``[slots]``. A run of
-  full-attention layers has ``max_len`` rows. A run of window layers
-  has ``window`` rows, a ring: position p lives in row ``p % window``,
-  ``slot_prefill`` writes a prompt's last ``window`` positions there,
-  and ``slot_decode_step`` writes row ``pos % window`` and attends the
-  rows filled so far (rope is applied before the write, so the order of
-  the rows does not matter), with the layer's sink logit in the
-  denominator where it has one;
-* a Mamba run keeps a *summary*: ``cache["ssm"][r]`` ``[L, slots, N,
-  C]`` float32, the selective scan's state after the slot's last token,
-  and ``cache["conv"][r]`` ``[L, K - 1, slots, C]`` at the model's
-  dtype, the last K - 1 inputs of its causal convolution (ops/ssm.py;
-  the slots beside the channels, so that the two dimensions the chip
-  tiles are whole multiples of a tile and not K - 1 = 3 rows). It has no rows and no position: it does not grow, a
-  token's step replaces it whole, and what it was before cannot be read
-  back. A model without Mamba layers has neither tuple;
-* a retention run keeps a summary too, a matrix a K/V head:
-  ``cache["ret"][r]`` ``[L, slots, G, Dv, D]`` float32, the head's
-  state S after the slot's last token (D = ``ops.retention.feature_dim``
-  of the head's width: 8,320 at 128), and ``cache["ret_z"][r]``
-  ``[L, slots, G, D]``, its normaliser. **A model of such layers alone
-  has no K/V at all**: no ``k``, no ``v``, no ring, and nothing in its
-  cache is sized by ``max_len``.
-
-**Layers that keep nothing.** A gated memory unit and a cross layer
-have no entry anywhere: the first gates the *memory*, the scan output
-(before its gate) of the last Mamba layer before it at the same
-position, which both programs carry from layer to layer and from run to
-run beside x and which no one keeps between tokens; the second
-projects queries alone and attends **the K and V of the last
-full-attention layer before it, in that layer's own cache**: one cache
-that grows, written by one layer and read by every cross layer behind
-it. ``slot_decode_step`` hands ``decode_attention`` the full layer's
-run's arrays as they lie after that run's in-place write of the step's
-token (the carry of another run's scan, closed over: no slice, no
-copy), with that layer's index; ``slot_prefill`` hands a cross layer
-the prompt's K and V as the full layer made them.
-
-A model with expert layers carries ``cache["load"]``, int32 [3]: the
-held experts that got a row, the rows routed to held experts and the
-fullest expert's rows in the last decode step, each summed over the
-expert layers, for the engine to fetch with the step's tokens.
+**One table, one entry a kind of mixer.** ``KINDS`` holds all that the
+serving side knows of each kind of ``transformer.MIXERS`` (``Kind``):
+the tuples of the cache its state lies in and their shapes, its layer
+of each program, the scopes its decode step names, and what the
+programs and the engine ask of it (rows or a summary, what it lends or
+borrows). The cache is a dict of those tuples, each with one entry a
+run of alike layers (``transformer.layer_runs``): an array where the
+run keeps that state, ``None`` where it does not (in a run of periods
+of several layers, a tuple of them, one a layer of the period). A model
+with expert layers carries ``cache["load"]``, int32 [3], the last
+decode step's counts (``_tally``), for the engine to fetch with the
+step's tokens.
 
 Continuous batching (serve/decode_scheduler.py) needs exactly this
 form: one sequence prefills into an open slot while the other slots
@@ -61,54 +20,9 @@ keep stepping, and a finished slot frees at once. Whole-batch
 generation (``generate``) is its all-rows-active case. Every shape is
 static, so serving is two compiled programs, each a ``lax.scan`` of
 ``transformer.block`` (the one definition of the layer) over each run's
-stacked layers and their index, with the run's whole state as the
-scan's carry:
-
-* ``slot_prefill`` runs a prompt through the block with the training
-  forward's rope, attention and scan (the flash and ``ssm_scan``
-  kernels on TPU, XLA off it) and writes into the carry at ``(layer,
-  slot)`` the roped K/V of the prompt's positions, or the state and the
-  convolution's tail after its last one. Where the model ends in
-  layers that mix nothing over the sequence (gated memory units, cross
-  layers) it runs in two stages: the whole prompt up to the last layer
-  that does mix, whose K and V alone are made at every position, and
-  the last position alone from there on (``prefill_stages``);
-* ``slot_decode_step`` feeds each row one token: an attention layer
-  ropes it at the row's own position, scatters its K/V into the carry
-  at ``[layer, rows, pos]`` and attends the layer's K/V, read out of
-  the carry after that write; a Mamba layer reads the rows' state out
-  of the carry, advances it by one position and writes it back (no
-  recompute, no dynamic shapes); a retention layer hands the run's
-  whole S and z to ``ops.retention.retention_step``, which on the TPU
-  is one kernel that reads each live row's state once and writes it
-  once where it lies, and touches no other row.
-
-**Which runs attend through which form.** A run of full-attention
-layers attends through
-``ops.attention.decode_attention``: the run's whole K and V, as the
-carry holds them after the write, with the layer's index and the rows'
-``pos``. On the TPU that is the kernel ``decode_attend``, which copies
-a row's K and V chunk by chunk up to the chunk that holds ``pos`` and
-nothing past it (no slice of a layer is made to feed it: the carry is
-its operand); elsewhere, for a shape the kernel has no chunk for and
-for a layer with a sink, it is ``cached_attention`` over the layer's slice under a per-row mask,
-which reads all ``max_len`` rows. A run of window layers is bounded by
-its ring and keeps ``cached_attention`` (with the layer's sink) on
-every platform. Both forms lean on the one invariant below: **a row
-past a slot's ``pos`` is never attended**, so a reused slot's stale
-tail and an idle row's garbage stay unread; the kernel does not even
-copy them, and zeroes what its last chunk holds of them.
-
-**The picked token stays on the device.** ``cache["tok"]``, int32
-[slots], is each row's last pick (:func:`pick`, the one place a token
-is chosen: ``slot_prefill`` picks a prompt's first token into its
-slot's entry, ``slot_decode_step`` each active row's next one,
-``generate`` draws through it). A step is told what to feed a row by
-one int32 a row: a token id, ``CARRY`` for the row's own last pick, or
-``IDLE``. So the serving engine dispatches step n + 1 before the host
-has seen step n's tokens: they are read where they were made, and what
-the step hands back for the host is the row of picks (with the three
-counts behind it), never ``[slots, vocab]`` logits.
+stacked layers and their index. ``cache["tok"]`` is each row's last
+pick (:func:`pick`), so the serving engine dispatches step n + 1
+before the host has seen step n's tokens (``slot_decode_step``).
 
 The cache is one set of buffers, written in place. Both programs take
 it donated, and every run's state is carried through the layers' scan,
@@ -161,11 +75,9 @@ Invariants the scheduler relies on:
 
 **Which part of the block an operation of a decode step computes** is
 in the compiled step's metadata: every stretch of ``slot_decode_step``
-is traced under the scope of its part (``transformer.PARTS``) and of its
-run of layers, and :func:`program_parts` reads the compiled text into
-``{instruction: [run, part]}``, the table a device trace is joined with
-(``JaxSlotEngine.parts()``, serve/decode_scheduler.py). A prefill has
-the block's own scopes and no table yet.
+is traced under the scope of its part (``transformer.PARTS``) and of
+its run of layers (:func:`program_parts`). A prefill has the block's
+own scopes and no table yet.
 
 Oracle: greedy decoding must match the per-step argmax of the FULL
 forward() on the growing prefix, which shares no cache code —
@@ -179,23 +91,19 @@ from __future__ import annotations
 
 import functools
 import re
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, NamedTuple,
+                    Optional, Tuple)
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.transformer import (BORROWERS, CROSS, EXPERTS,
-                                        FROM_THE_START, FULL, GMU,
-                                        LAYER_WEIGHTS, MAMBA,
-                                        PARTS, RETENTION, SUMMARIES, WINDOW,
-                                        TransformerConfig, block, kind_rope,
-                                        last_position,
-                                        layer_runs, layer_stacks,
-                                        no_rotation, nothing_lent,
-                                        one_period, period_of,
-                                        retain_from_the_start, roped_kinds,
-                                        run_layers, scan_run, unembed)
+from ray_tpu.models.transformer import (
+    CROSS, EXPERTS, FROM_THE_START, FULL, GMU, LAYER_WEIGHTS, MAMBA, PARTS,
+    RETENTION, WINDOW, TransformerConfig, block, kind_rope, last_position,
+    layer_runs, layer_stacks, no_rotation, nothing_lent, one_period,
+    period_of, retain_from_the_start, roped_kinds, run_layers, scan_run,
+    unembed)
 from ray_tpu.ops import retention, ssm
 from ray_tpu.ops.attention import (cached_attention, decode_attention,
                                    decode_rows_fetched, flash_attention)
@@ -210,177 +118,341 @@ CARRY, IDLE = -1, -2
 
 def pick(logits, key=None, temperature=None):
     """The next token of each row of ``logits`` [.., V], int32: the
-    likeliest, or one drawn at ``temperature`` where a key is given."""
+    likeliest, or one drawn at ``temperature`` where a key is given. The
+    one place a token is chosen: ``slot_prefill`` picks a prompt's first
+    token into its slot's ``cache["tok"]``, ``slot_decode_step`` each
+    active row's next one, ``generate`` draws through it."""
     if key is None:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return jax.random.categorical(key, logits / temperature).astype(jnp.int32)
 
 
-def _by_layer(runs, make) -> tuple:
-    """One entry a run of ``make(mixer, layers in the run)``, and for a
-    run of periods of several layers a tuple of them, one for each
-    layer of the period: the form of each of the cache's tuples."""
-    return tuple(
-        make(kind[0], n) if isinstance(kind[0], str)
-        else tuple(make(mixer, n) for mixer, _ in kind)
-        for kind, n in runs)
+# ------------------------------------------- the table of mixer kinds
+
+class Lent(NamedTuple):
+    """What travels from layer to layer beside x, None where the model
+    has no reader of it, and no one keeps between tokens: the *memory*,
+    the last Mamba layer's scan output before its gate, which gated
+    memory units read; the last full-attention layer's K and V, which
+    cross layers attend (a prefill's: the prompt's; a step's: its run's
+    whole arrays behind that run's in-place write, the carry of another
+    run's scan, closed over: no slice, no copy) with ``layer``, its
+    index in them; and a step's expert counts ``load``."""
+    memory: Any = None
+    k: Any = None
+    v: Any = None
+    layer: Any = None
+    load: Any = None
 
 
-def init_slot_cache(cfg: TransformerConfig, slots: int,
-                    max_len: int) -> Dict:
-    """The cache of ``slots`` sequences, each with a decode offset of
-    its own: tuples with one entry a run of alike layers (one in all
-    where the layers are all alike), ``None`` where the run keeps no
-    state of that kind; in a run of periods of several layers the entry
-    is a tuple, one for each layer of the period. ``k`` and ``v``: an
-    attention run's rows, a
-    window run at ``cfg.window`` of them. Where every query head has a
-    K/V head of its own a row is [H, Dh]; where G K/V heads serve more
-    query heads each it is flat, [G * Dh], the heads side by side: rows
-    of 768 or 1536 values tile the chip's memory as they are, which
-    [4, 192] or [8, 192] do not (the compiler's own layouts for those
-    cost two copies of K a step). ``ssm`` and ``conv``, in a model with
-    Mamba layers: a Mamba run's state [n, slots, N, C] float32 and its
-    convolution's tail [n, K - 1, slots, C]. ``ret`` and ``ret_z``, in
-    a model with retention layers: a retention run's state
-    [n, slots, G, Dv, D] and normaliser [n, slots, G, D], float32. A
-    model with no attention layer has no ``k`` and no ``v``; a gated
-    memory unit and a cross layer keep nothing anywhere."""
-    runs = layer_runs(cfg)
-
-    def rows(width):
-        def made(mixer, n):
-            if mixer in SUMMARIES + BORROWERS:
-                return None
-            G = cfg.kv_heads(mixer)
-            row = (G, width) if G == cfg.n_heads else (G * width,)
-            return jnp.zeros(
-                (n, slots, cfg.window if mixer == WINDOW else max_len)
-                + row, cfg.dtype)
-        return _by_layer(runs, made)
-
-    def summaries(of, shape, dtype):
-        return _by_layer(runs, lambda mixer, n: jnp.zeros(
-            (n,) + shape, dtype) if mixer == of else None)
-
-    mixers = {mixer for _, _, mixer, _ in run_layers(runs)}
-    cache = {"pos": jnp.zeros((slots,), jnp.int32),
-             "tok": jnp.zeros((slots,), jnp.int32)}
-    if mixers & {FULL, WINDOW}:
-        cache.update(k=rows(cfg.head_dim), v=rows(cfg.v_dim))
-    if cfg.has_mamba:
-        cache["ssm"] = summaries(
-            MAMBA, (slots, cfg.ssm_state, cfg.ssm_inner), jnp.float32)
-        cache["conv"] = summaries(
-            MAMBA, (cfg.ssm_conv - 1, slots, cfg.ssm_inner), cfg.dtype)
-    if cfg.has_retention:
-        G, D = cfg.kv_heads(RETENTION), retention.feature_dim(cfg.head_dim)
-        cache["ret"] = summaries(RETENTION, (slots, G, cfg.v_dim, D),
-                                 jnp.float32)
-        cache["ret_z"] = summaries(RETENTION, (slots, G, D), jnp.float32)
-    if any(ffn == EXPERTS for _, _, _, ffn in run_layers(runs)):
-        cache["load"] = jnp.zeros((3,), jnp.int32)
-    return cache
+class Context(NamedTuple):
+    """What every layer of one call of a program shares, made once at
+    its top: each roped kind's ``rope`` (a prefill's over the prompt's
+    positions, a step's at each row's own), the score scale, a prefill's
+    ``slot``; a step's rows' ``pos`` and ``active``, the ring's rows
+    ``filled`` so far, ``rows``, the in-place write's row index, and
+    ``where``, its row in a layer's rows (``Kind.within``)."""
+    cfg: TransformerConfig
+    ropes: Dict[str, Callable]
+    sm_scale: float
+    slot: Any = None
+    pos: Any = None
+    active: Any = None
+    filled: Any = None
+    rows: Any = None
+    where: Any = None
 
 
-# which two tuples of the cache hold a layer's state, by its mixer
-ROWS, SUMMARY, RETAINED = ("k", "v"), ("ssm", "conv"), ("ret", "ret_z")
+class Kind:
+    """All that the serving side knows of one kind of mixer, an entry of
+    ``KINDS``; this base keeps, lends and borrows nothing. ``state``:
+    the cache's tuples that hold its state, ``shapes`` a (shape, dtype)
+    for each, of a run of ``n`` such layers. ``prefill`` and ``step``:
+    its layer of each program, ``(at, mixer, x, lent, lp, i, first,
+    second, last=False) -> (x, lent, first, second)``: ``at`` the call's
+    ``Context``, ``lp`` layer i's weights, ``first`` and ``second`` its
+    run's whole state arrays (None where it keeps none), written at
+    layer i, ``last`` a prefill's stage change (``prefill_stages``).
+    ``parts``: the scopes its decode step names. ``rows``: its state is
+    a row a position, K and V, ``max_len`` of them where it ``grows``,
+    a ring of the window where not. ``lends`` and ``borrows``: what it
+    hands the layers behind it, what it reads of one before it in the
+    same pass (keeping nothing, mixing nothing over the sequence)."""
+    state: Tuple[str, ...] = ()
+    parts: Tuple[str, ...] = ()
+    rows = grows = False
+    lends = borrows = ""
+
+    def within(self, at, mixer):
+        """A step's ``at`` for its layer, made before its run's scan."""
+        return at
+
+    def lend(self, cfg, lent, k, v, layer=None):
+        """``lent`` with what the layer lends of its state ``k`` and
+        ``v`` (a step's: ``layer``, where they lie in them)."""
+        return lent
+
+    def gather(self, second):
+        """What a prefill's run carries through its scan in the place of
+        the layer's second array, and ``put`` writes behind the scan."""
+        return second
+
+    def put(self, second, gathered, slot):
+        return gathered
 
 
-def _state_names(mixer: str):
-    if mixer in BORROWERS:
-        return ()
-    return {MAMBA: SUMMARY, RETENTION: RETAINED}.get(mixer, ROWS)
+class _Attention(Kind):
+    state, rows = ("k", "v"), True
+
+    def shapes(self, cfg, mixer, n, slots, max_len):
+        """``max_len`` rows a slot, or a ring of ``cfg.window``. Where
+        every query head has a K/V head of its own a row is [H, Dh];
+        where G K/V heads serve more query heads each it is flat,
+        [G * Dh], the heads side by side: rows of 768 or 1536 values
+        tile the chip's memory as they are, which [4, 192] or [8, 192]
+        do not (the compiler's own layouts for those cost two copies of
+        K a step)."""
+        G = cfg.kv_heads(mixer)
+        length = max_len if self.grows else cfg.window
+        return tuple(((n, slots, length) + ((G, width) if G == cfg.n_heads
+                                            else (G * width,)), cfg.dtype)
+                     for width in (cfg.head_dim, cfg.v_dim))
+
+    def prefill(self, at, attention, x, lent, lp, i, ck, cv, last=False):
+        # ck/cv: the whole [L, slots, rows, G, Dh]
+        window = None if self.grows else at.cfg.window
+
+        def attend(q, k, v):
+            # the training forward's local attention, so the last
+            # token's logits are forward()'s; the roped k and v are
+            # what a later step attends
+            with jax.named_scope(f"{attention}_attention"):
+                return flash_attention(
+                    q, k, v, causal=True, sm_scale=at.sm_scale,
+                    window=window, sink=lp.get("sink")), (k, v)
+
+        x, (k, v), _ = block(lp, x, at.ropes.get(attention, no_rotation),
+                             attend, at.cfg, last=last)
+        ck = lax.dynamic_update_slice(
+            ck, _cache_rows(k, ck, window)[None],
+            (i, at.slot) + (0,) * (ck.ndim - 2))
+        cv = lax.dynamic_update_slice(
+            cv, _cache_rows(v, cv, window)[None],
+            (i, at.slot) + (0,) * (cv.ndim - 2))
+        return x, self.lend(at.cfg, lent, k, v), ck, cv
+
+    def within(self, at, mixer):
+        """The row a step writes each row's token at: its ``pos``, of a
+        ring ``pos % window``."""
+        return at._replace(
+            where=at.pos if self.grows else at.pos % at.cfg.window)
+
+    def step(self, at, attention, x, lent, lp, i, ck, cv, last=False):
+        """A full-attention layer attends through
+        ``ops.attention.decode_attention`` (on the TPU the kernel
+        ``decode_attend``, which copies a row's K and V out of the carry
+        up to the chunk that holds its ``pos``), a window layer, bounded
+        by its ring, through ``cached_attention`` with its sink on every
+        platform. Both lean on the invariant that **a row past a slot's
+        ``pos`` is never attended**, so a reused slot's stale tail and
+        an idle row's garbage stay unread."""
+        # ck/cv: the whole [L, B, rows, G, Dh]
+        B = x.shape[0]
+
+        def attend(q, k, v):
+            with jax.named_scope(f"{attention}_attention"):
+                # write, then attend: the layer's K/V are read out of
+                # the carry after the rows' new token is in it
+                nk = ck.at[i, at.rows, at.where].set(
+                    k[:, 0].reshape((B,) + ck.shape[3:]).astype(ck.dtype))
+                nv = cv.at[i, at.rows, at.where].set(
+                    v[:, 0].reshape((B,) + cv.shape[3:]).astype(cv.dtype))
+                if self.grows:
+                    # the carry itself is the operand: no slice of
+                    # a layer feeds the kernel
+                    o = decode_attention(
+                        q[:, 0], nk, nv, i, at.pos, sm_scale=at.sm_scale,
+                        sink=lp.get("sink"))
+                else:
+                    o = cached_attention(
+                        q[:, 0],
+                        lax.dynamic_index_in_dim(nk, i, keepdims=False),
+                        lax.dynamic_index_in_dim(nv, i, keepdims=False),
+                        at.filled, at.sm_scale, lp.get("sink"))
+            return o, (nk, nv)
+
+        x, (ck, cv), got = block(lp, x, at.ropes.get(attention, no_rotation),
+                                 attend, at.cfg)
+        lent = self.lend(at.cfg, lent, ck, cv, i)
+        return x, lent._replace(load=_tally(lent.load, got)), ck, cv
 
 
-def _cache_runs(cache: Dict, runs):
-    """Each run's state: a tuple with one pair of arrays for each layer
-    of the run's period (one pair, then, for a run of alike layers):
-    (K, V) of an attention layer, (state, tail) of a Mamba layer,
-    (S, z) of a retention layer, (None, None) of a layer that keeps
-    nothing."""
-    names = {name for _, _, mixer, _ in run_layers(runs)
-             for name in _state_names(mixer)}
-    for name in sorted(names):
-        if not (isinstance(cache.get(name), tuple)
-                and len(cache[name]) == len(runs)):
-            raise ValueError(
-                f"the cache holds a tuple cache[{name!r}], one array for "
-                f"each of the model's {len(runs)} runs of alike layers "
-                f"(None where a run keeps no such state: init_slot_cache)")
+class _Full(_Attention):
+    parts = ("qkv", "full_attention", "attn_out")
+    grows, lends = True, "kv"
 
-    def pair(mixer, held):
-        names = _state_names(mixer)
-        return tuple(held(name) for name in names) if names else (None, None)
-
-    return [(pair(kind[0], lambda name: cache[name][r]),)
-            if isinstance(kind[0], str)
-            else tuple(pair(mixer, lambda name: cache[name][r][j])
-                       for j, (mixer, _) in enumerate(kind))
-            for r, (kind, _) in enumerate(runs)]
+    def lend(self, cfg, lent, k, v, layer=None):
+        # where the model has cross layers, they attend these K and V
+        return lent._replace(k=k, v=v, layer=layer) if cfg.has_cross else lent
 
 
-def _with_states(cache: Dict, runs, states, **more) -> Dict:
-    """``cache`` with each run's state replaced (the form of
-    ``_cache_runs``)."""
-    new = {name: [None] * len(runs) for name in ROWS + SUMMARY + RETAINED
-           if name in cache}
-    for r, ((kind, _), state) in enumerate(zip(runs, states)):
-        layers = [dict(zip(_state_names(mixer), pair))
-                  for (mixer, _), pair in zip(period_of(kind), state)]
-        for name in new:
-            held = tuple(layer.get(name) for layer in layers)
-            new[name][r] = held[0] if isinstance(kind[0], str) else held
-    return dict(cache, **{name: tuple(held) for name, held in new.items()},
-                **more)
+class _Window(_Attention):
+    parts = ("qkv", "window_attention", "attn_out")
 
 
-def _layer_states(runs, states):
-    """[(mixer, (first, second)), ...] of every layer of one period of
-    every run, in the layers' order."""
-    return [(mixer, pair) for (kind, _), state in zip(runs, states)
-            for (mixer, _), pair in zip(period_of(kind), state)]
+class _Mamba(Kind):
+    state, parts = ("ssm", "conv"), ("mamba_mixer", "ssm_step")
+
+    def shapes(self, cfg, mixer, n, slots, max_len):
+        """The state [n, slots, N, C] float32, the selective scan's
+        after the slot's last token, and the convolution's tail [n,
+        K - 1, slots, C] at the model's dtype, the last K - 1 inputs
+        (ops/ssm.py; the slots beside the channels, so that the two
+        dimensions the chip tiles are whole multiples of a tile and not
+        K - 1 = 3 rows)."""
+        return (((n, slots, cfg.ssm_state, cfg.ssm_inner), jnp.float32),
+                ((n, cfg.ssm_conv - 1, slots, cfg.ssm_inner), cfg.dtype))
+
+    def prefill(self, at, mixer, x, lent, lp, i, cs, tails, last=False):
+        # cs: the whole [L, slots, N, C]
+        # the training forward's convolution and scan
+        x, (tail, state, *memory), _ = block(lp, x, None, FROM_THE_START,
+                                             at.cfg)
+        cs = lax.dynamic_update_slice(
+            cs, state[None].astype(cs.dtype), (i, at.slot, 0, 0))
+        tails = lax.dynamic_update_slice(
+            tails, tail.swapaxes(0, 1)[None].astype(tails.dtype),
+            (i, 0, 0, 0))
+        return (x, lent._replace(memory=memory[0]) if memory else lent,
+                cs, tails)
+
+    # a prefill's tails are gathered [L, K - 1, 1, C] and written into
+    # the slot once, behind the scan: carried through it, the tails'
+    # array (rows of the model's dtype, written at a slot that is no
+    # multiple of a tile) is copied whole on the way in and on the way
+    # out
+    def gather(self, tails):
+        return jnp.zeros(tails.shape[:2] + (1,) + tails.shape[3:],
+                         tails.dtype)
+
+    def put(self, tails, gathered, slot):
+        return lax.dynamic_update_slice(tails, gathered, (0, 0, slot, 0))
+
+    def step(self, at, mixer, x, lent, lp, i, cs, cc, last=False):
+        """The rows' state read out of the carry, advanced by one
+        position and written back: no recompute, no dynamic shapes."""
+        # [L, B, N, C] and [L, K-1, B, C]
+        with jax.named_scope("mamba_mixer"):
+            tail = lax.dynamic_index_in_dim(
+                cc, i, keepdims=False).swapaxes(0, 1)   # [B, K-1, C]
+
+        def conv(u, w, b):
+            return ssm.causal_conv(u, w, b, tail)
+
+        def step(u, dt, A, b, c, D):
+            with jax.named_scope("ssm_step"):
+                # the carry itself is the operand: a row's state is
+                # read once, advanced and written where it lies, an
+                # inactive row's bit for bit what it was
+                y, ns = ssm.carried_step(
+                    u[:, 0], dt[:, 0], A, b[:, 0], c[:, 0], D, cs, i,
+                    at.active)
+            return y[:, None], ns
+
+        x, (new_tail, cs, *made), got = block(
+            lp, x, None, ssm.Recurrence(conv, step), at.cfg)
+        # ... and so its convolution's tail
+        with jax.named_scope("mamba_mixer"):
+            cc = lax.dynamic_update_slice(cc, jnp.where(
+                at.active[:, None, None], new_tail.astype(cc.dtype),
+                tail).swapaxes(0, 1)[None], (i, 0, 0, 0))
+        return (x, lent._replace(load=_tally(lent.load, got),
+                                 memory=made[0] if made else lent.memory),
+                cs, cc)
 
 
-def _max_len(cfg: TransformerConfig, runs, states) -> int:
-    """Rows of a full-attention run's cache: the longest sequence a slot
-    holds (``cfg.max_seq`` where no layer keeps all its rows)."""
-    return next((ck.shape[2] for mixer, (ck, _) in _layer_states(runs, states)
-                 if mixer == FULL), cfg.max_seq)
+class _Retention(Kind):
+    state, parts = ("ret", "ret_z"), ("qkv", "retention_step", "attn_out")
+
+    def shapes(self, cfg, mixer, n, slots, max_len):
+        """The state, a matrix a K/V head, [n, slots, G, Dv, D] float32,
+        the head's S after the slot's last token (D =
+        ``ops.retention.feature_dim`` of the head's width: 8,320 at
+        128), and its normaliser z [n, slots, G, D]. A model of such
+        layers alone keeps nothing sized by ``max_len``."""
+        G, D = cfg.kv_heads(mixer), retention.feature_dim(cfg.head_dim)
+        return (((n, slots, G, cfg.v_dim, D), jnp.float32),
+                ((n, slots, G, D), jnp.float32))
+
+    def prefill(self, at, mixer, x, lent, lp, i, cs, cz, last=False):
+        # the whole [L, slots, G, Dv, D], [.., G, D]
+        # the training forward's retention, from nothing before it;
+        # the slot's state is the prompt's and nothing else's
+        x, (S, z), _ = block(lp, x, at.ropes.get(mixer, no_rotation),
+                             retain_from_the_start, at.cfg)
+        cs = lax.dynamic_update_slice(cs, S[None], (i, at.slot, 0, 0, 0))
+        cz = lax.dynamic_update_slice(cz, z[None], (i, at.slot, 0, 0))
+        return x, lent, cs, cz
+
+    def step(self, at, mixer, x, lent, lp, i, cs, cz, last=False):
+        # [L, B, G, Dv, D] and [L, B, G, D]
+        def retain(q, k, v, g):
+            with jax.named_scope("retention_step"):
+                # the carry itself is the operand: a live row's
+                # state is read and written where it lies, an
+                # inactive row's not at all
+                o, ns, nz = retention.retention_step(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], cs, cz, i,
+                    at.active)
+            return o[:, None], (ns, nz)
+
+        x, (cs, cz), got = block(lp, x, at.ropes.get(mixer, no_rotation),
+                                 retain, at.cfg)
+        return x, lent._replace(load=_tally(lent.load, got)), cs, cz
 
 
-def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
-    """How many positions of a slot a full-attention layer of
-    ``slot_decode_step`` fetches at a time from this cache: the chunk
-    ``ops.attention.decode_attention``'s kernel copies, all ``max_len``
-    where its XLA form runs, None for a model without such a layer. A
-    slot stepped at position p reads ``(p // n + 1) * n`` of its rows a
-    layer, which is what ``JaxSlotEngine`` counts from its host mirror."""
-    runs = layer_runs(cfg)
-    for mixer, (ck, cv) in _layer_states(runs, _cache_runs(cache, runs)):
-        if mixer == FULL:
-            q = jax.ShapeDtypeStruct(
-                (ck.shape[1], cfg.n_heads, cfg.head_dim), ck.dtype)
-            return decode_rows_fetched(q, ck, cv,
-                                       sink=mixer in cfg.sink_kinds)
-    return None
+class _GatedMemory(Kind):
+    parts, borrows = ("gmu",), "memory"
+
+    def prefill(self, at, mixer, x, lent, lp, i, first, second, last=False):
+        """Either program's layer, on the memory it is lent."""
+        x, _, got = block(lp, x, None, lent.memory, at.cfg)
+        return x, lent._replace(load=_tally(lent.load, got)), first, second
+
+    step = prefill
 
 
-def kv_readers(cfg: TransformerConfig) -> int:
-    """How many layers of a decode step read a full-attention layer's
-    rows, for each layer that holds such rows: 1, and with cross
-    layers, which read the rows of the full layer before them, as many
-    more as that layer lends to (8 where seven cross layers share one
-    cache)."""
-    mixers = [mixer for mixer, _ in cfg.layer_kinds or ()]
-    return 1 + mixers.count(CROSS) // max(1, mixers.count(FULL))
+class _Cross(Kind):
+    parts, borrows = ("qkv", "cross_attention", "attn_out"), "kv"
+
+    def prefill(self, at, mixer, x, lent, lp, i, first, second, last=False):
+        def attend(q, k, v):
+            # every position its own prefix of the full layer's K/V, or
+            # the last position, one query, all of it
+            with jax.named_scope(f"{mixer}_attention"):
+                return flash_attention(q, lent.k, lent.v, causal=True,
+                                       sm_scale=at.sm_scale), None
+
+        x, _, _ = block(lp, x, at.ropes.get(mixer, no_rotation), attend,
+                        at.cfg)
+        return x, lent, first, second
+
+    def step(self, at, mixer, x, lent, lp, i, first, second, last=False):
+        def attend(q, k, v):
+            with jax.named_scope(f"{mixer}_attention"):
+                # the full layer's carry is the operand, as it lies
+                # behind that layer's write of this step's token
+                return decode_attention(q[:, 0], lent.k, lent.v,
+                                        lent.layer, at.pos,
+                                        sm_scale=at.sm_scale), None
+
+        x, _, got = block(lp, x, at.ropes.get(mixer, no_rotation), attend,
+                          at.cfg)
+        return x, lent._replace(load=_tally(lent.load, got)), first, second
 
 
-def keeps_summaries(cfg: TransformerConfig) -> bool:
-    """Whether a layer of ``cfg`` keeps a summary a slot (a Mamba
-    layer's state, a retention layer's): every row a decode step steps
-    then has that state read and written whole, whatever its length."""
-    return cfg.has_mamba or cfg.has_retention
+KINDS = {FULL: _Full(), WINDOW: _Window(), MAMBA: _Mamba(),
+         RETENTION: _Retention(), GMU: _GatedMemory(), CROSS: _Cross()}
 
 
 def _cache_rows(t, like, window: Optional[int]):
@@ -396,32 +468,151 @@ def _cache_rows(t, like, window: Optional[int]):
 
 def _tally(load, got):
     """``load`` with an expert layer's counts added: held experts that
-    got a row, rows routed to them, the fullest one's rows."""
-    if got is None:
+    got a row, rows routed to them, the fullest one's rows; summed over
+    a step's expert layers (a prefill counts none: ``load`` None)."""
+    if got is None or load is None:
         return load
     with jax.named_scope("experts"):
         return load + jnp.stack([jnp.sum(got > 0), jnp.sum(got),
                                  jnp.max(got)])
 
 
+# ------------------------------------------------------------ the cache
+
+def init_slot_cache(cfg: TransformerConfig, slots: int,
+                    max_len: int) -> Dict:
+    """The cache of ``slots`` sequences, each with a decode offset of
+    its own: a tuple for each name of state that the kinds of the
+    model's layers keep (``KINDS``), in the form the module says."""
+    runs = layer_runs(cfg)
+
+    def zeros(mixer, n):
+        kind = KINDS[mixer]
+        if not kind.state:
+            return None, None
+        return tuple(jnp.zeros(shape, dtype) for shape, dtype in
+                     kind.shapes(cfg, mixer, n, slots, max_len))
+
+    cache = _with_states(
+        {"pos": jnp.zeros((slots,), jnp.int32),
+         "tok": jnp.zeros((slots,), jnp.int32)}, runs,
+        [tuple(zeros(mixer, n) for mixer, _ in period_of(kind))
+         for kind, n in runs])
+    if any(ffn == EXPERTS for _, _, _, ffn in run_layers(runs)):
+        cache["load"] = jnp.zeros((3,), jnp.int32)
+    return cache
+
+
+def _cache_runs(cache: Dict, runs):
+    """Each run's state: a tuple with one pair of arrays for each layer
+    of the run's period (one pair, then, for a run of alike layers),
+    its kind's ``state``, or (None, None)."""
+    names = {name for _, _, mixer, _ in run_layers(runs)
+             for name in KINDS[mixer].state}
+    for name in sorted(names):
+        if not (isinstance(cache.get(name), tuple)
+                and len(cache[name]) == len(runs)):
+            raise ValueError(
+                f"the cache holds a tuple cache[{name!r}], one array for "
+                f"each of the model's {len(runs)} runs of alike layers "
+                f"(None where a run keeps no such state: init_slot_cache)")
+
+    def pair(mixer, held):
+        return tuple(map(held, KINDS[mixer].state)) or (None, None)
+
+    return [(pair(kind[0], lambda name: cache[name][r]),)
+            if isinstance(kind[0], str)
+            else tuple(pair(mixer, lambda name: cache[name][r][j])
+                       for j, (mixer, _) in enumerate(kind))
+            for r, (kind, _) in enumerate(runs)]
+
+
+def _with_states(cache: Dict, runs, states, **more) -> Dict:
+    """``cache`` with each run's state (the form of ``_cache_runs``)
+    in its tuples."""
+    new = {name: [None] * len(runs) for _, _, mixer, _ in run_layers(runs)
+           for name in KINDS[mixer].state}
+    for r, ((kind, _), state) in enumerate(zip(runs, states)):
+        layers = [dict(zip(KINDS[mixer].state, pair))
+                  for (mixer, _), pair in zip(period_of(kind), state)]
+        for name in new:
+            held = tuple(layer.get(name) for layer in layers)
+            new[name][r] = held[0] if isinstance(kind[0], str) else held
+    return dict(cache, **{name: tuple(held) for name, held in new.items()},
+                **more)
+
+
+def _grown(runs, states):
+    """(mixer, (K, V)) of the first layer whose rows grow with the
+    sequence, a full-attention layer's; (None, None) where none does."""
+    return next(((mixer, pair) for (kind, _), state in zip(runs, states)
+                 for (mixer, _), pair in zip(period_of(kind), state)
+                 if KINDS[mixer].grows), (None, None))
+
+
+def _max_len(cfg: TransformerConfig, runs, states) -> int:
+    """Rows of a full-attention run's cache: the longest sequence a slot
+    holds (``cfg.max_seq`` where no layer keeps all its rows)."""
+    _, grown = _grown(runs, states)
+    return cfg.max_seq if grown is None else grown[0].shape[2]
+
+
+def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
+    """How many positions of a slot a full-attention layer of
+    ``slot_decode_step`` fetches at a time from this cache: the chunk
+    ``ops.attention.decode_attention``'s kernel copies, all ``max_len``
+    where its XLA form runs, None for a model without such a layer. A
+    slot stepped at position p reads ``(p // n + 1) * n`` of its rows a
+    layer, which is what ``JaxSlotEngine`` counts from its host mirror."""
+    runs = layer_runs(cfg)
+    mixer, grown = _grown(runs, _cache_runs(cache, runs))
+    if grown is None:
+        return None
+    ck, cv = grown
+    q = jax.ShapeDtypeStruct((ck.shape[1], cfg.n_heads, cfg.head_dim),
+                             ck.dtype)
+    return decode_rows_fetched(q, ck, cv, sink=mixer in cfg.sink_kinds)
+
+
+def kv_readers(cfg: TransformerConfig) -> int:
+    """How many layers of a decode step read a full-attention layer's
+    rows, for each layer that holds such rows: 1, and with cross
+    layers, which read the rows of the full layer before them, as many
+    more as that layer lends to (8 where seven cross layers share one
+    cache)."""
+    kinds = [KINDS[mixer] for mixer, _ in cfg.layer_kinds or ()]
+    return 1 + sum(kind.borrows == "kv" for kind in kinds) // max(
+        1, sum(kind.lends == "kv" for kind in kinds))
+
+
+def keeps_summaries(cfg: TransformerConfig) -> bool:
+    """Whether a layer of ``cfg`` keeps a summary a slot (a Mamba
+    layer's state, a retention layer's): every row a decode step steps
+    then has that state read and written whole, whatever its length."""
+    return any(KINDS[mixer].state and not KINDS[mixer].rows
+               for mixer, _ in cfg.layer_kinds or ())
+
+
+# ------------------------------------------------------- the two programs
+
 def prefill_stages(cfg: TransformerConfig) -> Tuple[int, Optional[int]]:
     """Where a prefill stops running the whole prompt: (the first layer
     that runs at the last position alone, the layer handed ``last`` or
     None). Behind the last layer that mixes over the sequence (every
-    layer from there on a gated memory unit or a cross layer) nothing
-    needs any position's activations but the last's, whose logits the
-    prefill is for. That last mixing layer itself, if it is an
-    attention layer, makes its K and V at every position (the cache's,
-    and what the cross layers attend) and everything else of itself at
-    the last (``block``'s ``last``). A model with no such layers runs
-    every layer at every position: (n_layers, None)."""
-    mixers = [mixer for mixer, _ in cfg.layer_kinds or ()]
-    tail = len(mixers)
-    while tail and mixers[tail - 1] in BORROWERS:
+    layer from there on one that borrows) nothing needs any position's
+    activations but the last's, whose logits the prefill is for. That
+    last mixing layer itself, if it keeps rows, makes its K and V at
+    every position (the cache's, and what the cross layers attend) and
+    everything else of itself at the last (``block``'s ``last``). A
+    model with no such layers runs every layer at every position:
+    (n_layers, None)."""
+    kinds = [KINDS[mixer] for mixer, _ in cfg.layer_kinds or ()]
+    tail = len(kinds)
+    while tail and kinds[tail - 1].borrows:
         tail -= 1
-    if tail == len(mixers) or tail == 0:
+    if tail == len(kinds) or tail == 0:
         return cfg.n_layers, None
-    return tail, tail - 1 if mixers[tail - 1] in (FULL, WINDOW) else None
+    return tail, tail - 1 if kinds[tail - 1].rows else None
 
 
 def _changes_stage(cfg: TransformerConfig, depth: int, n: int,
@@ -429,7 +620,10 @@ def _changes_stage(cfg: TransformerConfig, depth: int, n: int,
     """Whether the run of ``n`` periods of ``period`` layers whose
     first layer is layer ``depth`` is the one inside which a prefill
     goes on at the last position alone: it holds the change and is one
-    period, which needs no scan."""
+    period, which needs no scan. The stage changes inside such a run or
+    between runs (a scan's carry keeps its shape): a run of several
+    periods that holds the change is run whole at every position and
+    cut behind it."""
     tail, split = prefill_stages(cfg)
     last = depth + n * period - 1
     return n == 1 and tail < cfg.n_layers and (
@@ -467,11 +661,10 @@ def _either_stage(whole, cos, sin, T0: int):
 
 
 def _at_the_last(x, lent):
-    """x [1, T, D] and the memory beside it (``lent``'s first) cut to
-    the last position; the lent K and V stay whole."""
-    return last_position(x), tuple(
-        t if t is None or at else last_position(t)
-        for at, t in enumerate(lent))
+    """x [1, T, D] and the memory beside it cut to the last position;
+    the lent K and V stay whole."""
+    return last_position(x), lent._replace(
+        memory=None if lent.memory is None else last_position(lent.memory))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -480,8 +673,9 @@ def slot_prefill(params, tokens, cache: Dict, slot,
                  cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
     """Run one prompt [1, T0] through the stack, leaving in cache row
     ``slot`` (a traced index: one compiled program serves every slot)
-    each attention layer's K/V and each Mamba layer's state and tail
-    after the prompt's last position. Returns (last-token logits
+    each layer's state after the prompt's last position: the roped K/V
+    of the prompt's positions (of a ring, its last ``window``), a
+    summary computed from zeros. Returns (last-token logits
     [1, V], cache), the token picked from them in
     ``cache["tok"][slot]``; the cache given is consumed and the one
     returned is its memory, updated in place. Compiles once per
@@ -489,20 +683,14 @@ def slot_prefill(params, tokens, cache: Dict, slot,
     if retrace cost matters.
 
     **Two stages, where the model ends in layers that mix nothing over
-    the sequence** (``prefill_stages``): the whole prompt through the
-    layers up to the last one that does, of which an attention layer
-    makes its K and V at every position and the rest of itself at the
-    last; then the last position alone, x [1, 1, D] and the memory
-    beside it, through the gated memory units and cross layers behind
-    it, each cross layer's one query attending the K and V just made.
-    The stage changes inside a run of one period or between runs (a
-    scan's carry keeps its shape): a run of several periods that holds
-    the change is run whole at every position and cut behind it."""
+    the sequence** (``prefill_stages``): the whole prompt up to the last
+    layer that does, then the last position alone, x [1, 1, D] and the
+    memory beside it, each cross layer's one query attending the K and
+    V just made (``_changes_stage``)."""
     _, T0 = tokens.shape
     runs = layer_runs(cfg)
     states = _cache_runs(cache, runs)
     max_len = _max_len(cfg, runs, states)
-    sm_scale = cfg.head_dim ** -0.5
     ropes = {}
     for attention in roped_kinds(cfg, runs):
         cos, sin = kind_rope(cfg, attention, max_len)
@@ -510,81 +698,8 @@ def slot_prefill(params, tokens, cache: Dict, slot,
             apply_rotary, cos=cos, sin=sin, positions=jnp.arange(T0))
         if cfg.lends:
             ropes[attention] = _either_stage(ropes[attention], cos, sin, T0)
+    at = Context(cfg, ropes, cfg.head_dim ** -0.5, slot=slot)
     x = params["embed"][tokens]
-
-    # a layer of each kind: (x, lent, lp, i, first, second, last) ->
-    # (x, lent, first, second); ``lent`` (the memory, the last full
-    # layer's K, its V), ``first`` and ``second`` the layer's run's
-    # whole state arrays, written at (i, slot)
-    def attention_layer(attention):
-        window = cfg.window if attention == WINDOW else None
-
-        def layer(x, lent, lp, i, ck, cv, last=False):
-            # ck/cv: the whole [L, slots, rows, G, Dh]
-            def attend(q, k, v):
-                # the training forward's local attention, so the last
-                # token's logits are forward()'s; the roped k and v are
-                # what a later step attends
-                with jax.named_scope(f"{attention}_attention"):
-                    return flash_attention(
-                        q, k, v, causal=True, sm_scale=sm_scale,
-                        window=window, sink=lp.get("sink")), (k, v)
-
-            x, (k, v), _ = block(lp, x, ropes.get(attention, no_rotation),
-                                 attend, cfg, last=last)
-            ck = lax.dynamic_update_slice(
-                ck, _cache_rows(k, ck, window)[None],
-                (i, slot) + (0,) * (ck.ndim - 2))
-            cv = lax.dynamic_update_slice(
-                cv, _cache_rows(v, cv, window)[None],
-                (i, slot) + (0,) * (cv.ndim - 2))
-            if attention == FULL and cfg.has_cross:
-                lent = (lent[0], k, v)
-            return x, lent, ck, cv
-
-        return layer
-
-    def mamba_layer(x, lent, lp, i, cs, tails, last=False):
-        # cs: the whole [L, slots, N, C]
-        # the training forward's convolution and scan
-        x, (tail, state, *memory), _ = block(lp, x, None, FROM_THE_START,
-                                             cfg)
-        cs = lax.dynamic_update_slice(
-            cs, state[None].astype(cs.dtype), (i, slot, 0, 0))
-        tails = lax.dynamic_update_slice(
-            tails, tail.swapaxes(0, 1)[None].astype(tails.dtype),
-            (i, 0, 0, 0))
-        return x, (*memory, *lent[len(memory):]), cs, tails
-
-    def retention_layer(x, lent, lp, i, cs, cz, last=False):
-        # the whole [L, slots, G, Dv, D], [.., G, D]
-        # the training forward's retention, from nothing before it;
-        # the slot's state is the prompt's and nothing else's
-        x, (S, z), _ = block(lp, x, ropes.get(RETENTION, no_rotation),
-                             retain_from_the_start, cfg)
-        cs = lax.dynamic_update_slice(cs, S[None], (i, slot, 0, 0, 0))
-        cz = lax.dynamic_update_slice(cz, z[None], (i, slot, 0, 0))
-        return x, lent, cs, cz
-
-    def gmu_layer(x, lent, lp, i, first, second, last=False):
-        return block(lp, x, None, lent[0], cfg)[0], lent, first, second
-
-    def cross_layer(x, lent, lp, i, first, second, last=False):
-        def attend(q, k, v):
-            # every position its own prefix of the full layer's K/V, or
-            # the last position, one query, all of it
-            with jax.named_scope("cross_attention"):
-                return flash_attention(q, lent[1], lent[2], causal=True,
-                                       sm_scale=sm_scale), None
-
-        x, _, _ = block(lp, x, ropes.get(CROSS, no_rotation), attend, cfg)
-        return x, lent, first, second
-
-    def layer_of(mixer):
-        return {MAMBA: mamba_layer, RETENTION: retention_layer,
-                GMU: gmu_layer, CROSS: cross_layer}.get(
-                    mixer) or attention_layer(mixer)
-
     tail, split = prefill_stages(cfg)
 
     def run(x, lent, kind, layers, state, depth, n):
@@ -593,51 +708,43 @@ def slot_prefill(params, tokens, cache: Dict, slot,
         state as two tuples, each layer of the period's first array and
         its second."""
         period = period_of(kind)
-        layer = [layer_of(mixer) for mixer, _ in period]
+        kinds = [KINDS[mixer] for mixer, _ in period]
+        layer = [functools.partial(kind.prefill, at, mixer)
+                 for kind, (mixer, _) in zip(kinds, period)]
         firsts, held = zip(*state)
-        # a Mamba layer's tails are gathered [L, K - 1, 1, C] and
-        # written into the slot once, behind the scan: carried through
-        # it, the tails' array (rows of the model's dtype, written at a
-        # slot that is no multiple of a tile) is copied whole on the
-        # way in and on the way out
-        seconds = tuple(
-            jnp.zeros(cc.shape[:2] + (1,) + cc.shape[3:], cc.dtype)
-            if mixer == MAMBA else cc
-            for (mixer, _), cc in zip(period, held))
+        seconds = tuple(kind.gather(cc) for kind, cc in zip(kinds, held))
 
         def body(carry, lps, i, staged=False):
-            x, firsts, seconds, *lent = carry
-            firsts, seconds, lent = list(firsts), list(seconds), tuple(lent)
+            x, firsts, seconds, lent = carry
+            firsts, seconds = list(firsts), list(seconds)
             for j, lp in enumerate(lps if len(period) > 1 else (lps,)):
-                at = depth + i * len(period) + j
+                here = depth + i * len(period) + j
                 if cfg.differential:
-                    lp = dict(lp, depth=at)
-                if staged and at == tail and x.shape[1] > 1:
+                    lp = dict(lp, depth=here)
+                if staged and here == tail and x.shape[1] > 1:
                     x, lent = _at_the_last(x, lent)
                 x, lent, firsts[j], seconds[j] = layer[j](
                     x, lent, lp, i, firsts[j], seconds[j],
-                    last=staged and at == split)
-                if staged and at == split:
+                    last=staged and here == split)
+                if staged and here == split:
                     x, lent = _at_the_last(x, lent)
-            return (x, tuple(firsts), tuple(seconds), *lent)
+            return (x, tuple(firsts), tuple(seconds), lent)
 
         if depth >= tail and x.shape[1] > 1:
             x, lent = _at_the_last(x, lent)
-        carry = (x, firsts, seconds, *lent)
+        carry = (x, firsts, seconds, lent)
         if _changes_stage(cfg, depth, n, len(period)):
             # one period, no scan: the stage may change inside it
-            x, firsts, seconds, *lent = body(
+            x, firsts, seconds, lent = body(
                 carry, one_period(layers), 0, staged=True)
         else:
-            x, firsts, seconds, *lent = scan_run(body, carry, layers)
-        seconds = tuple(
-            lax.dynamic_update_slice(cc, tails, (0, 0, slot, 0))
-            if mixer == MAMBA else tails
-            for (mixer, _), cc, tails in zip(period, held, seconds))
-        return x, tuple(lent), tuple(zip(firsts, seconds))
+            x, firsts, seconds, lent = scan_run(body, carry, layers)
+        seconds = tuple(kind.put(cc, made, slot)
+                        for kind, cc, made in zip(kinds, held, seconds))
+        return x, lent, tuple(zip(firsts, seconds))
 
     new, depth = [], 0
-    lent = nothing_lent(cfg, x) if cfg.lends else (None, None, None)
+    lent = Lent(*nothing_lent(cfg, x))
     for ((kind, layers), (_, n)), state in zip(
             zip(layer_stacks(params, cfg), runs), states):
         x, lent, state = run(x, lent, kind, layers, state, depth, n)
@@ -654,18 +761,14 @@ def slot_prefill(params, tokens, cache: Dict, slot,
 def slot_decode_step(params, cache: Dict, token, active,
                      cfg: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
     """One continuous-batching step: each ACTIVE row is fed one token,
-    attends its own prefix (positions ``[0, pos]``, and of the cache no
-    row past them: ``ops.attention.decode_attention``; in a window
-    layer the last ``window`` positions of it, under a mask) and
-    advances its Mamba layers'
-    state by it, advances its own pos and picks its next token into
-    ``cache["tok"]``. Inactive rows are free riders — their logits are
-    garbage, their pos, tok and Mamba state frozen. ``token`` int32 [B]
-    says what a row is fed: a token id, or ``CARRY`` for the row's own
-    last pick, which never left the device. The cache given is consumed
-    and the one returned is its memory, with one position a row and
-    attention layer written in place and every Mamba layer's state
-    replaced where it lay.
+    attends its own prefix (positions ``[0, pos]``: ``_Attention.step``)
+    and advances its layers' summaries by it, advances its own pos and
+    picks its next token into ``cache["tok"]``. Inactive rows are free riders
+    — their logits are garbage, their pos, tok and summaries frozen.
+    ``token`` int32 [B] says what a row is fed: a token id, or ``CARRY``
+    for the row's own last pick, which never left the device. The cache
+    given is consumed and the one returned is its memory, written in
+    place.
 
     Two callers, two forms. With ``active`` bool [B]: (next-token
     logits [B, V], cache), for a caller that draws the token itself
@@ -690,7 +793,6 @@ def slot_decode_step(params, cache: Dict, token, active,
                   for a in roped_kinds(cfg, runs)}
     with jax.named_scope("embed"):
         x = params["embed"][token][:, None, :]  # [B, 1, D]
-    sm_scale = cfg.head_dim ** -0.5
     # row r attends positions [0, pos[r]] (pos[r] is written this
     # step): a full-attention run hands ``decode_attention`` the
     # positions themselves; of a ring, the rows filled so far, all once
@@ -701,157 +803,54 @@ def slot_decode_step(params, cache: Dict, token, active,
     with jax.named_scope("full_attention"):
         rows = jnp.arange(B)    # of the cache's in-place write, either kind
 
-    def row_rope(mixer):
-        if mixer not in tables:
-            return no_rotation
-        cos, sin = tables[mixer]
-
+    def row_rope(cos, sin):
         def rope(t):  # every row at its own position
             return rotate(t, cos[pos][:, None, None, :],
                           sin[pos][:, None, None, :])
         return rope
 
-    # a layer of each kind: (x, load, memory, shared, lp, i, first,
-    # second) -> (x, load, memory, shared, first, second); ``memory``
-    # the rows' memory [B, 1, C] (None in a model without gated memory
-    # units), ``shared`` (K, V, layer) of the last full-attention layer
-    # as they lie in its run's carry, behind that run's write
-    def attention_layer(attention):
-        window = cfg.window if attention == WINDOW else None
-        at = pos if window is None else pos % window
-        rope = row_rope(attention)
+    at = Context(cfg, {a: row_rope(*t) for a, t in tables.items()},
+                 cfg.head_dim ** -0.5, pos=pos, active=active,
+                 filled=filled, rows=rows)
 
-        def layer(x, load, memory, shared, lp, i, ck, cv):
-            # ck/cv: the whole [L, B, rows, G, Dh]
-            def attend(q, k, v):
-                with jax.named_scope(f"{attention}_attention"):
-                    # write, then attend: the layer's K/V are read out of
-                    # the carry after the rows' new token is in it
-                    nk = ck.at[i, rows, at].set(
-                        k[:, 0].reshape((B,) + ck.shape[3:]).astype(ck.dtype))
-                    nv = cv.at[i, rows, at].set(
-                        v[:, 0].reshape((B,) + cv.shape[3:]).astype(cv.dtype))
-                    if window is None:
-                        # the carry itself is the operand: no slice of
-                        # a layer feeds the kernel
-                        o = decode_attention(
-                            q[:, 0], nk, nv, i, pos, sm_scale=sm_scale,
-                            sink=lp.get("sink"))
-                    else:
-                        o = cached_attention(
-                            q[:, 0],
-                            lax.dynamic_index_in_dim(nk, i, keepdims=False),
-                            lax.dynamic_index_in_dim(nv, i, keepdims=False),
-                            filled, sm_scale, lp.get("sink"))
-                return o, (nk, nv)
-
-            x, (ck, cv), got = block(lp, x, rope, attend, cfg)
-            if attention == FULL and cfg.has_cross:
-                shared = (ck, cv, i)
-            return x, _tally(load, got), memory, shared, ck, cv
-
-        return layer
-
-    def mamba_layer(x, load, memory, shared, lp, i, cs, cc):
-        # [L, B, N, C] and [L, K-1, B, C]
-        with jax.named_scope("mamba_mixer"):
-            tail = lax.dynamic_index_in_dim(
-                cc, i, keepdims=False).swapaxes(0, 1)   # [B, K-1, C]
-
-        def conv(u, w, b):
-            return ssm.causal_conv(u, w, b, tail)
-
-        def step(u, dt, A, b, c, D):
-            with jax.named_scope("ssm_step"):
-                # the carry itself is the operand: a row's state is
-                # read once, advanced and written where it lies, an
-                # inactive row's bit for bit what it was
-                y, ns = ssm.carried_step(
-                    u[:, 0], dt[:, 0], A, b[:, 0], c[:, 0], D, cs, i,
-                    active)
-            return y[:, None], ns
-
-        x, (new_tail, cs, *made), got = block(
-            lp, x, None, ssm.Recurrence(conv, step), cfg)
-        # ... and so its convolution's tail
-        with jax.named_scope("mamba_mixer"):
-            cc = lax.dynamic_update_slice(cc, jnp.where(
-                active[:, None, None], new_tail.astype(cc.dtype),
-                tail).swapaxes(0, 1)[None], (i, 0, 0, 0))
-        return (x, _tally(load, got), made[0] if made else memory, shared,
-                cs, cc)
-
-    def retention_layer(x, load, memory, shared, lp, i, cs, cz):
-        # [L, B, G, Dv, D] and [L, B, G, D]
-        def retain(q, k, v, g):
-            with jax.named_scope("retention_step"):
-                # the carry itself is the operand: a live row's
-                # state is read and written where it lies, an
-                # inactive row's not at all
-                o, ns, nz = retention.retention_step(
-                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], cs, cz, i,
-                    active)
-            return o[:, None], (ns, nz)
-
-        x, (cs, cz), got = block(lp, x, row_rope(RETENTION), retain, cfg)
-        return x, _tally(load, got), memory, shared, cs, cz
-
-    def gmu_layer(x, load, memory, shared, lp, i, first, second):
-        x, _, got = block(lp, x, None, memory, cfg)
-        return x, _tally(load, got), memory, shared, first, second
-
-    def cross_layer(x, load, memory, shared, lp, i, first, second):
-        def attend(q, k, v):
-            with jax.named_scope("cross_attention"):
-                # the full layer's carry is the operand, as it lies
-                # behind that layer's write of this step's token
-                sk, sv, layer = shared
-                return decode_attention(q[:, 0], sk, sv, layer, pos,
-                                        sm_scale=sm_scale), None
-
-        x, _, got = block(lp, x, row_rope(CROSS), attend, cfg)
-        return x, _tally(load, got), memory, shared, first, second
-
-    def layer_of(mixer):
-        return {MAMBA: mamba_layer, RETENTION: retention_layer,
-                GMU: gmu_layer, CROSS: cross_layer}.get(
-                    mixer) or attention_layer(mixer)
-
-    def run(x, load, memory, shared, kind, layers, state, depth):
+    def run(x, lent, kind, layers, state, depth, n):
         period = period_of(kind)
-        layer = [layer_of(mixer) for mixer, _ in period]
+        kinds = [KINDS[mixer] for mixer, _ in period]
+        layer = [functools.partial(kind.step, kind.within(at, mixer), mixer)
+                 for kind, (mixer, _) in zip(kinds, period)]
 
         def body(carry, lps, i):
             x, firsts, seconds, load, memory = carry
-            firsts, seconds, within = list(firsts), list(seconds), shared
+            firsts, seconds = list(firsts), list(seconds)
+            within = lent._replace(load=load, memory=memory)
             for j, lp in enumerate(lps if len(period) > 1 else (lps,)):
                 if cfg.differential:
                     lp = dict(lp, depth=depth + i * len(period) + j)
-                x, load, memory, within, firsts[j], seconds[j] = layer[j](
-                    x, load, memory, within, lp, i, firsts[j], seconds[j])
-            return x, tuple(firsts), tuple(seconds), load, memory
+                x, within, firsts[j], seconds[j] = layer[j](
+                    x, within, lp, i, firsts[j], seconds[j])
+            return x, tuple(firsts), tuple(seconds), within.load, \
+                within.memory
 
         x, firsts, seconds, load, memory = scan_run(
-            body, (x, *zip(*state), load, memory), layers)
-        full = [j for j, (mixer, _) in enumerate(period) if mixer == FULL]
-        if full and cfg.has_cross:
-            # the run's last full layer, as the run leaves it
-            shared = (firsts[full[-1]], seconds[full[-1]],
-                      firsts[full[-1]].shape[0] - 1)
-        return x, load, memory, shared, tuple(zip(firsts, seconds))
+            body, (x, *zip(*state), lent.load, lent.memory), layers)
+        lent = lent._replace(load=load, memory=memory)
+        for kind, first, second in zip(kinds, firsts, seconds):
+            # what the run's layers lend, as the run leaves them
+            lent = kind.lend(cfg, lent, first, second, n - 1)
+        return x, lent, tuple(zip(firsts, seconds))
 
     load = jnp.zeros_like(cache["load"]) if "load" in cache else None
     with jax.named_scope("gmu"):
-        memory = nothing_lent(cfg, x)[0] if cfg.has_gmu else None
-    new, depth, shared = [], 0, None
+        lent = Lent(memory=nothing_lent(cfg, x)[0], load=load)
+    new, depth = [], 0
     for r, (((kind, layers), (_, n)), state) in enumerate(zip(
             zip(layer_stacks(params, cfg), runs), states)):
         with jax.named_scope(f"run{r}"):
-            x, load, memory, shared, state = run(
-                x, load, memory, shared, kind, layers, state, depth)
+            x, lent, state = run(x, lent, kind, layers, state, depth, n)
         new.append(state)
         depth += n * len(period_of(kind))
     logits = unembed(params, x[:, 0], eps=cfg.norm_eps)
+    load = lent.load
     with jax.named_scope("head"):
         tok = jnp.where(active, pick(logits), cache["tok"])
         cache = _with_states(cache, runs, new,
@@ -959,11 +958,7 @@ def decode_parts(cfg: TransformerConfig) -> List[str]:
     runs = layer_runs(cfg)
     want = {"embed", "head"} | {f"run{r}" for r in range(len(runs))}
     for _, _, mixer, ffn in run_layers(runs):
-        want |= ({"mamba_mixer", "ssm_step"} if mixer == MAMBA
-                 else {"gmu"} if mixer == GMU
-                 else {"qkv", "retention_step", "attn_out"}
-                 if mixer == RETENTION
-                 else {"qkv", f"{mixer}_attention", "attn_out"})
+        want |= set(KINDS[mixer].parts)
         want |= {"router", "experts"} if ffn == EXPERTS else {"mlp"}
     return sorted(want)
 

@@ -213,8 +213,8 @@ def test_the_decode_kernel_compiles_for_v5e_under_its_own_name(
         lambda: decode.init_slot_cache(cfg, slots, slot_len))
     assert decode.kv_rows_fetched(cfg, cache) == DECODE_CHUNKS[cell]
     runs = layer_runs(cfg)
-    full = next(pair for mixer, pair in decode._layer_states(
-        runs, decode._cache_runs(cache, runs)) if mixer == "full")
+    mixer, full = decode._grown(runs, decode._cache_runs(cache, runs))
+    assert mixer == "full"
 
     def array(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -327,6 +327,41 @@ def test_a_row_left_out_of_a_step_keeps_every_state_bit_for_bit():
     assert int(after["tok"][1]) == int(before["tok"][1])
 
 
+# the smallest model with a layer of each kind of mixer
+SMALLEST = {F: (F,), W: (W,), M: (M,), R: (R,), G: (M, G), X: (F, X)}
+
+
+@pytest.mark.parametrize("mixer", transformer.MIXERS)
+def test_a_kinds_entry_is_what_the_cache_and_the_parts_read(mixer):
+    """Every kind of mixer has its entry in ``decode.KINDS``; in the
+    smallest model with such a layer the cache holds the tuples of the
+    model's kinds' entries and no other, that kind's run exactly the
+    entry's arrays at the entry's shapes, and the decode step names the
+    entry's parts."""
+    assert set(decode.KINDS) == set(transformer.MIXERS)
+    kind = decode.KINDS[mixer]
+    cfg = hybrid(SMALLEST[mixer], differential=mixer != R)
+    slots, max_len = 3, 48
+    cache = jax.eval_shape(lambda: decode.init_slot_cache(cfg, slots, max_len))
+    present = {m for m, _ in cfg.layer_kinds}
+    assert set(cache) - {"pos", "tok"} == {
+        name for m in present for name in decode.KINDS[m].state}
+    runs = [(r, n) for r, (run, n) in enumerate(layer_runs(cfg))
+            if run[0] == mixer]
+    assert runs
+    for r, n in runs:
+        held = {name: cache[name][r] for name in cache
+                if isinstance(cache[name], tuple)}
+        assert {name for name, t in held.items() if t is not None} == set(
+            kind.state)
+        want = kind.shapes(cfg, mixer, n, slots, max_len) if kind.state \
+            else ()
+        assert [(held[name].shape, held[name].dtype) for name in kind.state] \
+            == [(tuple(shape), jnp.dtype(dtype)) for shape, dtype in want]
+    assert set(kind.parts) <= set(PARTS)
+    assert set(kind.parts) <= set(decode.decode_parts(cfg))
+
+
 # -------------------------------------------------- the engine's counters
 
 def test_the_engine_counts_the_shared_caches_readers_and_the_second_stage():
