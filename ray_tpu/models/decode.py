@@ -105,8 +105,8 @@ from ray_tpu.models.transformer import (
     period_of, retain_from_the_start, roped_kinds, run_layers, scan_run,
     unembed)
 from ray_tpu.ops import retention, ssm
-from ray_tpu.ops.attention import (cached_attention, decode_attention,
-                                   decode_rows_fetched, flash_attention)
+from ray_tpu.ops.attention import (decode_attention, decode_rows_fetched,
+                                   flash_attention)
 from ray_tpu.ops.rotary import apply_rotary, rotate
 
 
@@ -149,16 +149,15 @@ class Context(NamedTuple):
     """What every layer of one call of a program shares, made once at
     its top: each roped kind's ``rope`` (a prefill's over the prompt's
     positions, a step's at each row's own), the score scale, a prefill's
-    ``slot``; a step's rows' ``pos`` and ``active``, the ring's rows
-    ``filled`` so far, ``rows``, the in-place write's row index, and
-    ``where``, its row in a layer's rows (``Kind.within``)."""
+    ``slot``; a step's rows' ``pos`` and ``active``, ``rows``, the
+    in-place write's row index, and ``where``, its row in a layer's rows
+    (``Kind.within``)."""
     cfg: TransformerConfig
     ropes: Dict[str, Callable]
     sm_scale: float
     slot: Any = None
     pos: Any = None
     active: Any = None
-    filled: Any = None
     rows: Any = None
     where: Any = None
 
@@ -248,37 +247,31 @@ class _Attention(Kind):
             where=at.pos if self.grows else at.pos % at.cfg.window)
 
     def step(self, at, attention, x, lent, lp, i, ck, cv, last=False):
-        """A full-attention layer attends through
-        ``ops.attention.decode_attention`` (on the TPU the kernel
-        ``decode_attend``, which copies a row's K and V out of the carry
-        up to the chunk that holds its ``pos``), a window layer, bounded
-        by its ring, through ``cached_attention`` with its sink on every
-        platform. Both lean on the invariant that **a row past a slot's
-        ``pos`` is never attended**, so a reused slot's stale tail and
-        an idle row's garbage stay unread."""
+        """A layer attends through ``ops.attention.decode_attention``,
+        with its sink where it has one: on the TPU the kernel, which
+        copies a row's K and V out of the carry up to the chunk that
+        holds its position, ``decode_attend`` over a full layer's
+        growing rows, ``decode_ring`` over a window layer's ring, whose
+        rows ``[0, min(pos, window - 1)]`` hold the window. Both lean on
+        the invariant that **a row past a slot's ``pos`` is never
+        attended**, so a reused slot's stale tail and an idle row's
+        garbage stay unread."""
         # ck/cv: the whole [L, B, rows, G, Dh]
         B = x.shape[0]
 
         def attend(q, k, v):
             with jax.named_scope(f"{attention}_attention"):
                 # write, then attend: the layer's K/V are read out of
-                # the carry after the rows' new token is in it
+                # the carry after the rows' new token is in it; the
+                # carry itself is the operand: no slice of a layer
+                # feeds the kernel
                 nk = ck.at[i, at.rows, at.where].set(
                     k[:, 0].reshape((B,) + ck.shape[3:]).astype(ck.dtype))
                 nv = cv.at[i, at.rows, at.where].set(
                     v[:, 0].reshape((B,) + cv.shape[3:]).astype(cv.dtype))
-                if self.grows:
-                    # the carry itself is the operand: no slice of
-                    # a layer feeds the kernel
-                    o = decode_attention(
-                        q[:, 0], nk, nv, i, at.pos, sm_scale=at.sm_scale,
-                        sink=lp.get("sink"))
-                else:
-                    o = cached_attention(
-                        q[:, 0],
-                        lax.dynamic_index_in_dim(nk, i, keepdims=False),
-                        lax.dynamic_index_in_dim(nv, i, keepdims=False),
-                        at.filled, at.sm_scale, lp.get("sink"))
+                o = decode_attention(
+                    q[:, 0], nk, nv, i, at.pos, sm_scale=at.sm_scale,
+                    sink=lp.get("sink"), ring=not self.grows)
             return o, (nk, nv)
 
         x, (ck, cv), got = block(lp, x, at.ropes.get(attention, no_rotation),
@@ -563,15 +556,16 @@ def kv_rows_fetched(cfg: TransformerConfig, cache: Dict) -> Optional[int]:
     ``ops.attention.decode_attention``'s kernel copies, all ``max_len``
     where its XLA form runs, None for a model without such a layer. A
     slot stepped at position p reads ``(p // n + 1) * n`` of its rows a
-    layer, which is what ``JaxSlotEngine`` counts from its host mirror."""
+    layer, which is what ``JaxSlotEngine`` counts from its host mirror
+    (growing caches only: a window layer's ring is not counted)."""
     runs = layer_runs(cfg)
-    mixer, grown = _grown(runs, _cache_runs(cache, runs))
+    _, grown = _grown(runs, _cache_runs(cache, runs))
     if grown is None:
         return None
     ck, cv = grown
     q = jax.ShapeDtypeStruct((ck.shape[1], cfg.n_heads, cfg.head_dim),
                              ck.dtype)
-    return decode_rows_fetched(q, ck, cv, sink=mixer in cfg.sink_kinds)
+    return decode_rows_fetched(q, ck, cv)
 
 
 def kv_readers(cfg: TransformerConfig) -> int:
@@ -794,12 +788,7 @@ def slot_decode_step(params, cache: Dict, token, active,
     with jax.named_scope("embed"):
         x = params["embed"][token][:, None, :]  # [B, 1, D]
     # row r attends positions [0, pos[r]] (pos[r] is written this
-    # step): a full-attention run hands ``decode_attention`` the
-    # positions themselves; of a ring, the rows filled so far, all once
-    # pos[r] has passed the window
-    with jax.named_scope("window_attention"):
-        filled = jnp.arange(cfg.window or 0)[None, None, :] \
-            <= pos[:, None, None]                           # [B, 1, rows]
+    # step; ``decode_attention`` clips them to a ring's rows)
     with jax.named_scope("full_attention"):
         rows = jnp.arange(B)    # of the cache's in-place write, either kind
 
@@ -810,8 +799,7 @@ def slot_decode_step(params, cache: Dict, token, active,
         return rope
 
     at = Context(cfg, {a: row_rope(*t) for a, t in tables.items()},
-                 cfg.head_dim ** -0.5, pos=pos, active=active,
-                 filled=filled, rows=rows)
+                 cfg.head_dim ** -0.5, pos=pos, active=active, rows=rows)
 
     def run(x, lent, kind, layers, state, depth, n):
         period = period_of(kind)

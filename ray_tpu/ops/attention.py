@@ -38,10 +38,11 @@ none of these: a gradient through such a call recomputes ``attention``
 (the XLA form) and differentiates that.
 
 A decode step has a kernel of its own, ``decode_attention`` (at the
-end of this file): one new token a slot against a run's whole cache,
-each slot's K and V copied chunk by chunk up to its own position and no
-further; ``cached_attention`` is its XLA form and oracle, and the form
-of a window layer's ring.
+end of this file): one new token a slot against a run's whole cache, a
+full-attention run's growing rows or a window run's ring, each slot's K
+and V copied chunk by chunk up to its own position and no further, a
+sink folded into the denominator; ``cached_attention`` is its XLA form
+and oracle.
 
 Reference-parity note: the reference snapshot has no attention kernels
 at all (SURVEY.md §5.7 — absent); this op underpins the TPU-native
@@ -662,7 +663,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 # ------------------------------------------------ one new token a row
 
-DECODE_KERNEL = "decode_attend"     # the pallas_call's ``name=``
+# the pallas_call's ``name=``: over a growing cache, over a window's ring
+DECODE_KERNEL, DECODE_RING = "decode_attend", "decode_ring"
 # the least K and V, in bytes, of a row's chunk; chunks in VMEM at a time
 _DECODE_CHUNK_BYTES = 2 ** 18
 _DECODE_DEPTH = 3
@@ -703,10 +705,9 @@ def cached_attention(q, lk, lv, valid, sm_scale, sink=None):
     Every row of lk and lv is read, whatever ``valid`` says, and a row
     that is not valid weighs exactly 0: what lies there must be finite
     (0 times NaN is NaN), which a cache that is only ever written with
-    K and V is. This is the form of a window run's ring (bounded by the
-    window, with its sink) on every platform, of a full-attention run
-    wherever ``decode_attention`` does not take the kernel, and the
-    kernel's oracle."""
+    K and V is. This is the form of a run's cache, growing rows or a
+    window's ring, wherever ``decode_attention`` does not take the
+    kernel, and the kernel's oracle."""
     B, H, D = q.shape
     grouped = lk.ndim == 3
     if grouped:
@@ -733,21 +734,27 @@ def cached_attention(q, lk, lv, valid, sm_scale, sink=None):
     return o.astype(q.dtype)
 
 
-def _decode_kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-                   v_buf, sems, count_ref, m_ref, l_ref, acc_ref, *, sm_scale,
-                   chunk, stride):
+def _decode_kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, *refs, sm_scale,
+                   chunk, stride, has_sink=False, per=None):
     """Grid step b of a decode step's attention over the carried cache:
     row b up to its own position. Prefetched: layer_ref [1], the layer
-    of the run, and pos_ref [slots], the position each slot's new token
-    was written at.
+    of the run, and pos_ref [slots], the last position each slot
+    attends (its new token's; of a ring, that clipped to the ring's
+    last row).
 
     q_ref [H, C] and o_ref [H, Cv] are the row's blocks of the grid;
+    with ``has_sink`` sink_ref [H, 128] comes before o_ref, each head's
+    sink logit in every lane;
     k_hbm [L, slots, N, C] and v_hbm [L, slots, N, Cv] are the run's
     whole arrays where they lie, and the kernel copies a row's chunks of
     n = chunk * stride of its rows itself: ``stride`` 1 for flat rows
     (C the whole row, q widened to it), H where a position is H rows of
     one head each (column c of the scores is then position c // H of
-    head c % H, and a query head keeps its own head's columns).
+    head c % H, and a query head keeps its own head's columns). With
+    ``per`` (query heads a K/V head) the kernel widens the queries
+    itself: q_ref is [H, D], each row's queries are widened once into
+    the scratch qw_ref [H, C] (last of the scratch), and o_ref [H, Dv]
+    takes each head's own part of the [H, G * Dv] the products make.
 
     The kernel takes the chunks in one order, row after row, each row's
     from its first to ``pos // chunk`` and none past it, and keeps
@@ -762,10 +769,14 @@ def _decode_kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     m_ref, l_ref [H, w] and acc_ref [H, Cv] as the forward kernel keeps
     them; only the last chunk masks the columns past the position, and
     zeroes the value rows there, so that nothing a stale tail holds, NaN
-    included, reaches the output."""
+    included, reaches the output. The sink joins the float32 state once,
+    after the last chunk, as ``_flash_kernel``'s ``_finish`` has it."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
+    if has_sink:
+        sink_ref, *refs = refs
+    o_ref, k_buf, v_buf, sems, count_ref, m_ref, l_ref, acc_ref, *qw = refs
     b, slots = pl.program_id(0), pl.num_programs(0)
     n, depth = chunk * stride, k_buf.shape[0]
 
@@ -808,7 +819,8 @@ def _decode_kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
         issue(row, ahead, (c + depth - 1) % depth)
         for copy in copies(b, i, c % depth):
             copy.wait()
-        q, k, v = q_ref[...], k_buf[c % depth], v_buf[c % depth]
+        q = (qw[0] if qw else q_ref)[...]
+        k, v = k_buf[c % depth], v_buf[c % depth]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [H, n]
@@ -828,6 +840,16 @@ def _decode_kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
         _softmax_step(s, v, m_ref, l_ref, acc_ref)
         return c + 1
 
+    if per is not None:
+        # query head h is K/V head h // per's: its row of the widened
+        # queries is zero outside that head's D columns
+        G = k_buf.shape[-1] // q_ref.shape[-1]
+        head = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[0], 1), 0)
+        own = [(head >= g * per) & (head < (g + 1) * per) for g in range(G)]
+        q = q_ref[...]
+        qw[0][...] = jnp.concatenate(
+            [jnp.where(own[g], q, jnp.zeros_like(q)) for g in range(G)],
+            axis=1)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -836,42 +858,67 @@ def _decode_kernel(layer_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
                           count_ref[0])
     count_ref[0] = attend(last, c, True)
     l = jnp.sum(l_ref[...], axis=-1, keepdims=True)
-    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+    acc = acc_ref[...]
+    if per is not None:     # each head's own part of the whole rows
+        Dv = acc.shape[1] // G
+        acc = sum(jnp.where(own[g], acc[:, g * Dv:(g + 1) * Dv], 0.0)
+                  for g in range(G))
+    if has_sink:
+        # one more logit in the denominator, with no value row
+        sink, m = sink_ref[:, :1], m_ref[:, :1]             # [H, 1]
+        m_all = jnp.maximum(m, sink)
+        scale = jnp.exp(m - m_all)
+        l = l * scale + jnp.exp(sink - m_all)
+        acc = acc * scale
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
-def _decode_forward(q, k, v, layer, pos, sm_scale, chunk, stride,
-                    interpret):
-    """q [B, H, C]; k [L, B, N, C] and v [L, B, N, Cv], the run's whole
-    arrays, N = rows * stride; returns [B, H, Cv] at q's dtype."""
+def _decode_forward(q, k, v, sink, layer, pos, sm_scale, chunk, stride,
+                    name, interpret, per=None):
+    """q [B, H, C] (or [B, H, D] with ``per``, which the kernel widens);
+    k [L, B, N, C] and v [L, B, N, Cv], the run's whole arrays, N =
+    rows * stride; sink [H] or None; returns [B, H, Cv] (Cv // G with
+    ``per``) at q's dtype."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    B, H, C = q.shape
-    Cv = v.shape[3]
+    B, H = q.shape[:2]
+    C, Cv = k.shape[3], v.shape[3]
+    Co = Cv if per is None else Cv * q.shape[2] // C
     n = chunk * stride
 
     def row(b, layer_ref, pos_ref):
         return (b, 0, 0)
 
+    in_specs = [pl.BlockSpec((None, H, q.shape[2]), row),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [q, k, v]
+    if sink is not None:
+        # the same block at every grid step: copied in once
+        in_specs.append(pl.BlockSpec((H, _LANES),
+                                     lambda b, layer_ref, pos_ref: (0, 0)))
+        operands.append(jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None], (H, _LANES)))
     w = _scratch_lanes(n)
     return pl.pallas_call(
         functools.partial(_decode_kernel, sm_scale=sm_scale, chunk=chunk,
-                          stride=stride),
-        out_shape=jax.ShapeDtypeStruct((B, H, Cv), q.dtype),
+                          stride=stride, has_sink=sink is not None,
+                          per=per),
+        out_shape=jax.ShapeDtypeStruct((B, H, Co), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
-            in_specs=[pl.BlockSpec((None, H, C), row),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((None, H, Cv), row),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, H, Co), row),
             scratch_shapes=[pltpu.VMEM((_DECODE_DEPTH, n, C), k.dtype),
                             pltpu.VMEM((_DECODE_DEPTH, n, Cv), v.dtype),
                             pltpu.SemaphoreType.DMA((2, _DECODE_DEPTH)),
                             pltpu.SMEM((1,), jnp.int32),
                             pltpu.VMEM((H, w), jnp.float32),
                             pltpu.VMEM((H, w), jnp.float32),
-                            pltpu.VMEM((H, Cv), jnp.float32)]),
+                            pltpu.VMEM((H, Cv), jnp.float32)]
+            + ([] if per is None else [pltpu.VMEM((H, C), q.dtype)])),
         # the rows follow one another: a row starts the next one's
         # copies; three chunks of a row of 32 KB a position and their
         # scores outgrow the 16 MiB of VMEM a kernel is given by default
@@ -879,13 +926,13 @@ def _decode_forward(q, k, v, layer, pos, sm_scale, chunk, stride,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 2 ** 20),
         interpret=interpret,
-        name=DECODE_KERNEL,
+        name=name,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos.astype(jnp.int32),
-      q, k, v)
+      *operands)
 
 
 def decode_chunks(rows: int, row_bytes: int) -> int | None:
-    """Positions in a chunk of ``decode_attend`` for a cache of ``rows``
+    """Positions in a chunk of the decode kernel for a cache of ``rows``
     positions whose K and V together take ``row_bytes`` a position, or
     None where the kernel has no chunk for the shape (``rows`` no
     multiple of 128).
@@ -922,12 +969,12 @@ def decode_chunks(rows: int, row_bytes: int) -> int | None:
                 room[-1])
 
 
-def _decode_plan(q, k, v, chunk, interpret, sink=False):
+def _decode_plan(q, k, v, chunk, interpret):
     """(chunk, stride) of the kernel for these operands, or None where
     the XLA form runs (``decode_attention`` says when)."""
     rows, H = k.shape[2], q.shape[1]
     stride = 1 if k.ndim == 4 else H
-    if sink or not (interpret or _on_tpu()):
+    if not (interpret or _on_tpu()):
         return None
     if chunk is None:
         row_bytes = k.dtype.itemsize * (
@@ -947,70 +994,88 @@ def _decode_plan(q, k, v, chunk, interpret, sink=False):
     return chunk, stride
 
 
-def decode_rows_fetched(q, k, v, *, sink: bool = False,
-                        interpret: bool = False) -> int:
+def decode_rows_fetched(q, k, v, *, interpret: bool = False) -> int:
     """How many positions of a slot ``decode_attention`` copies at a
-    time for these operands (arrays or their shapes' structs; ``sink``
-    whether it is given one): the kernel's chunk, or all ``rows`` where
-    the XLA form runs. A slot at position p costs ``(p // n + 1) * n``
-    of them a layer, which is what the kernel copies for it."""
-    plan = _decode_plan(q, k, v, None, interpret, sink)
+    time for these operands (arrays or their shapes' structs; a ring's
+    too): the kernel's chunk, or all ``rows`` where the XLA form runs. A
+    slot at position p costs ``(p // n + 1) * n`` of them a layer, which
+    is what the kernel copies for it."""
+    plan = _decode_plan(q, k, v, None, interpret)
     return k.shape[2] if plan is None else plan[0]
 
 
 def decode_attention(q, k, v, layer, pos, *, sm_scale: float | None = None,
-                     sink=None, chunk: int | None = None,
+                     sink=None, ring: bool = False, chunk: int | None = None,
                      interpret: bool = False):
-    """A decode step's attention for a run of full-attention layers,
-    over the run's cache where it lies: one new token a slot, q
-    [B, H, D], against layer ``layer`` (a traced index) of k
-    [L, B, rows, H, D] and v [L, B, rows, H, Dv], or of the flat k
-    [L, B, rows, G * D] and v [L, B, rows, G * Dv] where G K/V heads
-    serve H / G query heads each; slot b attends positions
-    ``[0, pos[b]]`` (its new token is in the cache already) and nothing
-    past them; ``sink`` [H] is one more logit a head, in the
-    denominator only. Returns [B, H, Dv] at q's dtype. Softmax in
+    """A decode step's attention for a run of attention layers, over the
+    run's cache where it lies: one new token a slot, q [B, H, D],
+    against layer ``layer`` (a traced index) of k [L, B, rows, H, D] and
+    v [L, B, rows, H, Dv], or of the flat k [L, B, rows, G * D] and v
+    [L, B, rows, G * Dv] where G K/V heads serve H / G query heads each;
+    slot b attends positions ``[0, pos[b]]`` (its new token is in the
+    cache already) and nothing past them; ``sink`` [H] is one more logit
+    a head, in the denominator only. Under ``ring`` the rows are a
+    window's ring, position p in row ``p % rows``: a slot attends rows
+    ``[0, min(pos[b], rows - 1)]``, which hold the last ``rows``
+    positions in some order (softmax does not care which; K is stored
+    after its rope). Returns [B, H, Dv] at q's dtype. Softmax in
     float32, p cast to v's dtype, p@v accumulated in float32, as
     ``attention``.
 
     Two forms behind the one name, as ``flash_attention`` has. On the
-    TPU (or under ``interpret``) the kernel ``decode_attend``: k and v
-    are its operands whole, where they lie, ``layer`` and ``pos``
-    prefetched scalars, and the kernel copies a slot's K and V itself,
-    in chunks of positions **up to the chunk that holds ``pos[b]`` and
-    no further**: what lies past a slot's position, in the chunks not
-    copied or in the tail of its last one, is neither attended nor able
-    to reach the output (NaN included). Rows are taken as they lie:
-    flat ones with q widened to a whole row (``cached_attention``),
-    [H, D] ones as H rows a position of which a query head keeps its
-    own, so both products are plain matrix products of [H, C] with a
-    chunk. Chunks come from the shape (``decode_chunks``); an explicit
-    ``chunk`` wins.
+    TPU (or under ``interpret``) the kernel, named ``decode_attend``
+    (``decode_ring`` under ``ring``): k and v are its operands whole,
+    where they lie, ``layer`` and ``pos`` prefetched scalars, and the
+    kernel copies a slot's K and V itself, in chunks of positions **up
+    to the chunk that holds ``pos[b]`` and no further**: what lies past
+    a slot's position, in the chunks not copied or in the tail of its
+    last one, is neither attended nor able to reach the output (NaN
+    included). Rows are taken as they lie: flat ones with q widened to a
+    whole row (``cached_attention``; of a ring, by the kernel itself in
+    VMEM, which also keeps each head's own part of the output: a ring's
+    few rows move about as many bytes as widened queries and outputs
+    would), [H, D] ones as H rows a position of which a query head
+    keeps its own, so both products are plain matrix products of [H, C]
+    with a chunk. A ``sink`` joins the float32 softmax state once, after
+    a slot's last chunk. Chunks come from the shape (``decode_chunks``);
+    an explicit ``chunk`` wins.
 
     The XLA form, ``cached_attention`` over the layer's slice under a
-    mask: off the TPU, with a ``sink``, and on the TPU where the shape
-    has no chunk (``rows`` no multiple of 128, a row's width no multiple
-    of 128 lanes, [H, D] rows whose H is no power of two of whole
-    tiles). It reads all ``rows`` of every slot.
+    mask: off the TPU, and on the TPU where the shape has no chunk
+    (``rows`` no multiple of 128, a row's width no multiple of 128
+    lanes, [H, D] rows whose H is no power of two of whole tiles). It
+    reads all ``rows`` of every slot.
     ``decode_rows_fetched`` says which a shape gets."""
     B, H, D = q.shape
     sm_scale = sm_scale if sm_scale is not None else D ** -0.5
-    plan = _decode_plan(q, k, v, chunk, interpret, sink is not None)
+    if ring:
+        pos = jnp.minimum(pos, k.shape[2] - 1)
+    plan = _decode_plan(q, k, v, chunk, interpret)
     if plan is None:
         lk = jax.lax.dynamic_index_in_dim(k, layer, keepdims=False)
         lv = jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
         valid = jnp.arange(k.shape[2])[None, None, :] <= pos[:, None, None]
         return cached_attention(q, lk, lv, valid, sm_scale, sink)
     chunk, stride = plan
-    own = None
-    if stride == 1:
-        q, own = _widen(q, k.shape[3] // D)
-    else:   # a position's H rows follow one another: a bitcast
+    own = per = None
+    if stride > 1:  # a position's H rows follow one another: a bitcast
         k = k.reshape(k.shape[:2] + (-1, D))
         v = v.reshape(v.shape[:2] + (-1, v.shape[-1]))
+    elif ring:
+        # a ring's few rows a slot move about as many bytes as queries
+        # widened to whole rows and outputs of whole rows would, each
+        # written and read back (MiMo-V2-Flash's rings of 128 rows at 128
+        # slots: 84 MB a layer against 25 + 17 MB, each twice):
+        # the kernel widens them in VMEM and writes each head's own part
+        per = H // (k.shape[3] // D)
+    else:
+        q, own = _widen(q, k.shape[3] // D)
     # whole tiles of query rows (a padded head's output is dropped)
     padded = -H % (8 * 4 // q.dtype.itemsize)
     q = jnp.pad(q, ((0, 0), (0, padded), (0, 0)))
-    o = _decode_forward(q, k, v, layer, pos, sm_scale, chunk, stride,
-                        interpret)[:, :H]
+    if sink is not None:
+        sink = jnp.pad(sink, (0, padded))
+    o = _decode_forward(q, k, v, sink, layer, pos, sm_scale, chunk, stride,
+                        DECODE_RING if ring else DECODE_KERNEL, interpret,
+                        per)[:, :H]
     return o if own is None else _own_part(o, own)

@@ -82,7 +82,8 @@ name, to be read as deltas:
   kernel copies for the cache's shape; all ``max_len`` where its XLA
   form runs) and
   ``serve.engine.kv_rows_held`` (stepped slots x ``max_len``): their
-  ratio is how far the bounded read engages. Of a model whose layers
+  ratio is how far the bounded read engages. Both count growing caches
+  only; a window layer's ring is not in them. Of a model whose layers
   keep a summary a slot (Mamba, retention), likewise once a dispatched
   step: ``serve.engine.state_rows`` (the rows in it, each of which has
   its whole state read and written, owed an answer or not). Of a

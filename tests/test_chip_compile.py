@@ -12,10 +12,11 @@ cell's own shapes, and held to what keeps the slot cache one buffer:
 the cache aliased to the result, no temporary the size of a layer's K,
 and no operation that produces K or V but the in-place writes. A
 decode step names one Mosaic call ``decode_attend`` a run of
-full-attention layers (none for a window run), which copies each row
-of the carried cache itself, where it lies, up to the row's position;
-that kernel alone is compiled at each attending cell's decode shape,
-at the chunk ``decode_chunks`` gives it.
+full-attention layers and one ``decode_ring`` a run of window layers,
+which copies each row of the carried cache or ring itself, where it
+lies, up to the row's position; that kernel alone is compiled at each
+attending cell's decode shape and at each window cell's ring, at the
+chunk ``decode_chunks`` gives it.
 
 The second serving cell's programs (``mimo-v2-flash-ep16-d7``: layers of
 several kinds, a cache allocated by kind, a chip's share of the
@@ -51,7 +52,8 @@ the same at that cell's shapes: every leaf of the cache aliased, the
 one growing cache produced by nothing but its own layer's in-place
 write (the cross run takes the full run's arrays as they lie: no copy,
 no slice of a layer), a decode step two ``decode_attend`` calls (the
-full layer's, and one in the cross run's loop) and two ``ssm_step``, a
+full layer's, and one in the cross run's loop), one ``decode_ring``
+(the window run's) and two ``ssm_step``, a
 prefill one ``flash_fwd`` and two ``ssm_scan`` (the full layer's one
 query, the prompt's last, needs no kernel).
 
@@ -65,6 +67,7 @@ that is given this file: libtpu loads in one process at a time, so no
 other test file may do the same and nothing here runs at import.
 """
 
+import functools
 import importlib
 import math
 import re
@@ -74,7 +77,7 @@ import pytest
 KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 SCAN_KERNEL = "ssm_scan"
 STEP_KERNEL = "ssm_step"
-DECODE_KERNEL = "decode_attend"
+DECODE_KERNEL, DECODE_RING = "decode_attend", "decode_ring"
 RETENTION_KERNELS = ("retention_chunk", "retention_step")
 # B, T, H, Dh, the dtype, and whether the gradient is compiled too
 SHAPES = {
@@ -135,7 +138,7 @@ def mosaic_calls(compiled_text):
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
         compiled_text)
     return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL, STEP_KERNEL,
-                                          DECODE_KERNEL)
+                                          DECODE_KERNEL, DECODE_RING)
                             + RETENTION_KERNELS) or n for n in names)
 
 
@@ -236,6 +239,56 @@ def test_the_decode_kernel_compiles_for_v5e_under_its_own_name(
     G = cfg.kv_heads("full")
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 8 * math.prod(q.shape) * (1 if G == cfg.n_heads else G)
+
+
+# the fixture of each serving cell with window layers, and whether they
+# carry a sink
+RING_CELLS = {"kinds_cell": True, "shared_cell": False}
+
+
+@pytest.mark.parametrize("cell", sorted(RING_CELLS))
+def test_the_ring_kernel_compiles_for_v5e_under_its_own_name(
+        cell, request, one_chip, no_compile_cache, monkeypatch):
+    """``decode_ring`` alone at a window cell's decode shape, the first
+    window run's rings as the cell allocates them (with the layers' sink
+    where they have one), at the chunk ``decode_chunks`` chooses: Mosaic
+    accepts it, the rings reach the call as they lie, and the program
+    names its one custom call ``decode_ring``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import decode
+    from ray_tpu.models.transformer import layer_runs
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg, slots, slot_len, _ = request.getfixturevalue(cell)
+    cache = jax.eval_shape(
+        lambda: decode.init_slot_cache(cfg, slots, slot_len))
+    runs = layer_runs(cfg)
+    ring = next(pair for (kind, _), state in zip(
+        runs, decode._cache_runs(cache, runs))
+        for (mixer, _), pair in zip(decode.period_of(kind), state)
+        if mixer == "window")
+    assert ring[0].shape[2] == cfg.window
+
+    def array(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    k, v = (array(t.shape, cfg.dtype) for t in ring)
+    q = array((slots, cfg.n_heads, cfg.head_dim * (
+        2 if cfg.differential else 1)), cfg.dtype)
+    sink = array((cfg.n_heads,), jnp.float32) if RING_CELLS[cell] else None
+    assert attention.decode_rows_fetched(q, k, v) == 128
+    compiled = jax.jit(functools.partial(
+        attention.decode_attention, ring=True)).lower(
+            q, k, v, array((), jnp.int32), array((slots,), jnp.int32),
+            sink=sink).compile()
+    text = compiled.as_text()
+    assert "%decode_ring.1 = " in text
+    assert mosaic_calls(text) == [DECODE_RING]
+    assert not cache_producers(text, {k.shape, v.shape, k.shape[1:],
+                                      v.shape[1:]})
 
 
 # ------------------------------------- the slot cache, written in place
@@ -537,20 +590,32 @@ def test_a_cache_by_layer_kind_is_still_written_in_place(
 
     shapes = {leaf.shape for leaf in cache["k"] + cache["v"]} | {
         leaf.shape[1:] for leaf in cache["k"] + cache["v"]}
-    # a bitcast names memory; a copy-start/copy-done pair is the
-    # compiler's prefetch of one window layer's ring (50 MB) into the
-    # chip's fast memory, which is that layer's read and no second one
-    # (or, in the decode step, four slice-start/slice-done quarters of
-    # the ring, which a ``ConcatBitcast`` names as one array)
+    # a bitcast names memory; in a prefill a copy-start/copy-done pair
+    # is the compiler's prefetch of one window layer's ring (50 MB) into
+    # the chip's fast memory, which is that layer's read and no second
+    # one; a decode step reads every ring through the kernel, and has
+    # neither
     produced = [(name, op) for name, op in cache_producers(text, shapes)
-                if op not in ("bitcast", "copy-start", "copy-done",
-                              "ConcatBitcast")]
+                if op not in ("bitcast",) + (
+                    () if program == "decode"
+                    else ("copy-start", "copy-done"))]
     assert {op for _, op in produced} <= set(IN_PLACE), produced
     assert len(produced) == 8, produced     # K's and V's write, a run
-    # a kernel a run of layers in a prefill; in a decode step one for
-    # each of the two full-attention runs and none for a window run
+    # a kernel a run of layers: a prefill's flash forward; in a decode
+    # step the two full-attention runs' ``decode_attend`` and the two
+    # window runs' ``decode_ring``, each in its run's attention
     assert mosaic_calls(text) == (
-        [DECODE_KERNEL] * 2 if program == "decode" else ["flash_fwd"] * 4)
+        sorted([DECODE_KERNEL] * 2 + [DECODE_RING] * 2)
+        if program == "decode" else ["flash_fwd"] * 4)
+    if program == "decode":
+        table = decode.program_parts(text, decode.decode_parts(cfg))
+        assert sorted((run, re.sub(r"\.\d+$", "", name), part)
+                      for name, (run, part) in table.items()
+                      if name.startswith((DECODE_KERNEL, DECODE_RING))
+                      ) == [("run0", DECODE_KERNEL, "full_attention"),
+                            ("run1", DECODE_RING, "window_attention"),
+                            ("run2", DECODE_KERNEL, "full_attention"),
+                            ("run3", DECODE_RING, "window_attention")]
 
 
 @pytest.mark.parametrize("cell", [SERVING_CELL, KINDS_CELL])
@@ -617,7 +682,10 @@ def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
 # attending cells' ``decode`` since ``decode_attend`` copies each row's
 # chunks of K and V itself, from the run's arrays in ``pl.ANY`` (every
 # prefill, ``train`` and the fourth serving cell's programs as they
-# were). Left out: the Mosaic kernels' serialized bodies, which hold the
+# were); and the two window cells' ``decode`` since a window run's ring
+# is the kernel's operand as it lies (``decode_ring``, with the layers'
+# sink), where it was read whole by ``cached_attention`` (every other
+# program and the kernels' jaxprs as they were). Left out: the Mosaic kernels' serialized bodies, which hold the
 # line numbers of ops/attention.py, and the results' labels, which name
 # the cache's place in the result's tree
 TRAIN_CELL = "ouro-2.6b-d12.train-2k"
@@ -628,7 +696,7 @@ LOWERED = {
     "train": "7bc8d8cd2dc6b5c3",
 }
 LOWERED_KINDS = {
-    "decode": "d8958c81b432859c",
+    "decode": "f9f2accf32c665c1",
     "prefill-512": "47fd3173921c4b1c",
     "prefill-1024": "b6764f67406acf72",
     "prefill-2048": "159daa3d1d966ee3",
@@ -1227,7 +1295,7 @@ def test_the_fourth_cells_serving_programs_lower_to_the_text_on_record(
 
 SHARED_CELL = "phi-4-mini-flash.session-closed"
 LOWERED_SHARED = {
-    "decode": "2f58eb2a7ab9b3d6",
+    "decode": "52c320289f4c2b61",
     "prefill-1024": "5a92f917fea65eda",
     "prefill-2048": "0de56d1419d9fc90",
     "prefill-4096": "8d660b5aeb509d8d",
@@ -1266,8 +1334,9 @@ def test_a_cache_that_eight_layers_read_is_still_written_in_place(
     it; the temporaries stay far under that layer's K (a decode step's
     in the MB, a prefill's under 0.4 GB of activations); arguments and
     temporaries fit the chip; a decode step holds two ``decode_attend``
-    calls (the full layer's own and the one in the cross run's loop)
-    and one ``ssm_step`` a run with Mamba layers, a prefill one
+    calls (the full layer's own and the one in the cross run's loop),
+    one ``decode_ring`` (the window layers', in their run's loop) and
+    one ``ssm_step`` a run with Mamba layers, a prefill one
     ``flash_fwd`` (the window run's; the full layer attends from the
     prompt's last position alone) and one ``ssm_scan`` a Mamba run."""
     import jax
@@ -1357,8 +1426,8 @@ def test_a_cache_that_eight_layers_read_is_still_written_in_place(
     assert len(produced) == (2 * 2 + 1 if program == "decode"
                              else 2 * 2 + 2 * 2), produced
     assert mosaic_calls(text) == (
-        [DECODE_KERNEL] * 2 + [STEP_KERNEL] * 2 if program == "decode"
-        else ["flash_fwd"] + [SCAN_KERNEL] * 2)
+        sorted([DECODE_KERNEL] * 2 + [DECODE_RING] + [STEP_KERNEL] * 2)
+        if program == "decode" else ["flash_fwd"] + [SCAN_KERNEL] * 2)
 
 
 def test_the_fifth_cells_decode_step_hands_the_host_a_row_of_picks(
@@ -1387,6 +1456,9 @@ def test_the_fifth_cells_decode_step_hands_the_host_a_row_of_picks(
                      if name.startswith(DECODE_KERNEL))
     assert attends == [("run1", "full_attention"),
                        ("run2", "cross_attention")]
+    rings = [tuple(where) for name, where in table.items()
+             if name.startswith(DECODE_RING)]
+    assert rings == [("run0", "window_attention")]
 
 
 def test_the_fifth_cells_serving_programs_lower_to_the_text_on_record(

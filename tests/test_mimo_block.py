@@ -13,6 +13,7 @@ in tests/bench/test_family_mimo.py.
 
 import asyncio
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -286,6 +287,35 @@ def test_cached_decoding_is_the_full_forward_through_the_rings_wraps():
         hit, rows, fullest = (int(n) for n in cache["load"])
         assert 0 < hit <= 24 and hit <= rows <= 2 * 2 * 6
         assert 0 < fullest <= rows
+
+
+def test_the_rings_through_the_decode_kernel_are_the_full_forward(
+        decode_kernel_interpreted):
+    """The same through the decode kernel (interpreted): the window
+    layers' rings, with their sinks, as ``decode_ring`` and the full
+    layers' rows as ``decode_attend``; every step's logits are
+    ``forward``'s to float32 rounding, more than three wraps of the ring
+    of 8 on."""
+    cfg, params = tiny_model()
+    tokens = jax.random.randint(jax.random.key(2), (2, 40), 0, cfg.vocab)
+    full = forward(params, tokens, cfg)
+    for T0 in (5, 11):
+        cache = decode.init_slot_cache(cfg, 2, 40)
+        for row in range(2):
+            _, cache = decode.slot_prefill(
+                params, tokens[row:row + 1, :T0], cache, jnp.int32(row), cfg)
+        worst = 0.0
+        for t in range(T0, 40):
+            logits, cache = decode.slot_decode_step(
+                params, cache, tokens[:, t], jnp.ones(2, bool), cfg)
+            worst = max(worst, float(jnp.max(jnp.abs(logits - full[:, t]))))
+        assert worst < 2e-5, (T0, worst)
+    step = str(jax.make_jaxpr(functools.partial(
+        decode.slot_decode_step, cfg=cfg))(
+            params, cache, tokens[:, 0], jnp.ones(2, bool)))
+    # one call a run of layers: two full runs, two window runs
+    assert step.count("name=decode_ring") == 2
+    assert step.count("name=decode_attend") == 2
 
 
 def test_a_reused_slot_never_sees_its_predecessors_ring():

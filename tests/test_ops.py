@@ -543,6 +543,9 @@ _DECODE_CHUNKS = {
         (3200, 2 * 4 * (192 + 128)), 128),
     "jamba2-3b.rollout-closed": ((2048, 2 * 2 * 128), 512),
     "phi-4-mini-flash.session-closed": ((6144, 2 * 2 * 1280), 128),
+    "mimo-v2-flash-ep16-d7.reason-closed ring": (
+        (128, 2 * (8 * 192 + 8 * 128)), 128),
+    "phi-4-mini-flash.session-closed ring": ((512, 2 * 10 * 256), 128),
     "no multiple of 128": ((1000, 8192), None),
     "rows of 32 KB": ((2048, 32768), 128),
 }
@@ -638,9 +641,9 @@ def test_decode_rows_fetched_is_what_the_kernel_copies(cell, monkeypatch):
 def test_decode_attention_takes_the_kernel_by_platform_and_shape(
         monkeypatch):
     """Off the TPU the XLA form; on it the kernel where the shape has a
-    chunk (``decode_rows_fetched`` says which), the XLA form with a
-    sink and for rows that are no multiple of 128 or not whole lanes
-    wide; an explicit chunk wins."""
+    chunk (``decode_rows_fetched`` says which), with a sink too, named
+    ``decode_ring`` over a ring; the XLA form for rows that are no
+    multiple of 128 or not whole lanes wide; an explicit chunk wins."""
     import importlib
 
     attention_mod = importlib.import_module("ray_tpu.ops.attention")
@@ -650,12 +653,13 @@ def test_decode_attention_takes_the_kernel_by_platform_and_shape(
     def struct(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
 
-    def kernel_runs(q, k, v, sink=None):
+    def kernel_runs(q, k, v, sink=None, ring=False, name="decode_attend"):
         pos = jax.ShapeDtypeStruct((q.shape[0],), jnp.int32)
         layer = jax.ShapeDtypeStruct((), jnp.int32)
         # (a function of its own each time: a trace is kept by function)
-        return "decode_attend" in str(jax.make_jaxpr(
-            lambda *args: decode_attention(*args[:5], sink=args[5]))(
+        return name in str(jax.make_jaxpr(
+            lambda *args: decode_attention(*args[:5], sink=args[5],
+                                           ring=ring))(
                 q, k, v, layer, pos, sink))
 
     cell = struct(8, 16, 128), struct(2, 8, 1024, 16, 128), \
@@ -663,11 +667,16 @@ def test_decode_attention_takes_the_kernel_by_platform_and_shape(
     assert not kernel_runs(*cell) and fetched(*cell) == 1024
     monkeypatch.setattr(attention_mod, "_on_tpu", lambda: True)
     assert kernel_runs(*cell) and fetched(*cell) == 128
-    sink = jax.ShapeDtypeStruct((16,), jnp.float32)     # the XLA form's
-    assert not kernel_runs(*cell, sink) and fetched(*cell, sink=True) == 1024
+    sink = jax.ShapeDtypeStruct((16,), jnp.float32)
+    assert kernel_runs(*cell, sink)
     flat = struct(8, 64, 192), struct(1, 8, 3200, 768), \
         struct(1, 8, 3200, 512)
     assert kernel_runs(*flat) and fetched(*flat) == 128
+    ring = struct(8, 64, 192), struct(4, 8, 128, 1536), \
+        struct(4, 8, 128, 1024)
+    sink = jax.ShapeDtypeStruct((64,), jnp.float32)
+    assert kernel_runs(*ring, sink, ring=True, name="decode_ring")
+    assert not kernel_runs(*ring, sink, ring=True) and fetched(*ring) == 128
     for q, k, v in (
             (struct(8, 16, 128), struct(2, 8, 1000, 16, 128),
              struct(2, 8, 1000, 16, 128)),          # rows
@@ -679,18 +688,79 @@ def test_decode_attention_takes_the_kernel_by_platform_and_shape(
 
 
 def test_decode_attention_with_a_sink_is_the_xla_form():
-    """A layer with a sink logit attends through ``cached_attention``
-    on every platform (asked for the kernel too): the sink is in the
-    denominator, and the output is that form's to the bit."""
+    """Off the TPU a layer with a sink logit attends through
+    ``cached_attention``: the sink is in the denominator, and the output
+    is that form's to the bit."""
     from ray_tpu.ops.attention import cached_attention, decode_attention
 
     q, _, (ck, cv), pos, valid = _decode_case(
         "grouped-20on1-128", jnp.float32, _DECODE_POS["mixed"])
     sink = jnp.linspace(-1.0, 3.0, q.shape[1])
-    got = decode_attention(q, ck, cv, jnp.int32(1), pos, sink=sink,
-                           interpret=True)
+    got = decode_attention(q, ck, cv, jnp.int32(1), pos, sink=sink)
     want = cached_attention(q, ck[1], cv[1], valid, q.shape[-1] ** -0.5,
                             sink)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    bare = decode_attention(q, ck, cv, jnp.int32(1), pos, interpret=True)
+    bare = decode_attention(q, ck, cv, jnp.int32(1), pos)
     assert float(jnp.max(jnp.abs(bare - got))) > 1e-3
+
+
+# the two serving cells' window rings: H query heads of D on G K/V heads,
+# values Dv wide, a ring of ROWS positions, with a sink or not, in the
+# chunks ``decode_rows_fetched`` gives (None) or in more of them
+_RING_LAYOUTS = {
+    "mimo-128-64on8-192-128-sink": (64, 192, 128, 8, 128, True, None),
+    "phi-512-40on10-128": (40, 128, 128, 10, 512, False, None),
+    "a-sink-across-chunks-of-32": (64, 192, 128, 8, 128, True, 32),
+}
+# four slots' positions (a ring's row is the position modulo the rows)
+_RING_POS = {
+    "below-the-last-row": lambda rows: [0, 5, rows // 2 + 3, rows - 2],
+    "past-it": lambda rows: [rows - 1, rows, 2 * rows + 7, 25 * rows - 1],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("positions", sorted(_RING_POS))
+@pytest.mark.parametrize("layout", sorted(_RING_LAYOUTS))
+def test_the_decode_kernel_reads_a_ring_where_it_lies(
+        layout, positions, dtype, monkeypatch):
+    """``decode_ring`` (interpreted, at the chunk ``decode_chunks`` gives
+    the cell's ring) against ``cached_attention`` on the layer's ring:
+    a slot attends rows ``[0, min(pos, rows - 1)]``, with the layer's
+    sink in the denominator where it has one (once, however many chunks
+    a row takes); where the position is short of the ring's last row,
+    what lies past it (NaN and +-inf) does not reach the output."""
+    import importlib
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    H, D, Dv, G, rows, has_sink, chunk = _RING_LAYOUTS[layout]
+    pos = jnp.asarray(_RING_POS[positions](rows), jnp.int32)
+    B, layers, layer = len(pos), 2, 1
+    keys = jax.random.split(jax.random.key(12), 4)
+    q = jax.random.normal(keys[0], (B, H, D), dtype)
+    k = jax.random.normal(keys[1], (layers, B, rows, G * D), dtype)
+    v = jax.random.normal(keys[2], (layers, B, rows, G * Dv), dtype)
+    sink = (jax.random.normal(keys[3], (H,), jnp.float32) + 2.0
+            if has_sink else None)
+    last = jnp.minimum(pos, rows - 1)
+    past = (jnp.arange(rows)[None, :] > last[:, None])[None, :, :, None]
+    poison = jnp.asarray([jnp.nan, jnp.inf, -jnp.inf], dtype)[
+        jnp.arange(rows) % 3][None, None, :, None]
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    n = attention.decode_rows_fetched(q, k, v)
+    monkeypatch.undo()
+    assert n == 128
+    n = chunk or n
+    got = attention.decode_attention(
+        q, jnp.where(past, poison, k), jnp.where(past, poison, v),
+        jnp.int32(layer), pos, sink=sink, ring=True, chunk=n,
+        interpret=True)
+    valid = (jnp.arange(rows)[None, :] <= last[:, None])[:, None, :]
+    want = attention.cached_attention(
+        q, jnp.where(past, 0, k)[layer], jnp.where(past, 0, v)[layer],
+        valid, D ** -0.5, sink)
+    assert got.dtype == q.dtype and got.shape == want.shape
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=1e-5 if dtype == jnp.float32 else 2e-2)
