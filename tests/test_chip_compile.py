@@ -20,7 +20,10 @@ chunk ``decode_chunks`` gives it.
 
 The second serving cell's programs (``mimo-v2-flash-ep16-d7``: layers of
 several kinds, a cache allocated by kind, a chip's share of the
-experts) are held to the same, at that cell's shapes. Both cells'
+experts) are held to the same, at that cell's shapes; its decode step
+holds one ``expert_ffn`` call a run of expert layers, in the part
+``experts``, and nothing outside a fusion slices or copies an expert's
+matrix (its prefills keep the XLA tile loop). Both cells'
 decode steps hand the host a row of picked tokens and nothing of
 ``[slots, vocab]``; the serving programs of both cells and Ouro's train
 step lower to the text on record.
@@ -78,6 +81,7 @@ KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 SCAN_KERNEL = "ssm_scan"
 STEP_KERNEL = "ssm_step"
 DECODE_KERNEL, DECODE_RING = "decode_attend", "decode_ring"
+EXPERT_KERNEL = "expert_ffn"
 RETENTION_KERNELS = ("retention_chunk", "retention_step")
 # B, T, H, Dh, the dtype, and whether the gradient is compiled too
 SHAPES = {
@@ -138,7 +142,8 @@ def mosaic_calls(compiled_text):
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
         compiled_text)
     return sorted(kernel_of(n, KERNELS + (SCAN_KERNEL, STEP_KERNEL,
-                                          DECODE_KERNEL, DECODE_RING)
+                                          DECODE_KERNEL, DECODE_RING,
+                                          EXPERT_KERNEL)
                             + RETENTION_KERNELS) or n for n in names)
 
 
@@ -518,7 +523,7 @@ def test_the_second_serving_cells_shapes_are_the_ones_compiled_here(
 @pytest.mark.parametrize("program", ["decode", "prefill-512",
                                      "prefill-1024", "prefill-2048"])
 def test_a_cache_by_layer_kind_is_still_written_in_place(
-        program, kinds_cell, one_chip, no_compile_cache, monkeypatch):
+        program, kinds_cell, one_chip, no_compile_cache, as_on_the_tpu):
     """``slot_decode_step`` and ``slot_prefill`` of the model with
     layers of several kinds, at its cell's shapes, for the described
     v5e. Every leaf of the cache is aliased to the result (2.52 GB: the
@@ -534,8 +539,6 @@ def test_a_cache_by_layer_kind_is_still_written_in_place(
 
     from ray_tpu.models import decode, init_params
 
-    attention = importlib.import_module("ray_tpu.ops.attention")
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg, slots, slot_len, _ = kinds_cell
 
     def described(tree):
@@ -603,25 +606,39 @@ def test_a_cache_by_layer_kind_is_still_written_in_place(
     assert len(produced) == 8, produced     # K's and V's write, a run
     # a kernel a run of layers: a prefill's flash forward; in a decode
     # step the two full-attention runs' ``decode_attend`` and the two
-    # window runs' ``decode_ring``, each in its run's attention
+    # window runs' ``decode_ring``, each in its run's attention, and the
+    # three expert runs' ``expert_ffn`` (a prefill's rows take the loop)
     assert mosaic_calls(text) == (
-        sorted([DECODE_KERNEL] * 2 + [DECODE_RING] * 2)
+        sorted([DECODE_KERNEL] * 2 + [DECODE_RING] * 2 + [EXPERT_KERNEL] * 3)
         if program == "decode" else ["flash_fwd"] * 4)
     if program == "decode":
         table = decode.program_parts(text, decode.decode_parts(cfg))
         assert sorted((run, re.sub(r"\.\d+$", "", name), part)
                       for name, (run, part) in table.items()
-                      if name.startswith((DECODE_KERNEL, DECODE_RING))
+                      if name.startswith((DECODE_KERNEL, DECODE_RING,
+                                          EXPERT_KERNEL))
                       ) == [("run0", DECODE_KERNEL, "full_attention"),
                             ("run1", DECODE_RING, "window_attention"),
+                            ("run1", EXPERT_KERNEL, "experts"),
                             ("run2", DECODE_KERNEL, "full_attention"),
-                            ("run3", DECODE_RING, "window_attention")]
+                            ("run2", EXPERT_KERNEL, "experts"),
+                            ("run3", DECODE_RING, "window_attention"),
+                            ("run3", EXPERT_KERNEL, "experts")]
+        # the kernel reads each expert's matrices where they lie in the
+        # stack: nothing outside a fusion slices or copies one out
+        expert = cfg.d_model * cfg.d_expert
+        moved = [(name, shape, does)
+                 for _, name, shape, does, _ in outside_fusions(text)
+                 if math.prod(shape) == expert
+                 and does not in ("parameter", "get-tuple-element",
+                                  "bitcast")]
+        assert not moved, moved
 
 
 @pytest.mark.parametrize("cell", [SERVING_CELL, KINDS_CELL])
 def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
         cell, serving_cell, kinds_cell, one_chip, no_compile_cache,
-        monkeypatch):
+        as_on_the_tpu):
     """The served ``slot_decode_step`` of both cells, compiled for the
     described v5e: what it returns beside the cache is one int32 row
     (the picks, with the three expert counts behind them where the
@@ -632,8 +649,6 @@ def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
     the result."""
     import jax.numpy as jnp
 
-    attention = importlib.import_module("ray_tpu.ops.attention")
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg, slots, slot_len, _ = (serving_cell if cell == SERVING_CELL
                                else kinds_cell)
     compiled = compiled_decode(cell, cfg, slots, slot_len, one_chip)
@@ -685,7 +700,13 @@ def test_a_decode_step_hands_the_host_a_row_of_picks_and_no_logits(
 # were); and the two window cells' ``decode`` since a window run's ring
 # is the kernel's operand as it lies (``decode_ring``, with the layers'
 # sink), where it was read whole by ``cached_attention`` (every other
-# program and the kernels' jaxprs as they were). Left out: the Mosaic kernels' serialized bodies, which hold the
+# program and the kernels' jaxprs as they were); and the second serving
+# cell's ``decode`` since its expert layers stream the held experts'
+# matrices through one ``expert_ffn`` call a layer, where the tile loop
+# read them, and its prefills, whose rows take the loop, since both
+# forms count a held expert's rows by one comparison with each expert
+# where the loop's scatter-add counted them (every other program as it
+# was). Left out: the Mosaic kernels' serialized bodies, which hold the
 # line numbers of ops/attention.py, and the results' labels, which name
 # the cache's place in the result's tree
 TRAIN_CELL = "ouro-2.6b-d12.train-2k"
@@ -696,10 +717,10 @@ LOWERED = {
     "train": "7bc8d8cd2dc6b5c3",
 }
 LOWERED_KINDS = {
-    "decode": "f9f2accf32c665c1",
-    "prefill-512": "47fd3173921c4b1c",
-    "prefill-1024": "b6764f67406acf72",
-    "prefill-2048": "159daa3d1d966ee3",
+    "decode": "ea139dada34f37f8",
+    "prefill-512": "78119910e017573c",
+    "prefill-1024": "e7e2298fbbce35a6",
+    "prefill-2048": "41a149d7caa1008f",
 }
 # the forward kernel's as they were before layers had kinds (commit
 # 0b4d871); the gradient's since its forward names what the backward
@@ -785,9 +806,7 @@ def train_step_lowered(one_chip):
 
 
 def test_the_second_cells_serving_programs_lower_to_the_text_on_record(
-        kinds_cell, one_chip, monkeypatch):
-    attention = importlib.import_module("ray_tpu.ops.attention")
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+        kinds_cell, one_chip, as_on_the_tpu):
     assert serving_programs_lowered(kinds_cell, one_chip) == LOWERED_KINDS
 
 
@@ -890,8 +909,9 @@ def hybrid_cell():
 def as_on_the_tpu(monkeypatch):
     """This process's default backend is the CPU; the programs are for
     the described chip, so take the branches a TPU process takes."""
-    for ops in ("attention", "ssm", "retention"):
-        monkeypatch.setattr(importlib.import_module("ray_tpu.ops." + ops),
+    for module in ("ops.attention", "ops.ssm", "ops.retention",
+                   "parallel.experts"):
+        monkeypatch.setattr(importlib.import_module("ray_tpu." + module),
                             "_on_tpu", lambda: True)
 
 

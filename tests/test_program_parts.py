@@ -387,7 +387,8 @@ def test_a_cells_compiled_decode_step_names_a_run_in_every_loop_body(
         cell, one_chip, no_compile_cache, monkeypatch):
     from benchmarks import loader
 
-    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.ssm"):
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.ssm",
+                   "ray_tpu.parallel.experts"):
         monkeypatch.setattr(importlib.import_module(module), "_on_tpu",
                             lambda: True)
     bench = loader.load_benchmark()
